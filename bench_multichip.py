@@ -1,8 +1,11 @@
 """Multi-chip MPP bench: the carry-over acceptance record (MULTICHIP_rNN).
 
-Measures, on the 8-device virtual CPU mesh (the same
---xla_force_host_platform_device_count harness the driver's dryrun and
-tests/conftest.py use):
+A CPU bench by construction: it pins JAX_PLATFORMS=cpu and forces an
+8-device VIRTUAL mesh (the same --xla_force_host_platform_device_count
+harness the dryrun and tests/conftest.py use), so its counts are exact
+and its timings are host-platform numbers, never device metrics.  The
+mesh path's check on real chips is `chip_smoke.py --engine tpu-mpp`.
+Measures:
 
   1. Q3-class MPP join+agg WARM ROUNDS: per-round XLA trace/compile
      counts and wall time through the mesh-keyed compiled-fragment
@@ -47,7 +50,7 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + f" --xla_force_host_platform_device_count={N_DEVICES}"
     ).strip()
 
-import tidb_tpu  # noqa: F401,E402  (x64 + AOT cache fingerprint)
+import tidb_tpu  # noqa: F401,E402  (x64 + the persistent compile cache)
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -28,6 +28,12 @@ keep serving; the resumed worker must catch up and pass the peer-read
 probe).  Freshness-wait latency (p50/p99) is aggregated from every
 worker's ``freshness_wait_seconds`` histogram over DIAG metrics.
 
+A CPU bench: a chip belongs to ONE process and this is N workers plus
+the fleet's compile server, so until ROADMAP R2 gives the fleet a
+chip-ownership model every spawned process is pinned to
+JAX_PLATFORMS=cpu (this parent never initialises a backend); its
+timings are host-platform numbers, not device metrics.
+
 CLI: ``python bench_oltp.py --procs 3 --smoke`` is the fixed-seed CI
 preset (tier-1 via tests/test_serve.py); it emits one ``serve_oltp``
 JSON summary line and appends it to bench_history.jsonl.
@@ -485,7 +491,9 @@ def run_oltp(procs: int = 3, n_threads: int = 6, n_ops: int = 8,
                   # ride the interval flusher, frontier publish trails
                   # by <= one flush period (the strict peer-read probe
                   # flips to 'commit' per round to pin immediacy)
-                  sysvars={"tidb_wal_fsync": "interval"})
+                  sysvars={"tidb_wal_fsync": "interval"},
+                  # N workers + a compile server cannot share one chip
+                  env_extra={"JAX_PLATFORMS": "cpu"})
     t_boot = time.monotonic()
     fleet.start(timeout_s=300.0)
     emit({"metric": "oltp_fleet_up", "procs": procs, "port": fleet.port,

@@ -26,6 +26,14 @@ Output: one JSON line per metric (same convention as bench.py):
   {"metric": "serve_qps", "value": ..., "threads": N, ...}
   {"metric": "serve_sched", "sched_queue_depth": 0, ...}
 
+The fleet modes (--procs N >= 2, --hosts N) are CPU benches: a chip
+belongs to ONE process, and a fleet is N device-using workers plus a
+compile server whose warm-up executes programs on its own backend.
+Until ROADMAP R2 gives the fleet a chip-ownership model, every process
+these modes spawn is pinned to JAX_PLATFORMS=cpu and this parent never
+initialises a backend before spawning; their timings are host-platform
+numbers, not device metrics.  One worker on the chip is chip_smoke.py.
+
 Usage:
   python bench_serve.py                  # 8 threads, default mix
   python bench_serve.py --smoke          # small fixed-seed tier-1 run
@@ -863,7 +871,10 @@ def run_fleet(procs: int = 4, n_threads: int = 8, n_ops: int = 6,
     fleet = Fleet(
         procs, init="bench_serve:_fabric_seed",
         sysvars={"tidb_device_tenant_running_cap": "1"},
-        env_extra={"BENCH_FABRIC_SF": str(sf)}, slot_env=slot_env,
+        # N workers + a compile server cannot share one chip (see the
+        # module docstring): the fleet bench runs on XLA:CPU
+        env_extra={"BENCH_FABRIC_SF": str(sf), "JAX_PLATFORMS": "cpu"},
+        slot_env=slot_env,
         # workers coordinate over TCP: every segment op becomes a
         # traced hop into the parent, the topology the trace phase's
         # >=3-process stitching assertion rides on

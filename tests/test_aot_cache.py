@@ -1,56 +1,38 @@
-"""AOT compile-cache host fingerprinting (satellite, MULTICHIP_r05
-finding): XLA:CPU's persistent-cache key ignores host CPU features, so an
-artifact compiled on another machine loads with a ~3KB "could lead to
-SIGILL" warning per program and mis-tuned code. The cache directory —
-default AND explicit TIDB_TPU_JAX_CACHE=<dir> — is scoped by a
-(cpu-flags, machine-arch, jax-version) fingerprint subdirectory, making
-mismatched artifacts unreachable: they are skipped silently, never loaded
-with a warning flood."""
+"""Where the persistent compile cache lives (tidb_tpu/__init__.py): it is
+placed from OUTSIDE.  ``JAX_COMPILATION_CACHE_DIR=<dir>`` is used exactly
+as given — no subdirectory, no other variable — and only when it is unset
+does the cache default to the fixed path ``<checkout>/.jaxcache``.  The
+path is part of what a later process must find again: a directory the
+program moves by itself never hits."""
 
 import os
+import pathlib
 import subprocess
 import sys
 
-import jax
-
-import tidb_tpu
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
-class TestHostFingerprint:
-    def test_stable_and_hexish(self):
-        fp = tidb_tpu._host_fingerprint()
-        assert fp == tidb_tpu._host_fingerprint()
-        assert len(fp) == 12
-        assert all(c in "0123456789abcdef" for c in fp)
+def _cache_dir_of_fresh_process(env):
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import tidb_tpu, jax; "
+         "print(jax.config.jax_compilation_cache_dir)"],
+        env=env, capture_output=True, text=True, timeout=120, check=True)
+    return out.stdout.strip().splitlines()[-1]
 
-    def test_this_process_cache_dir_is_fingerprint_scoped(self):
-        cache_dir = jax.config.jax_compilation_cache_dir
-        if not cache_dir:
-            # operator opted out (TIDB_TPU_JAX_CACHE=off) or config
-            # failed: nothing to scope
-            assert os.environ.get("TIDB_TPU_JAX_CACHE") == "off"
-            return
-        assert os.path.basename(cache_dir) == tidb_tpu._host_fingerprint()
 
-    def test_explicit_dir_is_scoped_too(self, tmp_path):
-        """A SHARED explicit cache dir (network mount) must still key by
-        host fingerprint: artifacts a different machine wrote land in a
-        sibling subdirectory and can never be picked up here."""
-        out = subprocess.run(
-            [sys.executable, "-c",
-             "import tidb_tpu, jax; "
-             "print(jax.config.jax_compilation_cache_dir); "
-             "print(tidb_tpu._host_fingerprint())"],
-            env={**os.environ, "TIDB_TPU_JAX_CACHE": str(tmp_path),
-                 "JAX_PLATFORMS": "cpu"},
-            capture_output=True, text=True, timeout=120, check=True)
-        cache_dir, fp = out.stdout.strip().splitlines()[-2:]
-        assert cache_dir == os.path.join(str(tmp_path), fp)
-        # a foreign machine's artifacts would sit in a DIFFERENT subdir:
-        # same parent, disjoint leaf — unreachable by construction
-        foreign = os.path.join(str(tmp_path), "0" * 12)
-        assert foreign != cache_dir
-        assert os.path.dirname(foreign) == os.path.dirname(cache_dir)
+class TestCacheDirPlacement:
+    def test_env_var_is_used_exactly(self, tmp_path):
+        env = {**os.environ, "JAX_COMPILATION_CACHE_DIR": str(tmp_path),
+               "JAX_PLATFORMS": "cpu"}
+        assert _cache_dir_of_fresh_process(env) == str(tmp_path)
+
+    def test_unset_defaults_to_checkout_jaxcache(self):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env["JAX_PLATFORMS"] = "cpu"
+        assert _cache_dir_of_fresh_process(env) == str(REPO / ".jaxcache")
 
 
 _PERSIST_WORKLOAD = r"""
@@ -90,7 +72,8 @@ class TestPersistentExecutableCache:
         import json
         out = subprocess.run(
             [sys.executable, "-c", _PERSIST_WORKLOAD],
-            env={**os.environ, "TIDB_TPU_JAX_CACHE": str(cache_dir),
+            env={**os.environ,
+                 "JAX_COMPILATION_CACHE_DIR": str(cache_dir),
                  "JAX_PLATFORMS": "cpu"},
             capture_output=True, text=True, timeout=240, check=True)
         return json.loads(out.stdout.strip().splitlines()[-1])
@@ -99,6 +82,8 @@ class TestPersistentExecutableCache:
         first = self._run(tmp_path)
         assert first["rows"] == first["host"]
         assert first["sync_compiles"] >= 1  # cold: built + recorded
+        # the executables landed DIRECTLY under the directory given
+        assert any(f.endswith("-cache") for f in os.listdir(tmp_path))
         second = self._run(tmp_path)
         # the restart is WARM: the cold obtain found its signature in the
         # index (the "compile" under it is an AOT-cache deserialize)...

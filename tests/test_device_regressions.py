@@ -198,11 +198,8 @@ class TestTopkCacheGuard:
         # survive for the rest of the suite)
         monkeypatch.setattr(jax, "default_backend", lambda: "faketpu")
         monkeypatch.setattr(jax, "clear_caches", lambda: None)
-        be = getattr(getattr(jax, "extend", None), "backend", None)
-        if be is not None and hasattr(be, "clear_backends"):
-            monkeypatch.setattr(be, "clear_backends", lambda: None)
-        if hasattr(jax, "clear_backends"):
-            monkeypatch.setattr(jax, "clear_backends", lambda: None)
+        import jax.extend.backend as jax_backend
+        monkeypatch.setattr(jax_backend, "clear_backends", lambda: None)
         supervisor._reinit_backend()
         assert cleared == [True]
         assert dict(device_exec._TOPK_CACHE) == {}

@@ -42,8 +42,8 @@ class TestClassify:
         assert classify(RuntimeError("Connection refused")) == CLASS_TRANSPORT
 
     def test_filesystem_oserrors_are_not_transport(self):
-        # FileNotFoundError is a bug to surface, not tunnel weather to
-        # retry/degrade on
+        # FileNotFoundError is a bug to surface, not a transport fault
+        # to retry/degrade on
         from tidb_tpu.utils.backoff import CLASS_OTHER
         assert classify(FileNotFoundError("page.bin")) == CLASS_OTHER
         assert classify(PermissionError("denied")) == CLASS_OTHER

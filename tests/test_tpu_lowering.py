@@ -1,0 +1,125 @@
+"""The lowering the chip takes, run in tier-1.
+
+Several branches of the device executor are gated on
+``jax.default_backend()``: on XLA:CPU small packed keys aggregate through
+a scatter kernel, streamed partial states fold in numpy and join outputs
+are compacted before the aggregate; on a TPU the same queries take the
+sort + segment kernel (``ops/device._agg_impl``), the in-kernel
+``merge_partial_states`` fold and no compaction.  CPU tests therefore
+never executed what the chip runs.  Here ``default_backend`` reports
+``"tpu"`` while XLA:CPU does the work, so those arms trace, compile and
+answer — each held to exact parity with the host engine — before they
+meet the hardware (chip_smoke.py).
+"""
+
+import pathlib
+import random
+import sys
+
+import jax
+import pytest
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+
+import bench  # noqa: E402
+from tidb_tpu.executor import device_exec, device_join  # noqa: E402
+from tidb_tpu.ops import device as dev  # noqa: E402
+from tidb_tpu.testkit import TestKit  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _as_tpu(monkeypatch):
+    """Report "tpu" from default_backend, with no pipeline traced under
+    the CPU lowering left in the fragment cache (and none traced here
+    left for the tests that follow).  Every fused pipeline is its own
+    jit function held by _PIPE_CACHE, so dropping that is enough."""
+    def drop_compiled():
+        with device_exec._PIPE_LOCK:
+            device_exec._PIPE_CACHE.clear()
+    drop_compiled()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    monkeypatch.undo()
+    drop_compiled()
+
+
+@pytest.fixture(scope="module")
+def tpch():
+    tk = TestKit()
+    # 120k lineitem rows: the Q3 fragment is past the 65536-row floor at
+    # which the CPU lowering would start compacting
+    bench.gen_all(tk, 0.02)
+    return tk
+
+
+def _parity(tk, sql):
+    tk.must_exec("set tidb_result_cache = 'OFF'")
+    tk.must_exec("set tidb_executor_engine = 'tpu'")
+    got = tk.must_query(sql).rows
+    again = tk.must_query(sql).rows  # learned capacities: settled shapes
+    plan = tk.must_query("explain analyze " + sql).rows
+    tk.must_exec("set tidb_executor_engine = 'host'")
+    want = tk.must_query(sql).rows
+    assert want, "empty reference result proves nothing"
+    assert got == want and again == want
+    engines = [part for row in plan for part in row[2].split(", ")
+               if part.startswith("engine:")]
+    return engines
+
+
+def test_small_packed_key_aggregate_sorts(tpch, monkeypatch):
+    """Q1: two dict-coded keys pack into a few bits — the scatter kernel
+    on CPU, one int32 argsort + segment reduction on the chip."""
+    def no_scatter(*a, **k):
+        raise AssertionError("scatter aggregate ran under a tpu backend")
+    monkeypatch.setattr(dev, "_agg_scatter_impl", no_scatter)
+    assert _parity(tpch, bench.QUERIES["q1"]) == ["engine:tpu"]
+
+
+def test_join_aggregate_without_compaction(tpch, monkeypatch):
+    """Q3: the join fragment aggregates at the fact length, never
+    through the post-join compaction the CPU lowering learns."""
+    compact_caps = []
+    orig = device_join.compile_fragment
+
+    def spy(*a, compact_cap=None, **k):
+        compact_caps.append(compact_cap)
+        return orig(*a, compact_cap=compact_cap, **k)
+    monkeypatch.setattr(device_join, "compile_fragment", spy)
+    assert _parity(tpch, bench.QUERIES["q3"]) == ["engine:tpu"]
+    assert compact_caps and all(c is None for c in compact_caps)
+
+
+def test_streamed_aggregate_merges_in_kernel(monkeypatch):
+    """A streamed scan-aggregate with a packable key folds its partial
+    states through merge_partial_states' concat + sort kernel, not the
+    numpy fold."""
+    tk = TestKit()
+    tk.must_exec("use test")
+    tk.must_exec("create table s (cat varchar(8), d date, amount int)")
+    random.seed(11)
+    rows = [f"('c{i % 5}', '202{i % 3}-0{i % 9 + 1}-15', "
+            f"{random.randrange(1000)})" for i in range(20_000)]
+    for lo in range(0, len(rows), 2000):
+        tk.must_exec("insert into s values " + ",".join(rows[lo:lo + 2000]))
+
+    def no_host_fold(*a, **k):
+        raise AssertionError("numpy fold ran under a tpu backend")
+    monkeypatch.setattr(device_exec, "_merge_states_host", no_host_fold)
+    packs = []
+    orig = device_exec.merge_partial_states
+
+    def spy(state, parts, merge_cap, n_keys, nvals, merge_ops, key_pack):
+        packs.append(key_pack)
+        return orig(state, parts, merge_cap, n_keys, nvals, merge_ops,
+                    key_pack)
+    monkeypatch.setattr(device_exec, "merge_partial_states", spy)
+    # 7 blocks, flushed every 2: several folds onto a running state
+    monkeypatch.setattr(device_exec, "_MERGE_BUDGET_ROWS", 2 * 16)
+    tk.must_exec("set tidb_device_stream_rows = 3000")
+    engines = _parity(
+        tk, "select cat, d, count(*), sum(amount), min(amount), "
+            "max(amount) from s where amount > 10 group by cat, d "
+            "order by cat, d")
+    assert engines == ["engine:tpu-stream"]
+    assert len(packs) >= 4 and all(p is not None for p in packs)

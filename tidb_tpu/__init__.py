@@ -13,76 +13,35 @@ exact on device — the north star requires bit-exact parity (BASELINE.md).
 """
 
 import os as _os
-
-# XLA:CPU's AOT loader logs a ~3KB ERROR line per cached program because the
-# compile-time machine string carries XLA-internal tuning pseudo-features
-# (+prefer-no-scatter/+prefer-no-gather) the loader doesn't recognize; the
-# real ISA features match (same machine). Silence the C++ log stream unless
-# the operator asked for it. Must be set before the first jax backend init.
-_os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
+import sys as _sys
 
 import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
 
 # Persistent compilation cache: fused fragment programs (a TPC-H query is ONE
-# XLA program; Q18 costs ~30s to build) are compiled once per MACHINE, not
-# once per process — the reference's prepared-plan amortization idea
-# (planner/core/cache.go) applied at the XLA layer. Opt out with
-# TIDB_TPU_JAX_CACHE=off; override the location with TIDB_TPU_JAX_CACHE=<dir>.
-
-
-def _host_fingerprint() -> str:
-    """Host-machine-feature fingerprint scoping the AOT compile cache.
-
-    The XLA:CPU cache key ignores host CPU features: an AOT entry
-    compiled on a different machine (or by a different jax) loads with a
-    ~3KB "could lead to SIGILL" warning PER PROGRAM and mis-tuned code
-    (observed cross-machine in MULTICHIP_r05: mismatched feature sets on
-    every load). Keying the cache directory by (cpu flags, machine arch,
-    jax version) makes a mismatched artifact UNREACHABLE — stale entries
-    are skipped silently because another host simply writes to a
-    different subdirectory. NOTE: same-host entries can still print the
-    loader's mismatch warning — XLA bakes option pseudo-features
-    (+prefer-no-scatter/+prefer-no-gather) into the compile target and
-    the loader's naive comparison flags them against the real host flag
-    set; those entries ARE this machine's and are safe (and the warning
-    stream is silenced via TF_CPP_MIN_LOG_LEVEL above). The fingerprint
-    guards the cross-machine case only."""
-    import hashlib as _hl
-    import platform as _pl
+# XLA program) are compiled once per cache directory, not once per process.
+# The directory is placed from OUTSIDE: JAX_COMPILATION_CACHE_DIR, which jax
+# reads into `jax_compilation_cache_dir` itself, is used exactly as given;
+# only when it is unset does the cache default to <checkout>/.jaxcache — a
+# fixed path, because the path is part of what a later run must find again.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _cache_dir = _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jaxcache")
     try:
-        with open("/proc/cpuinfo") as _f:
-            _flags = next((ln for ln in _f if ln.startswith("flags")), "")
-    except OSError:
-        _flags = ""
-    return _hl.sha1(
-        (_flags + _pl.machine() + _jax.__version__).encode()
-    ).hexdigest()[:12]
-
-
-_cache_dir = _os.environ.get("TIDB_TPU_JAX_CACHE", "")
-if _cache_dir != "off":
-    # EVERY cache location — the default AND an explicit
-    # TIDB_TPU_JAX_CACHE=<dir> (typically a network share) — is scoped by
-    # the host fingerprint subdirectory: a shared dir populated by a
-    # machine with a different feature set can never serve its artifacts
-    # here (they'd load "could lead to SIGILL"-style), they are skipped
-    # silently by construction.
-    if not _cache_dir:
-        _cache_dir = _os.path.join(
-            _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
-            ".jaxcache")
-    try:
-        _cache_dir = _os.path.join(_cache_dir, _host_fingerprint())
         _os.makedirs(_cache_dir, exist_ok=True)
+    except OSError as _e:
+        # serve uncached, but say so: where the cache lives decides what a
+        # cold start costs
+        print(f"tidb_tpu: compile cache {_cache_dir!r} unavailable, "
+              f"running without a persistent cache: {_e}", file=_sys.stderr)
+    else:
         _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        # cache every fragment: the default 1s/small-entry filters would
-        # skip the many sub-second shrink-to-fit recompiles
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass  # cache is an optimization; never block startup on it
+# cache every fragment: the default 1s/small-entry filters would skip the
+# many sub-second shrink-to-fit recompiles
+_jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+_jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 __version__ = "0.1.0"
 

@@ -1,11 +1,10 @@
 """Compile service: async background compilation, persistent executable
 index, prewarmed bucket ladders, resilient remote compile.
 
-Why this exists (ROADMAP open item 5, BENCH_TPU_LIVE.json): the live-TPU
-run proved the production enemy is COMPILATION, not execution — Q1 ran
-22.7x faster than host but paid 147–379s of XLA compile per query shape,
-and one remote-compile "Connection refused" at Q5 zeroed the rest of the
-run.  PRs 1–7 made retries, hangs, HBM and admission owned resources;
+Why this exists: the July 2026 TPC-H SF1 run on a v5e showed the
+production enemy is COMPILATION, not execution — Q1 ran 22.7x faster
+than host but paid 147–379s of XLA compile per query shape, and one
+refused compile request at Q5 zeroed the rest of the run.  PRs 1–7 made retries, hangs, HBM and admission owned resources;
 this module does the same for the compile pipeline, applying the
 co-processing principle ("Revisiting Co-Processing for Hash Joins on the
 Coupled CPU-GPU Architecture", PAPERS.md) to compilation itself: while
@@ -349,9 +348,8 @@ def _scale_spec(spec, base: int, bucket: int):
 
 def _persist_dir():
     """The signature-index directory, or None when persistence is off.
-    Lives INSIDE the host-fingerprint-scoped jax compilation cache dir
-    (tidb_tpu/__init__.py), so a foreign machine's index — like its
-    executables — is unreachable by construction."""
+    Lives INSIDE the jax compilation cache dir (tidb_tpu/__init__.py):
+    the index only vouches for executables that directory holds."""
     d = os.environ.get("TIDB_TPU_COMPILE_INDEX", "")
     if d == "off":
         return None
@@ -537,8 +535,9 @@ def _obtain_impl(key, build, dict_refs, ctx, args, spec, shape, sig,
             "in the background (fragment served by the host engine)")
 
     if not br.allow(session=sid, group=group):
-        # compile path unhealthy (the Q5 dead-tunnel mode): don't even
-        # queue — degrade instantly, recover via the half-open probe
+        # compile path unhealthy (a compile endpoint refusing every
+        # request): don't even queue — degrade instantly, recover via
+        # the half-open probe
         with _LOCK:
             STATS["breaker_degrades"] += 1
         if _tsp is not None:

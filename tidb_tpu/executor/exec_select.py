@@ -1114,10 +1114,14 @@ class IndexJoinExec(HashJoinExec):
         p = self.plan
         data, nulls = p.left_keys[0].eval(left)
         vals = np.unique(data[~nulls])
-        if len(vals) > self.MAX_KEYS:
+        ds = p.right
+        if len(vals) > self.MAX_KEYS or \
+                self.ctx.domain.columnar_cache.is_bulk(ds.table_info.id):
+            # too many seeks — or a bulk-installed inner table, whose
+            # rows exist only in the columnar cache: KV seeks would find
+            # nothing and the join would silently lose every match
             return self.children[1].execute()
         from ..table import Table
-        ds = p.right
         txn = self.ctx.txn_for_read()
         tbl = Table(ds.table_info, txn)
         if p.index_join[0] == "pk":

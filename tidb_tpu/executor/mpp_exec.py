@@ -42,7 +42,7 @@ The single-chip compile-amortization stack carries across the mesh
   straight to the required capacity.
 
 Static shapes throughout: join expansions and agg states are capacity-
-bounded with overflow flags `pmax`-reduced across the mesh; the host
+bounded with overflow flags max-reduced across the mesh; the host
 retries with grown capacities — one extra compile, never wrong results.
 """
 
@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..utils.jaxcompat import shard_map
+from jax import shard_map
 
 from ..ops import device as dev
 from ..ops.device import DeviceUnsupported
@@ -431,17 +431,21 @@ def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
         f_out = dev._agg_impl(gk, gkn, gres, gresn, gvalid,
                               n_keys=n_keys, agg_ops=merge_ops,
                               capacity=capacity, pack=key_pack)
-        png_max = jax.lax.pmax(png, AXIS)
-        # exact per-join required totals (pmax: worst shard governs the
+        def mesh_max(x):
+            # not lax.pmax: the TPU compiler lowers a 64-bit all-reduce
+            # only for Sum ("UNIMPLEMENTED: Supported lowering only of
+            # Sum all reduce" on a v5e); gathering one scalar per shard
+            # and reducing locally gives every shard the same maximum
+            return jnp.max(jax.lax.all_gather(x, AXIS))
+
+        png_max = mesh_max(png)
+        # exact per-join required totals (the worst shard governs the
         # static capacity); int64 — totals exceed int32 at TPC-H scale
-        ovfs = tuple(jax.lax.pmax(o.astype(jnp.int64), AXIS)
-                     for o in overflows)
-        sovfs = tuple(jax.lax.pmax(o.astype(jnp.int32), AXIS)
-                      for o in span_ovfs)
+        ovfs = tuple(mesh_max(o.astype(jnp.int64)) for o in overflows)
+        sovfs = tuple(mesh_max(o.astype(jnp.int32)) for o in span_ovfs)
         # exact worst radix sub-bucket counts (not booleans): the retry
         # jumps straight to next_pow2(need)
-        xneeds_out = tuple(jax.lax.pmax(o.astype(jnp.int64), AXIS)
-                           for o in xneeds)
+        xneeds_out = tuple(mesh_max(o.astype(jnp.int64)) for o in xneeds)
         return f_out, png_max, ovfs, sovfs, xneeds_out
 
     n_res = len(val_plan)

@@ -7,7 +7,8 @@ traces; otherwise trace locally, ship the StableHLO to the server for
 the expensive XLA compile, and dispatch through the exported module so
 the local "compile" is an AOT-cache deserialize.
 
-Failure discipline (the BENCH_TPU_LIVE Q5 lesson): the client NEVER
+Failure discipline (one refused compile request must not cost the
+queries after it): the client NEVER
 raises out of ``serve`` — a dead socket, torn frame or server-side error
 returns ``(None, classified_error)`` so the caller builds inline and the
 compile-scoped breaker (9010) records the remote failure; a down-window

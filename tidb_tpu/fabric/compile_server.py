@@ -1,8 +1,8 @@
 """The separated compile server: one subprocess per host owns the
 expensive XLA compiles for the whole fleet.
 
-Why (BENCH_TPU_LIVE + ISSUE 14): live compiles ran 147-379s per shape,
-and N worker processes would pay them N times — compilation must be a
+Why (ISSUE 14): compiles measured on a v5e in July 2026 ran 147-379s per
+shape, and N worker processes would pay them N times — compilation must be a
 shared fleet-level resource.  The split of labor follows the
 PJIT/shard_map compile-helper shape (SNIPPETS.md [3]): the WORKER traces
 (cheap Python, needs the query's builder closures), the SERVER compiles

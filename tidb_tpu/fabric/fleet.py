@@ -9,15 +9,20 @@ only the workers' listening sockets receive connections), and optionally
 the separated compile server subprocess — and supervises worker
 lifecycles:
 
-* **ready protocol**: each worker prints one ``fabric_worker_ready``
-  JSON line (slot, pid, shared port, direct port); a per-child reader
-  thread collects it plus the drain-time summary line.  Every other
-  stdout line is forwarded to :attr:`Fleet.lines` for the bench.
+* **ready protocol**: each worker prints one ``fabric_worker_backend``
+  JSON line (the platform/device it took) before it touches data and
+  one ``fabric_worker_ready`` line (slot, pid, shared port, direct
+  port) once it serves; a per-child reader thread collects them plus
+  the drain-time summary line.  Every other stdout line is forwarded to
+  :attr:`Fleet.lines` for the bench.  A worker that exits before ready
+  fails :meth:`Fleet.start` at once, with the tail of its stderr.
 * **restart-on-crash**: a worker exiting outside a shutdown is
   reclaimed (its segment lease + running counts zeroed, counted in
   ``fabric_lease_reclaims``) and respawned after an exponential backoff
   (`BACKOFF_BASE_S * 2^k`, capped) — `RESPAWN_LIMIT` consecutive fast
-  deaths park the slot instead of hot-looping a crashing binary.
+  deaths park the slot instead of hot-looping a crashing binary; a
+  parked slot is recorded in :attr:`Fleet.errors`, which
+  :meth:`Fleet.check` raises.
   Respawns count into the segment (``fabric_respawns``) so every worker
   and the bench see the same number.
 * **drain-on-shutdown**: SIGTERM → workers stop accepting, finish
@@ -36,6 +41,7 @@ lifecycles:
 
 from __future__ import annotations
 
+import collections
 import json
 import logging
 import os
@@ -59,6 +65,8 @@ RESPAWN_LIMIT = 5
 STABLE_S = 10.0
 #: a lease older than this is a dead worker (worker.HEARTBEAT_S * 8)
 LEASE_TIMEOUT_S = 2.0
+#: stderr lines kept per slot for start/park error reports
+STDERR_TAIL_LINES = 40
 
 
 class _Slot:
@@ -72,6 +80,9 @@ class _Slot:
         self.crashes = 0          # consecutive fast deaths
         self.started_at = 0.0
         self.parked = False
+        self.backend = None       # the fabric_worker_backend line
+        self.stderr_reader = None
+        self.stderr_tail = collections.deque(maxlen=STDERR_TAIL_LINES)
 
 
 class Fleet:
@@ -116,6 +127,7 @@ class Fleet:
                          (slot_env or {}).items()}
         self.slots = [_Slot(i) for i in range(procs)]
         self.lines: list = []      # non-protocol worker stdout lines
+        self.errors: list = []     # slots parked after ready (see check)
         self.net_coord = bool(net_coord)
         self.coord_server = None
         self.coord_addr = ""
@@ -147,15 +159,40 @@ class Fleet:
             self._spawn(s)
         deadline = time.monotonic() + timeout_s
         for s in self.slots:
-            if not s.ready.wait(max(deadline - time.monotonic(), 0.1)):
+            # nothing respawns a worker before the monitor starts below,
+            # so an exit here is final: fail now, not at the deadline
+            while not s.ready.wait(0.05):
+                rc = s.proc.poll()
+                if rc is None and time.monotonic() < deadline:
+                    continue
+                why = (f"exited with code {rc} before ready"
+                       if rc is not None else
+                       f"not ready within {timeout_s}s")
+                self.shutdown(drain=False)
                 raise RuntimeError(
-                    f"fabric worker slot {s.idx} not ready within "
-                    f"{timeout_s}s (see its stderr above)")
+                    f"fabric worker slot {s.idx} {why}; its stderr "
+                    f"ended:\n{self._stderr_text(s)}")
         self._monitor = threading.Thread(target=self._monitor_loop,
                                          daemon=True,
                                          name="fabric-fleet-monitor")
         self._monitor.start()
         return self
+
+    def check(self):
+        """Raise if the monitor has parked a slot: a worker that keeps
+        dying after ready is an error, not a line in :attr:`lines`."""
+        with self._mu:
+            errors = list(self.errors)
+        if errors:
+            raise RuntimeError("; ".join(errors))
+
+    def _stderr_text(self, s: _Slot) -> str:
+        # the reader thread sees EOF right after the exit: give it a beat
+        # so the tail holds the traceback's last lines
+        t = s.stderr_reader
+        if t is not None and s.proc.poll() is not None:
+            t.join(2.0)
+        return "\n".join(s.stderr_tail) or "(nothing on stderr)"
 
     def _reserve_port(self):
         """Hold the advertised number with a bound, never-listening
@@ -244,12 +281,17 @@ class Fleet:
         s.proc = self._popen_worker(s, env)
         threading.Thread(target=self._read_worker, args=(s, s.proc),
                          daemon=True, name=f"fabric-read-{s.idx}").start()
+        s.stderr_reader = threading.Thread(
+            target=self._read_stderr, args=(s, s.proc), daemon=True,
+            name=f"fabric-stderr-{s.idx}")
+        s.stderr_reader.start()
 
     def _popen_worker(self, s: _Slot, env: dict):
         argv = [sys.executable, "-m", "tidb_tpu.fabric.worker"]
         if self.hosts <= 1:
             return subprocess.Popen(argv, env=env, stdout=subprocess.PIPE,
-                                    text=True, cwd=os.getcwd())
+                                    stderr=subprocess.PIPE, text=True,
+                                    cwd=os.getcwd())
         # multi-host: the worker joins its host's process group (the
         # first live worker of the host leads a fresh group), so
         # kill_host / the fabric-kill-host failpoint can take out the
@@ -261,8 +303,8 @@ class Fleet:
             pgid = 0  # the old leader's group is gone: lead a new one
         try:
             proc = subprocess.Popen(
-                argv, env=env, stdout=subprocess.PIPE, text=True,
-                cwd=os.getcwd(),
+                argv, env=env, stdout=subprocess.PIPE,
+                stderr=subprocess.PIPE, text=True, cwd=os.getcwd(),
                 preexec_fn=_setpgid_fn(pgid))  # noqa: PLW1509 — single-
             #   threaded child pre-exec; only setpgid runs
         except (OSError, subprocess.SubprocessError):
@@ -271,8 +313,9 @@ class Fleet:
             # the leader died between the aliveness probe and the fork:
             # this worker becomes the host's new group leader
             proc = subprocess.Popen(
-                argv, env=env, stdout=subprocess.PIPE, text=True,
-                cwd=os.getcwd(), preexec_fn=_setpgid_fn(0))  # noqa: PLW1509
+                argv, env=env, stdout=subprocess.PIPE,
+                stderr=subprocess.PIPE, text=True, cwd=os.getcwd(),
+                preexec_fn=_setpgid_fn(0))  # noqa: PLW1509
             pgid = 0
         if not pgid:
             self._host_pgid[host] = proc.pid
@@ -298,6 +341,19 @@ class Fleet:
         for idx in self.host_slots(host):
             self.kill_worker(idx, sig)
 
+    def _read_stderr(self, s: _Slot, proc):
+        """Pass the worker's stderr through to ours, keeping the tail
+        for the start/park error reports."""
+        for line in proc.stderr:
+            s.stderr_tail.append(line.rstrip("\n"))
+            try:
+                sys.stderr.write(line)
+                sys.stderr.flush()
+            except (ValueError, OSError):
+                # our own stderr is closed or broken: keep draining, a
+                # full pipe would block the worker
+                pass
+
     def _read_worker(self, s: _Slot, proc):
         for line in proc.stdout:
             line = line.rstrip("\n")
@@ -306,6 +362,9 @@ class Fleet:
             except ValueError:
                 obj = None
             if isinstance(obj, dict) and obj.get("metric") == \
+                    "fabric_worker_backend":
+                s.backend = obj
+            elif isinstance(obj, dict) and obj.get("metric") == \
                     "fabric_worker_ready":
                 s.pid = obj["pid"]
                 s.direct_port = obj["direct_port"]
@@ -348,7 +407,11 @@ class Fleet:
                 s.crashes += 1
                 if s.crashes > RESPAWN_LIMIT:
                     s.parked = True
+                    err = (f"fabric worker slot {s.idx} parked after "
+                           f"{s.crashes} fast deaths (last exit {rc}); "
+                           f"its stderr ended:\n{self._stderr_text(s)}")
                     with self._mu:
+                        self.errors.append(err)
                         self.lines.append(json.dumps({
                             "metric": "fabric_slot_parked",
                             "slot": s.idx, "exit": rc,

@@ -74,7 +74,15 @@ def main() -> int:
         print("worker: TIDB_TPU_FABRIC_COORD not set", file=sys.stderr)
         return 2
 
-    import tidb_tpu  # noqa: F401 — x64 + fingerprint-scoped AOT cache
+    import tidb_tpu  # noqa: F401 — x64 + the persistent compile cache
+    from ..ops import residency
+    # take the device FIRST and say which one it is: a worker that came
+    # up on the wrong platform (no chip, or a chip another process
+    # holds) must be visible before it generates or replays any data
+    backend = {k: v for k, v in residency.backend_report().items()
+               if k != "bytes_in_use"}
+    print(json.dumps({"metric": "fabric_worker_backend", "slot": slot,
+                      **backend}), flush=True)
     from . import conn_id_base, state
     from .coord import Coordinator
     from ..session.session import Session
@@ -246,7 +254,7 @@ def main() -> int:
 
     print(json.dumps({"metric": "fabric_worker_ready", "slot": slot,
                       "pid": os.getpid(), "port": shared.port,
-                      "direct_port": direct.port}), flush=True)
+                      "direct_port": direct.port, **backend}), flush=True)
     stop.wait()
 
     # -- drain ---------------------------------------------------------------
@@ -267,6 +275,9 @@ def main() -> int:
                            "rejected_timeout",
                            "sched_admission_waits_ms")},
         "compile": compile_service.report_gauges(),
+        # platform/device_kind/count again, with each device's allocator
+        # bytes at drain — on a mesh, whether every device held a shard
+        "backend": residency.backend_report(),
         "fabric": {k: v for k, v in state.snapshot().items()
                    if isinstance(v, (int, float))},
     }

@@ -3,8 +3,8 @@ statement's span trace.
 
 The resilience stack converts classified failures into silent host
 fallbacks (``raise DeviceUnsupported`` → the caller's host path).  That
-is the right serving behavior — and exactly what made the BENCH_TPU_LIVE
-post-mortem blind: a query that "worked" slowly left no record of WHICH
+is the right serving behavior — and exactly what leaves a post-mortem
+blind: a query that "worked" slowly left no record of WHICH
 layer (admission refusal, open breaker, pending/failed compile, OOM
 ladder, classified runtime failure) pushed it off the device.  With the
 span tracer (session/tracing.py) every degradation decision must be

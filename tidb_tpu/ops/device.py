@@ -54,7 +54,7 @@ def next_pow2(n: int) -> int:
 #
 # XLA programs are compiled per SHAPE: tracing against the exact row count
 # means any delta append, different table, or different scale factor forces a
-# full recompile — the dominant cost on a remote device (BENCH_TPU_LIVE: Q3
+# full recompile — the dominant cost measured on a v5e (July 2026, SF1: Q3
 # spent 378s compiling for 45s of compute). Device arrays are therefore
 # padded up to a small set of geometric buckets (`bucket_rows`), with the
 # live row count threaded through the jitted program as a TRACED scalar:
@@ -139,9 +139,9 @@ def to_device_col(col, bucket: int | None = None) -> DeviceCol:
 
     The device arrays are cached on the Column THROUGH the residency
     manager (ops/residency.py): a table's working set is uploaded to HBM
-    once per columnar-cache version and reused across queries (the
-    transfer — not the kernel — dominates when the device sits across a
-    fabric/tunnel), with every cached upload byte-accounted against
+    once per columnar-cache version and reused across queries (for a
+    resident working set the host→HBM transfer, not the kernel, would
+    dominate), with every cached upload byte-accounted against
     `tidb_device_mem_budget`, LRU-evictable under pressure, and stamped
     with the device epoch so a fenced/restarted backend never serves a
     stale buffer.

@@ -612,6 +612,28 @@ def resident_bytes() -> int:
         return _BYTES[0]
 
 
+def backend_report() -> dict:
+    """The backend this process holds, as jax reports it: platform,
+    ``device_kind``, device count, jax version, where compiled programs
+    are cached, and per device the allocator's ``bytes_in_use`` (None
+    where the backend keeps no memory stats, as the in-process CPU
+    client).  Initialises the backend when nothing has yet —
+    fabric/worker.py calls it first for exactly that reason, so a
+    process that came up on the wrong platform says so before it loads
+    any data."""
+    import jax
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
+        "jax": jax.__version__,
+        "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+        "bytes_in_use": [(d.memory_stats() or {}).get("bytes_in_use")
+                         for d in devs],
+    }
+
+
 def snapshot() -> dict:
     with _LOCK:
         return {

@@ -25,7 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

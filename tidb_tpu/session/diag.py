@@ -106,8 +106,11 @@ def payload(session, kind: str, arg: str = "") -> dict:
         return {"kind": kind, "local": perf.local_rows(),
                 "fleet": perf.fleet_rows(), "stats": perf.stats()}
     if kind == "status":
-        from ..fabric import state
-        return {"kind": kind, "fabric": state.snapshot()}
+        # the HTTP /status body (a fleet worker runs no HTTP listener),
+        # with the fabric gauges also under their historical key
+        from ..server.http_status import status_payload
+        body = status_payload(session.domain)
+        return {"kind": kind, "fabric": body["device_fabric"], **body}
     raise KeyError(kind)
 
 

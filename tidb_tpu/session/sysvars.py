@@ -303,10 +303,10 @@ for _v in [
     # read: a session SET must not resize the shared pool)
     SysVar("tidb_compile_workers", SCOPE_BOTH, "2", "int", 1, 64),
     # wall-clock deadline (seconds) for ONE background compile attempt,
-    # enforced by the device-runtime supervisor: a hung remote compile is
+    # enforced by the device-runtime supervisor: a hung compile is
     # abandoned + fenced like any device hang, then retried on the
-    # compileRetry curve. 0 = no deadline (the default: CPU-backend
-    # builds are in-process and cannot tunnel-hang)
+    # compileRetry curve. 0 = no deadline (the default: in-process
+    # builds have no endpoint to stall on)
     SysVar("tidb_compile_timeout", SCOPE_BOTH, "0", "float", 0),
     SysVar("tidb_broadcast_join_threshold_size", SCOPE_BOTH,
            str(100 * 1024 * 1024), "int", 0),

@@ -166,11 +166,12 @@ class _Entry:
     that mismatch would pass get()'s version check while leaking the next
     commit's rows."""
 
-    __slots__ = ("vv", "col_sig", "lock", "delta_pos")
+    __slots__ = ("vv", "col_sig", "lock", "delta_pos", "bulk")
 
-    def __init__(self, version, col_sig, view):
+    def __init__(self, version, col_sig, view, bulk=False):
         self.vv = (version, view)        # atomic ref swap on publish
         self.col_sig = col_sig
+        self.bulk = bulk                 # install_bulk: base rows not in KV
         self.lock = threading.Lock()     # serializes apply/compact
         self.delta_pos: dict[int, tuple[int, int]] = {}  # handle->(seg,pos)
 
@@ -381,12 +382,21 @@ class ColumnarCache:
         version = self.storage.mvcc.table_version(tid)
         col_sig = tuple(c.id for c in info.public_columns())
         e = _Entry(version, col_sig,
-                   _View(columns, handles, None, (), len(handles)))
+                   _View(columns, handles, None, (), len(handles)),
+                   bulk=True)
         with self._lock:
             self._entries[tid] = e
             if content_tag is not None:
                 self._bulk_tags[tid] = str(content_tag)
         return e.view
+
+    def is_bulk(self, table_id: int) -> bool:
+        """True while the table's rows are a bulk install: they live in
+        this cache only, so a KV seek (point get, index-lookup join)
+        finds nothing and readers must scan the view."""
+        with self._lock:
+            e = self._entries.get(table_id)
+        return e is not None and e.bulk
 
     def bulk_tag(self, table_id: int) -> "str | None":
         """The content_tag a bulk install declared for this table, if
