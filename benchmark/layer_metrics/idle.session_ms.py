@@ -1,0 +1,10 @@
+"""Device idle time per traced request, mean over the chips, while the
+innermost program span open on the host was
+the ``statement`` span itself, ``session.plan_query`` or ``executor.build``
+(``harness/trace_owners.py``; the five ``idle.*`` sum to the idle time)."""
+
+from benchmark.harness import trace_owners
+
+
+def read(obs):
+    return trace_owners.idle_ms(obs, "session")

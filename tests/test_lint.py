@@ -626,6 +626,109 @@ class TestTraceCoverage:
                        {"executor/rogue.py": TRACE_COV_BAD}) == []
 
 
+# -- span-chokepoints / kernel-scope-vocabulary -------------------------------
+
+SPAN_CHOKE_OK = """
+from ..session import tracing
+
+def device_agg(plan):
+    with tracing.span("upload.h2d") as usp:
+        pass
+
+def _fetch(make_tree):
+    with tracing.span("fetch.d2h"):
+        return make_tree()
+
+def _assemble_agg(plan):
+    with tracing.span("host.assemble", rows=1):
+        return 1
+"""
+
+SPAN_CHOKE_BAD = """
+from ..session import tracing
+
+def device_agg(plan):
+    with tracing.span("upload.hd2"):      # misspelt
+        pass
+
+def _fetch(make_tree):
+    return make_tree()                    # the span is gone
+
+def helper():
+    with tracing.span("host.assemble"):   # right span, wrong function
+        pass
+"""
+
+SCOPE_VOCAB = """
+KERNEL_SCOPES = (
+    "k_filter",     # comments between the names are fine
+    "k_agg_sort",
+)
+"""
+
+SCOPE_USES = """
+import jax
+
+def body(x, name):
+    with jax.named_scope("k_filter"):
+        x = x + 1
+    with jax.named_scope("k_agg_srot"):
+        x = x * 2
+    with jax.named_scope(name):
+        x = x - 1
+    return x
+
+@jax.named_scope("k_agg_sort")
+def decorated(x):
+    return x
+
+@jax.named_scope("sort")
+def collides_with_a_primitive(x):
+    return x
+"""
+
+
+class TestSpanChokepoints:
+    def test_named_functions_open_their_spans(self):
+        assert run_one("span-chokepoints",
+                       {"executor/device_exec.py": SPAN_CHOKE_OK}) == []
+
+    def test_missing_misspelt_and_misplaced_found(self):
+        out = run_one("span-chokepoints",
+                      {"executor/device_exec.py": SPAN_CHOKE_BAD})
+        assert sorted(f.ident for f in out) == [
+            "span@_assemble_agg:host.assemble", "span@_fetch:fetch.d2h",
+            "span@device_agg:upload.h2d"]
+
+    def test_tree_without_the_layer_is_skipped(self):
+        assert run_one("span-chokepoints",
+                       {"executor/rogue.py": SPAN_CHOKE_BAD}) == []
+
+
+class TestKernelScopeVocabulary:
+    def test_misspelt_computed_and_foreign_scopes_found(self):
+        out = run_one("kernel-scope-vocabulary",
+                      {"ops/device.py": SCOPE_VOCAB,
+                       "executor/device_exec.py": SCOPE_USES})
+        assert sorted(f.ident for f in out) == [
+            "scope@body:None", "scope@body:k_agg_srot",
+            "scope@collides_with_a_primitive:sort"]
+
+    def test_no_vocabulary_no_check(self):
+        assert run_one("kernel-scope-vocabulary",
+                       {"executor/device_exec.py": SCOPE_USES}) == []
+
+    def test_package_vocabulary_is_the_programs(self):
+        """The rule reads the tuple from the AST; it must see exactly
+        what the program imports."""
+        from tidb_tpu.lint.rules.trace_cov import KernelScopeVocabulary
+        from tidb_tpu.ops.device import KERNEL_SCOPES
+        text = open(sys.modules["tidb_tpu.ops.device"].__file__).read()
+        got = KernelScopeVocabulary._vocabulary(
+            make_ctx({"ops/device.py": text}))
+        assert got == set(KERNEL_SCOPES) and len(KERNEL_SCOPES) == 8
+
+
 # -- codec-rpc-trace ----------------------------------------------------------
 
 CODEC_RPC_BAD = """

@@ -123,3 +123,100 @@ def test_streamed_aggregate_merges_in_kernel(monkeypatch):
             "order by cat, d")
     assert engines == ["engine:tpu-stream"]
     assert len(packs) >= 4 and all(p is not None for p in packs)
+
+
+# -- kernel names (ISSUE 24) --------------------------------------------------
+#
+# The traced bodies mark their stages with jax.named_scope from ONE
+# vocabulary (ops/device.KERNEL_SCOPES); the benchmark sums device time by
+# those names.  What the chip's programs are lowered from must carry every
+# name that applies to its shape.
+
+_SCOPES_BY_SHAPE = {
+    # scan -> filter -> group by: the sort + segment kernel
+    "q1": ("k_filter", "k_agg_sort", "k_agg_segment", "k_agg_gather"),
+    # host-indexed joins (no in-program build) under the aggregate
+    "q3": ("k_filter", "k_join_probe", "k_agg_sort", "k_agg_segment",
+           "k_agg_gather"),
+    # order by/limit over more groups than one small fetch holds (at this
+    # scale Q3's own groups fit one, and its top-k runs on the host)
+    "topn": ("k_agg_sort", "k_topk"),
+    # two devices: radix exchange, in-program sort join, partials merged
+    "mpp_q3": ("k_filter", "k_exchange", "k_join_build", "k_join_probe",
+               "k_agg_sort", "k_agg_segment", "k_agg_gather"),
+}
+
+
+@pytest.fixture(scope="module")
+def lowered(tpch):
+    """{shape: the debug-info text every program of one execution was
+    lowered to}, under the tpu arms (module-scoped, so it patches
+    default_backend itself: it is set up before _as_tpu)."""
+    texts = {}
+    current = []
+    orig = dev.observed_jit
+
+    def spy(fn, **jit_kw):
+        run = orig(fn, **jit_kw)
+
+        def call(*a, **k):
+            current.append(run.lower(*a, **k).as_text(debug_info=True))
+            return run(*a, **k)
+        return call
+
+    def drop_compiled():
+        with device_exec._PIPE_LOCK:
+            device_exec._PIPE_CACHE.clear()
+            device_exec._TOPK_CACHE.clear()
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        mp.setattr(dev, "observed_jit", spy)
+        drop_compiled()
+        tpch.must_exec("set tidb_result_cache = 'OFF'")
+        topn = ("select l_orderkey, sum(l_quantity) as q from lineitem "
+                "group by l_orderkey order by q desc, l_orderkey limit 5")
+        for shape, engine, sql in (
+                ("q1", "tpu", bench.QUERIES["q1"]),
+                ("q3", "tpu", bench.QUERIES["q3"]),
+                ("topn", "tpu", topn),
+                ("mpp_q3", "tpu-mpp", bench.QUERIES["q3"])):
+            tpch.must_exec(f"set tidb_executor_engine = '{engine}'")
+            tpch.must_exec("set tidb_mpp_devices = 2")
+            del current[:]
+            rows = tpch.must_query(sql).rows
+            assert rows and current, shape
+            texts[shape] = "\n".join(current)
+        drop_compiled()
+    tpch.must_exec("set tidb_executor_engine = 'tpu'")
+    return texts
+
+
+@pytest.mark.parametrize("shape,scope", [
+    (shape, scope) for shape, scopes in _SCOPES_BY_SHAPE.items()
+    for scope in scopes])
+def test_lowered_programs_name_their_kernels(lowered, shape, scope):
+    import re
+    assert scope in dev.KERNEL_SCOPES
+    # a part of a name-stack path: after a "/" or, where the text nests
+    # its locations (under shard_map), at the start of one
+    assert re.search(rf'["/]{scope}/', lowered[shape])
+
+
+def test_lowered_program_names_carry_the_scopes_tag(lowered):
+    """The persistent compile cache hashes a module without its debug
+    info, so scopes alone would be served a cached, unnamed executable:
+    the module names carry KERNEL_SCOPES_TAG."""
+    import re
+    for shape, text in lowered.items():
+        names = set(re.findall(r"module @(\w+)", text))
+        assert names and all(
+            n.endswith("_" + dev.KERNEL_SCOPES_TAG) for n in names), \
+            (shape, names)
+
+
+def test_mesh_merge_counts_as_exchange(lowered):
+    """Where scopes nest the outermost names the kernel: the final merge
+    of the gathered partials is _agg_impl under k_exchange."""
+    assert "k_exchange/k_agg_sort/" in lowered["mpp_q3"]
+    assert "k_exchange/" not in lowered["q3"]

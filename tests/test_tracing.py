@@ -622,3 +622,230 @@ class TestToDictSnapshot:
         tr._finish(True)
         done = tr.to_dict()
         assert done["spans"] == len(tr.spans)
+
+
+# -- one trace on one clock (ISSUE 24) ----------------------------------------
+#
+# Spans are also jax.profiler.TraceAnnotation events, so that a profiler
+# session shows them on the clock of the device's operations; the three
+# host<->device boundaries have spans of their own; parsing and uploaded
+# bytes have counters.
+
+_BOUNDARY_SPANS = ("statement", "executor.run", "device.dispatch",
+                   "supervisor.call", "upload.h2d", "fetch.d2h",
+                   "host.assemble")
+
+_PROFILED_SQL = {
+    "agg": "select b, sum(a), count(*) from t where a >= 0 group by b",
+    "join": ("select t.b, sum(u.v) from t join u on t.a = u.k "
+             "where u.v >= 0 group by t.b"),
+}
+
+
+def _tree_spans(node, depth=0, out=None):
+    """[(name, depth)] of a DIAG TRACEJSON tree, in start order."""
+    out = [] if out is None else out
+    out.append((node["name"], depth))
+    for child in sorted(node.get("children", ()),
+                        key=lambda c: c["start_s"]):
+        _tree_spans(child, depth + 1, out)
+    return out
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """{kind: (DIAG TRACEJSON tree of the statement, [(name, start_ns,
+    end_ns)] of the host events the profiler recorded meanwhile)}: one
+    warm statement of each kind, sampled, under jax.profiler."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    tk = TestKit()
+    tk.must_exec("create table t (a int primary key, b int)")
+    tk.must_exec("create table u (k int primary key, v int)")
+    tk.must_exec("insert into t values " + ",".join(
+        f"({i}, {i % 3})" for i in range(64)))
+    tk.must_exec("insert into u values " + ",".join(
+        f"({i}, {i * 2})" for i in range(64)))
+    tk.must_exec("set tidb_executor_engine = 'tpu'")
+    tk.must_exec("set tidb_result_cache = 'OFF'")
+    out = {}
+    for kind, sql in _PROFILED_SQL.items():
+        want = tk.must_query(sql).rows       # compiles; learns capacities
+        tk.must_query(sql)
+        plan = tk.must_query("explain analyze " + sql).rows
+        assert any("engine:tpu" in r[2] for r in plan), plan
+        d = str(tmp_path_factory.mktemp(f"prof_{kind}"))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        tk.must_exec("set tidb_trace_sampling_rate = 1")
+        tracing.reset_for_tests()
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            got = tk.must_query(sql).rows
+        finally:
+            jax.profiler.stop_trace()
+            tk.must_exec("set tidb_trace_sampling_rate = 0")
+        assert got == want
+        trees = [tr for tr in json.loads(tk.must_query(
+            "DIAG TRACEJSON").rows[0][0])["rows"]
+            if tr["root"].get("tags", {}).get("stmt") == "SelectStmt"]
+        assert len(trees) == 1
+        path, = glob.glob(f"{d}/plugins/profile/*/*.xplane.pb")
+        names = {n for n, _d in _tree_spans(trees[0]["root"])}
+        events = sorted(
+            (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name in names)
+        out[kind] = (trees[0]["root"], sorted(events, key=lambda e: e[1]))
+    return out
+
+
+class TestSpansOnTheProfilersClock:
+    @pytest.mark.parametrize("span", _BOUNDARY_SPANS)
+    @pytest.mark.parametrize("kind", sorted(_PROFILED_SQL))
+    def test_span_is_a_host_event_of_the_profile(self, profiled, kind, span):
+        tree, events = profiled[kind]
+        assert span in {n for n, _d in _tree_spans(tree)}, \
+            "the statement opened no such span"
+        assert span in {n for n, _s, _e in events}
+
+    @pytest.mark.parametrize("kind", sorted(_PROFILED_SQL))
+    def test_order_and_nesting_match_the_span_tree(self, profiled, kind):
+        tree, events = profiled[kind]
+        want = _tree_spans(tree)
+        # same names in the same start order ...
+        assert [n for n, _s, _e in events] == [n for n, _d in want]
+        # ... and the same nesting: an event's depth is the number of
+        # earlier events still open when it starts
+        stack, got = [], []
+        for name, s, e in events:
+            while stack and stack[-1] <= s:
+                stack.pop()
+            assert not stack or e <= stack[-1], f"{name} outlives its parent"
+            got.append((name, len(stack)))
+            stack.append(e)
+        assert got == want
+
+    @pytest.mark.parametrize("kind", sorted(_PROFILED_SQL))
+    def test_boundary_spans_carry_their_tags(self, profiled, kind):
+        from benchmark.harness.observe import find_spans
+        tree, _events = profiled[kind]
+        up, = find_spans(tree, "upload.h2d")
+        assert up["tags"]["cols"] >= 2 and up["tags"]["bytes"] == 0  # warm
+        assert all(f["tags"]["bytes"] > 0
+                   for f in find_spans(tree, "fetch.d2h"))
+        asm, = find_spans(tree, "host.assemble")
+        assert asm["tags"]["rows"] == 3
+
+
+class TestNothingNewWhenSamplingIsOff:
+    def test_no_annotation_is_constructed(self, tk, monkeypatch):
+        import jax
+        made = []
+        real = jax.profiler.TraceAnnotation
+
+        class Counting(real):
+            def __init__(self, name, **kw):
+                made.append(name)
+                super().__init__(name, **kw)
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+        tk.must_exec("set tidb_executor_engine = 'tpu'")
+        tk.must_exec("set tidb_trace_sampling_rate = 0")
+        q = _fresh_q()
+        tk.must_query(q)
+        assert made == []
+        assert tracing.span("upload.h2d") is tracing._NOOP
+        tk.must_exec("set tidb_trace_sampling_rate = 1")
+        tk.must_query(q)
+        assert made[0] == "statement" and "fetch.d2h" in made
+
+    def test_tracing_module_never_imports_jax(self):
+        """Loaded by path: the package's own __init__ imports jax, which
+        is the package's business; a process that has not imported jax
+        gets spans without annotations and stays JAX-free."""
+        import subprocess
+        import sys
+        code = (
+            "import importlib.util, sys\n"
+            f"spec = importlib.util.spec_from_file_location('tr', "
+            f"{tracing.__file__!r})\n"
+            "tr = importlib.util.module_from_spec(spec)\n"
+            "spec.loader.exec_module(tr)\n"
+            "t = tr.begin('statement')\n"
+            "with tr.span('upload.h2d') as sp:\n"
+            "    assert sp is not None\n"
+            "tr.finish(t)\n"
+            "assert t._ann is None and len(t.spans) == 2\n"
+            "assert not [m for m in sys.modules if m.split('.')[0] "
+            "in ('jax', 'jaxlib')], 'jax was imported'\n")
+        p = subprocess.run([sys.executable, "-c", code],
+                           capture_output=True, text=True, timeout=60)
+        assert p.returncode == 0, p.stderr
+
+
+class TestBoundaryCounters:
+    def _status(self, tk):
+        from tidb_tpu.server.http_status import status_payload
+        return status_payload(tk.domain)
+
+    def test_statements_and_parse_seconds_count_client_texts(self, tk):
+        s0 = self._status(tk)["server"]
+        tk.must_query("select 1")
+        tk.must_exec("select 2; select 3")          # one text, two statements
+        tk.must_query("DIAG STATUS")                # not SQL: never parsed
+        tk.session._internal += 1                   # the engine's own SQL
+        try:
+            tk.must_query("select 4")
+        finally:
+            tk.session._internal -= 1
+        assert tk.exec_error("selec nonsense") is not None   # parse error
+        s1 = self._status(tk)["server"]
+        assert s1["statements"] - s0["statements"] == 3
+        assert s1["parse_s"] > s0["parse_s"]
+        tk.must_query("DIAG STATUS")
+        assert self._status(tk)["server"] == s1
+
+    def test_upload_bytes_count_what_reaches_the_device_once(self, tk):
+        tk.must_exec("set tidb_executor_engine = 'tpu'")
+        tk.must_exec("set tidb_result_cache = 'OFF'")
+        tk.must_exec("set tidb_trace_sampling_rate = 1")
+        r0 = self._status(tk)["device_residency"]
+        q = _fresh_q()
+        tk.must_query(q)
+        r1 = self._status(tk)["device_residency"]
+        # two int columns (a, b) and their null masks, at one row bucket
+        assert r1["uploads"] - r0["uploads"] == 2
+        from tidb_tpu.ops import device as dev
+        nb = dev.bucket_rows(16, dev.shape_buckets(tk.session))
+        grew = r1["upload_bytes"] - r0["upload_bytes"]
+        assert grew == 2 * nb * (8 + 1)      # int64 data + bool nulls
+        up = [sp for sp in tracing.last_trace().spans
+              if sp.name == "upload.h2d"]
+        assert [sp.tags for sp in up] == [{"cols": 2, "bytes": grew}]
+        tk.must_query(q)                            # resident: no copy
+        r2 = self._status(tk)["device_residency"]
+        assert r2["upload_bytes"] == r1["upload_bytes"]
+        up = [sp for sp in tracing.last_trace().spans
+              if sp.name == "upload.h2d"]
+        assert [sp.tags for sp in up] == [{"cols": 2, "bytes": 0}]
+
+
+def test_benchmark_reads_the_programs_vocabulary():
+    """benchmark/harness/trace_owners.py keeps its own copy of the kernel
+    names and of the span -> owner table (the yardstick may not import
+    what it measures); both must name what the program has."""
+    import pathlib
+    import sys
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from benchmark.harness import trace_owners
+    from tidb_tpu.ops.device import KERNEL_SCOPES
+    assert trace_owners.KERNELS == KERNEL_SCOPES
+    pkg = pathlib.Path(tracing.__file__).resolve().parents[1]
+    opened = set()
+    for f in pkg.rglob("*.py"):
+        opened |= set(re.findall(r'tracing\.(?:span|begin)\(\s*"([^"]+)"',
+                                 f.read_text()))
+    assert set(trace_owners.SPAN_OWNER) <= opened

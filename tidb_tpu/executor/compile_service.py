@@ -367,7 +367,11 @@ def _persist_hash(key) -> str:
     repr-stable by construction) + backend identity: the same signature
     on a different backend or mesh width is a different executable."""
     import jax
-    ident = repr((key, jax.default_backend(), jax.device_count()))
+    from ..ops.device import KERNEL_SCOPES_TAG
+    # the scopes tag: the programs' names carry it, so an index entry
+    # written under another tag vouches for executables jax will not find
+    ident = repr((key, jax.default_backend(), jax.device_count(),
+                  KERNEL_SCOPES_TAG))
     return hashlib.sha1(ident.encode()).hexdigest()
 
 

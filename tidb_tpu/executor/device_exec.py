@@ -481,39 +481,44 @@ def _build_pipeline(cond_fns, key_fns, n_keys, val_plan, agg_ops,
         _count_trace()
         first = next(iter(env.values()))[0]
         n = first.shape[0]
-        live = jnp.arange(n) < n_live
-        if cond_fns:
-            mask = None
-            for f in cond_fns:
-                d, nl = f(env)
-                m = (d != 0) & ~nl
-                mask = m if mask is None else (mask & m)
-            mask = jnp.broadcast_to(mask, (n,)) & live
-        else:
-            mask = live
+        with jax.named_scope("k_filter"):
+            live = jnp.arange(n) < n_live
+            if cond_fns:
+                mask = None
+                for f in cond_fns:
+                    d, nl = f(env)
+                    m = (d != 0) & ~nl
+                    mask = m if mask is None else (mask & m)
+                mask = jnp.broadcast_to(mask, (n,)) & live
+            else:
+                mask = live
+        # key expressions count as k_agg_sort, aggregate inputs as
+        # k_agg_gather: XLA fuses each into its first consumer there
         key_cols, key_nulls = [], []
-        for f in key_fns:
-            d, nl = dev.broadcast_1d(*f(env), n)
-            key_cols.append(d.astype(jnp.int64))
-            key_nulls.append(nl)
-        if not key_cols:
-            key_cols = [jnp.zeros(n, dtype=jnp.int64)]
-            key_nulls = [jnp.zeros(n, dtype=bool)]
+        with jax.named_scope("k_agg_sort"):
+            for f in key_fns:
+                d, nl = dev.broadcast_1d(*f(env), n)
+                key_cols.append(d.astype(jnp.int64))
+                key_nulls.append(nl)
+            if not key_cols:
+                key_cols = [jnp.zeros(n, dtype=jnp.int64)]
+                key_nulls = [jnp.zeros(n, dtype=bool)]
         val_cols, val_nulls = [], []
         # one eval per distinct compiled expr: AVG plans (sum, count) over
         # the SAME fn — sharing the traced (d, nl) lets the kernel's
         # identity-based null-row dedup fire and XLA CSE the value rows
         evaled = {}
-        for f, conv in val_plan:
-            hit = evaled.get(id(f))
-            if hit is None:
-                hit = dev.broadcast_1d(*f(env), n)
-                evaled[id(f)] = hit
-            d, nl = hit
-            if conv == "int":
-                d = d.astype(jnp.int64)
-            val_cols.append(d)
-            val_nulls.append(nl)
+        with jax.named_scope("k_agg_gather"):
+            for f, conv in val_plan:
+                hit = evaled.get(id(f))
+                if hit is None:
+                    hit = dev.broadcast_1d(*f(env), n)
+                    evaled[id(f)] = hit
+                d, nl = hit
+                if conv == "int":
+                    d = d.astype(jnp.int64)
+                val_cols.append(d)
+                val_nulls.append(nl)
         if raw_tail:
             return (tuple(key_cols), tuple(key_nulls), tuple(val_cols),
                     tuple(val_nulls), mask)
@@ -597,13 +602,16 @@ def device_agg(plan, chunk: Chunk, conds, ctx=None) -> Chunk:
     used = _agg_used_columns(plan, conds)
     dcols = {}
     env = {}
-    for idx in used:
-        dc = dev.to_device_col(chunk.columns[idx], bucket=nb)
-        dcols[idx] = dc
-        env[idx] = (dc.data, dc.nulls)
+    from ..session import tracing
+    with tracing.span("upload.h2d") as usp:
+        up0 = _upload_mark(usp)
+        for idx in used:
+            dc = dev.to_device_col(chunk.columns[idx], bucket=nb)
+            dcols[idx] = dc
+            env[idx] = (dc.data, dc.nulls)
+        _upload_tags(usp, up0, len(env))
     if not env:
         raise DeviceUnsupported("no columns")
-    from ..session import tracing
     tracing.event("device.upload", cols=len(env), bucket=nb, rows=n)
 
     # --- host-side planning only below (no device ops until dispatch) ---
@@ -637,6 +645,40 @@ def device_agg(plan, chunk: Chunk, conds, ctx=None) -> Chunk:
     return _assemble_agg(plan, key_meta, slots, dcols, body, f.out_rows)
 
 
+def _upload_mark(sp):
+    """What this thread had uploaded when the ``upload.h2d`` span `sp`
+    opened (None: no trace is active, nothing is read)."""
+    if sp is None:
+        return 0
+    from ..ops import residency
+    return residency.thread_upload_bytes()
+
+
+def _upload_tags(sp, mark, cols):
+    """Close an ``upload.h2d`` span's account: the columns it placed and
+    the bytes of them that were not resident already (the residency
+    ledger's publishes on this thread; a join index's own arrays are
+    off the ledger and not counted)."""
+    if sp is not None:
+        from ..ops import residency
+        sp.tags.update(cols=cols,
+                       bytes=residency.thread_upload_bytes() - mark)
+
+
+def _fetch(make_tree):
+    """``jax.device_get(make_tree())`` under a ``fetch.d2h`` span: the
+    slices `make_tree` dispatches (each a device program of its own), the
+    wait for the device to finish what they depend on, and the copy
+    back."""
+    from ..session import tracing
+    with tracing.span("fetch.d2h") as sp:
+        out = jax.device_get(make_tree())
+        if sp is not None:
+            sp.tags["bytes"] = sum(
+                a.nbytes for a in jax.tree_util.tree_leaves(out))
+    return out
+
+
 #: below this payload, one batched round trip beats two (per-transfer
 #: latency dominates small copies)
 _SMALL_FETCH_BYTES = 1 << 18
@@ -663,12 +705,11 @@ class AggFetch:
         self._body = None
         self.out_rows = None  # rows in body(); set on fetch
         if self._cap * row_bytes <= _SMALL_FETCH_BYTES:
-            out = jax.device_get(
-                (agg_out[:4], n_groups, tuple(extras)))
+            out = _fetch(lambda: (agg_out[:4], n_groups, tuple(extras)))
             self._body, ngv, self.extras = out
             self.ng = self.out_rows = int(ngv)
         else:
-            out = jax.device_get((n_groups, tuple(extras)))
+            out = _fetch(lambda: (n_groups, tuple(extras)))
             self.ng = int(out[0])
             self.extras = out[1]
 
@@ -684,7 +725,7 @@ class AggFetch:
                 idx = _topk_indices(self._keys, self._key_nulls,
                                     self._results, self._result_nulls,
                                     self.ng, self._cap, specs, kf)
-                self._body = jax.device_get(tuple(
+                self._body = _fetch(lambda: tuple(
                     tuple(a[idx] for a in t)
                     for t in (self._keys, self._key_nulls,
                               self._results, self._result_nulls)))
@@ -693,9 +734,9 @@ class AggFetch:
 
             def sl(t):
                 return tuple(a[:k] for a in t)
-            self._body = jax.device_get(
-                (sl(self._keys), sl(self._key_nulls),
-                 sl(self._results), sl(self._result_nulls)))
+            self._body = _fetch(lambda: (
+                sl(self._keys), sl(self._key_nulls),
+                sl(self._results), sl(self._result_nulls)))
             self.out_rows = self.ng
         return self._body
 
@@ -727,6 +768,7 @@ def _topk_indices(keys, key_nulls, results, result_nulls, ng, cap, specs,
     if fn is None:
         descs = [s[2] for s in specs]
 
+        @jax.named_scope("k_topk")
         def run(by_arrays, ng_):
             _count_trace()
             lex = []  # sort keys, minor → major
@@ -874,7 +916,15 @@ def _plan_agg(plan, dcols):
 
 
 def _assemble_agg(plan, key_meta, slots, dcols, out_host, ng):
-    """Device agg outputs (already copied to host) → result Chunk."""
+    """Device agg outputs (already copied to host) → result Chunk, under
+    a ``host.assemble`` span."""
+    from ..session import tracing
+    with tracing.span("host.assemble", rows=ng):
+        return _assemble_agg_chunk(plan, key_meta, slots, dcols, out_host,
+                                   ng)
+
+
+def _assemble_agg_chunk(plan, key_meta, slots, dcols, out_host, ng):
     from .agg_cache import note_agg_pass
     note_agg_pass()
     key_out, key_null_out, results, result_nulls = out_host

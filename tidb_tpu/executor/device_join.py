@@ -527,22 +527,26 @@ def _join_expand(bk, bvalid, pk, pvalid, cap):
     would interleave genuine max-valued keys with padding and overcount."""
     nb = bk.shape[0]
     npr = pk.shape[0]
-    nb_valid = jnp.sum(bvalid)
-    order = jnp.lexsort((bk, ~bvalid))  # valid-first, then key-sorted
-    in_prefix = jnp.arange(nb) < nb_valid
-    sb = jnp.where(in_prefix, bk[order], jnp.iinfo(jnp.int64).max)
-    lo = jnp.minimum(jnp.searchsorted(sb, pk, side="left"), nb_valid)
-    hi = jnp.minimum(jnp.searchsorted(sb, pk, side="right"), nb_valid)
-    cnt = jnp.where(pvalid, hi - lo, 0)
-    cum = jnp.concatenate([jnp.zeros(1, dtype=cnt.dtype), jnp.cumsum(cnt)])
-    total = cum[-1]
-    pos = jnp.arange(cap)
-    pi = jnp.clip(jnp.searchsorted(cum, pos, side="right") - 1, 0, npr - 1)
-    valid = pos < total
-    within = pos - cum[pi]
-    bpos = lo[pi] + within
-    bi = order[jnp.clip(bpos, 0, jnp.maximum(nb - 1, 0))]
-    valid = valid & bvalid[bi] & pvalid[pi]
+    with jax.named_scope("k_join_build"):
+        nb_valid = jnp.sum(bvalid)
+        order = jnp.lexsort((bk, ~bvalid))  # valid-first, then key-sorted
+        in_prefix = jnp.arange(nb) < nb_valid
+        sb = jnp.where(in_prefix, bk[order], jnp.iinfo(jnp.int64).max)
+    with jax.named_scope("k_join_probe"):
+        lo = jnp.minimum(jnp.searchsorted(sb, pk, side="left"), nb_valid)
+        hi = jnp.minimum(jnp.searchsorted(sb, pk, side="right"), nb_valid)
+        cnt = jnp.where(pvalid, hi - lo, 0)
+        cum = jnp.concatenate([jnp.zeros(1, dtype=cnt.dtype),
+                               jnp.cumsum(cnt)])
+        total = cum[-1]
+        pos = jnp.arange(cap)
+        pi = jnp.clip(jnp.searchsorted(cum, pos, side="right") - 1,
+                      0, npr - 1)
+        valid = pos < total
+        within = pos - cum[pi]
+        bpos = lo[pi] + within
+        bi = order[jnp.clip(bpos, 0, jnp.maximum(nb - 1, 0))]
+        valid = valid & bvalid[bi] & pvalid[pi]
     # report the EXACT required size, not a boolean: an overflow retry can
     # then jump straight to next_pow2(total) instead of doubling — each
     # doubling is a full XLA recompile, and starting from a tiny dimension
@@ -550,6 +554,7 @@ def _join_expand(bk, bvalid, pk, pvalid, cap):
     return pi, bi, valid, total
 
 
+@jax.named_scope("k_join_build")
 def _combined_join_keys(lkds, lknulls, lvalid, rkds, rknulls, rvalid):
     """Fold multi-column equi-join keys into ONE int64 key per side using
     DATA-DEPENDENT range packing: per key column, [min, max] over both
@@ -686,21 +691,23 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
             n = next(env[leaf.offset + i][0].shape[0]
                      for i in range(leaf.ncols)
                      if leaf.offset + i in env)
-            if leaf_cond_fns[leaf.leaf_id]:
-                mask = None
-                for f in leaf_cond_fns[leaf.leaf_id]:
-                    d, nl = f(env)
-                    m = (d != 0) & ~nl
-                    mask = m if mask is None else mask & m
-                mask = jnp.broadcast_to(mask, (n,))
-                mask = mask & (jnp.arange(n) < n_lives[leaf.leaf_id])
-            else:
-                mask = jnp.arange(n) < n_lives[leaf.leaf_id]
-            return {leaf.leaf_id: jnp.arange(n)}, mask
+            with jax.named_scope("k_filter"):
+                if leaf_cond_fns[leaf.leaf_id]:
+                    mask = None
+                    for f in leaf_cond_fns[leaf.leaf_id]:
+                        d, nl = f(env)
+                        m = (d != 0) & ~nl
+                        mask = m if mask is None else mask & m
+                    mask = jnp.broadcast_to(mask, (n,))
+                    mask = mask & (jnp.arange(n) < n_lives[leaf.leaf_id])
+                else:
+                    mask = jnp.arange(n) < n_lives[leaf.leaf_id]
+                return {leaf.leaf_id: jnp.arange(n)}, mask
 
         overflows = []
         span_ovfs = []
 
+        @jax.named_scope("k_join_probe")
         def gather_env(idxmap, valid, node, nullmaps=None):
             """env of gathered (relation-space) columns for `node`'s
             subtree, keyed by global column index. Unused columns' gathers
@@ -723,6 +730,7 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                         out[leaf.offset + i] = (d[idx], nli)
             return out
 
+        @jax.named_scope("k_join_probe")
         def eval_indexed(node, lidx_map, lvalid, lnull, ridx_map, rvalid,
                          rnull):
             """Host-indexed join paths ('uniq' gather / 'expand' CSR), for
@@ -860,63 +868,74 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                         f"{node.kind} join needs an indexed build side")
                 lenv = gather_env(lidx, lvalid, node.left, lnull)
                 renv = gather_env(ridx, rvalid, node.right, rnull)
-                lkds, lknulls = zip(*[
-                    dev.broadcast_1d(*f(lenv), lvalid.shape[0])
-                    for f in node._lk_fns])
-                rkds, rknulls = zip(*[
-                    dev.broadcast_1d(*f(renv), rvalid.shape[0])
-                    for f in node._rk_fns])
+                with jax.named_scope("k_join_probe"):
+                    lkds, lknulls = zip(*[
+                        dev.broadcast_1d(*f(lenv), lvalid.shape[0])
+                        for f in node._lk_fns])
+                with jax.named_scope("k_join_build"):
+                    rkds, rknulls = zip(*[
+                        dev.broadcast_1d(*f(renv), rvalid.shape[0])
+                        for f in node._rk_fns])
                 pk_d, pvalid, bk_d, bvalid, sovf = _combined_join_keys(
                     lkds, lknulls, lvalid, rkds, rknulls, rvalid)
                 span_ovfs.append(sovf)
                 pi, bi, valid, total = _join_expand(
                     bk_d, bvalid, pk_d, pvalid, node.cap)
                 overflows.append(total)
-                idxmap = {k: v[pi] for k, v in lidx.items()}
-                idxmap.update({k: v[bi] for k, v in ridx.items()})
-                nullmaps = {k: v[pi] for k, v in lnull.items()}
-                nullmaps.update({k: v[bi] for k, v in rnull.items()})
+                with jax.named_scope("k_join_probe"):
+                    idxmap = {k: v[pi] for k, v in lidx.items()}
+                    idxmap.update({k: v[bi] for k, v in ridx.items()})
+                    nullmaps = {k: v[pi] for k, v in lnull.items()}
+                    nullmaps.update({k: v[bi] for k, v in rnull.items()})
             if node._oc_fns and node.kind == "inner":
                 jenv = gather_env(idxmap, valid, node, nullmaps)
-                for f in node._oc_fns:
-                    d, nl = f(jenv)
-                    valid = valid & (d != 0) & ~nl
+                with jax.named_scope("k_filter"):
+                    for f in node._oc_fns:
+                        d, nl = f(jenv)
+                        valid = valid & (d != 0) & ~nl
             return idxmap, valid, nullmaps
 
         idxmap, valid, nullmaps = eval_node(root)
         fenv = gather_env(idxmap, valid, root, nullmaps)
-        mask = valid
-        for f in cond_fns:
-            d, nl = f(fenv)
-            mask = mask & (d != 0) & ~nl
-        kept_total = jnp.sum(mask)
-        if compact_cap is not None:
-            # scatter-compact kept rows to the front: the aggregate then
-            # sorts/buckets compact_cap rows instead of the fact length.
-            # kept_total > compact_cap is detected host-side (extras) and
-            # recompiled — same contract as a join-capacity overflow.
-            cidx = jnp.cumsum(mask) - 1
-            tgt = jnp.where(mask, cidx, compact_cap)
-            sel = jnp.zeros(compact_cap, dtype=jnp.int64).at[tgt].set(
-                jnp.arange(mask.shape[0]), mode="drop")
-            fenv = {k: (d[sel], nl[sel]) for k, (d, nl) in fenv.items()}
-            mask = jnp.arange(compact_cap) < kept_total
+        with jax.named_scope("k_filter"):
+            mask = valid
+            for f in cond_fns:
+                d, nl = f(fenv)
+                mask = mask & (d != 0) & ~nl
+            kept_total = jnp.sum(mask)
+            if compact_cap is not None:
+                # scatter-compact kept rows to the front: the aggregate
+                # then sorts/buckets compact_cap rows instead of the fact
+                # length.  kept_total > compact_cap is detected host-side
+                # (extras) and recompiled — same contract as a
+                # join-capacity overflow.
+                cidx = jnp.cumsum(mask) - 1
+                tgt = jnp.where(mask, cidx, compact_cap)
+                sel = jnp.zeros(compact_cap, dtype=jnp.int64).at[tgt].set(
+                    jnp.arange(mask.shape[0]), mode="drop")
+                fenv = {k: (d[sel], nl[sel])
+                        for k, (d, nl) in fenv.items()}
+                mask = jnp.arange(compact_cap) < kept_total
         n_out = mask.shape[0]
+        # as in the scan pipeline: key expressions are k_agg_sort,
+        # aggregate inputs k_agg_gather
         key_cols, key_nulls = [], []
-        for f in key_fns:
-            d, nl = dev.broadcast_1d(*f(fenv), n_out)
-            key_cols.append(d.astype(jnp.int64))
-            key_nulls.append(nl)
-        if not key_cols:
-            key_cols = [jnp.zeros(n_out, dtype=jnp.int64)]
-            key_nulls = [jnp.zeros(n_out, dtype=bool)]
+        with jax.named_scope("k_agg_sort"):
+            for f in key_fns:
+                d, nl = dev.broadcast_1d(*f(fenv), n_out)
+                key_cols.append(d.astype(jnp.int64))
+                key_nulls.append(nl)
+            if not key_cols:
+                key_cols = [jnp.zeros(n_out, dtype=jnp.int64)]
+                key_nulls = [jnp.zeros(n_out, dtype=bool)]
         val_cols, val_nulls = [], []
-        for f, conv in val_plan:
-            d, nl = dev.broadcast_1d(*f(fenv), n_out)
-            if conv == "int":
-                d = d.astype(jnp.int64)
-            val_cols.append(d)
-            val_nulls.append(nl)
+        with jax.named_scope("k_agg_gather"):
+            for f, conv in val_plan:
+                d, nl = dev.broadcast_1d(*f(fenv), n_out)
+                if conv == "int":
+                    d = d.astype(jnp.int64)
+                val_cols.append(d)
+                val_nulls.append(nl)
         if raw_tail:
             raw = (tuple(key_cols), tuple(key_nulls), tuple(val_cols),
                    tuple(val_nulls), mask)
@@ -1086,23 +1105,27 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     for leaf in leaves:
         leaf.bucket = buckets[leaf.leaf_id] = dev.bucket_rows(
             leaf.chunk.num_rows, per_double)
-    dcols = _global_dcols(leaves, buckets=buckets)
+    from ..session import tracing
+    from .device_exec import _upload_mark, _upload_tags
+    with tracing.span("upload.h2d") as usp:
+        up0 = _upload_mark(usp)
+        # env: every base column once, device-resident (bucket-padded)
+        dcols = _global_dcols(leaves, buckets=buckets)
+        env = {}
+        for leaf in leaves:
+            for i, dc in _leaf_env(leaf, buckets[leaf.leaf_id]).items():
+                env[leaf.offset + i] = (dc.data, dc.nulls)
+        jidx = tuple(jn.strategy[2].device_arrays()
+                     if jn.strategy is not None else () for jn in joins)
+        _upload_tags(usp, up0, len(env))
     agg_meta_full = _plan_agg(agg_plan, dcols)
     key_fns, key_meta, key_pack, val_plan, agg_ops, slots = agg_meta_full
     agg_meta = (key_fns, val_plan, agg_ops, slots)
-
-    # env: every base column once, device-resident (bucket-padded)
-    env = {}
-    for leaf in leaves:
-        for i, dc in _leaf_env(leaf, buckets[leaf.leaf_id]).items():
-            env[leaf.offset + i] = (dc.data, dc.nulls)
     n_lives = tuple(np.int64(leaf.chunk.num_rows) for leaf in leaves)
 
     sig = fragment_sig(leaves, joins, agg_conds, agg_plan)
     dict_refs = tuple(dc.dictionary for dc in dcols.values()
                       if dc.dictionary is not None)
-    jidx = tuple(jn.strategy[2].device_arrays() if jn.strategy is not None
-                 else () for jn in joins)
 
     n_frag = _fill_caps(root, sig)
     learned_ng = _CAP_STORE.get((sig, "agg"))

@@ -229,6 +229,7 @@ def _dest_hash(key_ds):
     return h
 
 
+@jax.named_scope("k_exchange")
 def _exchange_leaf(col_pairs, h, valid, n_shards, n_sub, cap):
     """Repartition one leaf's per-shard rows by the key hash `h`:
     two-level radix partition (high bits → destination shard, low bits →
@@ -314,17 +315,20 @@ def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
                                         (rlid, node._rk_fns, cap_r)):
                 leaf = leaves[leaf_id]
                 n = env[leaf.offset][0].shape[0]
-                valid = base_mask(leaf, n)
-                # pre-exchange filter: leaf conds cut exchange volume
-                for f in leaf_cond_fns[leaf_id]:
-                    d, nl = f(env)
-                    valid = valid & jnp.broadcast_to((d != 0) & ~nl, (n,))
+                with jax.named_scope("k_filter"):
+                    valid = base_mask(leaf, n)
+                    # pre-exchange filter: leaf conds cut exchange volume
+                    for f in leaf_cond_fns[leaf_id]:
+                        d, nl = f(env)
+                        valid = valid & jnp.broadcast_to((d != 0) & ~nl,
+                                                         (n,))
                 conds_consumed.add(leaf_id)
-                kds, knulls = zip(*[dev.broadcast_1d(*f(env), n)
-                                    for f in kfns])
-                for nl in knulls:
-                    valid = valid & ~nl    # null keys never match: drop
-                h = _dest_hash(kds)
+                with jax.named_scope("k_exchange"):
+                    kds, knulls = zip(*[dev.broadcast_1d(*f(env), n)
+                                        for f in kfns])
+                    for nl in knulls:
+                        valid = valid & ~nl  # null keys never match: drop
+                    h = _dest_hash(kds)
                 cols = [env[leaf.offset + i] for i in range(leaf.ncols)]
                 out_cols, out_valid, need = _exchange_leaf(
                     cols, h, valid, n_shards, n_sub, xcap)
@@ -333,6 +337,7 @@ def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
                 leaf_valid[leaf_id] = out_valid
                 xneeds.append(need)
 
+        @jax.named_scope("k_filter")
         def leaf_rel(leaf):
             n = env[leaf.offset][0].shape[0]
             mask = leaf_valid.get(leaf.leaf_id)
@@ -344,6 +349,7 @@ def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
                     mask = mask & jnp.broadcast_to((d != 0) & ~nl, (n,))
             return {leaf.leaf_id: jnp.arange(n)}, mask
 
+        @jax.named_scope("k_join_probe")
         def gather_env(idxmap, node):
             out = {}
             for leaf in leaves:
@@ -364,49 +370,58 @@ def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
             ridx, rvalid = eval_node(node.right)
             lenv = gather_env(lidx, node.left)
             renv = gather_env(ridx, node.right)
-            lkds, lknulls = zip(*[
-                dev.broadcast_1d(*f(lenv), lvalid.shape[0])
-                for f in node._lk_fns])
-            rkds, rknulls = zip(*[
-                dev.broadcast_1d(*f(renv), rvalid.shape[0])
-                for f in node._rk_fns])
+            with jax.named_scope("k_join_probe"):
+                lkds, lknulls = zip(*[
+                    dev.broadcast_1d(*f(lenv), lvalid.shape[0])
+                    for f in node._lk_fns])
+            with jax.named_scope("k_join_build"):
+                rkds, rknulls = zip(*[
+                    dev.broadcast_1d(*f(renv), rvalid.shape[0])
+                    for f in node._rk_fns])
             pk_d, pvalid, bk_d, bvalid, sovf = _combined_join_keys(
                 lkds, lknulls, lvalid, rkds, rknulls, rvalid)
             span_ovfs.append(sovf)
             pi, bi, valid, ovf = _join_expand(
                 bk_d, bvalid, pk_d, pvalid, node.cap)
             overflows.append(ovf)
-            idxmap = {k: v[pi] for k, v in lidx.items()}
-            idxmap.update({k: v[bi] for k, v in ridx.items()})
+            with jax.named_scope("k_join_probe"):
+                idxmap = {k: v[pi] for k, v in lidx.items()}
+                idxmap.update({k: v[bi] for k, v in ridx.items()})
             if node._oc_fns:
                 jenv = gather_env(idxmap, node)
-                for f in node._oc_fns:
-                    d, nl = f(jenv)
-                    valid = valid & (d != 0) & ~nl
+                with jax.named_scope("k_filter"):
+                    for f in node._oc_fns:
+                        d, nl = f(jenv)
+                        valid = valid & (d != 0) & ~nl
             return idxmap, valid
 
         idxmap, valid = eval_node(root)
         fenv = gather_env(idxmap, root)
-        mask = valid
-        for f in cond_fns:
-            d, nl = f(fenv)
-            mask = mask & (d != 0) & ~nl
+        with jax.named_scope("k_filter"):
+            mask = valid
+            for f in cond_fns:
+                d, nl = f(fenv)
+                mask = mask & (d != 0) & ~nl
         n_out = mask.shape[0]
+        # as in the scan pipeline: key expressions are k_agg_sort,
+        # aggregate inputs k_agg_gather
         key_cols, key_nulls = [], []
-        for f in key_fns:
-            d, nl = dev.broadcast_1d(*f(fenv), n_out)
-            key_cols.append(d.astype(jnp.int64))
-            key_nulls.append(nl)
-        if not key_cols:
-            key_cols = [jnp.zeros(n_out, dtype=jnp.int64)]
-            key_nulls = [jnp.zeros(n_out, dtype=bool)]
+        with jax.named_scope("k_agg_sort"):
+            for f in key_fns:
+                d, nl = dev.broadcast_1d(*f(fenv), n_out)
+                key_cols.append(d.astype(jnp.int64))
+                key_nulls.append(nl)
+            if not key_cols:
+                key_cols = [jnp.zeros(n_out, dtype=jnp.int64)]
+                key_nulls = [jnp.zeros(n_out, dtype=bool)]
         val_cols, val_nulls = [], []
-        for f, conv in val_plan:
-            d, nl = dev.broadcast_1d(*f(fenv), n_out)
-            if conv == "int":
-                d = d.astype(jnp.int64)
-            val_cols.append(d)
-            val_nulls.append(nl)
+        with jax.named_scope("k_agg_gather"):
+            for f, conv in val_plan:
+                d, nl = dev.broadcast_1d(*f(fenv), n_out)
+                if conv == "int":
+                    d = d.astype(jnp.int64)
+                val_cols.append(d)
+                val_nulls.append(nl)
 
         # stage 1: per-shard partial aggregation into bounded state
         pk, pkn, pres, presn, png, pvalid = dev._agg_impl(
@@ -420,17 +435,22 @@ def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
         def g(x):
             return jax.lax.all_gather(x, AXIS, tiled=True)
 
-        gk = tuple(g(k) for k in pk)
-        gkn = tuple(g(k) for k in pkn)
-        gres = tuple(g(r) for r in pres)
-        gresn = tuple(g(r) for r in presn)
-        gvalid = g(pvalid)
+        with jax.named_scope("k_exchange"):
+            gk = tuple(g(k) for k in pk)
+            gkn = tuple(g(k) for k in pkn)
+            gres = tuple(g(r) for r in pres)
+            gresn = tuple(g(r) for r in presn)
+            gvalid = g(pvalid)
 
-        # stage 2: replicated final merge — just another _agg_impl over
-        # the gathered partials with partial→merge op mapping
-        f_out = dev._agg_impl(gk, gkn, gres, gresn, gvalid,
-                              n_keys=n_keys, agg_ops=merge_ops,
-                              capacity=capacity, pack=key_pack)
+            # stage 2: replicated final merge — just another _agg_impl
+            # over the gathered partials with partial→merge op mapping;
+            # its own scopes nest under k_exchange, and the outermost
+            # names the kernel: the merge is a cost of the exchange
+            f_out = dev._agg_impl(gk, gkn, gres, gresn, gvalid,
+                                  n_keys=n_keys, agg_ops=merge_ops,
+                                  capacity=capacity, pack=key_pack)
+
+        @jax.named_scope("k_exchange")
         def mesh_max(x):
             # not lax.pmax: the TPU compiler lowers a 64-bit all-reduce
             # only for Sum ("UNIMPLEMENTED: Supported lowering only of
@@ -688,14 +708,19 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
     # mesh placement: sharded fact (and shuffled build) columns +
     # replicated dimensions, bucket-padded, residency-ledgered
     env, env_specs = {}, {}
-    for leaf in leaves:
-        sharded = leaf.leaf_id in sharded_ids
-        spec = (P(AXIS), P(AXIS)) if sharded else (P(), P())
-        for i in range(leaf.ncols):
-            c, hd, hn = host_cols[leaf.offset + i]
-            env[leaf.offset + i] = _place_col(
-                c, hd, hn, mesh, sharded, leaf_total[leaf.leaf_id])
-            env_specs[leaf.offset + i] = spec
+    from ..session import tracing
+    from .device_exec import _upload_mark, _upload_tags
+    with tracing.span("upload.h2d") as usp:
+        up0 = _upload_mark(usp)
+        for leaf in leaves:
+            sharded = leaf.leaf_id in sharded_ids
+            spec = (P(AXIS), P(AXIS)) if sharded else (P(), P())
+            for i in range(leaf.ncols):
+                c, hd, hn = host_cols[leaf.offset + i]
+                env[leaf.offset + i] = _place_col(
+                    c, hd, hn, mesh, sharded, leaf_total[leaf.leaf_id])
+                env_specs[leaf.offset + i] = spec
+        _upload_tags(usp, up0, len(env))
     # per-leaf LIVE row counts as TRACED scalars (leaf_id order): the
     # program masks padding in-body, so a row-count change inside the
     # bucket is a re-dispatch, never a retrace

@@ -27,7 +27,7 @@ from __future__ import annotations
 import ast
 
 from ..engine import Rule, register
-from ._util import call_name
+from ._util import call_name, const_str
 
 #: rel-path -> function names whose DeviceUnsupported raises are
 #: degradation decisions (the run_device / compile-service chokepoints)
@@ -44,6 +44,23 @@ AUDITED = {
 #: an exception raise counts as a degradation site when its constructor
 #: leaf-name is one of these
 DEGRADE_EXCEPTIONS = ("DeviceUnsupported",)
+
+#: rel-path -> {function: span it must open}: the host<->device
+#: boundaries the benchmark reads by span name (``upload.h2d_ms``,
+#: ``fetch.d2h_ms``, ``assemble.host_ms`` and the ``idle.*`` owners).  A
+#: refactor that moves the work out from under its span would leave the
+#: metric reading an empty span, not failing.
+SPAN_CHOKEPOINTS = {
+    "executor/device_exec.py": {"device_agg": "upload.h2d",
+                                "_fetch": "fetch.d2h",
+                                "_assemble_agg": "host.assemble"},
+    "executor/device_join.py": {"device_join_agg": "upload.h2d"},
+    "executor/mpp_exec.py": {"_run_mpp_impl": "upload.h2d"},
+}
+
+#: where the kernel vocabulary is defined, and under which name
+KERNEL_VOCAB_FILE = "ops/device.py"
+KERNEL_VOCAB_NAME = "KERNEL_SCOPES"
 
 
 def _is_trace_call(node, leaves) -> bool:
@@ -193,3 +210,87 @@ class CodecRpcTrace(Rule):
                         "...) around the handler (server) — or allowlist "
                         "with a reason"))
         return out
+
+
+@register
+class SpanChokepoints(Rule):
+    """The functions in SPAN_CHOKEPOINTS each open their span by its
+    literal name (``tracing.span("upload.h2d")``)."""
+
+    name = "span-chokepoints"
+    title = "host<->device boundaries open the span the benchmark reads"
+
+    def run(self, ctx):
+        out = []
+        for rel, wanted in SPAN_CHOKEPOINTS.items():
+            sf = ctx.file(rel)
+            if sf is None:
+                continue  # fixture tree without this layer
+            found = {}
+            for top in ast.walk(sf.tree):
+                if isinstance(top, (ast.FunctionDef, ast.AsyncFunctionDef)) \
+                        and top.name in wanted:
+                    found[top.name] = top
+            for fn, span in sorted(wanted.items()):
+                top = found.get(fn)
+                opened = top is not None and any(
+                    _is_trace_call(n, ("span",)) and n.args
+                    and const_str(n.args[0]) == span
+                    for n in ast.walk(top))
+                if not opened:
+                    out.append(self.finding(
+                        rel, top.lineno if top is not None else 1,
+                        f"span@{fn}:{span}",
+                        f"{fn} must open tracing.span({span!r}): the "
+                        "benchmark's per-layer metrics read that boundary "
+                        "by the span's name"))
+        return out
+
+
+@register
+class KernelScopeVocabulary(Rule):
+    """Every ``jax.named_scope`` in the package names a kernel of THE
+    vocabulary (``ops/device.py`` KERNEL_SCOPES), as a string literal:
+    the benchmark sums device time by these names, and a misspelt or
+    computed scope silently becomes ``unnamed``."""
+
+    name = "kernel-scope-vocabulary"
+    title = "named_scope literals come from the kernel vocabulary"
+
+    def run(self, ctx):
+        vocab = self._vocabulary(ctx)
+        if vocab is None:
+            return []  # fixture tree without the kernel layer
+        out = []
+        for sf in ctx.package_files:
+            seen: dict[str, int] = {}
+            for node in ast.walk(sf.tree):
+                if not (isinstance(node, ast.Call)
+                        and call_name(node).rsplit(".", 1)[-1]
+                        == "named_scope"):
+                    continue
+                name = const_str(node.args[0]) if node.args else None
+                if name in vocab:
+                    continue
+                ident = f"scope@{sf.qualname(node)}:{name}"
+                k = seen.get(ident, 0)
+                seen[ident] = k + 1
+                out.append(self.finding(
+                    sf.rel, node.lineno, ident + (f"#{k}" if k else ""),
+                    f"jax.named_scope({name!r}): not a string literal of "
+                    f"{KERNEL_VOCAB_NAME} ({KERNEL_VOCAB_FILE}); the "
+                    "device time under it would be reported as unnamed"))
+        return out
+
+    @staticmethod
+    def _vocabulary(ctx):
+        sf = ctx.file(KERNEL_VOCAB_FILE)
+        if sf is None:
+            return None
+        for node in sf.tree.body:
+            if isinstance(node, ast.Assign) and any(
+                    isinstance(t, ast.Name) and t.id == KERNEL_VOCAB_NAME
+                    for t in node.targets) \
+                    and isinstance(node.value, (ast.Tuple, ast.List)):
+                return {const_str(e) for e in node.value.elts}
+        return None

@@ -1239,30 +1239,37 @@ def _agg_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
         # backend-adaptive lowering: dense-bucket scatters beat the XLA CPU
         # backend's (slow, serial) sort by ~100x; on TPU scatters serialize
         # and the sort+segment path below is the right shape
-        return _agg_scatter_impl(key_cols, key_nulls, val_cols, val_nulls,
-                                 mask, n_keys, agg_ops, capacity, pack)
+        with jax.named_scope("k_agg_segment"):
+            return _agg_scatter_impl(key_cols, key_nulls, val_cols,
+                                     val_nulls, mask, n_keys, agg_ops,
+                                     capacity, pack)
     n = mask.shape[0]
-    kept = jnp.sum(mask)
-    pos = jnp.arange(n)
-    in_range = pos < kept
+    with jax.named_scope("k_agg_segment"):
+        kept = jnp.sum(mask)
+        pos = jnp.arange(n)
+        in_range = pos < kept
     if pack is not None:
-        total_bits = sum(b for b, _o in pack)
-        dt = jnp.int32 if total_bits < 31 else jnp.int64
-        packed = jnp.zeros(n, dtype=dt)
-        for i, (bits, offset) in enumerate(pack):
-            # add the offset BEFORE narrowing: a large-valued key with a
-            # small span (decimals, sparse ids) overflows int32 if cast
-            # first; the shifted value always fits `bits`
-            shifted = (key_cols[i].astype(jnp.int64)
-                       + jnp.asarray(offset + 1, dtype=jnp.int64)).astype(dt)
-            v = jnp.where(key_nulls[i], jnp.zeros((), dtype=dt), shifted)
-            packed = (packed << bits) | v
-        sort_val = jnp.where(mask, packed, jnp.iinfo(dt).max)
-        order = jnp.argsort(sort_val, stable=True)
-        sv = sort_val[order]
-        prev = jnp.concatenate([sv[:1], sv[:-1]])
-        is_new = (jnp.zeros(n, dtype=bool).at[0].set(n > 0) | (sv != prev))
-        is_new = is_new & in_range
+        with jax.named_scope("k_agg_sort"):
+            total_bits = sum(b for b, _o in pack)
+            dt = jnp.int32 if total_bits < 31 else jnp.int64
+            packed = jnp.zeros(n, dtype=dt)
+            for i, (bits, offset) in enumerate(pack):
+                # add the offset BEFORE narrowing: a large-valued key with
+                # a small span (decimals, sparse ids) overflows int32 if
+                # cast first; the shifted value always fits `bits`
+                shifted = (key_cols[i].astype(jnp.int64)
+                           + jnp.asarray(offset + 1, dtype=jnp.int64)
+                           ).astype(dt)
+                v = jnp.where(key_nulls[i], jnp.zeros((), dtype=dt), shifted)
+                packed = (packed << bits) | v
+            sort_val = jnp.where(mask, packed, jnp.iinfo(dt).max)
+            order = jnp.argsort(sort_val, stable=True)
+            sv = sort_val[order]
+        with jax.named_scope("k_agg_segment"):
+            prev = jnp.concatenate([sv[:1], sv[:-1]])
+            is_new = (jnp.zeros(n, dtype=bool).at[0].set(n > 0)
+                      | (sv != prev))
+            is_new = is_new & in_range
     else:
         # combined sort: minor-to-major stable argsort over keys, then
         # kept-first. Each key is the compound (null_flag, masked value) —
@@ -1272,32 +1279,40 @@ def _agg_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
         # arbitrary raw data (join-gather garbage), and sorting by it
         # would interleave rows of distinct groups that differ only in
         # minor keys, splintering the group blocks.
-        order = jnp.arange(n)
-        for i in range(n_keys - 1, -1, -1):
-            mk = jnp.where(key_nulls[i], 0, key_cols[i])
-            order = order[jnp.argsort(mk[order], stable=True)]
-            order = order[jnp.argsort(key_nulls[i][order], stable=True)]
-        order = order[jnp.argsort(~mask[order], stable=True)]
+        with jax.named_scope("k_agg_sort"):
+            order = jnp.arange(n)
+            for i in range(n_keys - 1, -1, -1):
+                mk = jnp.where(key_nulls[i], 0, key_cols[i])
+                order = order[jnp.argsort(mk[order], stable=True)]
+                order = order[jnp.argsort(key_nulls[i][order], stable=True)]
+            order = order[jnp.argsort(~mask[order], stable=True)]
         # boundary flags on the sorted, kept prefix
-        is_new = jnp.zeros(n, dtype=bool).at[0].set(n > 0)
+        with jax.named_scope("k_agg_segment"):
+            is_new = jnp.zeros(n, dtype=bool).at[0].set(n > 0)
         for i in range(n_keys):
-            k = key_cols[i][order]
-            kn = key_nulls[i][order]
-            prev = jnp.concatenate([k[:1], k[:-1]])
-            prev_n = jnp.concatenate([kn[:1], kn[:-1]])
-            changed = jnp.where(kn | prev_n, kn != prev_n, k != prev)
-            is_new = is_new | changed
-        is_new = is_new & in_range
-    n_groups = jnp.sum(is_new)
-    # slots past n_groups hold garbage — callers slice [:n_groups] / mask
-    # with `valid`
-    starts, ends, end_idx, span_sum = _group_spans(is_new, kept, n, capacity)
-    # representative row (first of group in sort order = first in original
-    # order for equal keys, since the sorts are stable)
-    rep_safe = jnp.clip(order[jnp.clip(starts, 0, jnp.maximum(n - 1, 0))],
-                        0, jnp.maximum(n - 1, 0))
-    key_out = tuple(k[rep_safe] for k in key_cols)
-    key_null_out = tuple(kn[rep_safe] for kn in key_nulls)
+            with jax.named_scope("k_agg_sort"):
+                k = key_cols[i][order]
+                kn = key_nulls[i][order]
+            with jax.named_scope("k_agg_segment"):
+                prev = jnp.concatenate([k[:1], k[:-1]])
+                prev_n = jnp.concatenate([kn[:1], kn[:-1]])
+                changed = jnp.where(kn | prev_n, kn != prev_n, k != prev)
+                is_new = is_new | changed
+        with jax.named_scope("k_agg_segment"):
+            is_new = is_new & in_range
+    with jax.named_scope("k_agg_segment"):
+        n_groups = jnp.sum(is_new)
+        # slots past n_groups hold garbage — callers slice [:n_groups] /
+        # mask with `valid`
+        starts, ends, end_idx, span_sum = _group_spans(is_new, kept, n,
+                                                       capacity)
+        # representative row (first of group in sort order = first in
+        # original order for equal keys, since the sorts are stable)
+        rep_safe = jnp.clip(
+            order[jnp.clip(starts, 0, jnp.maximum(n - 1, 0))],
+            0, jnp.maximum(n - 1, 0))
+        key_out = tuple(k[rep_safe] for k in key_cols)
+        key_null_out = tuple(kn[rep_safe] for kn in key_nulls)
     # -- batched count/sum_i path: ALL integer sums and their non-null
     # counters fold into ONE (m, n) matrix — one axis-1 gather by `order`,
     # one 2D cumsum, one boundary subtraction. Per-slot gathers+cumsums
@@ -1311,25 +1326,29 @@ def _agg_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
         if opn not in ("count", "sum_i"):
             continue
         nn_row = nn_rows_by_src.get(id(val_nulls[j]))
-        if nn_row is None:
-            nn_row = len(batch_rows)
-            batch_rows.append((~(val_nulls[j] | ~mask)).astype(jnp.int64))
-            nn_rows_by_src[id(val_nulls[j])] = nn_row
-        if opn == "count":
-            slot_plan[j] = ("count", nn_row)
-        else:
-            v64 = val_cols[j].astype(jnp.int64)
-            v_row = len(batch_rows)
-            batch_rows.append(jnp.where(val_nulls[j] | ~mask, 0, v64))
-            slot_plan[j] = ("sum_i", nn_row, v_row)
+        with jax.named_scope("k_agg_gather"):
+            if nn_row is None:
+                nn_row = len(batch_rows)
+                batch_rows.append(
+                    (~(val_nulls[j] | ~mask)).astype(jnp.int64))
+                nn_rows_by_src[id(val_nulls[j])] = nn_row
+            if opn == "count":
+                slot_plan[j] = ("count", nn_row)
+            else:
+                v64 = val_cols[j].astype(jnp.int64)
+                v_row = len(batch_rows)
+                batch_rows.append(jnp.where(val_nulls[j] | ~mask, 0, v64))
+                slot_plan[j] = ("sum_i", nn_row, v_row)
     spans2d = None
     if batch_rows:
-        M = jnp.stack(batch_rows, axis=0)          # (m, n)
-        SM = jnp.take(M, order, axis=1)            # one gather
-        C = jnp.concatenate(
-            [jnp.zeros((M.shape[0], 1), dtype=jnp.int64),
-             jnp.cumsum(SM, axis=1)], axis=1)
-        spans2d = C[:, ends] - C[:, jnp.minimum(starts, n)]
+        with jax.named_scope("k_agg_gather"):
+            M = jnp.stack(batch_rows, axis=0)          # (m, n)
+            SM = jnp.take(M, order, axis=1)            # one gather
+        with jax.named_scope("k_agg_segment"):
+            C = jnp.concatenate(
+                [jnp.zeros((M.shape[0], 1), dtype=jnp.int64),
+                 jnp.cumsum(SM, axis=1)], axis=1)
+            spans2d = C[:, ends] - C[:, jnp.minimum(starts, n)]
 
     results = []
     result_nulls = []
@@ -1337,8 +1356,9 @@ def _agg_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
         if opn == "first":
             # first row's own value AND null flag (mirrors host first_row;
             # a NULL in the representative row must stay NULL)
-            results.append(val_cols[j][rep_safe])
-            result_nulls.append(val_nulls[j][rep_safe])
+            with jax.named_scope("k_agg_segment"):
+                results.append(val_cols[j][rep_safe])
+                result_nulls.append(val_nulls[j][rep_safe])
             continue
         if opn == "cnt_dist":
             # COUNT(DISTINCT v): re-sort with the value as the MINOR key
@@ -1349,72 +1369,85 @@ def _agg_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
             # start a run). Reference: executor/aggfuncs count distinct
             # via a per-group hash set; sorted runs are the static-shape
             # equivalent.
-            v64 = val_cols[j].astype(jnp.int64)
-            if pack is not None:
-                order2 = jnp.lexsort((v64, val_nulls[j], sort_val))
-            else:
-                order2 = jnp.arange(n)
-                order2 = order2[jnp.argsort(v64[order2], stable=True)]
-                order2 = order2[jnp.argsort(val_nulls[j][order2],
-                                            stable=True)]
-                for i in range(n_keys - 1, -1, -1):
-                    # NULL-MASKED key: a NULL group's rows carry garbage
-                    # raw key values; sorting by them would cluster the
-                    # group internally and restart value runs at every
-                    # cluster boundary (overcounting distinct). Masking
-                    # to 0 keeps the whole null group one value-sorted
-                    # block; the null-flag stage still separates it from
-                    # a real 0-keyed group.
-                    mk = jnp.where(key_nulls[i], 0, key_cols[i])
-                    order2 = order2[jnp.argsort(mk[order2], stable=True)]
-                    order2 = order2[jnp.argsort(key_nulls[i][order2],
+            with jax.named_scope("k_agg_sort"):
+                v64 = val_cols[j].astype(jnp.int64)
+                if pack is not None:
+                    order2 = jnp.lexsort((v64, val_nulls[j], sort_val))
+                else:
+                    order2 = jnp.arange(n)
+                    order2 = order2[jnp.argsort(v64[order2], stable=True)]
+                    order2 = order2[jnp.argsort(val_nulls[j][order2],
                                                 stable=True)]
-                order2 = order2[jnp.argsort(~mask[order2], stable=True)]
-            v2 = v64[order2]
-            vn2 = val_nulls[j][order2]
-            prev_v2 = jnp.concatenate([v2[:1], v2[:-1]])
-            new_run = is_new | (v2 != prev_v2)
-            live = ~vn2 & in_range & mask[order2]
-            results.append(span_sum(jnp.where(live & new_run, 1, 0)
-                                    .astype(jnp.int64)))
-            result_nulls.append(jnp.zeros(capacity, dtype=bool))
+                    for i in range(n_keys - 1, -1, -1):
+                        # NULL-MASKED key: a NULL group's rows carry
+                        # garbage raw key values; sorting by them would
+                        # cluster the group internally and restart value
+                        # runs at every cluster boundary (overcounting
+                        # distinct). Masking to 0 keeps the whole null
+                        # group one value-sorted block; the null-flag
+                        # stage still separates it from a real 0-keyed
+                        # group.
+                        mk = jnp.where(key_nulls[i], 0, key_cols[i])
+                        order2 = order2[jnp.argsort(mk[order2],
+                                                    stable=True)]
+                        order2 = order2[jnp.argsort(key_nulls[i][order2],
+                                                    stable=True)]
+                    order2 = order2[jnp.argsort(~mask[order2], stable=True)]
+            with jax.named_scope("k_agg_gather"):
+                v2 = v64[order2]
+                vn2 = val_nulls[j][order2]
+            with jax.named_scope("k_agg_segment"):
+                prev_v2 = jnp.concatenate([v2[:1], v2[:-1]])
+                new_run = is_new | (v2 != prev_v2)
+                live = ~vn2 & in_range & mask[order2]
+                results.append(span_sum(jnp.where(live & new_run, 1, 0)
+                                        .astype(jnp.int64)))
+                result_nulls.append(jnp.zeros(capacity, dtype=bool))
             continue
         if opn == "count":
             _tag, nn_row = slot_plan[j]
-            results.append(spans2d[nn_row])
-            result_nulls.append(jnp.zeros(capacity, dtype=bool))
+            with jax.named_scope("k_agg_segment"):
+                results.append(spans2d[nn_row])
+                result_nulls.append(jnp.zeros(capacity, dtype=bool))
             continue
         if opn == "sum_i":
             _tag, nn_row, v_row = slot_plan[j]
-            results.append(spans2d[v_row])
-            result_nulls.append(spans2d[nn_row] == 0)
+            with jax.named_scope("k_agg_segment"):
+                results.append(spans2d[v_row])
+                result_nulls.append(spans2d[nn_row] == 0)
             continue
-        v = val_cols[j][order]
-        vn = val_nulls[j][order] | ~in_range
-        nonnull = span_sum((~vn).astype(jnp.int64))
-        if opn == "sum_f":
-            # segmented scan, NOT prefix-sum differences: c[end]-c[start]
-            # carries the whole column's magnitude into each group's
-            # rounding error (catastrophic cancellation); the scan resets
-            # per group so error stays group-local
-            run = _seg_running(jnp.add, is_new,
-                               jnp.where(vn, 0.0, v.astype(jnp.float64)))
-            results.append(run[end_idx])
-        elif opn == "min":
-            big = (jnp.inf if jnp.issubdtype(v.dtype, jnp.floating)
-                   else jnp.iinfo(v.dtype).max)
-            run = _seg_running(jnp.minimum, is_new, jnp.where(vn, big, v))
-            results.append(run[end_idx])
-        elif opn == "max":
-            small = (-jnp.inf if jnp.issubdtype(v.dtype, jnp.floating)
-                     else jnp.iinfo(v.dtype).min)
-            run = _seg_running(jnp.maximum, is_new, jnp.where(vn, small, v))
-            results.append(run[end_idx])
-        else:
+        if opn not in ("sum_f", "min", "max"):
             raise ValueError(opn)
-        result_nulls.append(nonnull == 0)
-    valid = jnp.arange(capacity) < n_groups
-    return key_out, key_null_out, tuple(results), tuple(result_nulls), n_groups, valid
+        with jax.named_scope("k_agg_gather"):
+            v = val_cols[j][order]
+            vn = val_nulls[j][order] | ~in_range
+        with jax.named_scope("k_agg_segment"):
+            nonnull = span_sum((~vn).astype(jnp.int64))
+            if opn == "sum_f":
+                # segmented scan, NOT prefix-sum differences:
+                # c[end]-c[start] carries the whole column's magnitude
+                # into each group's rounding error (catastrophic
+                # cancellation); the scan resets per group so error stays
+                # group-local
+                run = _seg_running(
+                    jnp.add, is_new,
+                    jnp.where(vn, 0.0, v.astype(jnp.float64)))
+            elif opn == "min":
+                big = (jnp.inf if jnp.issubdtype(v.dtype, jnp.floating)
+                       else jnp.iinfo(v.dtype).max)
+                run = _seg_running(jnp.minimum, is_new,
+                                   jnp.where(vn, big, v))
+            else:
+                small = (-jnp.inf if jnp.issubdtype(v.dtype, jnp.floating)
+                         else jnp.iinfo(v.dtype).min)
+                run = _seg_running(jnp.maximum, is_new,
+                                   jnp.where(vn, small, v))
+            results.append(run[end_idx])
+            result_nulls.append(nonnull == 0)
+    with jax.named_scope("k_agg_segment"):
+        valid = jnp.arange(capacity) < n_groups
+    return (key_out, key_null_out, tuple(results), tuple(result_nulls),
+            n_groups, valid)
 
 
 #: compile observability hooks, installed by executor.device_exec at
@@ -1431,13 +1464,56 @@ def _note_trace():
         _trace_cb()
 
 
+#: THE vocabulary of device kernel names: every traced body marks its
+#: stages with ``jax.named_scope("<one of these>")``, the scope lands in
+#: each HLO instruction's ``op_name`` and so in the profiler's trace, and
+#: the benchmark sums device time by it (``kernel.*`` metrics; what falls
+#: under no scope is reported as ``kernel.unnamed_share``).  The ``k_``
+#: prefix keeps them apart from the JAX primitive names that share those
+#: paths (``sort``, ``gather``).  Where scopes nest, the OUTERMOST names
+#: the kernel (the mesh's final merge is ``k_exchange`` although it runs
+#: ``_agg_impl``).  The ``kernel-scope-vocabulary`` lint holds every
+#: ``named_scope`` literal in the package to this tuple.  PERF.md §3 says
+#: what each covers.
+KERNEL_SCOPES = (
+    "k_filter",        # scan predicates, live/padding and null masks
+    "k_agg_sort",      # group-key expressions, packing, the grouping
+                       # argsort(s) and the sorted keys
+    "k_agg_segment",   # group boundaries, prefix/segment reductions,
+                       # compaction to `capacity`
+    "k_agg_gather",    # aggregate-input expressions and their gather
+                       # through the sort permutation
+    "k_join_build",    # in-program build side: key folding, build sort
+    "k_join_probe",    # probe, expansion, the lazy per-row gather chain
+    "k_topk",          # order-by/limit over the aggregate's groups
+    "k_exchange",      # mesh: radix bucketing, all_to_all / all_gather,
+                       # the merge of received partials
+)
+
+#: suffix of every jitted query program's name.  jax's persistent
+#: compilation cache hashes the module with its debug info stripped
+#: (jax/_src/cache_key.py), and a name scope IS debug info: a program
+#: that differs from a cached one only in its scopes would be served the
+#: cached executable, whose instructions carry the OLD op_names.  The
+#: module's name does take part in the key, so the programs' names carry
+#: this tag: bump it whenever a scope is added, moved or renamed.  Price:
+#: one cold compile per program in a cache directory an older tag filled.
+KERNEL_SCOPES_TAG = "ks1"
+
+
 def observed_jit(fn, **jit_kw):
     """jax.jit + compile accounting (mirror of device_exec._timed_jit for
     kernels living below the executor layer): the body must call
     _note_trace(); a dispatch whose trace count moved charges its wall
-    time as compile seconds."""
+    time as compile seconds.  The program is named ``<fn>_<scopes tag>``
+    (see KERNEL_SCOPES_TAG)."""
     import time as _time
-    jfn = jax.jit(fn, **jit_kw)
+
+    @functools.wraps(fn)
+    def tagged(*args, **kw):
+        return fn(*args, **kw)
+    tagged.__name__ = f"{fn.__name__}_{KERNEL_SCOPES_TAG}"
+    jfn = jax.jit(tagged, **jit_kw)
 
     def run(*args, **kw):
         if _tls_traces is None:
@@ -1448,6 +1524,7 @@ def observed_jit(fn, **jit_kw):
         if _tls_traces() > before and _charge_compile is not None:
             _charge_compile(_time.perf_counter() - t0)
         return out
+    run.lower = jfn.lower   # the program as dispatched, for inspection
     return run
 
 
@@ -1473,11 +1550,13 @@ _agg_kernel = observed_jit(
 def _join_count_impl(build_key, probe_key, build_null, probe_null):
     """Pass 1: sort build side, count matches per probe row."""
     _note_trace()
-    order = jnp.argsort(build_key, stable=True)
-    sb = build_key[order]
-    lo = jnp.searchsorted(sb, probe_key, side="left")
-    hi = jnp.searchsorted(sb, probe_key, side="right")
-    cnt = jnp.where(probe_null, 0, hi - lo)
+    with jax.named_scope("k_join_build"):
+        order = jnp.argsort(build_key, stable=True)
+        sb = build_key[order]
+    with jax.named_scope("k_join_probe"):
+        lo = jnp.searchsorted(sb, probe_key, side="left")
+        hi = jnp.searchsorted(sb, probe_key, side="right")
+        cnt = jnp.where(probe_null, 0, hi - lo)
     return order, sb, lo, cnt
 
 
@@ -1487,15 +1566,17 @@ _join_count_kernel = observed_jit(_join_count_impl)
 def _join_expand_impl(order, lo, cnt, build_null, total):
     """Pass 2 (static total): expand match pairs."""
     _note_trace()
-    cum = jnp.cumsum(cnt)
-    pos = jnp.arange(total, dtype=jnp.int64)
-    probe_idx = jnp.searchsorted(cum, pos, side="right")
-    base = jnp.where(probe_idx > 0, cum[jnp.clip(probe_idx - 1, 0, None)], 0)
-    within = pos - base
-    safe_probe = jnp.clip(probe_idx, 0, lo.shape[0] - 1)
-    bpos = lo[safe_probe] + within
-    build_idx = order[jnp.clip(bpos, 0, order.shape[0] - 1)]
-    keep = ~build_null[build_idx]
+    with jax.named_scope("k_join_probe"):
+        cum = jnp.cumsum(cnt)
+        pos = jnp.arange(total, dtype=jnp.int64)
+        probe_idx = jnp.searchsorted(cum, pos, side="right")
+        base = jnp.where(probe_idx > 0,
+                         cum[jnp.clip(probe_idx - 1, 0, None)], 0)
+        within = pos - base
+        safe_probe = jnp.clip(probe_idx, 0, lo.shape[0] - 1)
+        bpos = lo[safe_probe] + within
+        build_idx = order[jnp.clip(bpos, 0, order.shape[0] - 1)]
+        keep = ~build_null[build_idx]
     return probe_idx, build_idx, keep
 
 

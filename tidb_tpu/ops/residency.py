@@ -103,6 +103,7 @@ _ENTRIES: "collections.OrderedDict[int, _Entry]" = collections.OrderedDict()
 
 STATS = {
     "uploads": 0,          # publishes that installed a new cached value
+    "upload_bytes": 0,     # ... and the bytes they put on the device
     "hits": 0,             # lookups served from cache
     "hbm_evictions": 0,    # entries evicted (budget, grow, epoch, OOM)
     "hbm_evicted_bytes": 0,
@@ -285,6 +286,14 @@ def current_group() -> str:
     return getattr(_TLS, "group", DEFAULT_GROUP)
 
 
+def thread_upload_bytes() -> int:
+    """Bytes the CALLING thread's publishes have installed so far: the
+    ``upload.h2d`` span takes its ``bytes`` tag from the growth of this
+    over its extent, so a concurrent session's uploads are not charged
+    to it (the process total is ``STATS["upload_bytes"]``)."""
+    return getattr(_TLS, "upload_bytes", 0)
+
+
 def set_budget(n: int):
     """Set the budget in bytes directly (tests / embedders); 0 = auto."""
     with _LOCK:
@@ -394,6 +403,8 @@ def publish(col, data, nulls):
             _GROUP_BYTES[group] += nbytes
             _fleet_charge_locked(group, nbytes)
             STATS["uploads"] += 1
+            STATS["upload_bytes"] += nbytes
+            _TLS.upload_bytes = thread_upload_bytes() + nbytes
             ev0 = STATS["hbm_evictions"]
             _enforce_budget_locked(keep_token=token, group=group)
             budget_evicted = STATS["hbm_evictions"] - ev0

@@ -247,6 +247,7 @@ def _radix_bucket(h, valid, n_dest, n_sub):
     return jnp.where(valid, dest * n_sub + sub, nb), nb
 
 
+@jax.named_scope("k_exchange")
 def _bucketize(keys, vals, valid, n_dest, cap, n_sub=RADIX_SUB):
     """Two-level RADIX partition ("Efficient Multiway Hash Join on
     Reconfigurable Hardware", PAPERS.md): the mix64 hash's HIGH bits pick
@@ -282,6 +283,7 @@ def _bucketize(keys, vals, valid, n_dest, cap, n_sub=RADIX_SUB):
     return bk, bvals, bvalid, dropped
 
 
+@jax.named_scope("k_exchange")
 def _exchange_hash(keys, vals, valid, axis, n_dest, cap):
     """Radix-partition exchange: two-level bucketize locally, one tiled
     all_to_all over ICI.  After this, every row on shard i satisfies

@@ -67,6 +67,8 @@ def status_payload(domain) -> dict:
     return {
         "version": "8.0.11-tpu-htap",
         "connections": len(domain.sessions),
+        # client statements parsed and the parser's seconds for them
+        "server": domain.observe.server_snapshot(),
         "kv_engine": domain.store.backend,
         # the backend this process holds (platform, device_kind, count)
         # and each device's allocator bytes — which chip answered, and

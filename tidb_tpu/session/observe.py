@@ -156,10 +156,25 @@ class Observability:
         self.gauges: dict = {}
         # per-layer latency histograms (HIST_BUCKETS registry above)
         self.histograms: dict[str, Histogram] = {}
+        # client SQL texts parsed and the seconds the parser took
+        # (Session.execute): parsing happens before the statement's
+        # trace begins, so it has a counter where the rest has spans
+        self.statements = 0
+        self.parse_s = 0.0
 
     def inc(self, name, n=1):
         with self._lock:
             self.counters[name] += n
+
+    def note_parse(self, seconds: float, statements: int):
+        with self._lock:
+            self.statements += statements
+            self.parse_s += seconds
+
+    def server_snapshot(self) -> dict:
+        """The ``/status`` ``server`` section."""
+        with self._lock:
+            return {"statements": self.statements, "parse_s": self.parse_s}
 
     def set_gauge(self, name, value):
         with self._lock:

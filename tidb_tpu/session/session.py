@@ -1040,7 +1040,11 @@ class Session:
         # sibling worker's DDL must be visible before this statement
         # plans against the local infoschema
         self.domain.maybe_reload_schema()
+        t0 = time.perf_counter()
         stmts = self.parser.parse(sql)
+        if not self._internal:
+            self.domain.observe.note_parse(time.perf_counter() - t0,
+                                           len(stmts))
         return [self._execute_stmt(s) for s in stmts]
 
     def prepare(self, sql: str):

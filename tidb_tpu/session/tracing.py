@@ -39,6 +39,17 @@ statement); ``TRACE <stmt>`` is always-on, and a sampled statement that
 crosses the slow-log threshold always keeps its rendered tree on the
 :class:`~tidb_tpu.session.observe.SlowQueryItem`.
 
+One clock with the device trace: every span that opens under an active
+trace also enters a ``jax.profiler.TraceAnnotation`` of its name on the
+thread that opened it, so when a ``jax.profiler`` session is running the
+spans are host events of the SAME trace as the device's operations (the
+benchmark's ``idle.*`` metrics give each idle gap of the device to the
+innermost span open at that instant).  This module never imports jax: a
+process that has not imported it (the benchmark's parent, every client)
+gets no annotations and stays JAX-free.  Durations, offsets and the
+ring keep their own monotonic clock; the annotation adds nothing to
+what ``DIAG TRACEJSON`` / ``TRACE`` / the slow log render.
+
 Locking: each trace has its own tiny lock (span/event appends from
 worker threads); the ring has one.  Neither is ever held across a
 blocking call, and no serving mutex (scheduler/supervisor/residency/
@@ -52,6 +63,7 @@ from __future__ import annotations
 import collections
 import itertools
 import os
+import sys
 import threading
 import time
 
@@ -107,7 +119,7 @@ class Trace:
     __slots__ = ("trace_id", "parent_id", "origin", "name", "conn_id",
                  "started_at", "_t0", "spans", "dropped", "_lock", "root",
                  "finished", "dur_s", "succ", "n_events", "gid",
-                 "origin_gid", "remote")
+                 "origin_gid", "remote", "_ann")
 
     def __init__(self, name, origin="sampled", conn_id=None, parent_id=None,
                  tags=None):
@@ -134,6 +146,9 @@ class Trace:
         self.dur_s = None
         self.succ = True
         self.n_events = 0
+        #: the root span's profiler annotation, with the thread that
+        #: entered it (begin); left by finish on that thread only
+        self._ann = None
         self.root = self._start_span(name, -1, dict(tags or ()))
 
     # -- recording (any thread holding this trace via TLS) -------------------
@@ -311,8 +326,21 @@ class _NoopCtx:
 _NOOP = _NoopCtx()
 
 
+def _annotate(name):
+    """Enter a ``jax.profiler.TraceAnnotation`` called `name` on the
+    calling thread and return it (leave it with ``__exit__`` on the SAME
+    thread), or None in a process that never imported jax.  Outside a
+    profiler session the annotation is a flag check in the runtime."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _SpanCtx:
-    __slots__ = ("tr", "name", "tags", "sp", "prev")
+    __slots__ = ("tr", "name", "tags", "sp", "prev", "ann")
 
     def __init__(self, tr, name, tags):
         self.tr = tr
@@ -327,11 +355,14 @@ class _SpanCtx:
         self.prev = parent
         if sp is not None:
             _TLS.span = sp
+            self.ann = _annotate(self.name)
         return sp
 
     def __exit__(self, et, ev, tb):
         sp = self.sp
         if sp is not None:
+            if self.ann is not None:
+                self.ann.__exit__(et, ev, tb)
             self.tr._end_span(
                 sp, error=et.__name__ if et is not None else None)
             _TLS.span = self.prev
@@ -371,6 +402,9 @@ def begin(name, *, origin="sampled", conn_id=None, parent_id=None,
     _TLS.span = tr.root
     with _RING_LOCK:
         STATS["started"] += 1
+    ann = _annotate(name)
+    if ann is not None:
+        tr._ann = (ann, threading.get_ident())
     return tr
 
 
@@ -380,6 +414,12 @@ def finish(tr: Trace, succ: bool = True):
     if getattr(_TLS, "trace", None) is tr:
         _TLS.trace = None
         _TLS.span = None
+    ann = tr._ann
+    if ann is not None and ann[1] == threading.get_ident():
+        # the root's annotation belongs to the thread that began the
+        # trace; a finish from elsewhere leaves it to that thread
+        tr._ann = None
+        ann[0].__exit__(None, None, None)
     if not tr._finish(succ):
         return
     with _RING_LOCK:
