@@ -1,15 +1,17 @@
 """The lowering the chip takes, run in tier-1.
 
 Several branches of the device executor are gated on
-``jax.default_backend()``: on XLA:CPU small packed keys aggregate through
-a scatter kernel, streamed partial states fold in numpy and join outputs
-are compacted before the aggregate; on a TPU the same queries take the
-sort + segment kernel (``ops/device._agg_impl``), the in-kernel
-``merge_partial_states`` fold and no compaction.  CPU tests therefore
-never executed what the chip runs.  Here ``default_backend`` reports
-``"tpu"`` while XLA:CPU does the work, so those arms trace, compile and
-answer — each held to exact parity with the host engine — before they
-meet the hardware (chip_smoke.py).
+``jax.default_backend()``: on XLA:CPU packed keys above the dense bound
+aggregate through a scatter kernel, streamed partial states fold in numpy
+and join outputs are compacted before the aggregate; on a TPU the same
+queries take the sort + segment kernel (``ops/device._agg_impl``), the
+in-kernel ``merge_partial_states`` fold and no compaction.  CPU tests
+therefore never executed what the chip runs.  Here ``default_backend``
+reports ``"tpu"`` while XLA:CPU does the work, so those arms trace,
+compile and answer — each held to exact parity with the host engine —
+before they meet the hardware (chip_smoke.py).  Small packed key spaces
+(Q1, Q6) take the dense arm on every backend; its lowering is held here
+too.
 """
 
 import pathlib
@@ -67,13 +69,21 @@ def _parity(tk, sql):
     return engines
 
 
-def test_small_packed_key_aggregate_sorts(tpch, monkeypatch):
-    """Q1: two dict-coded keys pack into a few bits — the scatter kernel
-    on CPU, one int32 argsort + segment reduction on the chip."""
+def test_small_packed_key_aggregate_reduces_densely(tpch, monkeypatch):
+    """Q1: two dict-coded keys pack into 5 bits — 32 buckets, one masked
+    reduction each; never the scatter kernel under a tpu backend."""
     def no_scatter(*a, **k):
         raise AssertionError("scatter aggregate ran under a tpu backend")
     monkeypatch.setattr(dev, "_agg_scatter_impl", no_scatter)
+    arms = []
+    orig = dev.agg_arm
+
+    def spy(pack, agg_ops, gathered=False):
+        arms.append(orig(pack, agg_ops, gathered))
+        return arms[-1]
+    monkeypatch.setattr(dev, "agg_arm", spy)
     assert _parity(tpch, bench.QUERIES["q1"]) == ["engine:tpu"]
+    assert arms and set(arms) == {"dense"}
 
 
 def test_join_aggregate_without_compaction(tpch, monkeypatch):
@@ -132,9 +142,20 @@ def test_streamed_aggregate_merges_in_kernel(monkeypatch):
 # those names.  What the chip's programs are lowered from must carry every
 # name that applies to its shape.
 
+Q6 = """
+select sum(l_extendedprice * l_discount) as revenue
+from lineitem
+where l_shipdate >= date '1994-01-01'
+  and l_shipdate < date '1994-01-01' + interval '1' year
+  and l_discount between 0.06 - 0.01 and 0.06 + 0.01
+  and l_quantity < 24
+"""
+
 _SCOPES_BY_SHAPE = {
-    # scan -> filter -> group by: the sort + segment kernel
+    # scan -> filter -> group by 32 / 2 buckets: the dense arm, under the
+    # same names (key packing, aggregate inputs, bucket reductions)
     "q1": ("k_filter", "k_agg_sort", "k_agg_segment", "k_agg_gather"),
+    "q6": ("k_filter", "k_agg_sort", "k_agg_segment", "k_agg_gather"),
     # host-indexed joins (no in-program build) under the aggregate
     "q3": ("k_filter", "k_join_probe", "k_agg_sort", "k_agg_segment",
            "k_agg_gather"),
@@ -153,14 +174,16 @@ def lowered(tpch):
     lowered to}, under the tpu arms (module-scoped, so it patches
     default_backend itself: it is set up before _as_tpu)."""
     texts = {}
-    current = []
+    current, programs = [], []
     orig = dev.observed_jit
 
     def spy(fn, **jit_kw):
         run = orig(fn, **jit_kw)
 
         def call(*a, **k):
-            current.append(run.lower(*a, **k).as_text(debug_info=True))
+            low = run.lower(*a, **k)
+            current.append(low.as_text(debug_info=True))
+            programs.append(low)
             return run(*a, **k)
         return call
 
@@ -178,15 +201,18 @@ def lowered(tpch):
                 "group by l_orderkey order by q desc, l_orderkey limit 5")
         for shape, engine, sql in (
                 ("q1", "tpu", bench.QUERIES["q1"]),
+                ("q6", "tpu", Q6),
                 ("q3", "tpu", bench.QUERIES["q3"]),
+                ("q5", "tpu", bench.QUERIES["q5"]),
                 ("topn", "tpu", topn),
                 ("mpp_q3", "tpu-mpp", bench.QUERIES["q3"])):
             tpch.must_exec(f"set tidb_executor_engine = '{engine}'")
             tpch.must_exec("set tidb_mpp_devices = 2")
-            del current[:]
+            del current[:], programs[:]
             rows = tpch.must_query(sql).rows
             assert rows and current, shape
             texts[shape] = "\n".join(current)
+            texts[shape, "programs"] = list(programs)
         drop_compiled()
     tpch.must_exec("set tidb_executor_engine = 'tpu'")
     return texts
@@ -203,12 +229,93 @@ def test_lowered_programs_name_their_kernels(lowered, shape, scope):
     assert re.search(rf'["/]{scope}/', lowered[shape])
 
 
+def _op_results(text, op):
+    """Element counts of the results of every `op` in a StableHLO text."""
+    import math
+    import re
+    out = []
+    for m in re.finditer(
+            rf'stablehlo\.{op}\b[^\n]*->\s*\(?tensor<([0-9x]*)[a-z]', text):
+        out.append(math.prod(int(d) for d in m.group(1).split("x") if d))
+    return out
+
+
+def _fact_length(text):
+    import re
+    return max(int(n) for n in re.findall(r"tensor<(\d+)xi64>", text))
+
+
+@pytest.mark.parametrize("shape", ["q1", "q6"])
+def test_dense_programs_neither_sort_nor_gather_at_fact_length(lowered,
+                                                              shape):
+    """Q1 and Q6 aggregate by masked reductions: no sort, no scatter, and
+    no gather or cumsum whose result is as long as the fact table."""
+    text = lowered[shape]
+    n = _fact_length(text)
+    assert n >= 131072
+    assert "stablehlo.sort" not in text
+    assert "stablehlo.scatter" not in text
+    for op in ("gather", "dynamic_gather", "reduce_window"):
+        assert all(size < n for size in _op_results(text, op)), op
+    assert _op_results(text, "reduce")      # the reductions are there
+
+
+@pytest.mark.parametrize("shape", ["q3", "q5"])
+def test_join_fragments_still_sort(lowered, shape):
+    """Q3 groups by (l_orderkey, o_orderdate, o_shippriority), far past
+    the dense bound; Q5 by n_name, under it, but its inputs come out of
+    the probe's gather chain: both trace the program the parent traced."""
+    text = lowered[shape]
+    assert "stablehlo.sort" in text
+    n = _fact_length(text)
+    assert any(size >= n for size in _op_results(text, "gather")
+               + _op_results(text, "dynamic_gather"))
+
+
+@pytest.mark.parametrize("shape", ["q1", "q6"])
+def test_dense_programs_leave_no_instruction_unnamed(lowered, shape):
+    """Every instruction of the compiled dense programs falls to a
+    KERNEL_SCOPES name by the rule the benchmark's trace reader applies
+    (benchmark/harness/trace_owners.py: its own scope, else the nearest
+    instruction around it that has one), so kernel.unnamed_share stays
+    at 0 to the digits it is printed with."""
+    import math
+    import re
+    from benchmark.harness import trace_owners
+    assert trace_owners.KERNELS == dev.KERNEL_SCOPES
+    programs = lowered[shape, "programs"]
+    assert programs
+    n = _fact_length(lowered[shape])
+    checked = 0
+    for low in programs:
+        compiled = low.compile()
+        sizes = {m.group(1): math.prod(
+                     int(d) for d in m.group(2).split(",") if d)
+                 for m in re.finditer(
+                     r"%?([\w.-]+) = \w+\[([\d,]*)\]", compiled.as_text())}
+        for hm in compiled.runtime_executable().hlo_modules():
+            buf = hm.as_serialized_hlo_module_proto()
+            mod = trace_owners._Module(buf, (0, len(buf)))
+            unnamed = [name for name in mod.by_name
+                       if mod.kernel(name, dev.KERNEL_SCOPES) is None]
+            # what the compiler folds to a constant loses its scope: the
+            # all-false NULL flags of COUNT, `capacity` booleans.  Nothing
+            # at the fact length may go unnamed
+            assert all(sizes[name] < n // 16 for name in unnamed), \
+                (shape, unnamed[:8])
+            assert len(unnamed) <= len(mod.by_name) // 50
+            checked += len(mod.by_name)
+    assert checked > 20
+
+
 def test_lowered_program_names_carry_the_scopes_tag(lowered):
     """The persistent compile cache hashes a module without its debug
     info, so scopes alone would be served a cached, unnamed executable:
     the module names carry KERNEL_SCOPES_TAG."""
     import re
     for shape, text in lowered.items():
+        if not isinstance(shape, str):
+            continue
         names = set(re.findall(r"module @(\w+)", text))
         assert names and all(
             n.endswith("_" + dev.KERNEL_SCOPES_TAG) for n in names), \
@@ -220,3 +327,44 @@ def test_mesh_merge_counts_as_exchange(lowered):
     of the gathered partials is _agg_impl under k_exchange."""
     assert "k_exchange/k_agg_sort/" in lowered["mpp_q3"]
     assert "k_exchange/" not in lowered["q3"]
+
+
+# -- which arm a fragment took, as the program reports it (ISSUE 26) ---------
+
+def _device_pipelines(tk):
+    import json
+    rows = tk.must_query("DIAG STATUS").rows
+    return json.loads(rows[0][0])["device_pipelines"]
+
+
+def _agg_annotations(tk, sql):
+    plan = tk.must_query("explain analyze " + sql).rows
+    return [part for row in plan for part in row[2].split(", ")
+            if part.startswith("agg:")]
+
+
+@pytest.mark.parametrize("shape,sql,counter,arm", [
+    ("q1", bench.QUERIES["q1"], "agg_dense", "dense"),
+    ("q6", Q6, "agg_dense", "dense"),
+    ("wide_key", "select l_orderkey, sum(l_quantity) from lineitem "
+                 "group by l_orderkey order by l_orderkey limit 3",
+     "agg_sorted", "sort"),
+    ("q3", bench.QUERIES["q3"], "agg_sorted", "sort"),
+    # n_name packs into 5 bits, but a join fragment's inputs are gathered
+    ("q5", bench.QUERIES["q5"], "agg_sorted", "sort"),
+])
+def test_fragments_count_their_aggregate_arm(tpch, shape, sql, counter,
+                                             arm):
+    """DIAG STATUS device_pipelines.agg_dense / agg_sorted advance by one
+    per dispatched aggregate fragment, on the side dev.agg_arm named, and
+    EXPLAIN ANALYZE names the arm."""
+    tpch.must_exec("set tidb_result_cache = 'OFF'")
+    tpch.must_exec("set tidb_executor_engine = 'tpu'")
+    before = _device_pipelines(tpch)
+    assert tpch.must_query(sql).rows
+    after = _device_pipelines(tpch)
+    grew = {k: after[k] - before[k]
+            for k in ("agg_dense", "agg_sorted", "agg_scatter")}
+    assert grew == {"agg_dense": 0, "agg_sorted": 0, "agg_scatter": 0,
+                    counter: 1}, shape
+    assert _agg_annotations(tpch, sql) == [f"agg:{arm}"]

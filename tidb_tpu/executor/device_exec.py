@@ -284,7 +284,11 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # how each pipeline resolution was served — drives the
                # per-fragment compile_mode EXPLAIN ANALYZE annotation
                "mode_cached": 0, "mode_prewarmed": 0,
-               "mode_async_pending": 0, "mode_sync": 0}
+               "mode_async_pending": 0, "mode_sync": 0,
+               # aggregate fragments dispatched, by the arm of
+               # ops/device._agg_impl that dev.agg_arm named for them
+               # (scatter exists on XLA:CPU only) — note_agg_arm
+               "agg_dense": 0, "agg_sorted": 0, "agg_scatter": 0}
 _PIPE_LOCK = _threading.Lock()
 _PIPE_TLS = _threading.local()
 
@@ -313,7 +317,9 @@ def _tls_stats() -> dict:
         st = _PIPE_TLS.stats = {"hits": 0, "misses": 0, "traces": 0,
                                 "compiles": 0, "compile_s": 0.0,
                                 "mode_cached": 0, "mode_prewarmed": 0,
-                                "mode_async_pending": 0, "mode_sync": 0}
+                                "mode_async_pending": 0, "mode_sync": 0,
+                                "agg_dense": 0, "agg_sorted": 0,
+                                "agg_scatter": 0}
     return st
 
 
@@ -326,6 +332,19 @@ def _bump(key, amt=1):
     st = _tls_stats()
     if key in st:
         st[key] += amt
+
+
+#: dev.agg_arm's name -> its counter
+AGG_ARM_STATS = {"dense": "agg_dense", "sort": "agg_sorted",
+                 "scatter": "agg_scatter"}
+
+
+def note_agg_arm(pack, agg_ops, gathered=False):
+    """Count one dispatched aggregate fragment (scan pipeline, join
+    fragment, mesh partial) under the arm its program aggregates by;
+    EXPLAIN ANALYZE's ``agg:`` annotation and the benchmark's
+    ``agg.dense_share`` read the counters."""
+    _bump(AGG_ARM_STATS[dev.agg_arm(pack, tuple(agg_ops), gathered)])
 
 
 def pipe_cache_stats(thread_local: bool = False) -> dict:
@@ -622,6 +641,7 @@ def device_agg(plan, chunk: Chunk, conds, ctx=None) -> Chunk:
     sig_exprs, dict_refs = _agg_sig(plan, conds, dcols)
     est = _estimate_groups(plan, n, ctx)
     capacity = dev.next_pow2(min(n, max(est, 16)))
+    note_agg_arm(key_pack, agg_ops)
     while True:
         key = (sig_exprs, capacity, key_pack, tuple(agg_ops))
         cap = capacity
@@ -1151,6 +1171,7 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
     est = _estimate_groups(plan, n, ctx)
     capacity = dev.next_pow2(min(batch_rows, max(est, 16)))
     merge_cap = capacity  # grows to the true total on merge overflow
+    note_agg_arm(key_pack, agg_ops)
     for _attempt in range(8):
         key = (sig_exprs, "stream", capacity, key_pack, tuple(agg_ops))
         cap = capacity

@@ -51,7 +51,7 @@ from ..ops import device as dev
 from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
-    _plan_agg, _timed_jit, acquire_pipeline)
+    _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm)
 from .join_index import build_join_index
 
 
@@ -944,7 +944,8 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                                 tuple(val_cols), tuple(val_nulls), mask,
                                 n_keys=len(key_cols),
                                 agg_ops=tuple(agg_ops),
-                                capacity=capacity, pack=key_pack)
+                                capacity=capacity, pack=key_pack,
+                                gathered=True)
         return agg_out, tuple(overflows), tuple(span_ovfs), kept_total
 
     return _timed_jit(run)
@@ -1156,6 +1157,7 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     import sys as _sys
     import time as _time
     _dbg = _os.environ.get("TIDB_TPU_DEBUG_JOIN")
+    note_agg_arm(key_pack, agg_ops, gathered=True)
     for _attempt in range(12):
         caps = [jn.cap for jn in joins]
         key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
@@ -1513,6 +1515,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
             root, leaves, joins, probe, agg_plan, agg_conds, ctx,
             page_rows, dcols, agg_meta_full, merge_ops, sig, dict_refs,
             env_dim, probe_arrays, jidx, n)
+    note_agg_arm(key_pack, agg_ops, gathered=True)
     for _attempt in range(4):
         caps = [page_rows] * len(joins)
         key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops), None,

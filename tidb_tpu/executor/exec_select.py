@@ -94,6 +94,12 @@ class QueryExecutor:
                  if st1["mode_" + m] - st0["mode_" + m] > 0), None)
             if mode is not None:
                 self.annotate(compile_mode=mode)
+            # which arm of ops/device._agg_impl the fragment's aggregate
+            # took (device_exec.note_agg_arm): agg:dense | sort | scatter
+            from .device_exec import AGG_ARM_STATS
+            self.annotate(agg=next(
+                (arm for arm, k in AGG_ARM_STATS.items()
+                 if st1[k] - st0[k] > 0), None))
             from .supervisor import abandoned_calls
             n_abandoned = abandoned_calls()
             if n_abandoned:

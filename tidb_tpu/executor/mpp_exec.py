@@ -63,7 +63,7 @@ from ..ops.device import DeviceUnsupported
 from ..parallel.mpp import RADIX_SUB, _mix64, _radix_bucket
 from .device_exec import (
     _assemble_agg, _estimate_groups, _plan_agg, acquire_pipeline,
-    engine_mode)
+    engine_mode, note_agg_arm)
 from .device_join import (
     _CAP_STORE, _JoinNode, _Leaf, _cap_store_put, _combined_join_keys,
     _join_expand, _shift_expr, collect_tree, fragment_sig)
@@ -428,7 +428,7 @@ def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
             tuple(key_cols), tuple(key_nulls),
             tuple(val_cols), tuple(val_nulls), mask,
             n_keys=n_keys, agg_ops=agg_ops, capacity=capacity,
-            pack=key_pack)
+            pack=key_pack, gathered=True)
 
         # exchange: every shard's bounded partial state (capacity rows —
         # tiny next to N) rides ICI to every shard
@@ -448,7 +448,8 @@ def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
             # names the kernel: the merge is a cost of the exchange
             f_out = dev._agg_impl(gk, gkn, gres, gresn, gvalid,
                                   n_keys=n_keys, agg_ops=merge_ops,
-                                  capacity=capacity, pack=key_pack)
+                                  capacity=capacity, pack=key_pack,
+                                  gathered=True)
 
         @jax.named_scope("k_exchange")
         def mesh_max(x):
@@ -804,6 +805,7 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
     from ..utils.failpoint import FailpointError
     from ..errors import BackoffExhaustedError
     bo = Backoffer.for_session(ctx)
+    note_agg_arm(key_pack, agg_ops, gathered=True)
     while True:
         for jn, cap in zip(joins, caps):
             jn.cap = cap

@@ -1081,16 +1081,17 @@ def _group_spans(is_new, kept, n, capacity):
     return starts, ends, end_idx, span_sum
 
 
-#: dense-bucket aggregation bound: bucket arrays up to 2^26 slots (the
-#: packed-key space) are cheaper than one 100k+-element sort on the XLA CPU
-#: backend, where sort lowers to a slow single-threaded path. Bucket
-#: memory scales with the ACTUAL key span, capped by the BYTE budget
-#: below (26 bits + one value column ≈ 4.3GB transient — the budget, not
-#: this constant, is usually the binding bound). A 60M-value l_orderkey
-#: GROUP BY (TPC-H Q18's inner agg at SF10, 26-bit span) stays on O(n)
-#: scatters instead of falling onto the serial sort (measured: the sort
-#: path made SF10 Q18 7x slower than host; the path only exists on the
-#: CPU backend, so the budget sizes against host RAM, not HBM)
+#: scatter-arm bound (XLA:CPU only, above the dense bound; see agg_arm):
+#: bucket arrays up to 2^26 slots (the packed-key space) are cheaper than
+#: one 100k+-element sort on the XLA CPU backend, where sort lowers to a
+#: slow single-threaded path. Bucket memory scales with the ACTUAL key
+#: span, capped by the BYTE budget below (26 bits + one value column ≈
+#: 4.3GB transient — the budget, not this constant, is usually the
+#: binding bound). A 60M-value l_orderkey GROUP BY (TPC-H Q18's inner agg
+#: at SF10, 26-bit span) stays on O(n) scatters instead of falling onto
+#: the serial sort (measured: the sort path made SF10 Q18 7x slower than
+#: host; the arm only exists on the CPU backend, so the budget sizes
+#: against host RAM, not HBM)
 _SCATTER_AGG_BITS = 26
 
 
@@ -1110,28 +1111,34 @@ def _host_ram_bytes() -> int:
 _SCATTER_AGG_BUDGET_BYTES = min(6 << 30, max(_host_ram_bytes() // 4, 1 << 30))
 
 
+def _packed_bucket(key_cols, key_nulls, pack):
+    """(bucket id per row, bucket count B) of the two bucket arms: the
+    statically packed group key as the sort arm packs it (NULL is 0, a
+    value shifts by offset + 1), int64, clipped into [0, B)."""
+    B = 1 << sum(b for b, _o in pack)
+    bucket = jnp.zeros(key_cols[0].shape[0], dtype=jnp.int64)
+    for i, (bits, offset) in enumerate(pack):
+        shifted = (key_cols[i].astype(jnp.int64)
+                   + jnp.asarray(offset + 1, dtype=jnp.int64))
+        v = jnp.where(key_nulls[i], jnp.zeros((), dtype=jnp.int64), shifted)
+        bucket = (bucket << bits) | v
+    return jnp.clip(bucket, 0, B - 1), B
+
+
 def _agg_scatter_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
                       n_keys, agg_ops, capacity, pack):
     """Dense-bucket aggregation: bucket id = the statically packed group
     key; per aggregate ONE scatter-add/min/max over the bucket space, then
     a compaction scatter into the capacity-sized output slots.
 
-    XLA-CPU-only lowering choice (see _agg_impl): scatters there are tight
-    O(n) loops (~100x faster than the backend's sort), while on TPU
-    non-unique scatters serialize and the sort path wins. Both produce
-    identical group sets; bucket order = packed-key order, and the
+    XLA-CPU-only arm (see agg_arm), for packed key spaces above the dense
+    bound: scatters there are tight O(n) loops (~100x faster than the
+    backend's sort), while on TPU non-unique scatters serialize. Both
+    produce identical group sets; bucket order = packed-key order, and the
     representative row per group is the scatter-min of kept row positions,
     so first_row/key decode semantics match the stable-sort path."""
     n = mask.shape[0]
-    total_bits = sum(b for b, _o in pack)
-    B = 1 << total_bits
-    bucket = jnp.zeros(n, dtype=jnp.int64)
-    for i, (bits, offset) in enumerate(pack):
-        shifted = (key_cols[i].astype(jnp.int64)
-                   + jnp.asarray(offset + 1, dtype=jnp.int64))
-        v = jnp.where(key_nulls[i], jnp.zeros((), dtype=jnp.int64), shifted)
-        bucket = (bucket << bits) | v
-    bucket = jnp.clip(bucket, 0, B - 1)
+    bucket, B = _packed_bucket(key_cols, key_nulls, pack)
     pos = jnp.arange(n)
     ones = jnp.where(mask, 1, 0)
     cnt_rows = jnp.zeros(B, dtype=jnp.int64).at[bucket].add(ones)
@@ -1202,43 +1209,219 @@ def _agg_scatter_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
             n_groups, valid)
 
 
-def _agg_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
-              n_keys, agg_ops, capacity, pack=None):
-    """One fused kernel: filter mask + group-by + aggregate.
+#: dense-arm bound: packed key spaces of at most this many buckets
+#: aggregate by one masked reduction per bucket. Work grows as buckets x
+#: reductions x rows; the sort arm costs the same whatever the bucket
+#: count. Set from a sweep on the v5e through the served path (6M-row
+#: lineitem, sum + count(*); PERF.md section 6, PR 26): dense 17 ms at
+#: 256 buckets, 100 ms at 2,048, 215 ms at 4,096 against the sort arm's
+#: 375-407 ms. 2,048 still wins 3.8x there, but only 1.3x at Q1's ten
+#: value rows (Q1 scaled from its 32 buckets: 484 against 642 ms); at
+#: 1,024 both shapes win by more than 2x.
+_DENSE_AGG_BUCKETS = 1024
 
-    Sort-based grouping + boundary arithmetic — the XLA/TPU-native answer to
-    the reference's hash tables (executor/aggregate.go): static shapes, no
-    data-dependent control flow, and NO scatters (XLA lowers scatter-adds to
-    a serialized loop on TPU; sort + cumsum + gather are all parallel).
-    Per aggregate: exclusive-prefix-sum, then sum over a group = csum[end] -
-    csum[start]; min/max via segmented associative scan. Groups beyond
-    `capacity` are detected (n_groups > capacity) and the caller retries
-    with a bigger static capacity — one extra compile, never wrong results.
+#: aggregates the dense arm computes: integer sums wrap the same in any
+#: order, min/max/first do not depend on it. A float sum in another order
+#: is another answer, and COUNT(DISTINCT) needs the sorted runs.
+_DENSE_AGG_OPS = frozenset({"count", "sum_i", "min", "max", "first"})
 
-    key_cols: tuple of int64 arrays (dict codes / ints). agg_ops: tuple of
-    ("sum_i"|"sum_f"|"count"|"min"|"max"|"first") aligned with val_cols.
 
-    pack: optional static tuple of (bits, offset) per key when every key's
-    value range fits a known bit width (dict codes, dates). All keys, their
-    null flags, and the filter mask then fold into ONE sort key — int32
-    when it fits (64-bit ALU ops are emulated pairs on TPU) — giving one
-    argsort instead of 2·n_keys+1. NULL packs as 0 (its own group);
-    filtered-out rows pack as the dtype max and sort last.
-    """
-    if (pack is not None
-            and sum(b for b, _o in pack) <= _SCATTER_AGG_BITS
+def agg_arm(pack, agg_ops, gathered=False) -> str:
+    """Which arm of _agg_impl aggregates a fragment with this static key
+    packing and these ops: "dense" | "scatter" | "sort". Host-callable
+    (the dispatchers count fragments by it) and what _agg_impl itself
+    asks at trace time.
+
+    - dense: the packed key space holds at most _DENSE_AGG_BUCKETS
+      buckets, every op is in _DENSE_AGG_OPS and the inputs are not
+      `gathered`. Any backend: tier-1 on XLA:CPU traces the program the
+      chip runs.
+    - scatter: XLA:CPU only, above the dense bound (or gathered), inside
+      the scatter bit and byte budgets.
+    - sort: the rest (no static packing, wide keys, sum_f, cnt_dist).
+
+    gathered: the aggregate's inputs come out of a join's per-row gather
+    chain in the same program (device_join.compile_fragment, the mesh
+    body). Those fragments keep the arm they had: on the v5e TPC-H Q5's
+    aggregate (32 buckets) fell by 0.23 s under the dense arm and the
+    compiler then ran the probe chain's same gathers 1.35 s slower
+    (PERF.md section 6, PR 26)."""
+    if pack is None:
+        return "sort"
+    bits = sum(b for b, _o in pack)
+    if (not gathered and (1 << bits) <= _DENSE_AGG_BUCKETS
+            and all(op in _DENSE_AGG_OPS for op in agg_ops)):
+        return "dense"
+    if (bits <= _SCATTER_AGG_BITS
             # live bucket arrays scale with the aggregate count: cnt +
             # rep + rank + tgt + live + per-agg acc + nullable nn caches
             # all stay resident through compaction — bound total BYTES,
             # not just key bits, or a many-column agg at 25 bits pins
             # gigabytes of 32M-slot arrays at once
-            and (1 << sum(b for b, _o in pack)) * (2 * len(val_cols) + 6)
-            * 8 <= _SCATTER_AGG_BUDGET_BYTES
+            and (1 << bits) * (2 * len(agg_ops) + 6) * 8
+            <= _SCATTER_AGG_BUDGET_BYTES
             and "cnt_dist" not in agg_ops
             and jax.default_backend() == "cpu"):
-        # backend-adaptive lowering: dense-bucket scatters beat the XLA CPU
-        # backend's (slow, serial) sort by ~100x; on TPU scatters serialize
-        # and the sort+segment path below is the right shape
+        return "scatter"
+    return "sort"
+
+
+def _agg_dense_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
+                    agg_ops, capacity, pack):
+    """Dense masked reduction: bucket id = the statically packed group
+    key (as the sort arm packs it: NULL is 0, values shift by offset + 1);
+    per bucket b and aggregate input, ONE reduction of
+    where(bucket == b, v, identity) over the row axis. The TPU compiler
+    fuses the compare and the select into the reduction and stores
+    nothing of shape (B, n); XLA:CPU stores the select (1,024 buckets
+    over 2M rows: 55 GB), which keeps this arm to test sizes there. No
+    argsort, no gather through a permutation, no scatter and no cumsum at
+    the fact length.
+
+    Same contract as the sort arm: groups in packed-key order, the
+    representative row of a group (keys, `first`) is its first kept row,
+    integer sums are the same int64 two's-complement sums, result_null =
+    no non-null kept row. Live buckets compact in bucket order into the
+    capacity-sized outputs; n_groups may exceed capacity (caller
+    retries)."""
+    n = mask.shape[0]
+    with jax.named_scope("k_agg_sort"):
+        packed, B = _packed_bucket(key_cols, key_nulls, pack)
+        # filtered-out rows get bucket B, which no reduction selects
+        bucket = jnp.where(mask, packed, B).astype(jnp.int32)
+        onehot = bucket[None, :] == jnp.arange(B, dtype=jnp.int32)[:, None]
+    # row positions and counts fit 32 bits (64-bit ALU ops are emulated
+    # pairs on TPU); counts widen to int64 after the reduction
+    cnt_dt = jnp.int32 if n < (1 << 31) - 1 else jnp.int64
+
+    # jnp.sum alone would widen an int32 count before it reduces
+    row_sum = functools.partial(jnp.sum, promote_integers=False)
+
+    def reduce_rows(red, v, identity, keep=None):
+        sel = onehot if keep is None else onehot & keep[None, :]
+        return red(jnp.where(sel, v[None, :], identity), axis=1,
+                   initial=identity)
+
+    with jax.named_scope("k_agg_segment"):
+        rep = reduce_rows(jnp.min, jnp.arange(n, dtype=cnt_dt),
+                          jnp.asarray(n, dtype=cnt_dt))
+        live = rep < n
+        n_groups = jnp.sum(live)
+        # output slot g <- the g-th live bucket: the first bucket whose
+        # inclusive live count exceeds g (a (slots, B) compare, no sort).
+        # Slots past n_groups hold garbage, as in the sort arm
+        slots = min(capacity, B)
+        src = jnp.minimum(jnp.sum(
+            jnp.cumsum(live)[None, :] <= jnp.arange(slots)[:, None],
+            axis=1), B - 1)
+        rep_out = jnp.clip(rep[src], 0, max(n - 1, 0))
+
+        def compact(arr_B):
+            return jnp.pad(arr_B[src], (0, capacity - slots))
+
+        def first_row(col):
+            return jnp.pad(col[rep_out], (0, capacity - slots))
+
+        key_out = tuple(first_row(k) for k in key_cols)
+        key_null_out = tuple(first_row(kn) for kn in key_nulls)
+
+    # non-null kept rows per bucket, once per distinct null array (avg =
+    # sum + count over one column share it)
+    nn_by_src = {}
+
+    def nonnull_counts(j):
+        hit = nn_by_src.get(id(val_nulls[j]))
+        if hit is None:
+            with jax.named_scope("k_agg_segment"):
+                hit = compact(reduce_rows(
+                    row_sum, jnp.ones(n, dtype=cnt_dt),
+                    jnp.asarray(0, dtype=cnt_dt), ~val_nulls[j]
+                ).astype(jnp.int64))
+            nn_by_src[id(val_nulls[j])] = hit
+        return hit
+
+    results = []
+    result_nulls = []
+    for j, opn in enumerate(agg_ops):
+        if opn == "first":
+            with jax.named_scope("k_agg_segment"):
+                results.append(first_row(val_cols[j]))
+                result_nulls.append(first_row(val_nulls[j]))
+            continue
+        nn = nonnull_counts(j)
+        if opn == "count":
+            results.append(nn)
+            with jax.named_scope("k_agg_segment"):
+                result_nulls.append(jnp.zeros(capacity, dtype=bool))
+            continue
+        v = val_cols[j]
+        if opn == "sum_i":
+            with jax.named_scope("k_agg_gather"):
+                z = jnp.where(val_nulls[j], 0, v.astype(jnp.int64))
+            with jax.named_scope("k_agg_segment"):
+                acc = reduce_rows(row_sum, z, jnp.int64(0))
+        elif opn in ("min", "max"):
+            if jnp.issubdtype(v.dtype, jnp.floating):
+                lo, hi = -jnp.inf, jnp.inf
+            else:
+                lo, hi = jnp.iinfo(v.dtype).min, jnp.iinfo(v.dtype).max
+            red, ident = (jnp.min, hi) if opn == "min" else (jnp.max, lo)
+            with jax.named_scope("k_agg_segment"):
+                acc = reduce_rows(red, v, jnp.asarray(ident, dtype=v.dtype),
+                                  ~val_nulls[j])
+        else:
+            raise ValueError(opn)
+        with jax.named_scope("k_agg_segment"):
+            results.append(compact(acc))
+            result_nulls.append(nn == 0)
+    with jax.named_scope("k_agg_segment"):
+        valid = jnp.arange(capacity) < n_groups
+    return (key_out, key_null_out, tuple(results), tuple(result_nulls),
+            n_groups, valid)
+
+
+def _agg_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
+              n_keys, agg_ops, capacity, pack=None, gathered=False):
+    """One fused kernel: filter mask + group-by + aggregate, in one of
+    three arms chosen at trace time by agg_arm(pack, agg_ops, gathered):
+
+    - dense (_agg_dense_impl): small packed key spaces whose inputs are
+      not `gathered`, any backend — one masked reduction per bucket, no
+      sort, gather or scatter at the fact length (TPC-H Q6's one group,
+      Q1's four).
+    - scatter (_agg_scatter_impl): XLA:CPU only, above the dense bound
+      or gathered.
+    - sort (below): the rest. Sort-based grouping + boundary arithmetic —
+      the XLA/TPU-native answer to the reference's hash tables
+      (executor/aggregate.go): static shapes, no data-dependent control
+      flow, and NO scatters (XLA lowers scatter-adds to a serialized loop
+      on TPU; sort + cumsum + gather are all parallel). Per aggregate:
+      exclusive-prefix-sum, then sum over a group = csum[end] -
+      csum[start]; min/max via segmented associative scan.
+
+    Every arm detects groups beyond `capacity` (n_groups > capacity) and
+    the caller retries with a bigger static capacity — one extra compile,
+    never wrong results.
+
+    key_cols: tuple of int64 arrays (dict codes / ints). agg_ops: tuple of
+    ("sum_i"|"sum_f"|"count"|"min"|"max"|"first"|"cnt_dist") aligned with
+    val_cols.
+
+    pack: optional static tuple of (bits, offset) per key when every key's
+    value range fits a known bit width (dict codes, dates). In the sort
+    arm all keys, their null flags, and the filter mask then fold into ONE
+    sort key — int32 when it fits (64-bit ALU ops are emulated pairs on
+    TPU) — giving one argsort instead of 2·n_keys+1. NULL packs as 0 (its
+    own group); filtered-out rows pack as the dtype max and sort last.
+
+    gathered: static; the caller says its inputs come out of a join's
+    gather chain in this program (see agg_arm).
+    """
+    arm = agg_arm(pack, agg_ops, gathered)
+    if arm == "dense":
+        return _agg_dense_impl(key_cols, key_nulls, val_cols, val_nulls,
+                               mask, agg_ops, capacity, pack)
+    if arm == "scatter":
         with jax.named_scope("k_agg_segment"):
             return _agg_scatter_impl(key_cols, key_nulls, val_cols,
                                      val_nulls, mask, n_keys, agg_ops,
@@ -1478,11 +1661,14 @@ def _note_trace():
 KERNEL_SCOPES = (
     "k_filter",        # scan predicates, live/padding and null masks
     "k_agg_sort",      # group-key expressions, packing, the grouping
-                       # argsort(s) and the sorted keys
+                       # argsort(s) and the sorted keys (dense arm: the
+                       # packing alone, nothing sorts)
     "k_agg_segment",   # group boundaries, prefix/segment reductions,
-                       # compaction to `capacity`
+                       # compaction to `capacity` (dense arm: the
+                       # per-bucket masked reductions)
     "k_agg_gather",    # aggregate-input expressions and their gather
-                       # through the sort permutation
+                       # through the sort permutation (dense arm: the
+                       # expressions alone, nothing is gathered)
     "k_join_build",    # in-program build side: key folding, build sort
     "k_join_probe",    # probe, expansion, the lazy per-row gather chain
     "k_topk",          # order-by/limit over the aggregate's groups
