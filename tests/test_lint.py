@@ -635,6 +635,10 @@ def device_agg(plan):
     with tracing.span("upload.h2d") as usp:
         pass
 
+def _stream_block(col_arrays, lo, hi, batch_rows):
+    with tracing.span("upload.h2d") as sp:
+        return {}
+
 def _fetch(make_tree):
     with tracing.span("fetch.d2h"):
         return make_tree()
@@ -698,7 +702,7 @@ class TestSpanChokepoints:
                       {"executor/device_exec.py": SPAN_CHOKE_BAD})
         assert sorted(f.ident for f in out) == [
             "span@_assemble_agg:host.assemble", "span@_fetch:fetch.d2h",
-            "span@device_agg:upload.h2d"]
+            "span@_stream_block:upload.h2d", "span@device_agg:upload.h2d"]
 
     def test_tree_without_the_layer_is_skipped(self):
         assert run_one("span-chokepoints",

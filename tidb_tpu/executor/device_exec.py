@@ -288,7 +288,14 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # aggregate fragments dispatched, by the arm of
                # ops/device._agg_impl that dev.agg_arm named for them
                # (scatter exists on XLA:CPU only) — note_agg_arm
-               "agg_dense": 0, "agg_sorted": 0, "agg_scatter": 0}
+               "agg_dense": 0, "agg_sorted": 0, "agg_scatter": 0,
+               # scan-aggregate fragments dispatched, by the path
+               # scan_stream_rows (or tidb_device_stream_rows) chose:
+               # device_agg over resident columns / device_agg_streaming
+               # in blocks; and the bytes the streamed blocks carried to
+               # the device, which the residency ledger never sees
+               "scan_resident": 0, "scan_streamed": 0,
+               "stream_upload_bytes": 0}
 _PIPE_LOCK = _threading.Lock()
 _PIPE_TLS = _threading.local()
 
@@ -561,6 +568,56 @@ def _agg_used_columns(plan, conds) -> set:
     return used
 
 
+#: the longest input a program that sorts may take whole.  The dense
+#: arm's programs compile in seconds at any length and keep nothing at
+#: input length.  One with an argsort and segment scans can cost the TPU
+#: compiler tens of GB of HOST memory: a `group by l_suppkey` over the
+#: resident SF10 lineitem (67,108,864-row bucket) ended its worker at
+#: the v5e host's 40 GiB (PERF.md §6, PR 27).  Beyond this bound such a
+#: scan runs in page-sized blocks, as every long input did before the
+#: choice went from rows to bytes.
+_SORTED_SCAN_MAX_ROWS = 1 << 24
+
+
+def _scan_arm(plan, chunk: Chunk, used) -> str:
+    """The arm `_agg_impl` would aggregate this scan by, planned over the
+    columns' metadata alone (nothing is uploaded)."""
+    dcols = {i: dev.meta_device_col(chunk.columns[i])[0] for i in used}
+    _kf, _km, key_pack, _vp, agg_ops, _sl = _plan_agg(plan, dcols)
+    return dev.agg_arm(key_pack, tuple(agg_ops))
+
+
+def scan_stream_rows(plan, chunk: Chunk, conds, ctx=None) -> int:
+    """Block length for a scan-aggregate when the session sets no
+    ``tidb_device_stream_rows``: 0 = the input stays resident and runs
+    `device_agg`; else `device_agg_streaming`'s block rows.  Decided by
+    `residency.scan_fits_resident` from the bytes the used columns take
+    at their row bucket against the tenant's share of the residency
+    budget; a paged input streams by pages, one that does not fit in
+    the largest power-of-two blocks (at most a page) that do, and one
+    past `_SORTED_SCAN_MAX_ROWS` whose program would sort by pages too."""
+    from ..ops import residency
+    from ..storage.paged import DEFAULT_PAGE_ROWS, chunk_is_paged
+    if chunk_is_paged(chunk):
+        return DEFAULT_PAGE_ROWS
+    residency.attach(ctx)       # the budget and the tenant of THIS session
+    used = sorted(_agg_used_columns(plan, conds))
+    cols = [chunk.columns[i] for i in used]
+    nb = dev.bucket_rows(chunk.num_rows, dev.shape_buckets(ctx))
+    if not residency.scan_fits_resident(
+            False, residency.upload_nbytes(cols, nb)):
+        fit = (residency.resident_scan_bytes()
+               // max(residency.upload_nbytes(cols, 1), 1))
+        return min(DEFAULT_PAGE_ROWS, 1 << max(fit.bit_length() - 1, 10))
+    if chunk.num_rows > _SORTED_SCAN_MAX_ROWS:
+        try:
+            if _scan_arm(plan, chunk, used) != "dense":
+                return DEFAULT_PAGE_ROWS
+        except DeviceUnsupported:
+            pass        # device_agg raises it again, to the host engine
+    return 0
+
+
 def _agg_struct_parts(plan, conds) -> list:
     """The STRUCTURAL part of a scan-agg fragment's signature (conds,
     group exprs, agg descs — everything except dictionary content).  One
@@ -642,6 +699,7 @@ def device_agg(plan, chunk: Chunk, conds, ctx=None) -> Chunk:
     est = _estimate_groups(plan, n, ctx)
     capacity = dev.next_pow2(min(n, max(est, 16)))
     note_agg_arm(key_pack, agg_ops)
+    _bump("scan_resident")
     while True:
         key = (sig_exprs, capacity, key_pack, tuple(agg_ops))
         cap = capacity
@@ -683,6 +741,27 @@ def _upload_tags(sp, mark, cols):
         from ..ops import residency
         sp.tags.update(cols=cols,
                        bytes=residency.thread_upload_bytes() - mark)
+
+
+def _stream_block(col_arrays, lo, hi, batch_rows):
+    """One block of a streamed scan on the device, under an
+    ``upload.h2d`` span: rows [lo, hi) of every used column and null
+    mask, padded to `batch_rows` so one compiled program serves every
+    block (live rows are masked by the traced n_live).  The copies are
+    enqueued, not waited for: block k+1's transfer overlaps block k's
+    program.  Nothing keeps these arrays, so their bytes count under
+    ``device_pipelines.stream_upload_bytes`` and not in the residency
+    ledger."""
+    from ..session import tracing
+    with tracing.span("upload.h2d") as sp:
+        env = {idx: (jnp.asarray(dev.pad_host(d[lo:hi], batch_rows)),
+                     jnp.asarray(dev.pad_host(nl[lo:hi], batch_rows, True)))
+               for idx, (d, nl) in col_arrays.items()}
+        nbytes = sum(a.nbytes for pair in env.values() for a in pair)
+        _bump("stream_upload_bytes", nbytes)
+        if sp is not None:
+            sp.tags.update(cols=len(env), bytes=nbytes)
+    return env
 
 
 def _fetch(make_tree):
@@ -1152,6 +1231,7 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
         # sorted kernel counts distinct value runs per group (reference:
         # the two-phase distinct agg, executor/aggregate.go partial
         # dedup + final count)
+        _bump("scan_streamed")
         return _stream_count_distinct(plan, conds, chunk, col_arrays,
                                       dcols, cond_fns, key_fns, key_meta,
                                       key_pack, val_plan, slots,
@@ -1162,6 +1242,7 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
         raise DeviceUnsupported("non-mergeable agg in streamed pipeline")
     merge_ops = tuple(_MERGE_OPS[op] for op in agg_ops)
     sig_exprs, dict_refs = _agg_sig(plan, conds, dcols)
+    _bump("scan_streamed")
     if _want_host_tail(key_pack, batch_rows):
         return _stream_agg_host_tail(
             plan, chunk, conds, batch_rows, ctx, col_arrays, dcols,
@@ -1189,22 +1270,13 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
         overflow = False
         for lo in range(0, n, batch_rows):
             hi = min(lo + batch_rows, n)
-            # the asarray calls enqueue this block's host→HBM copies; the
-            # kernel dispatch below is async, so block k+1's transfer
-            # overlaps block k's compute. Every block — the tail included —
-            # pads to the SAME batch_rows shape (live rows masked by the
-            # traced n_live), so one compiled program serves the whole
-            # stream at any input size
-            env = {idx: (jnp.asarray(dev.pad_host(d[lo:hi], batch_rows)),
-                         jnp.asarray(dev.pad_host(nl[lo:hi], batch_rows,
-                                                  True)))
-                   for idx, (d, nl) in col_arrays.items()}
+            env = _stream_block(col_arrays, lo, hi, batch_rows)
             buffered.append(fn(env, np.int64(hi - lo)))
             if len(buffered) >= k_flush:
                 # incremental fold: HBM holds at most k_flush partials +
                 # the running state, never all n/batch_rows of them
                 ngs = [int(g) for g in
-                       jax.device_get([p[4] for p in buffered])]
+                       _fetch(lambda: [p[4] for p in buffered])]
                 max_ng = max(max_ng, *ngs)
                 if max_ng > capacity:
                     overflow = True
@@ -1214,7 +1286,7 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
                     merge_ops, key_pack)
                 buffered = []
         if not overflow and buffered:
-            ngs = [int(g) for g in jax.device_get([p[4] for p in buffered])]
+            ngs = [int(g) for g in _fetch(lambda: [p[4] for p in buffered])]
             max_ng = max(max_ng, *ngs)
             if max_ng <= capacity:
                 state, merge_cap = merge_partial_states(
@@ -1229,7 +1301,7 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
         raise DeviceUnsupported("streamed agg capacity did not converge")
     if state is None:
         raise DeviceUnsupported("empty streamed input")
-    out = jax.device_get(state[:5])
+    out = _fetch(lambda: state[:5])
     key_out, key_null_out, results, result_nulls, n_groups = out
     ng = int(n_groups)
     if ng == 0 and not plan.group_exprs:
@@ -1277,10 +1349,8 @@ def _stream_agg_host_tail(plan, chunk, conds, batch_rows, ctx, col_arrays,
     states = []
     for lo in range(0, n, batch_rows):
         hi = min(lo + batch_rows, n)
-        env = {idx: (jnp.asarray(dev.pad_host(d[lo:hi], batch_rows)),
-                     jnp.asarray(dev.pad_host(nl[lo:hi], batch_rows, True)))
-               for idx, (d, nl) in col_arrays.items()}
-        raw = fn(env, np.int64(hi - lo))
+        raw = fn(_stream_block(col_arrays, lo, hi, batch_rows),
+                 np.int64(hi - lo))
         page = page_singleton_state(raw[0], raw[1], raw[2], raw[3],
                                     raw[4], agg_ops)
         state, _cap = _merge_states_host([page], 16, n_keys, nvals,
@@ -1291,7 +1361,7 @@ def _stream_agg_host_tail(plan, chunk, conds, batch_rows, ctx, col_arrays,
     state, _cap = (_merge_states_host(states, 16, n_keys, nvals,
                                       merge_ops, key_pack)
                    if len(states) > 1 else (states[0], 0))
-    out = jax.device_get(state[:5])
+    out = _fetch(lambda: state[:5])
     key_out, key_null_out, results, result_nulls, n_groups = out
     ng = int(n_groups)
     if ng == 0 and not plan.group_exprs:
@@ -1353,12 +1423,10 @@ def _stream_count_distinct(plan, conds, chunk, col_arrays, dcols, cond_fns,
         partials = []
         for lo in range(0, n, batch_rows):
             hi = min(lo + batch_rows, n)
-            env = {idx: (jnp.asarray(dev.pad_host(d[lo:hi], batch_rows)),
-                         jnp.asarray(dev.pad_host(nl[lo:hi], batch_rows,
-                                                  True)))
-                   for idx, (d, nl) in col_arrays.items()}
-            partials.append(fn(env, np.int64(hi - lo)))
-        counts = [int(c) for c in jax.device_get([p[4] for p in partials])]
+            partials.append(fn(_stream_block(col_arrays, lo, hi,
+                                             batch_rows),
+                               np.int64(hi - lo)))
+        counts = [int(c) for c in _fetch(lambda: [p[4] for p in partials])]
         if max(counts) <= capacity:
             break
         capacity = dev.next_pow2(max(counts))
@@ -1384,7 +1452,7 @@ def _stream_count_distinct(plan, conds, chunk, col_arrays, dcols, cond_fns,
     total = int(mask.shape[0])
     final_cap = dev.next_pow2(max(est, 16))
     while True:
-        out = jax.device_get(dev._agg_impl(
+        out = _fetch(lambda: dev._agg_impl(
             key_cat, key_null_cat, val_cat, val_null_cat, mask,
             n_keys=n_keys, agg_ops=("cnt_dist",),
             capacity=min(final_cap, dev.next_pow2(total)), pack=key_pack))
@@ -1432,7 +1500,7 @@ def merge_partial_states(state, parts, merge_cap, n_keys, nvals, merge_ops,
         out = dev._agg_impl(key_cat, key_null_cat, val_cat, val_null_cat,
                             mask, n_keys=n_keys, agg_ops=merge_ops,
                             capacity=merge_cap, pack=key_pack)
-        ng = int(jax.device_get(out[4]))
+        ng = int(_fetch(lambda: out[4]))
         if ng <= merge_cap:
             return out, merge_cap
         merge_cap = dev.next_pow2(ng)

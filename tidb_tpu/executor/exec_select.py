@@ -549,7 +549,7 @@ class HashAggExec(QueryExecutor):
         # MPP: the same fused fragment, SPMD over the session's device mesh
         # (partition-parallel partial agg / broadcast join + collectives)
         from .mpp_exec import mpp_mesh, mpp_agg, mpp_join_agg
-        from ..storage.paged import chunk_is_paged, DEFAULT_PAGE_ROWS
+        from ..storage.paged import chunk_is_paged
         mesh = mpp_mesh(self.ctx)
         if mesh is not None and raw is not None and chunk_is_paged(raw):
             # paged scans ARE mesh-legal within the residency budget now
@@ -604,16 +604,12 @@ class HashAggExec(QueryExecutor):
                 batch = 0
             paged_in = chunk_is_paged(raw)
             if batch == 0:
-                # auto: a paged (disk-resident) input MUST stream — its
-                # columns exceed what one transfer (or one chip's HBM)
-                # should hold; very large RAM-resident inputs stream too,
-                # bounding HBM by the page size instead of the table.
-                # batch=-1 opts resident inputs out of auto-streaming
-                # (debug/bench escape hatch); paged inputs always stream.
-                if paged_in or raw.num_rows > 4 * DEFAULT_PAGE_ROWS:
-                    batch = DEFAULT_PAGE_ROWS
-            elif batch < 0:
-                batch = DEFAULT_PAGE_ROWS if paged_in else 0
+                # auto: the input stays resident in HBM when its used
+                # columns and the program's working set fit the residency
+                # budget; a paged (disk-resident) input, or one that does
+                # not fit, streams in blocks of at most a page
+                from .device_exec import scan_stream_rows
+                batch = scan_stream_rows(eff_p, raw, conds, self.ctx)
             if batch > 0 and (paged_in or raw.num_rows > batch):
                 from .device_exec import device_agg_streaming
                 try:

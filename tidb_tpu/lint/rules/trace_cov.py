@@ -52,6 +52,7 @@ DEGRADE_EXCEPTIONS = ("DeviceUnsupported",)
 #: metric reading an empty span, not failing.
 SPAN_CHOKEPOINTS = {
     "executor/device_exec.py": {"device_agg": "upload.h2d",
+                                "_stream_block": "upload.h2d",
                                 "_fetch": "fetch.d2h",
                                 "_assemble_agg": "host.assemble"},
     "executor/device_join.py": {"device_join_agg": "upload.h2d"},

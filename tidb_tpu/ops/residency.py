@@ -336,6 +336,51 @@ def effective_budget() -> int:
     return override if override > 0 else _auto_budget()
 
 
+# -- resident or streamed ----------------------------------------------------
+
+#: what a resident scan-aggregate needs on the device beside its input
+#: columns, as a multiple of their bytes.  Read from the allocator's
+#: peak on a v5e at TPC-H SF10 (PERF.md §6, PR 27): the dense arm's
+#: programs keep nothing at input length, Q1 peaks at 1.002x its seven
+#: columns and Q6 at 1.0007x its four.  A quarter leaves a hundred times
+#: that; programs that sort do not run over inputs long enough for their
+#: buffers to matter (device_exec._SORTED_SCAN_MAX_ROWS).
+SCAN_WORKING_SET = 0.25
+
+
+def upload_nbytes(cols, rows: int) -> int:
+    """Bytes `ops/device.to_device_col` places for `cols` padded to `rows`:
+    the data at its host width (int32 codes for a dictionary-encoded
+    column) plus the one-byte null mask."""
+    return rows * sum((4 if c.is_object() else c.data.dtype.itemsize) + 1
+                      for c in cols)
+
+
+def resident_scan_bytes(budget: "int | None" = None) -> int:
+    """The most bytes of input columns a resident scan may take so that
+    they and its working set fit `budget` (the calling tenant's share of
+    ``tidb_device_mem_budget`` when None); 0 = unlimited."""
+    if budget is None:
+        budget = group_share()
+    return max(int(budget / (1.0 + SCAN_WORKING_SET)), 1) if budget > 0 else 0
+
+
+def scan_fits_resident(paged: bool, col_bytes: int,
+                       budget: "int | None" = None) -> bool:
+    """Whether a scan's input stays resident (one program over whole
+    columns cached through this ledger) or streams in blocks that nothing
+    keeps.  A paged input always streams: its columns are on disk
+    because they exceed what the host should hold.  An in-memory one
+    stays resident when its used columns (`col_bytes`, at their row
+    bucket) and the program's working set fit `budget`.  The ledger's
+    LRU makes the room; what does not fit the whole budget cannot be
+    made to."""
+    if paged:
+        return False
+    cap = resident_scan_bytes(budget)
+    return cap == 0 or col_bytes <= cap
+
+
 # -- the cache protocol (ops/device.to_device_col) ---------------------------
 
 def lookup(col, want_rows: int):

@@ -143,7 +143,9 @@ for _v in [
     SysVar("tidb_mesh_shape", SCOPE_BOTH, "1", "str"),
     # streamed device pipeline batch bound: bounds HBM + transfer memory
     # for larger-than-memory inputs at the cost of re-transfer per run
-    # (0 = off: whole-table transfers, HBM-resident column cache)
+    # (0 = auto: an in-memory input whose used columns and working set
+    # fit the residency budget stays HBM-resident, whole-table; a paged
+    # one, or one that does not fit, streams — device_exec.scan_stream_rows)
     SysVar("tidb_device_stream_rows", SCOPE_BOTH, "0", "int", 0),
     # shape-canonicalization granularity: geometric row buckets per
     # doubling that device uploads pad to (ops/device.py bucket_rows) so

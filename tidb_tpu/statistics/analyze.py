@@ -18,36 +18,120 @@ TOPN_SIZE = 8
 CM_DEPTH = 4
 CM_WIDTH = 512
 
-def _cm_indices(key) -> list[int]:
-    """One 128-bit hash per value; the depth row indices derive from its
-    halves (reference: cmsketch.go hashes once with murmur128 and mixes
-    h1 + i*h2). Numeric keys canonicalize so int 2 and float 2.0 collide
-    deliberately — query constants may arrive as either type."""
-    if isinstance(key, float) and key.is_integer():
-        key = int(key)
-    if isinstance(key, float):
-        key = key.hex()
-    import hashlib
-    digest = hashlib.blake2b(str(key).encode(), digest_size=16).digest()
-    h1 = int.from_bytes(digest[:8], "little")
-    h2 = int.from_bytes(digest[8:], "little") | 1
-    return [((h1 + d * h2) & 0xFFFFFFFFFFFFFFFF) % CM_WIDTH
-            for d in range(CM_DEPTH)]
+#: version of the sketch's hash, stored in every sketch: a blob built
+#: under another hash (the blake2b sketches of version 1 were bare
+#: depth x width lists) answers 0, and the caller estimates from the NDV
+CM_VERSION = 2
+
+_U64 = np.uint64
+_INT64_LO, _INT64_HI = float(-2 ** 63), float(2 ** 63)
 
 
-def build_cmsketch(values, counts) -> list[list[int]]:
+def _mix64(x):
+    """splitmix64's finalizer over a uint64 array (wraps modulo 2**64)."""
+    x = (x ^ (x >> _U64(30))) * _U64(0xBF58476D1CE4E5B9)
+    x = (x ^ (x >> _U64(27))) * _U64(0x94D049BB133111EB)
+    return x ^ (x >> _U64(31))
+
+
+def _hash_numbers(vals) -> np.ndarray:
+    """uint64 hash per number. A float with an integral value hashes as
+    that integer, so int 2 and float 2.0 collide deliberately: query
+    constants may arrive as either type."""
+    vals = np.asarray(vals)
+    if vals.dtype.kind == "f":
+        vals = vals.astype(np.float64)
+        whole = ((vals == np.floor(vals)) & (vals >= _INT64_LO)
+                 & (vals < _INT64_HI))
+        bits = np.where(whole,
+                        np.where(whole, vals, 0).astype(np.int64)
+                        .view(_U64),
+                        vals.view(_U64) ^ _U64(0x9E3779B97F4A7C15))
+    else:
+        bits = vals.astype(np.int64).view(_U64)
+    return _mix64(bits + _U64(0x9E3779B97F4A7C15))
+
+
+def _hash_bytes(vals) -> np.ndarray:
+    """uint64 hash per byte string, one pass of array operations per
+    eight bytes of the longest value. All-zero words add nothing, so the
+    hash does not depend on how far the others pad a value."""
+    fixed = np.asarray(vals, dtype=np.bytes_)
+    n, width = fixed.shape[0], max(fixed.dtype.itemsize, 1)
+    words = -(-width // 8)
+    buf = np.zeros((n, words * 8), dtype=np.uint8)
+    buf[:, :width] = fixed.view(np.uint8).reshape(n, width)
+    buf = buf.view("<u8")
+    acc = np.zeros(n, dtype=_U64)
+    for j in range(words):
+        w = buf[:, j]
+        acc += np.where(w != 0, _mix64(w ^ _U64((j + 1) * 0xD6E8FEB86659FD93
+                                                & 0xFFFFFFFFFFFFFFFF)),
+                        _U64(0))
+    return _mix64(acc ^ _U64(0xA0761D6478BD642F))
+
+
+def _cm_hash(values) -> np.ndarray:
+    """uint64 hash of every value of a column's (or a query's) keys:
+    numeric arrays and all-bytes object arrays hash as arrays; any other
+    object array (wide decimals held as Python ints, TopN keys read
+    back as str) goes value by value through the same two hashes."""
+    vals = np.asarray(values)
+    if vals.dtype.kind in "iufb":
+        return _hash_numbers(vals)
+    if vals.dtype.kind == "S":
+        return _hash_bytes(vals)
+    flat = vals.ravel()
+    if all(isinstance(v, (bytes, bytearray)) for v in flat):
+        return _hash_bytes(flat)
+    out = np.empty(len(flat), dtype=_U64)
+    for i, v in enumerate(flat):
+        if isinstance(v, (bool, np.bool_)):
+            v = int(v)
+        if isinstance(v, (int, np.integer)) and -2 ** 63 <= v < 2 ** 63 \
+                or isinstance(v, (float, np.floating)):
+            out[i] = _hash_numbers(np.array([v]))[0]
+        else:
+            out[i] = _hash_bytes([
+                bytes(v) if isinstance(v, (bytes, bytearray))
+                else str(v).encode("utf-8", "surrogateescape")])[0]
+    return out
+
+
+def _cm_indices(values) -> list:
+    """Per sketch row, the column index of every value: one 64-bit hash
+    per value, the rows derive from two mixes of it (reference:
+    cmsketch.go hashes once with murmur128 and mixes h1 + i*h2). The high
+    half of each row's hash is scaled into [0, CM_WIDTH) by multiply and
+    shift; a 64-bit modulo costs ten times the hash."""
+    h = _cm_hash(values)
+    step = _mix64(h ^ _U64(0xE7037ED1A0B428DB)) | _U64(1)
+    out = []
+    for _d in range(CM_DEPTH):
+        out.append((((h >> _U64(32)) * _U64(CM_WIDTH)) >> _U64(32))
+                   .astype(np.int64))
+        h = h + step
+    return out
+
+
+def build_cmsketch(values, counts) -> dict:
     """Count-min sketch over (distinct value, count) pairs (reference:
     statistics/cmsketch.go:46): depth×width counters; lookup takes the
     min across rows — an overestimate, never an underestimate."""
-    rows = [[0] * CM_WIDTH for _ in range(CM_DEPTH)]
-    for v, c in zip(values, counts):
-        for d, idx in enumerate(_cm_indices(_val_key(v))):
-            rows[d][idx] += int(c)
-    return rows
+    idx = _cm_indices(values)
+    weights = np.asarray(counts, dtype=np.float64)
+    rows = [np.bincount(idx[d], weights=weights, minlength=CM_WIDTH)
+            .astype(np.int64).tolist() for d in range(CM_DEPTH)]
+    return {"v": CM_VERSION, "rows": rows}
 
 
-def cm_query(cm: list[list[int]], key) -> int:
-    return min(row[idx] for row, idx in zip(cm, _cm_indices(key)))
+def cm_query(cm, key) -> int:
+    """Point estimate of `key`; 0 (no estimate: the caller falls back to
+    the NDV average) for a sketch built under another CM_VERSION."""
+    if not isinstance(cm, dict) or cm.get("v") != CM_VERSION:
+        return 0
+    idx = _cm_indices(np.array([key], dtype=object))
+    return min(row[int(i[0])] for row, i in zip(cm["rows"], idx))
 
 
 def _val_key(v):
@@ -59,14 +143,39 @@ def _val_key(v):
     return float(v)
 
 
+def _distinct_counts(col, nn):
+    """(sorted distinct non-null values, their counts), as
+    ``np.unique(col.data[nn], return_counts=True)`` gives them (`nn`:
+    the non-null rows, None when every row is one). A string
+    column is counted from its dictionary codes (cached on the column;
+    bulk loaders install them) and never sorted as Python objects; an
+    integer column whose values span little more than its rows is
+    counted by ``np.bincount`` instead of a sort."""
+    if col.is_object():
+        codes, uniques = col.dict_encode()
+        codes = np.asarray(codes)
+        counts = np.bincount(codes if nn is None else codes[nn],
+                             minlength=len(uniques))
+        seen = counts > 0
+        return np.asarray(uniques, dtype=object)[seen], counts[seen]
+    data = col.data if nn is None else col.data[nn]
+    if data.dtype.kind in "iu" and len(data):
+        lo, hi = int(data.min()), int(data.max())
+        if hi - lo < max(2 * len(data), 1 << 16):
+            counts = np.bincount((data - data.dtype.type(lo))
+                                 .astype(np.int64, copy=False))
+            seen = np.flatnonzero(counts)
+            return (seen + lo).astype(data.dtype), counts[seen]
+    return np.unique(data, return_counts=True)
+
+
 def _column_stats(col):
-    nn = ~col.nulls
-    data = col.data[nn]
-    cs = {"null_count": int(col.nulls.sum())}
-    if not len(data):
+    n_null = int(col.nulls.sum())
+    cs = {"null_count": n_null}
+    if n_null == len(col.nulls):
         cs["ndv"] = 0
         return cs
-    uniques, counts = np.unique(data, return_counts=True)
+    uniques, counts = _distinct_counts(col, ~col.nulls if n_null else None)
     cs["ndv"] = int(len(uniques))
     # TopN: exact counts for the most frequent values
     k = min(TOPN_SIZE, len(uniques))
@@ -75,22 +184,26 @@ def _column_stats(col):
     cs["topn"] = [[_val_key(uniques[i]), int(counts[i])] for i in top]
     # CMSketch over the non-TopN remainder: point estimates for values the
     # TopN missed (reference: cmsketch.go TopN+CMSketch split)
-    top_set = set(top.tolist())
-    rest = [i for i in range(len(uniques)) if i not in top_set]
-    if rest:
+    rest = np.ones(len(uniques), dtype=bool)
+    rest[top] = False
+    if rest.any():
         cs["cmsketch"] = build_cmsketch(uniques[rest], counts[rest])
-    if data.dtype != object:
-        vals = data.astype(np.float64)
-        cs["min"] = float(vals.min())
-        cs["max"] = float(vals.max())
-        # equal-depth histogram over the sorted column: bucket upper
-        # bounds at quantile positions + cumulative counts
+    if uniques.dtype != object:
+        vals = uniques.astype(np.float64)
+        cs["min"] = float(vals[0])
+        cs["max"] = float(vals[-1])
+        # equal-depth histogram over the sorted column, read off the
+        # distinct values and their running counts: bucket upper bounds
+        # at quantile positions + cumulative counts
         nb = min(HIST_BUCKETS, len(uniques))
         if nb >= 2:
-            sv = np.sort(vals)
-            pos = ((np.arange(1, nb + 1) * len(sv)) // nb) - 1
-            bounds = sv[pos]
-            cum = np.searchsorted(sv, bounds, side="right")
+            running = np.cumsum(counts)
+            total = int(running[-1])
+            pos = ((np.arange(1, nb + 1) * total) // nb) - 1
+            bounds = vals[np.searchsorted(running, pos, side="right")]
+            # two integers past 2**53 may share one float: the count runs
+            # to the last distinct value that equals the bound
+            cum = running[np.searchsorted(vals, bounds, side="right") - 1]
             cs["hist"] = {"bounds": [float(b) for b in bounds],
                           "cum": [int(c) for c in cum]}
     return cs
