@@ -39,8 +39,21 @@ class TestQuantizedPacks:
         mn, span = base.packs[0]
         widened = build_join_index((_col(list(range(2, 101)) + [mn + span - 1]),))
         assert widened.packs == base.packs
-        assert widened.kind == base.kind
-        assert widened.starts.shape == base.starts.shape
+        assert widened.kind == base.kind == "dense"
+        # a unique dense build is a slot table over the quantized span
+        assert widened.slots.shape == base.slots.shape == (span,)
+        assert widened.sig() == base.sig()
+
+    def test_non_unique_csr_shape_stable_across_within_slack_delta(self):
+        base = build_join_index((_col(list(range(1, 101)) * 2),))
+        mn, span = base.packs[0]
+        widened = build_join_index(
+            (_col(list(range(2, 101)) * 2 + [1, mn + span - 1]),))
+        assert widened.packs == base.packs
+        assert widened.kind == base.kind == "dense"
+        assert not base.unique and base.slots is None
+        assert widened.starts.shape == base.starts.shape == (span + 1,)
+        assert widened.sig() == base.sig()
 
     def test_slack_region_matches_nothing(self):
         """Correctness under slack: probe keys inside the widened-but-
@@ -48,11 +61,14 @@ class TestQuantizedPacks:
         idx = build_join_index((_col([10, 20, 30]),))
         mn, span = idx.packs[0]
         assert mn <= 10 and mn + span - 1 >= 30
-        # dense CSR: counts are zero for every slack slot
-        if idx.kind == "dense":
-            starts = np.asarray(idx.starts)
-            counts = np.diff(starts)
-            assert counts.sum() == 3  # only the real keys hold rows
+        # slot table: every slack slot (and every gap) reads "absent"
+        assert idx.kind == "dense" and idx.unique
+        assert np.count_nonzero(idx.slots >= 0) == 3
+        assert sorted(idx.slots[idx.slots >= 0]) == [0, 1, 2]
+        # CSR (non-unique build): counts are zero for every slack slot
+        dup = build_join_index((_col([10, 20, 20, 30]),))
+        assert dup.kind == "dense" and not dup.unique
+        assert np.diff(np.asarray(dup.starts)).sum() == 4
 
 
 class TestZeroCompileDelta:

@@ -289,6 +289,11 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # ops/device._agg_impl that dev.agg_arm named for them
                # (scatter exists on XLA:CPU only) — note_agg_arm
                "agg_dense": 0, "agg_sorted": 0, "agg_scatter": 0,
+               # host-indexed joins of dispatched join fragments, by how
+               # a probe key finds its build rows: by address (a `dense`
+               # JoinIndex) or by binary search (`sorted`) —
+               # note_join_layouts
+               "join_direct": 0, "join_search": 0,
                # scan-aggregate fragments dispatched, by the path
                # scan_stream_rows (or tidb_device_stream_rows) chose:
                # device_agg over resident columns / device_agg_streaming
@@ -326,7 +331,8 @@ def _tls_stats() -> dict:
                                 "mode_cached": 0, "mode_prewarmed": 0,
                                 "mode_async_pending": 0, "mode_sync": 0,
                                 "agg_dense": 0, "agg_sorted": 0,
-                                "agg_scatter": 0}
+                                "agg_scatter": 0, "join_direct": 0,
+                                "join_search": 0}
     return st
 
 
@@ -352,6 +358,18 @@ def note_agg_arm(pack, agg_ops, gathered=False):
     EXPLAIN ANALYZE's ``agg:`` annotation and the benchmark's
     ``agg.dense_share`` read the counters."""
     _bump(AGG_ARM_STATS[dev.agg_arm(pack, tuple(agg_ops), gathered)])
+
+
+def note_join_layouts(strategies):
+    """Count the host-indexed joins of one dispatched join fragment (its
+    strategy snapshot: ``_JoinNode.strategy`` per join) by the layout of
+    their index; EXPLAIN ANALYZE's ``join:`` annotation and the
+    benchmark's ``join.direct_share`` read the counters.  Joins built
+    inside the program (no index) count under neither."""
+    for st in strategies:
+        if st is not None and st[2] is not None:
+            _bump("join_direct" if st[2].kind == "dense"
+                  else "join_search")
 
 
 def pipe_cache_stats(thread_local: bool = False) -> dict:
@@ -735,8 +753,8 @@ def _upload_mark(sp):
 def _upload_tags(sp, mark, cols):
     """Close an ``upload.h2d`` span's account: the columns it placed and
     the bytes of them that were not resident already (the residency
-    ledger's publishes on this thread; a join index's own arrays are
-    off the ledger and not counted)."""
+    ledger's publishes on this thread, a join index's arrays among
+    them)."""
     if sp is not None:
         from ..ops import residency
         sp.tags.update(cols=cols,

@@ -6,10 +6,13 @@ compiles into ONE jitted XLA program over HBM-resident base tables:
 
 - joins whose build side is a base-table leaf use HOST-BUILT indexes
   (executor/join_index.py): the ordering work runs once per table version
-  in numpy and the compiled program only gathers / binary-searches. A
+  in numpy and the compiled program only gathers: a probe key addresses
+  a table over the key span directly whenever that table's bytes are
+  affordable, and binary-searches the host-sorted keys only when they
+  are not (a `sorted` build: log2(n) dependent gathers a probe row). A
   UNIQUE build side (every TPC-H fact⋈dim join) adds nothing to the
-  output shape — the join is a gather with the probe side's exact
-  capacity, no expansion pass and no overflow retry at all.
+  output shape — the join is one gather of a row id with the probe
+  side's exact capacity, no expansion pass and no overflow retry at all.
 - non-unique indexed builds expand through a static-capacity CSR walk
   (cnt → cumsum → searchsorted), still sort-free on device.
 - joins outside the index language (bushy subtrees, computed keys) fall
@@ -51,7 +54,8 @@ from ..ops import device as dev
 from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
-    _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm)
+    _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm,
+    note_join_layouts)
 from .join_index import build_join_index
 
 
@@ -478,15 +482,7 @@ def _strategy_sig(jn):
     if st is None:
         return f"S{jn.pos}:-"
     kind, side, idx = st
-    # n_valid is a TRACED runtime input (it rides in jidx next to the
-    # lookup arrays) and the arrays pad to geometric buckets, so the
-    # signature carries only the BUCKETED shape identity (rows_len +
-    # dtype) and the structural unique flag — a within-bucket build-side
-    # INSERT rebuilds the cheap numpy index and reuses the compiled
-    # program with zero new XLA compiles (the last recompile trigger,
-    # ROADMAP item 1)
-    return (f"S{jn.pos}:{kind}/{side}/{idx.kind}/{idx.packs}/"
-            f"{int(idx.unique)}/{idx.rows_len}/{idx.rows.dtype}")
+    return f"S{jn.pos}:{kind}/{side}/{idx.sig()}"
 
 
 #: learned exact sizes per fragment: (sig, join_pos) → last observed match
@@ -758,7 +754,11 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
             # without retracing); every bound derived from it is traced
             a0, a1, nv = jidx[node.pos]
             safe_hi = jnp.maximum(nv - 1, 0)
-            if idx.kind == "dense":
+            if idx.slots is not None:
+                # unique dense build: the slot holds the row id, or -1
+                slot = a0[jnp.clip(key, 0, idx.span - 1)].astype(jnp.int64)
+                cnt = jnp.where(ok & (slot >= 0), 1, 0)
+            elif idx.kind == "dense":
                 k_c = jnp.clip(key, 0, idx.span - 1)
                 pos0 = a0[k_c].astype(jnp.int64)
                 cnt = jnp.where(ok, (a0[k_c + 1] - a0[k_c]).astype(jnp.int64),
@@ -782,8 +782,19 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                 return dict(pidx_map), valid, dict(pnull)
 
             if kind == "uniq":
-                bi = a1[jnp.clip(pos0, 0, safe_hi)].astype(jnp.int64)
-                hit = (cnt > 0) & bvalid[bi]
+                if idx.slots is not None:
+                    bi = jnp.maximum(slot, 0)
+                else:
+                    bi = a1[jnp.clip(pos0, 0, safe_hi)].astype(jnp.int64)
+                bside = node.right if side == "right" else node.left
+                # a slot table built under the leaf's whole filter (or
+                # over a leaf without one) reads -1 for every row the
+                # build mask removes; any other index may list rows the
+                # mask does not keep
+                slots_hold_mask = (
+                    idx.slots is not None and isinstance(bside, _Leaf)
+                    and (idx.filtered or not bside.conds))
+                hit = cnt > 0 if slots_hold_mask else (cnt > 0) & bvalid[bi]
                 if node._oc_fns and jkind == "left":
                     # ON-clause residuals are part of the MATCH for outer
                     # joins — evaluate on the joined candidate row first
@@ -1158,6 +1169,7 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     import time as _time
     _dbg = _os.environ.get("TIDB_TPU_DEBUG_JOIN")
     note_agg_arm(key_pack, agg_ops, gathered=True)
+    note_join_layouts(jn.strategy for jn in joins)
     for _attempt in range(12):
         caps = [jn.cap for jn in joins]
         key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
@@ -1505,6 +1517,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
 
     for jn in joins:
         jn.cap = page_rows  # every join is a probe-shaped gather
+    note_join_layouts(jn.strategy for jn in joins)
     from .device_exec import _want_host_tail
     if _want_host_tail(key_pack, page_rows):
         # raw-tail path: XLA keeps the fused scan->gather-join->expression

@@ -100,6 +100,13 @@ class QueryExecutor:
             self.annotate(agg=next(
                 (arm for arm, k in AGG_ARM_STATS.items()
                  if st1[k] - st0[k] > 0), None))
+            # how the fragment's host-indexed joins look a key up
+            # (device_exec.note_join_layouts): join:direct x5
+            layouts = [(name, st1[k] - st0[k])
+                       for name, k in (("direct", "join_direct"),
+                                       ("search", "join_search"))]
+            self.annotate(join="+".join(
+                f"{name} x{n}" for name, n in layouts if n > 0) or None)
             from .supervisor import abandoned_calls
             n_abandoned = abandoned_calls()
             if n_abandoned:

@@ -311,6 +311,9 @@ class _RowSet:
 def _host_lookup_uniq(idx: JoinIndex, key: np.ndarray, ok: np.ndarray):
     """numpy mirror of the compiled fragment's unique-index probe
     (device_join.eval_indexed, 'uniq' path): (hit, build_row)."""
+    if idx.slots is not None:
+        slot = idx.slots[np.clip(key, 0, idx.span - 1)].astype(np.int64)
+        return ok & (slot >= 0), np.maximum(slot, 0)
     if idx.kind == "dense":
         k = np.clip(key, 0, idx.span - 1)
         pos0 = idx.starts[k].astype(np.int64)

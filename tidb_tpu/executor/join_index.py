@@ -11,15 +11,29 @@ numpy, and is cached on the Column exactly like the HBM upload
 (utils/chunk.py Column._device). The device-side lookup degenerates to
 gathers and searchsorteds — no sort in the compiled program at all.
 
-Two layouts:
-- ``dense`` — CSR over the key span (``starts`` of size span+1, ``rows``
-  listing valid row ids in key order). Applies when the packed key span
-  is within a small factor of the row count: TPC-H keys are dense
-  1..N, so every PK/FK join takes this path. Lookup = 2 gathers.
-- ``sorted`` — row ids argsorted by packed key + the sorted key array.
-  Applies to sparse/composite keys (e.g. partsupp's (partkey, suppkey)
-  whose packed span is ~nb²). Lookup = binary search into the
-  host-sorted array.
+Two layouts, told apart by how a probe key finds its build rows:
+- ``dense``: the key IS the address. A table over the packed key span,
+  read with one gather per probe row and no search. A UNIQUE build
+  side's table holds the row id itself, or -1 where no row has that key
+  (a gap, a NULL key, a row the pushed-down filter removed, the
+  quantized slack): ``slots``, one gather a lookup. A non-unique one is
+  CSR (``starts`` of span + 1 entries, ``rows`` listing row ids in key
+  order): two gathers and an expansion.
+- ``sorted``: row ids argsorted by packed key + the sorted key array;
+  a lookup is a binary search, ceil(log2(n)) dependent gathers per probe
+  row, each an emulated 64-bit compare on the TPU.
+
+The layout is chosen from the table's BYTES (`direct_table_fits`): the
+table is direct-addressed when it fits the device budget the residency
+ledger reports and stays under `_DIRECT_MAX_BYTES`; the uploaded arrays
+enter that ledger (`JoinIndex.device_arrays`). Rows do not enter the
+rule: TPC-H's keys are NOT dense 1..N (cl. 4.2.3: ``o_orderkey`` uses 8
+of every 32 values, so its span is 4.4x its rows, 24 MB of table at SF1
+and 243 MB at SF10; Q5's (``c_nationkey``, ``c_custkey``) pair spans 26x
+its rows in 16 MB), and a search at fact length cost the chip 18-20
+dependent gathers where an address costs one (PERF.md §6, PR 28). What stays
+``sorted`` is a composite key space like partsupp's (``ps_partkey``,
+``ps_suppkey``): 2e9 slots, 8 GB at SF1.
 
 Either layout knows whether the (non-null) build keys are UNIQUE. A
 unique build side makes the join output shape the PROBE side's shape —
@@ -34,8 +48,8 @@ Multi-column keys fold into one int64 by range packing with host-known
 Version tolerance (ROADMAP "version-tolerant pack"): the per-column
 (min, span) is QUANTIZED to a geometric grid (`_quantize_range`) instead
 of being exact.  The packs are baked into compiled-fragment signatures
-and dense-CSR array shapes (`device_join._strategy_sig`,
-`JoinIndex.starts`), so with exact bounds ANY dimension-table delta that
+and the dense tables' shapes (`JoinIndex.sig`, `JoinIndex.slots` /
+`starts`), so with exact bounds ANY dimension-table delta that
 nudged a key's min/max — one UPDATE widening a range by 1 — changed the
 signature and forced a full XLA recompile.  With ~1/16-of-magnitude
 slack on each end, a delta that stays inside the widened range rebuilds
@@ -52,6 +66,8 @@ runtime arguments — never baked into the compiled program.  A build-side
 INSERT that stays inside the bucket (and inside the quantized pack
 range) rebuilds only this cheap numpy index: same array shapes, same
 fragment signature, same compiled executable, zero new XLA compiles.
+A unique dense table has no such array at all: its length is the
+quantized span, and a new row lands in a slot that read -1.
 Padding is inert by construction: ``rows`` pads with 0 (only reachable
 behind a ``cnt`` guard that is 0 there) and ``sorted_keys`` pads with
 int64 max (sorts after every real key, so probe searchsorted results
@@ -65,10 +81,11 @@ import numpy as np
 
 from ..ops.device import bucket_rows
 
-#: dense CSR is worth it while the span stays within this factor of the
-#: row count (beyond that the starts array dwarfs the table)
-_DENSE_SLACK = 4
-_DENSE_FLOOR = 65536
+#: the most bytes one direct-address table may take, whatever the device
+#: holds: 6% of a v5e's HBM.  It is what decides on the in-process CPU
+#: backend, whose budget is unlimited, so that tests take the side the
+#: chip takes; on a 16 GB chip it decides too (the budget admits 12.8 GB)
+_DIRECT_MAX_BYTES = 1 << 30
 
 #: pack quantization: grid = 2^(bit_length(span)-1-SLACK_BITS) ≈ span/16
 #: (min floors to the grid, max ceils) — ≤ ~12.5% span overshoot buys
@@ -86,29 +103,65 @@ def _quantize_range(mn: int, mx: int) -> tuple[int, int]:
     return mn_q, mx_q
 
 
+def direct_table_fits(table_bytes: int) -> bool:
+    """Whether a direct-address table of `table_bytes` is affordable: it
+    stays resident beside the columns, so it has to fit the device budget
+    as a resident scan's input does (`residency.scan_fits_resident`,
+    against the whole budget: the index is cached per table version and
+    serves every tenant), and under `_DIRECT_MAX_BYTES`."""
+    from ..ops import residency
+    return (table_bytes <= _DIRECT_MAX_BYTES
+            and residency.scan_fits_resident(
+                False, table_bytes, residency.effective_budget()))
+
+
 class JoinIndex:
     """Host index over one ordered key-column tuple of a base chunk."""
 
-    __slots__ = ("kind", "packs", "unique", "n_rows", "n_valid", "span",
-                 "starts", "rows", "sorted_keys", "avg_cnt", "max_cnt",
-                 "rows_len", "_dev")
+    __slots__ = ("kind", "packs", "unique", "filtered", "n_rows",
+                 "n_valid", "span", "slots", "starts", "rows",
+                 "sorted_keys", "avg_cnt", "max_cnt", "rows_len", "_owner")
 
     def __init__(self):
-        self._dev = None
+        self.slots = None
+        self.filtered = False
+        self._owner = None
+
+    def sig(self) -> str:
+        """What a compiled fragment bakes in of this index: the layout,
+        the packs (hence a dense table's length), uniqueness, and the
+        bucketed length and dtype of the row-id array.  n_valid is a
+        TRACED runtime input, so a within-bucket build-side INSERT
+        rebuilds the cheap numpy index and reuses the compiled program;
+        a slot table has no row-id array (rows_len 0), and whether it
+        was built under the leaf's filter decides whether the program
+        still reads the build mask."""
+        ids = self.slots if self.slots is not None else self.rows
+        return (f"{self.kind}/{self.packs}/{int(self.unique)}/"
+                f"{int(self.filtered)}/{self.rows_len}/{ids.dtype}")
 
     def device_arrays(self):
-        """Upload (lazily, once) and return the (a0, a1, n_valid) lookup
-        tuple the compiled fragment takes as runtime arguments: the CSR
-        starts / sorted keys, the bucket-padded row ids, and the live
-        entry count as a TRACED scalar (np.int64, the n_lives
-        convention) — a same-shape index refresh re-dispatches the
-        compiled program without retracing."""
-        if self._dev is None:
-            import jax.numpy as jnp
-            a0 = self.starts if self.kind == "dense" else self.sorted_keys
-            self._dev = (jnp.asarray(a0), jnp.asarray(self.rows),
-                         np.int64(self.n_valid))
-        return self._dev
+        """The (a0, a1, n_valid) lookup tuple the compiled fragment takes
+        as runtime arguments: the slot table (a1 None) / the CSR starts /
+        the sorted keys, the bucket-padded row ids, and the live entry
+        count as a TRACED scalar (np.int64, the n_lives convention) — a
+        same-shape index refresh re-dispatches the compiled program
+        without retracing.  The arrays are uploaded once and cached
+        through the residency ledger like a column's (`direct_table_fits`
+        admitted their bytes against its budget): counted, evictable,
+        and dropped at a device epoch bump."""
+        import jax.numpy as jnp
+        from ..ops import residency
+        a0 = (self.slots if self.slots is not None
+              else self.starts if self.kind == "dense" else self.sorted_keys)
+        if self._owner is None:
+            self._owner = residency.CacheOwner()
+        dev = residency.lookup(self._owner, len(a0))
+        if dev is None:
+            dev = residency.publish(
+                self._owner, jnp.asarray(a0),
+                None if self.slots is not None else jnp.asarray(self.rows))
+        return dev[0], dev[1], np.int64(self.n_valid)
 
 
 def _pack_host(datas, valid, packs):
@@ -137,6 +190,10 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
     15% of orders but an unfiltered count expands all of them). The tag
     keys the cache per predicate set; one Column can hold one index at a
     time (queries alternating predicate sets rebuild — numpy, cheap).
+    `mask_fn` is the leaf's WHOLE filter: a slot table built under it
+    (`JoinIndex.filtered`) is all a probe reads, its -1 stands for a
+    filtered row as for a gap, and the compiled fragment does not gather
+    the build side's mask again.
 
     packs / force_sorted / pad_rows override the shape-determining
     choices for PARTITIONED builds (executor/hybrid_join.py): every radix
@@ -146,8 +203,8 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
     signature and the zero-recompile invariant would die P ways.  `packs`
     are the whole-table quantized ranges (probe keys outside a
     partition's narrower true range simply find no match); force_sorted
-    skips the dense-CSR layout (a per-partition `starts` array spans the
-    WHOLE key range — P copies of it would dwarf the data); `pad_rows`
+    skips the dense layout (a per-partition table spans the WHOLE key
+    range — P copies of it would dwarf the data); `pad_rows`
     floors the bucket so all partitions pad to the largest one's."""
     host = columns[0]
     # the cached tuple PINS the column objects: a live reference can never
@@ -200,6 +257,7 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
         return None
 
     idx = JoinIndex()
+    idx.filtered = mask_fn is not None
     idx.packs = tuple(packs)
     idx.n_rows = nb
     idx.n_valid = n_valid
@@ -220,23 +278,32 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
         out[:len(arr)] = arr
         return out
 
-    if not force_sorted and span_total <= max(_DENSE_SLACK * nb,
-                                              _DENSE_FLOOR):
+    if not force_sorted and direct_table_fits(
+            (span_total + 1) * np.dtype(row_dt).itemsize):
         idx.kind = "dense"
-        counts = np.bincount(packed[valid], minlength=span_total)
-        starts = np.empty(span_total + 1, dtype=row_dt)
-        starts[0] = 0
-        np.cumsum(counts, out=starts[1:])
-        # row ids grouped by key: stable argsort with invalid rows parked
-        # past every real key
-        sort_key = np.where(valid, packed, np.int64(span_total))
-        order = np.argsort(sort_key, kind="stable")
-        idx.starts = starts
-        idx.rows = _pad_rows(order[:n_valid])
-        idx.max_cnt = int(counts.max(initial=0))
-        idx.unique = idx.max_cnt <= 1
         idx.sorted_keys = None
-        idx.avg_cnt = n_valid / max(int(np.count_nonzero(counts)), 1)
+        keys = packed[valid]
+        ids = np.flatnonzero(valid).astype(row_dt)
+        slots = np.full(span_total, -1, dtype=row_dt)
+        slots[keys] = ids
+        # a key two rows share kept the later one's id
+        idx.unique = bool((slots[keys] == ids).all())
+        if idx.unique:
+            idx.slots = slots
+            idx.starts = idx.rows = None
+            idx.rows_len = 0
+            idx.max_cnt = min(n_valid, 1)
+            idx.avg_cnt = 1.0
+        else:
+            counts = np.bincount(keys, minlength=span_total)
+            starts = np.empty(span_total + 1, dtype=row_dt)
+            starts[0] = 0
+            np.cumsum(counts, out=starts[1:])
+            idx.starts = starts
+            # row ids grouped by key (stable: in row order within a key)
+            idx.rows = _pad_rows(ids[np.argsort(keys, kind="stable")])
+            idx.max_cnt = int(counts.max(initial=0))
+            idx.avg_cnt = n_valid / max(int(np.count_nonzero(counts)), 1)
     else:
         idx.kind = "sorted"
         sort_key = np.where(valid, packed, np.iinfo(np.int64).max)
