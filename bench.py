@@ -10,9 +10,9 @@ The first line, ``bench_backend``, names the device JAX found (platform,
 ``device_kind``, count).  A platform other than ``tpu`` exits non-zero —
 the bench never selects the CPU after failing to find a chip.  The only
 way to run it on XLA:CPU is for the CALLER to export ``JAX_PLATFORMS=cpu``
-(tier-1's tests/test_bench_watchdog.py path, and ``--quick``); every line
-then says ``"platform": "cpu"``.  This process holds the chip from its
-first JAX call; it spawns nothing that needs it.
+(tier-1's tests/test_bench_watchdog.py path); every line then says
+``"platform": "cpu"``.  This process holds the chip from its first JAX
+call; it spawns nothing that needs it.
 
 Watchdog layering (innermost fires first; each outer layer covers the
 failure mode the inner one cannot):
@@ -556,53 +556,7 @@ def _peak_rss_mb() -> int:
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss // 1024
 
 
-def quick_main():
-    """`python bench.py --quick` — the bench-daily analog (reference:
-    Makefile:275-282 bench-daily + util/benchdaily): SF0.01 Q1+Q3 on the
-    CPU backend in ~30s, one JSON line per query APPENDED to
-    bench_history.jsonl (committed), so per-commit regressions like
-    r03's Q1 dip are visible in-round from the file's history."""
-    import subprocess
-    import jax
-    # --quick IS the explicit CPU mode (jax is already imported, so the
-    # config update, not the env var, is what takes effect)
-    jax.config.update("jax_platforms", "cpu")
-    tk = TestKit()
-    tk.must_exec("set tidb_mem_quota_query = 0")
-    n = gen_all(tk, 0.01)
-    git_rev = ""
-    try:
-        git_rev = subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"], capture_output=True,
-            text=True, cwd=os.path.dirname(os.path.abspath(__file__)),
-        ).stdout.strip()
-    except Exception:
-        pass
-    hist = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "bench_history.jsonl")
-    stamp = time.strftime("%Y-%m-%d %H:%M:%S")
-    with open(hist, "a") as f:
-        for qname in ("q1", "q3"):
-            sql = QUERIES[qname]
-            tk.must_exec("set tidb_executor_engine = 'tpu'")
-            time_query(tk, sql, repeats=1)           # compile
-            dev_t, dev_rows = time_query(tk, sql, repeats=3)
-            tk.must_exec("set tidb_executor_engine = 'host'")
-            host_t, host_rows = time_query(tk, sql, repeats=2)
-            line = {"metric": f"quick_{qname}", "value": round(n / dev_t),
-                    "unit": "lineitem_rows/s",
-                    "vs_baseline": round(host_t / dev_t, 3),
-                    "device_s": round(dev_t, 4), "host_s": round(host_t, 4),
-                    "parity": dev_rows == host_rows,
-                    "rev": git_rev, "at": stamp}
-            _emit(line)
-            f.write(json.dumps(line) + "\n")
-
-
 def main():
-    if "--quick" in sys.argv:
-        quick_main()
-        return
     watchdog_s = int(os.environ.get("BENCH_TIMEOUT_S", "2700"))
 
     def _on_alarm(signum, frame):
@@ -719,8 +673,8 @@ def _bench_loop(tk, qnames, sf, n, meta, query_budget_s=0) -> int:
     # query's error line carries its full trace — set it on chip runs,
     # where the post-mortem matters.  Default OFF: sampling also wires a
     # per-operator runtime-stats collector through every traced query,
-    # and the bench_history/vs_baseline records must stay comparable
-    # with the pre-tracing rounds (same rule as bench_serve.py's p99s)
+    # and vs_baseline must stay comparable with the pre-tracing rounds
+    # (same rule as bench_serve.py's p99s)
     if os.environ.get("BENCH_TRACE", "") == "1":
         tk.must_exec("set tidb_trace_sampling_rate = 1")
     failures = 0

@@ -36,7 +36,7 @@ timings are host-platform numbers, not device metrics.
 
 CLI: ``python bench_oltp.py --procs 3 --smoke`` is the fixed-seed CI
 preset (tier-1 via tests/test_serve.py); it emits one ``serve_oltp``
-JSON summary line and appends it to bench_history.jsonl.
+JSON summary line.
 """
 
 from __future__ import annotations
@@ -676,9 +676,7 @@ def main(argv=None) -> int:
     ap.add_argument("--no-chaos", action="store_true",
                     help="baseline round only (no kill/stall rounds)")
     ap.add_argument("--smoke", action="store_true",
-                    help="fixed-seed CI preset (3 workers, chaos on); "
-                         "appends the serve_oltp line to "
-                         "bench_history.jsonl")
+                    help="fixed-seed CI preset (3 workers, chaos on)")
     args = ap.parse_args(argv)
     if args.smoke:
         args.procs, args.threads, args.ops, args.seed = 3, 6, 6, 0
@@ -689,23 +687,6 @@ def main(argv=None) -> int:
     except AssertionError as e:
         _emit({"metric": "oltp_violation", "error": str(e)[:2000]})
         return 1
-    if args.smoke:
-        import subprocess
-        rev = ""
-        try:
-            rev = subprocess.run(
-                ["git", "rev-parse", "--short", "HEAD"],
-                capture_output=True, text=True,
-                cwd=os.path.dirname(os.path.abspath(__file__)),
-            ).stdout.strip()
-        except Exception:  # noqa: BLE001
-            pass
-        hist = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "bench_history.jsonl")
-        line = {**summary, "rev": rev,
-                "at": time.strftime("%Y-%m-%d %H:%M:%S")}
-        with open(hist, "a") as f:
-            f.write(json.dumps(line) + "\n")
     return 0
 
 

@@ -220,8 +220,7 @@ def _bits_of(buckets):
     return (buckets - 1).bit_length()
 
 
-def test_arm_at_the_threshold_and_one_bit_over(monkeypatch):
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+def test_arm_at_the_threshold_and_one_bit_over():
     bits = _bits_of(dev._DENSE_AGG_BUCKETS)
     ops = ("sum_i", "count", "min", "max", "first")
     assert dev.agg_arm(((bits, 0),), ops) == "dense"
@@ -232,38 +231,37 @@ def test_arm_at_the_threshold_and_one_bit_over(monkeypatch):
 
 
 @pytest.mark.parametrize("op", ["sum_f", "cnt_dist"])
-def test_ops_the_dense_arm_leaves_to_the_sort(monkeypatch, op):
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+def test_ops_the_dense_arm_leaves_to_the_sort(op):
     assert dev.agg_arm(((2, 0),), ("sum_i", op)) == "sort"
 
 
 def test_arm_does_not_ask_the_backend_below_the_bound(monkeypatch):
-    """No default_backend() test selects the dense arm; above the bound
-    XLA:CPU keeps its scatter arm."""
+    """agg_arm reads its arguments only: below the bound, above it, with
+    gathered inputs or without a packing, it never asks for the backend."""
     def boom():
-        raise AssertionError("the dense arm asked for the backend")
+        raise AssertionError("agg_arm asked for the backend")
     monkeypatch.setattr(jax, "default_backend", boom)
     assert dev.agg_arm(((1, 0),), ("sum_i",)) == "dense"
-    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     bits = _bits_of(dev._DENSE_AGG_BUCKETS) + 1
-    assert dev.agg_arm(((bits, 0),), ("sum_i",)) == "scatter"
+    assert dev.agg_arm(((bits, 0),), ("sum_i",)) == "sort"
     assert dev.agg_arm(((bits, 0),), ("cnt_dist",)) == "sort"
+    assert dev.agg_arm(((1, 0),), ("sum_i",), gathered=True) == "sort"
+    assert dev.agg_arm(None, ("sum_i",)) == "sort"
 
 
-@pytest.mark.parametrize("backend,arm", [("tpu", "sort"),
-                                         ("cpu", "scatter")])
-def test_gathered_inputs_keep_the_arm_they_had(monkeypatch, backend, arm):
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_gathered_inputs_keep_the_arm_they_had(monkeypatch, backend):
     """A join fragment's aggregate (inputs out of the probe's gather
-    chain) is not the dense arm's, whatever its key space."""
+    chain) is not the dense arm's, whatever its key space and whatever
+    the backend is called."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert dev.agg_arm(((1, 0),), ("sum_i",), gathered=True) == arm
+    assert dev.agg_arm(((1, 0),), ("sum_i",), gathered=True) == "sort"
     assert dev.agg_arm(((1, 0),), ("sum_i",)) == "dense"
 
 
-def test_one_bit_over_the_threshold_traces_the_sort(monkeypatch):
+def test_one_bit_over_the_threshold_traces_the_sort():
     """_agg_impl asks agg_arm: a key space one bit past the bound lowers
     to a program with a sort, at the bound to one without."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     bits = _bits_of(dev._DENSE_AGG_BUCKETS)
     n = 256
 
@@ -283,7 +281,6 @@ def test_merge_partial_states_folds_densely(monkeypatch):
     """The streamed path's fold (concatenated partial states through
     _agg_impl with the merge ops) under a small pack: the chip's arm,
     and the same state the sort arm folds to."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     keys, key_nulls, pack, v, vn, mask = _inputs(11, n=6000)
     ops = ("count", "sum_i", "min", "max", "first")
     merge_ops = tuple(device_exec._MERGE_OPS[o] for o in ops)

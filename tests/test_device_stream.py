@@ -153,3 +153,61 @@ class TestStreamedAgg:
                             "order by grp").rows
         tk.must_exec("set tidb_executor_engine = 'auto'")
         assert sum(int(r[1]) for r in got) == N_ROWS
+
+
+class TestWideKeySpanFoldsOnTheDevice:
+    """A packed key span wider than a block (the case a numpy tail took
+    on XLA:CPU until ISSUE 29): each block aggregates in its program (the
+    sort arm), the partial states fold through merge_partial_states on
+    the device, and merge_cap grows when the blocks' groups together
+    outnumber it."""
+
+    def test_merge_cap_grows_and_the_answer_is_the_hosts(self, monkeypatch):
+        from tidb_tpu.executor import device_exec
+        from tidb_tpu.ops import device as dev
+        tk = TestKit()
+        tk.must_exec("use test")
+        tk.must_exec("create table w (k int, v int)")
+        # 9,000 rows, 3 blocks of 3,000; block b holds the 600 keys
+        # 70,000*b + 7*j (five rows each): 600 groups a block, 1,800 in
+        # all, over a span of 144,194 (18 bits, 262,144 > 3,000)
+        rows = [f"({70_000 * (i // BATCH) + 7 * (i % 600)}, {i % 101})"
+                for i in range(3 * BATCH)]
+        for lo in range(0, len(rows), 3000):
+            tk.must_exec("insert into w values "
+                         + ",".join(rows[lo:lo + 3000]))
+
+        def no_host_fold(*a, **k):
+            raise AssertionError("the streamed scan folded in numpy")
+        monkeypatch.setattr(device_exec, "_merge_states_host", no_host_fold)
+        folds = []
+        orig = device_exec.merge_partial_states
+
+        def spy(state, parts, merge_cap, n_keys, nvals, merge_ops, key_pack):
+            out, grown = orig(state, parts, merge_cap, n_keys, nvals,
+                              merge_ops, key_pack)
+            folds.append((key_pack, merge_cap, grown, int(out[4])))
+            return out, grown
+        monkeypatch.setattr(device_exec, "merge_partial_states", spy)
+        # fold after every block: a running state meets new partials
+        monkeypatch.setattr(device_exec, "_MERGE_BUDGET_ROWS", 1)
+        sql = ("select k, count(*), sum(v), min(v), max(v) from w "
+               "where v > 3 group by k order by k")
+        tk.must_exec("set tidb_result_cache = 'OFF'")
+        tk.must_exec("set tidb_executor_engine = 'tpu'")
+        tk.must_exec(f"set tidb_device_stream_rows = {BATCH}")
+        got = tk.must_query(sql).rows
+        plan = tk.must_query("explain analyze " + sql).rows
+        tk.must_exec("set tidb_device_stream_rows = 0")
+        tk.must_exec("set tidb_executor_engine = 'host'")
+        want = tk.must_query(sql).rows
+        assert len(want) == 1800 and got == want
+        notes = [p for r in plan for p in r[2].split(", ")]
+        assert "engine:tpu-stream" in notes and "agg:sort" in notes
+        packs = {f[0] for f in folds}
+        assert len(packs) == 1 and None not in packs
+        (bits, _offset), = packs.pop()
+        assert (1 << bits) > BATCH
+        assert dev.agg_arm(((bits, 0),), ("sum_i",)) == "sort"
+        assert any(grown > cap for _p, cap, grown, _ng in folds), folds
+        assert max(ng for *_x, ng in folds) == 1800

@@ -223,3 +223,63 @@ class TestPagedProbeJoin:
         tkj.must_exec("set tidb_executor_engine = 'host'")
         host = tkj.must_query(JOINQ.format(f="reffact")).rows
         assert dev == host
+
+    def test_group_overflow_restarts_the_pass_and_answers_exactly(
+            self, tkj, monkeypatch):
+        """Grouped by the fact key (no statistics on the paged table: 64
+        groups estimated, about 250 a page kept): the first pass overflows
+        its per-page capacity, the pass restarts once at the observed
+        size, the folded state outgrows merge_cap, and the answer is the
+        host's.  The second execution starts from the learned capacity:
+        no restart, nothing compiled.  (Key span 16,384 > 2,500 page
+        rows: a numpy tail answered this on XLA:CPU until ISSUE 29.)"""
+        from tidb_tpu.executor import device_exec, device_join
+        sql = ("select fk, count(*), sum(v), max(v) from {f}, dim "
+               "where {f}.dk = dim.dk and v > 900 group by fk order by fk")
+
+        def no_host_fold(*a, **k):
+            raise AssertionError("the paged join folded in numpy")
+        monkeypatch.setattr(device_exec, "_merge_states_host", no_host_fold)
+        capacities, merge_caps = [], []
+        orig_compile = device_join.compile_fragment
+
+        def spy_compile(root, leaves, joins, agg_plan, agg_conds, caps,
+                        capacity, *a, **k):
+            capacities.append(capacity)
+            return orig_compile(root, leaves, joins, agg_plan, agg_conds,
+                                caps, capacity, *a, **k)
+        monkeypatch.setattr(device_join, "compile_fragment", spy_compile)
+        orig_merge = device_exec.merge_partial_states
+
+        def spy_merge(state, parts, merge_cap, *a):
+            out, grown = orig_merge(state, parts, merge_cap, *a)
+            merge_caps.append((merge_cap, grown))
+            return out, grown
+        monkeypatch.setattr(device_exec, "merge_partial_states", spy_merge)
+
+        tkj.must_exec("set tidb_result_cache = 'OFF'")
+        tkj.must_exec("set tidb_executor_engine = 'tpu'")
+        tkj.must_exec("set tidb_device_stream_rows = 2500")
+        first = tkj.must_query(sql.format(f="fact")).rows
+        stats = dict(device_join.LAST_PAGED_STATS.items())
+        restarts, folds = list(capacities), list(merge_caps)
+        del capacities[:], merge_caps[:]
+        again = tkj.must_query(sql.format(f="fact")).rows
+        plan = tkj.must_query("explain analyze "
+                              + sql.format(f="fact")).rows
+        tkj.must_exec("set tidb_device_stream_rows = 0")
+        tkj.must_exec("set tidb_executor_engine = 'host'")
+        host = tkj.must_query(sql.format(f="reffact")).rows
+        assert len(host) > 1000 and first == host and again == host
+        assert "engine:tpu" in [p for r in plan for p in r[2].split(", ")]
+        # one discovery restart: two programs, the second at the observed
+        # per-page group count
+        assert len(restarts) == 2 and restarts[0] < restarts[1], restarts
+        assert stats["capacity"] == restarts[1] and stats["pages"] == 5
+        assert stats["groups"] == len(host)
+        assert any(grown > cap for cap, grown in folds), folds
+        # learned: the second and third executions build nothing, and
+        # their merge starts at the learned total
+        assert capacities == []
+        assert merge_caps and all(cap == grown >= len(host)
+                                  for cap, grown in merge_caps)

@@ -205,6 +205,64 @@ class TestRecompileRegression:
         assert _traces() == t0, \
             "build-side within-bucket delta re-traced the join fragment"
 
+    @pytest.mark.parametrize("case,group,first_builds,groups", [
+        # 5 groups, estimated right: ONE program ever
+        ("few_groups", "{dim}.g", 1, range(5, 6)),
+        # grouped by the fact's key: the estimate (64) overflows, the
+        # fragment recompiles once at the observed count and learns it
+        ("learned_capacity", "{fact}.a", 2, range(2001, 70_001)),
+    ])
+    def test_join_fragment_compiles_only_for_its_aggregate_capacity(
+            self, case, group, first_builds, groups, monkeypatch):
+        """A fact-length fragment (92,682-row bucket) whose filter keeps
+        one row in twenty: the aggregate runs at the fact length, so the
+        only shape a join fragment ever learns is its capacities.  The
+        first execution builds one program plus one per capacity it had
+        to discover; the second builds at most the one at the learned
+        aggregate capacity; the third builds nothing.  (Until ISSUE 29
+        XLA:CPU also recompiled such a fragment to compact its kept
+        rows, which the chip never did.)"""
+        from tidb_tpu.executor import device_join
+        tk = TestKit()
+        table, dim = f"jc_{case}", f"jcd_{case}"
+        _install_fact(tk, table, 70_000)
+        tk.must_exec(f"create table {dim} (k bigint primary key, "
+                     "g varchar(8))")
+        tk.must_exec(f"insert into {dim} values " + ", ".join(
+            f"({i}, 'g{i % 5}')" for i in range(1, 51)))
+        tk.must_exec(f"analyze table {dim}")
+        builds = []
+        orig = device_join.compile_fragment
+
+        def spy(root, leaves, joins, agg_plan, agg_conds, caps, capacity,
+                *a, **k):
+            builds.append(capacity)
+            return orig(root, leaves, joins, agg_plan, agg_conds, caps,
+                        capacity, *a, **k)
+        monkeypatch.setattr(device_join, "compile_fragment", spy)
+        tk.must_exec("set tidb_result_cache = 'OFF'")
+        tk.must_exec("set tidb_executor_engine = 'tpu'")
+        group = group.format(dim=dim, fact=table)
+        q = (f"select {group}, sum({table}.v), count(*) from {table} "
+             f"join {dim} on {table}.k = {dim}.k where {table}.v >= 96 "
+             f"group by {group} order by {group}")
+        first = tk.must_query(q).rows
+        assert len(first) in groups
+        assert len(builds) == first_builds, builds
+        assert builds == sorted(builds)
+        learned = dev.next_pow2(max(len(first), 16))
+        del builds[:]
+        t0 = _traces()
+        assert tk.must_query(q).rows == first
+        assert builds in ([], [learned]), (builds, learned)
+        assert _traces() - t0 == len(builds)
+        t1 = _traces()
+        del builds[:]
+        assert tk.must_query(q).rows == first
+        assert builds == [] and _traces() == t1
+        tk.must_exec("set tidb_executor_engine = 'host'")
+        assert tk.must_query(q).rows == first
+
 
 # ---------------------------------------------------------------------------
 # padding invariants: padded rows never escape

@@ -1,24 +1,20 @@
 """The lowering the chip takes, run in tier-1.
 
-Several branches of the device executor are gated on
-``jax.default_backend()``: on XLA:CPU packed keys above the dense bound
-aggregate through a scatter kernel, streamed partial states fold in numpy
-and join outputs are compacted before the aggregate; on a TPU the same
-queries take the sort + segment kernel (``ops/device._agg_impl``), the
-in-kernel ``merge_partial_states`` fold and no compaction.  CPU tests
-therefore never executed what the chip runs.  Here ``default_backend``
-reports ``"tpu"`` while XLA:CPU does the work, so those arms trace,
-compile and answer — each held to exact parity with the host engine —
-before they meet the hardware (chip_smoke.py).  Small packed key spaces
-(Q1, Q6) take the dense arm on every backend; its lowering is held here
-too.
+The device executor has ONE lowering: ``ops/device.py``, ``device_exec.py``,
+``device_join.py``, ``hybrid_join.py`` and ``mpp_exec.py`` never ask
+``jax.default_backend()`` (held below), so what XLA:CPU traces, compiles
+and answers here — each held to exact parity with the host engine — is
+the program the chip runs: the dense arm for small packed key spaces
+(Q1, Q6), the sort + segment kernel (``ops/device._agg_impl``) for the
+rest and for every join fragment, at the fact length, and the in-kernel
+``merge_partial_states`` fold of streamed partial states.
 """
 
+import ast
 import pathlib
 import random
 import sys
 
-import jax
 import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
@@ -29,28 +25,40 @@ from tidb_tpu.ops import device as dev  # noqa: E402
 from tidb_tpu.testkit import TestKit  # noqa: E402
 
 
-@pytest.fixture(autouse=True)
-def _as_tpu(monkeypatch):
-    """Report "tpu" from default_backend, with no pipeline traced under
-    the CPU lowering left in the fragment cache (and none traced here
-    left for the tests that follow).  Every fused pipeline is its own
-    jit function held by _PIPE_CACHE, so dropping that is enough."""
-    def drop_compiled():
-        with device_exec._PIPE_LOCK:
-            device_exec._PIPE_CACHE.clear()
-    drop_compiled()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    yield
-    monkeypatch.undo()
-    drop_compiled()
+_ONE_LOWERING = ("ops/device.py", "executor/device_exec.py",
+                 "executor/device_join.py", "executor/hybrid_join.py",
+                 "executor/mpp_exec.py")
+
+
+@pytest.mark.parametrize("module", _ONE_LOWERING)
+def test_the_device_executor_never_asks_for_the_backend(module):
+    """No name or attribute `default_backend` anywhere in the modules
+    that build and dispatch XLA programs: a branch on it is a program
+    tier-1 runs and the chip does not (or the reverse)."""
+    path = pathlib.Path(dev.__file__).resolve().parents[1] / module
+    tree = ast.parse(path.read_text())
+    asked = [node.lineno for node in ast.walk(tree)
+             if (isinstance(node, ast.Attribute)
+                 and node.attr == "default_backend")
+             or (isinstance(node, ast.Name) and node.id == "default_backend")
+             or (isinstance(node, ast.alias)
+                 and node.name == "default_backend")]
+    assert not asked, (module, asked)
+
+
+def _drop_compiled():
+    """Forget every fused pipeline (each is its own jit function held by
+    _PIPE_CACHE), so that the next execution traces under the caller's
+    spies whatever an earlier test of this worker compiled."""
+    with device_exec._PIPE_LOCK:
+        device_exec._PIPE_CACHE.clear()
+        device_exec._TOPK_CACHE.clear()
 
 
 @pytest.fixture(scope="module")
 def tpch():
     tk = TestKit()
-    # 120k lineitem rows: the Q3 fragment is past the 65536-row floor at
-    # which the CPU lowering would start compacting
-    bench.gen_all(tk, 0.02)
+    bench.gen_all(tk, 0.02)    # 120k lineitem rows
     return tk
 
 
@@ -71,10 +79,7 @@ def _parity(tk, sql):
 
 def test_small_packed_key_aggregate_reduces_densely(tpch, monkeypatch):
     """Q1: two dict-coded keys pack into 5 bits — 32 buckets, one masked
-    reduction each; never the scatter kernel under a tpu backend."""
-    def no_scatter(*a, **k):
-        raise AssertionError("scatter aggregate ran under a tpu backend")
-    monkeypatch.setattr(dev, "_agg_scatter_impl", no_scatter)
+    reduction each."""
     arms = []
     orig = dev.agg_arm
 
@@ -82,22 +87,30 @@ def test_small_packed_key_aggregate_reduces_densely(tpch, monkeypatch):
         arms.append(orig(pack, agg_ops, gathered))
         return arms[-1]
     monkeypatch.setattr(dev, "agg_arm", spy)
+    _drop_compiled()
     assert _parity(tpch, bench.QUERIES["q1"]) == ["engine:tpu"]
     assert arms and set(arms) == {"dense"}
 
 
 def test_join_aggregate_without_compaction(tpch, monkeypatch):
-    """Q3: the join fragment aggregates at the fact length, never
-    through the post-join compaction the CPU lowering learns."""
-    compact_caps = []
-    orig = device_join.compile_fragment
+    """Q3: the join fragment aggregates at the fact length — the mask
+    the aggregate takes is as long as the probe leaf's row bucket — and
+    compile_fragment has no parameter to shorten it."""
+    import inspect
+    assert "compact_cap" not in inspect.signature(
+        device_join.compile_fragment).parameters
+    lengths = []
+    orig = dev._agg_impl
 
-    def spy(*a, compact_cap=None, **k):
-        compact_caps.append(compact_cap)
-        return orig(*a, compact_cap=compact_cap, **k)
-    monkeypatch.setattr(device_join, "compile_fragment", spy)
+    def spy(key_cols, key_nulls, val_cols, val_nulls, mask, **kw):
+        lengths.append((mask.shape[0], kw.get("gathered", False)))
+        return orig(key_cols, key_nulls, val_cols, val_nulls, mask, **kw)
+    monkeypatch.setattr(dev, "_agg_impl", spy)
+    _drop_compiled()
     assert _parity(tpch, bench.QUERIES["q3"]) == ["engine:tpu"]
-    assert compact_caps and all(c is None for c in compact_caps)
+    n_fact = int(tpch.must_query("select count(*) from lineitem").rows[0][0])
+    assert lengths and all(g for _n, g in lengths)
+    assert all(n >= n_fact for n, _g in lengths), (lengths, n_fact)
 
 
 def test_streamed_aggregate_merges_in_kernel(monkeypatch):
@@ -114,7 +127,7 @@ def test_streamed_aggregate_merges_in_kernel(monkeypatch):
         tk.must_exec("insert into s values " + ",".join(rows[lo:lo + 2000]))
 
     def no_host_fold(*a, **k):
-        raise AssertionError("numpy fold ran under a tpu backend")
+        raise AssertionError("the streamed scan folded in numpy")
     monkeypatch.setattr(device_exec, "_merge_states_host", no_host_fold)
     packs = []
     orig = device_exec.merge_partial_states
@@ -171,8 +184,7 @@ _SCOPES_BY_SHAPE = {
 @pytest.fixture(scope="module")
 def lowered(tpch):
     """{shape: the debug-info text every program of one execution was
-    lowered to}, under the tpu arms (module-scoped, so it patches
-    default_backend itself: it is set up before _as_tpu)."""
+    lowered to}."""
     texts = {}
     current, programs = [], []
     orig = dev.observed_jit
@@ -187,15 +199,9 @@ def lowered(tpch):
             return run(*a, **k)
         return call
 
-    def drop_compiled():
-        with device_exec._PIPE_LOCK:
-            device_exec._PIPE_CACHE.clear()
-            device_exec._TOPK_CACHE.clear()
-
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(jax, "default_backend", lambda: "tpu")
         mp.setattr(dev, "observed_jit", spy)
-        drop_compiled()
+        _drop_compiled()
         tpch.must_exec("set tidb_result_cache = 'OFF'")
         topn = ("select l_orderkey, sum(l_quantity) as q from lineitem "
                 "group by l_orderkey order by q desc, l_orderkey limit 5")
@@ -213,7 +219,7 @@ def lowered(tpch):
             assert rows and current, shape
             texts[shape] = "\n".join(current)
             texts[shape, "programs"] = list(programs)
-        drop_compiled()
+        _drop_compiled()
     tpch.must_exec("set tidb_executor_engine = 'tpu'")
     return texts
 
@@ -364,7 +370,6 @@ def test_fragments_count_their_aggregate_arm(tpch, shape, sql, counter,
     assert tpch.must_query(sql).rows
     after = _device_pipelines(tpch)
     grew = {k: after[k] - before[k]
-            for k in ("agg_dense", "agg_sorted", "agg_scatter")}
-    assert grew == {"agg_dense": 0, "agg_sorted": 0, "agg_scatter": 0,
-                    counter: 1}, shape
+            for k in ("agg_dense", "agg_sorted")}
+    assert grew == {"agg_dense": 0, "agg_sorted": 0, counter: 1}, shape
     assert _agg_annotations(tpch, sql) == [f"agg:{arm}"]

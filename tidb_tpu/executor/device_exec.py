@@ -286,9 +286,12 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                "mode_cached": 0, "mode_prewarmed": 0,
                "mode_async_pending": 0, "mode_sync": 0,
                # aggregate fragments dispatched, by the arm of
-               # ops/device._agg_impl that dev.agg_arm named for them
-               # (scatter exists on XLA:CPU only) — note_agg_arm
-               "agg_dense": 0, "agg_sorted": 0, "agg_scatter": 0,
+               # ops/device._agg_impl that dev.agg_arm named for them —
+               # note_agg_arm
+               "agg_dense": 0, "agg_sorted": 0,
+               # never bumped: benchmark/layer_metrics/agg.dense_share.py
+               # reads the key
+               "agg_scatter": 0,
                # host-indexed joins of dispatched join fragments, by how
                # a probe key finds its build rows: by address (a `dense`
                # JoinIndex) or by binary search (`sorted`) —
@@ -331,8 +334,7 @@ def _tls_stats() -> dict:
                                 "mode_cached": 0, "mode_prewarmed": 0,
                                 "mode_async_pending": 0, "mode_sync": 0,
                                 "agg_dense": 0, "agg_sorted": 0,
-                                "agg_scatter": 0, "join_direct": 0,
-                                "join_search": 0}
+                                "join_direct": 0, "join_search": 0}
     return st
 
 
@@ -348,8 +350,7 @@ def _bump(key, amt=1):
 
 
 #: dev.agg_arm's name -> its counter
-AGG_ARM_STATS = {"dense": "agg_dense", "sort": "agg_sorted",
-                 "scatter": "agg_scatter"}
+AGG_ARM_STATS = {"dense": "agg_dense", "sort": "agg_sorted"}
 
 
 def note_agg_arm(pack, agg_ops, gathered=False):
@@ -503,7 +504,7 @@ def _expr_sig(e) -> str:
 
 
 def _build_pipeline(cond_fns, key_fns, n_keys, val_plan, agg_ops,
-                    capacity, pack, raw_tail=False):
+                    capacity, pack):
     """Close the compiled expression fns over one traceable program and jit
     it: mask, keys, values and the aggregate all fuse into a single XLA
     executable — no eager op dispatch between operators.
@@ -513,13 +514,7 @@ def _build_pipeline(cond_fns, key_fns, n_keys, val_plan, agg_ops,
     n_live are masked out before the aggregate, so padding can never
     survive a filter or contribute to any group. n_live is a traced
     scalar — within-bucket row-count changes re-dispatch without a
-    retrace.
-
-    raw_tail: stop before the in-kernel aggregate and return the
-    evaluated (key_cols, key_nulls, val_cols, val_nulls, mask) rows —
-    the CPU-backend streamed path aggregates them in numpy (see
-    _merge_states_host: the XLA-CPU group-by pays in the packed key
-    span; a host reduceat over one block is row-proportional)."""
+    retrace."""
 
     def pipeline(env, n_live):
         _count_trace()
@@ -563,9 +558,6 @@ def _build_pipeline(cond_fns, key_fns, n_keys, val_plan, agg_ops,
                     d = d.astype(jnp.int64)
                 val_cols.append(d)
                 val_nulls.append(nl)
-        if raw_tail:
-            return (tuple(key_cols), tuple(key_nulls), tuple(val_cols),
-                    tuple(val_nulls), mask)
         return dev._agg_impl(tuple(key_cols), tuple(key_nulls),
                              tuple(val_cols), tuple(val_nulls), mask,
                              n_keys=n_keys, agg_ops=agg_ops,
@@ -1261,12 +1253,6 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
     merge_ops = tuple(_MERGE_OPS[op] for op in agg_ops)
     sig_exprs, dict_refs = _agg_sig(plan, conds, dcols)
     _bump("scan_streamed")
-    if _want_host_tail(key_pack, batch_rows):
-        return _stream_agg_host_tail(
-            plan, chunk, conds, batch_rows, ctx, col_arrays, dcols,
-            (key_fns, key_meta, key_pack, val_plan, agg_ops, slots),
-            merge_ops, sig_exprs, dict_refs, cond_fns)
-
     est = _estimate_groups(plan, n, ctx)
     capacity = dev.next_pow2(min(batch_rows, max(est, 16)))
     merge_cap = capacity  # grows to the true total on merge overflow
@@ -1319,66 +1305,6 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
         raise DeviceUnsupported("streamed agg capacity did not converge")
     if state is None:
         raise DeviceUnsupported("empty streamed input")
-    out = _fetch(lambda: state[:5])
-    key_out, key_null_out, results, result_nulls, n_groups = out
-    ng = int(n_groups)
-    if ng == 0 and not plan.group_exprs:
-        raise DeviceUnsupported("empty global aggregate")
-    return _assemble_agg(plan, key_meta, slots, dcols,
-                         (key_out, key_null_out, results, result_nulls), ng)
-
-
-def _want_host_tail(key_pack, block_rows: int) -> bool:
-    """CPU backend only: aggregate blocks in numpy when the packed key
-    SPAN dwarfs the block — the in-kernel dense-bucket agg pays O(span)
-    per block there (SF10 Q18: 67M-slot orderkey space over 4M-row
-    pages). A small span (Q1's 6-group flag pair) stays in-kernel, where
-    the scatter agg is O(rows) with tiny buckets and the raw rows never
-    leave the program."""
-    if key_pack is None or jax.default_backend() != "cpu":
-        return False
-    bits = sum(b for b, _o in key_pack)
-    # span > block rows: the dense-bucket pass would touch more slots
-    # than there are rows (Q18's 24-bit orderkey space over 4M pages);
-    # below that the in-kernel scatter is O(rows) and keeps the raw rows
-    # inside the program
-    return (1 << bits) > max(block_rows, 1)
-
-
-def _stream_agg_host_tail(plan, chunk, conds, batch_rows, ctx, col_arrays,
-                          dcols, agg_meta_full, merge_ops, sig_exprs,
-                          dict_refs, cond_fns):
-    """CPU-backend streamed scan-agg: raw-tail pipeline per block + numpy
-    partial aggregation + one numpy fold (same shape as the paged join's
-    host tail — XLA keeps the fused filter/expression work, the host does
-    the row-proportional group-by)."""
-    key_fns, key_meta, key_pack, val_plan, agg_ops, slots = agg_meta_full
-    n = chunk.num_rows
-    n_keys = max(len(key_fns), 1)
-    nvals = len(val_plan)
-    key = (sig_exprs, "stream-rawtail", key_pack, tuple(agg_ops))
-
-    def build():
-        return _build_pipeline(cond_fns, key_fns, n_keys, val_plan,
-                               tuple(agg_ops), 1, key_pack, raw_tail=True)
-    fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
-                          spec=_stream_spec(col_arrays, batch_rows),
-                          shape="agg", sig=sig_exprs, ladder=False)
-    states = []
-    for lo in range(0, n, batch_rows):
-        hi = min(lo + batch_rows, n)
-        raw = fn(_stream_block(col_arrays, lo, hi, batch_rows),
-                 np.int64(hi - lo))
-        page = page_singleton_state(raw[0], raw[1], raw[2], raw[3],
-                                    raw[4], agg_ops)
-        state, _cap = _merge_states_host([page], 16, n_keys, nvals,
-                                         merge_ops, key_pack)
-        states.append(state)
-    if not states:
-        raise DeviceUnsupported("empty streamed input")
-    state, _cap = (_merge_states_host(states, 16, n_keys, nvals,
-                                      merge_ops, key_pack)
-                   if len(states) > 1 else (states[0], 0))
     out = _fetch(lambda: state[:5])
     key_out, key_null_out, results, result_nulls, n_groups = out
     ng = int(n_groups)
@@ -1491,19 +1417,9 @@ def merge_partial_states(state, parts, merge_cap, n_keys, nvals, merge_ops,
     merged state of `merge_cap` output slots via the mergeable-agg kernel;
     grows merge_cap on overflow (inputs stay alive, so the retry is
     exact). Returns (state, merge_cap) — state is an _agg_impl output
-    tuple whose [4] is the live group count.
-
-    On the XLA-CPU backend with a packable key the fold runs in numpy
-    instead: partial states are small and COMPACT (a few hundred k rows
-    per flush), where the backend's serial sort and the dense-bucket
-    scatter both pay in the key SPAN (measured: 13.5s of SF10 Q3's 45s
-    device time was one 3.9M-row merge over a 67M-slot orderkey space);
-    numpy's multiway argsort does the same fold in row-proportional
-    time. On TPU the states stay in HBM and the sort kernel merges."""
+    tuple whose [4] is the live group count. The states stay on the
+    device; only the group count comes back."""
     alls = ([state] if state is not None else []) + list(parts)
-    if key_pack is not None and jax.default_backend() == "cpu":
-        return _merge_states_host(alls, merge_cap, n_keys, nvals,
-                                  merge_ops, key_pack)
     key_cat = tuple(jnp.concatenate([p[0][k] for p in alls])
                     for k in range(n_keys))
     key_null_cat = tuple(jnp.concatenate([p[1][k] for p in alls])
@@ -1549,7 +1465,9 @@ def page_singleton_state(key_cols, key_nulls, val_cols, val_nulls, mask,
 
 
 def _merge_states_host(alls, merge_cap, n_keys, nvals, merge_ops, key_pack):
-    """numpy fold of partial-agg states (CPU backend only). Packs the key
+    """numpy fold of partial-agg states, for the hybrid join
+    (hybrid_join.py), which folds its device partitions and its host
+    partitions together on the host on every backend. Packs the key
     tuple EXACTLY like _agg_impl (null -> slot 0, value+offset+1), stable
     argsort so the first-occurrence row of every group is the earliest
     partial's representative (matching the kernel's stable-sort 'first'

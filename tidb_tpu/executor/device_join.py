@@ -611,8 +611,8 @@ def _pack_probe(kds, knulls, pvalid, packs):
 
 
 def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
-                     capacity, key_pack, agg_meta, compact_cap=None,
-                     raw_tail=False, strategies=None):
+                     capacity, key_pack, agg_meta, raw_tail=False,
+                     strategies=None):
     """Build the jitted end-to-end program. caps: per-join static
     capacities aligned with `joins`. Returns jitted fn(env, jidx, n_lives)
     where env is {global_col: (data, nulls)} and jidx is a per-join tuple
@@ -626,20 +626,16 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
     filter, probe a join, or reach the aggregate. Traced scalars: a
     within-bucket row-count change re-dispatches without recompiling.
 
-    compact_cap: when set (CPU backend, learned from a prior run), the
-    post-join/filter rows are scatter-compacted to this static width
-    before the aggregate — a fact-shaped fragment output with a sparse
-    validity mask (the price of the gather-join design) would otherwise
-    drag the full fact length through the group-by sort.
+    The aggregate runs at the fact length: the fragment's output is
+    fact-shaped with a sparse validity mask (the price of the gather-join
+    design).
 
     raw_tail: stop BEFORE the in-kernel aggregate and return the evaluated
     (key_cols, key_nulls, val_cols, val_nulls, mask) row arrays instead.
-    CPU-backend paged paths aggregate those in numpy: the XLA-CPU
-    group-by pays in the packed-key SPAN (dense buckets) or a serial
-    sort, both dwarfing a host reduceat over one page (measured: 26s of
-    SF10 Q3's device time was 15 pages of in-kernel scatter-agg against
-    a 67M-slot orderkey space). The join/filter/expression work — the
-    part XLA is good at — stays fused in the program."""
+    Its one user is the hybrid join (hybrid_join.py), which aggregates
+    the rows of its device partitions and of its host partitions together
+    in numpy on every backend; the join/filter/expression work stays
+    fused in the program."""
     for jn, cap in zip(joins, caps):
         jn.cap = cap
     if strategies is None:
@@ -914,19 +910,6 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                 d, nl = f(fenv)
                 mask = mask & (d != 0) & ~nl
             kept_total = jnp.sum(mask)
-            if compact_cap is not None:
-                # scatter-compact kept rows to the front: the aggregate
-                # then sorts/buckets compact_cap rows instead of the fact
-                # length.  kept_total > compact_cap is detected host-side
-                # (extras) and recompiled — same contract as a
-                # join-capacity overflow.
-                cidx = jnp.cumsum(mask) - 1
-                tgt = jnp.where(mask, cidx, compact_cap)
-                sel = jnp.zeros(compact_cap, dtype=jnp.int64).at[tgt].set(
-                    jnp.arange(mask.shape[0]), mode="drop")
-                fenv = {k: (d[sel], nl[sel])
-                        for k, (d, nl) in fenv.items()}
-                mask = jnp.arange(compact_cap) < kept_total
         n_out = mask.shape[0]
         # as in the scan pipeline: key expressions are k_agg_sort,
         # aggregate inputs k_agg_gather
@@ -1146,23 +1129,6 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     else:
         est = _estimate_groups(agg_plan, n_frag, ctx)
         capacity = dev.next_pow2(min(n_frag, max(est, 16)))
-    # post-join compaction (CPU backend only — scatter-cheap there): learn
-    # the kept-row count and re-shape the aggregate input to it
-    # post-join compaction backend gate: 'auto' = CPU only (scatter-cheap
-    # there; TPU scatters serialize), 'on'/'off' override — flippable at
-    # runtime so a TPU window can A/B it without code edits
-    try:
-        _cmode = ctx.get_sysvar("tidb_device_compact")
-    except Exception:
-        _cmode = "auto"
-    compact_enabled = (_cmode == "on" or (_cmode != "off"
-                                 and jax.default_backend() == "cpu"))
-    compact_cap = None
-    if compact_enabled and n_frag > 65536:
-        learned_kept = _CAP_STORE.get((sig, "compact"))
-        if learned_kept is not None and dev.next_pow2(
-                max(learned_kept, 8)) * 2 <= n_frag:
-            compact_cap = dev.next_pow2(max(learned_kept, 8))
 
     import os as _os
     import sys as _sys
@@ -1172,17 +1138,16 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     note_join_layouts(jn.strategy for jn in joins)
     for _attempt in range(12):
         caps = [jn.cap for jn in joins]
-        key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
-               compact_cap)
+        key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops))
         t0 = _time.perf_counter()
 
-        def build(caps=tuple(caps), cap=capacity, ccap=compact_cap):
+        def build(caps=tuple(caps), cap=capacity):
             # the leaves/joins/plan objects are OWNED by this execution;
             # when the compile service defers this builder to a worker the
             # query has already degraded to host, so nothing mutates them
             return compile_fragment(root, leaves, joins, agg_plan,
                                     agg_conds, list(caps), cap, key_pack,
-                                    agg_meta, compact_cap=ccap)
+                                    agg_meta)
         fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
                               args=(env, jidx, n_lives), shape="join",
                               sig=sig)
@@ -1195,7 +1160,7 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         ng = f.ng
         if _dbg:
             print(f"[device_join] attempt {_attempt}: caps={caps} "
-                  f"agg_cap={capacity} compact={compact_cap} kept={kept} "
+                  f"agg_cap={capacity} kept={kept} "
                   f"totals={[int(o) for o in overflows]} "
                   f"{_time.perf_counter() - t0:.2f}s",
                   file=_sys.stderr, flush=True)
@@ -1223,28 +1188,6 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
                 jn.exp_cap = tight
                 retry = True
             _cap_store_put((sig, jn.pos), total)
-        # profitability gates below compare against the CURRENT root cap
-        # (node caps move under shrink-to-fit; the pre-loop n_frag is stale
-        # after the first retry)
-        root_cap = root.cap if isinstance(root, _JoinNode) else n_frag
-        compact_ovf = compact_cap is not None and kept > compact_cap
-        if compact_ovf:
-            # truncated aggregate input: results (and ng) are invalid —
-            # recompile with the real kept count before anything else
-            compact_cap = dev.next_pow2(max(kept, 8))
-            if compact_cap * 2 > root_cap:
-                compact_cap = None  # not worth compacting
-            _cap_store_put((sig, "compact"), kept)
-            _fill_caps(root, sig)
-            continue
-        _cap_store_put((sig, "compact"), kept)
-        if (compact_enabled and compact_cap is None
-                and dev.next_pow2(max(kept, 8)) * 2 <= root_cap
-                and root_cap > 65536):
-            # compaction newly profitable: one recompile buys an agg that
-            # works on kept rows instead of the fact length, forever
-            compact_cap = dev.next_pow2(max(kept, 8))
-            retry = True
         tight_ng = dev.next_pow2(max(ng, 16))
         if ng > capacity:
             capacity = tight_ng
@@ -1518,20 +1461,10 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
     for jn in joins:
         jn.cap = page_rows  # every join is a probe-shaped gather
     note_join_layouts(jn.strategy for jn in joins)
-    from .device_exec import _want_host_tail
-    if _want_host_tail(key_pack, page_rows):
-        # raw-tail path: XLA keeps the fused scan->gather-join->expression
-        # work; the per-page group-by runs in numpy, which is
-        # row-proportional where the XLA-CPU aggregate pays in the packed
-        # key SPAN. No capacity discovery, no restarts.
-        return _paged_join_agg_host_tail(
-            root, leaves, joins, probe, agg_plan, agg_conds, ctx,
-            page_rows, dcols, agg_meta_full, merge_ops, sig, dict_refs,
-            env_dim, probe_arrays, jidx, n)
     note_agg_arm(key_pack, agg_ops, gathered=True)
     for _attempt in range(4):
         caps = [page_rows] * len(joins)
-        key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops), None,
+        key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
                "paged")
 
         def build(caps=tuple(caps), cap=capacity):
@@ -1604,82 +1537,6 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
     f = AggFetch(state, topn=resolve_topn(agg_plan, slots))
     ng = f.ng
     _cap_store_put((sig, "groups"), ng)
-    if ng == 0 and not agg_plan.group_exprs:
-        raise DeviceUnsupported("empty global aggregate")
-    body = f.body()
-    out = _assemble_agg(agg_plan, key_meta, slots, dcols, body, f.out_rows)
-    stats["fetch_s"] = _time.perf_counter() - t5
-    stats["groups"] = ng
-    LAST_PAGED_STATS.clear()
-    LAST_PAGED_STATS.update(
-        {k: (round(v, 2) if isinstance(v, float) else v)
-         for k, v in stats.items()})
-    return out
-
-
-def _paged_join_agg_host_tail(root, leaves, joins, probe, agg_plan,
-                              agg_conds, ctx, page_rows, dcols,
-                              agg_meta_full, merge_ops, sig, dict_refs,
-                              env_dim, probe_arrays, jidx, n):
-    """CPU-backend paged fragment: raw-tail program per page + numpy
-    partial aggregation + one numpy fold at the end (see
-    compile_fragment raw_tail / device_exec._merge_states_host)."""
-    import time as _time
-    from .device_exec import (AggFetch, _merge_states_host,
-                              page_singleton_state, resolve_topn)
-    key_fns, key_meta, key_pack, val_plan, agg_ops, slots = agg_meta_full
-    agg_meta = (key_fns, val_plan, agg_ops, slots)
-    n_keys = max(len(key_fns), 1)
-    nvals = len(val_plan)
-    key = (sig, key_pack, tuple(agg_ops), "rawtail")
-
-    def build():
-        return compile_fragment(root, leaves, joins, agg_plan, agg_conds,
-                                [page_rows] * len(joins), 1, key_pack,
-                                agg_meta, raw_tail=True)
-    fn = acquire_pipeline(key, build, dict_refs, ctx=ctx, shape="join",
-                          sig=sig)
-
-    def pad_page(arr, lo, hi, null_pad=False):
-        return jnp.asarray(dev.pad_host(arr[lo:hi], page_rows, null_pad))
-
-    base_lives = [np.int64(leaf.chunk.num_rows) for leaf in leaves]
-    stats = {"pages": 0, "slice_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0,
-             "merge_s": 0.0}
-    states = []
-    for lo in range(0, n, page_rows):
-        hi = min(lo + page_rows, n)
-        env = dict(env_dim)
-        t0 = _time.perf_counter()
-        for gidx, (d, nl) in probe_arrays.items():
-            env[gidx] = (pad_page(d, lo, hi), pad_page(nl, lo, hi, True))
-        t1 = _time.perf_counter()
-        lives = list(base_lives)
-        lives[probe.leaf_id] = np.int64(hi - lo)
-        raw, _ovf, _sovf, _kept = fn(env, jidx, tuple(lives))
-        t2 = _time.perf_counter()
-        # per-page compaction keeps at most one compact state per page in
-        # RAM (zero-copy views of the page's buffers drop right after)
-        page = page_singleton_state(raw[0], raw[1], raw[2], raw[3],
-                                    raw[4], agg_ops)
-        state, _cap = _merge_states_host([page], 16, n_keys, nvals,
-                                         merge_ops, key_pack)
-        states.append(state)
-        t3 = _time.perf_counter()
-        stats["pages"] += 1
-        stats["slice_s"] += t1 - t0
-        stats["dispatch_s"] += t2 - t1
-        stats["sync_s"] += t3 - t2
-    if not states:
-        raise DeviceUnsupported("empty paged fragment input")
-    t4 = _time.perf_counter()
-    state, _cap = (_merge_states_host(states, 16, n_keys, nvals,
-                                      merge_ops, key_pack)
-                   if len(states) > 1 else (states[0], 0))
-    stats["merge_s"] = _time.perf_counter() - t4
-    t5 = _time.perf_counter()
-    f = AggFetch(state, topn=resolve_topn(agg_plan, slots))
-    ng = f.ng
     if ng == 0 and not agg_plan.group_exprs:
         raise DeviceUnsupported("empty global aggregate")
     body = f.body()

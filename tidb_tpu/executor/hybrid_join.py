@@ -56,10 +56,10 @@ EXPLAIN ANALYZE annotations, /status and /metrics; failpoint
 `device-join-spill` (storage/paged.SpillSet.write) with a
 spilled-pages-drained chaos invariant.
 
-Known live-TPU caveat (documented in ROADMAP): the merge of partial
-states runs host-side (the CPU backend's row-proportional fold); the
-in-HBM merge for the TPU backend rides with the item-2 adaptive
-aggregation work.
+Known live-TPU caveat (ROADMAP D9): the merge of partial states runs
+host-side on every backend (device_exec._merge_states_host, a numpy
+fold over device and host partitions together); an in-HBM merge is not
+written.
 """
 
 from __future__ import annotations

@@ -65,7 +65,6 @@ SYSVAR_SCOPE = {
     "tidb_device_dispatch_rows": SESSION,
     "tidb_device_stream_rows": SESSION,
     "tidb_device_shape_buckets": SESSION,
-    "tidb_device_compact": SESSION,
 }
 
 #: names outside the registry that still look like serving-stack knobs

@@ -22,7 +22,6 @@ expression/*_vec.go → compile_expr tracing numpy-identical semantics.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -1081,38 +1080,8 @@ def _group_spans(is_new, kept, n, capacity):
     return starts, ends, end_idx, span_sum
 
 
-#: scatter-arm bound (XLA:CPU only, above the dense bound; see agg_arm):
-#: bucket arrays up to 2^26 slots (the packed-key space) are cheaper than
-#: one 100k+-element sort on the XLA CPU backend, where sort lowers to a
-#: slow single-threaded path. Bucket memory scales with the ACTUAL key
-#: span, capped by the BYTE budget below (26 bits + one value column ≈
-#: 4.3GB transient — the budget, not this constant, is usually the
-#: binding bound). A 60M-value l_orderkey GROUP BY (TPC-H Q18's inner agg
-#: at SF10, 26-bit span) stays on O(n) scatters instead of falling onto
-#: the serial sort (measured: the sort path made SF10 Q18 7x slower than
-#: host; the arm only exists on the CPU backend, so the budget sizes
-#: against host RAM, not HBM)
-_SCATTER_AGG_BITS = 26
-
-
-def _host_ram_bytes() -> int:
-    try:
-        return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
-    except (ValueError, OSError, AttributeError):
-        return 8 << 30
-
-
-#: peak bytes the scatter path may hold in bucket arrays at once —
-#: a quarter of physical RAM, capped at 6GB: the buckets live inside XLA
-#: where the engine's quota tracker can't see them, so the bound must
-#: come from the machine, not a constant (a 26-bit span with one value
-#: column pins ~4.3GB transient; on a small host that must divert to
-#: the sort path instead of inviting the OOM killer)
-_SCATTER_AGG_BUDGET_BYTES = min(6 << 30, max(_host_ram_bytes() // 4, 1 << 30))
-
-
 def _packed_bucket(key_cols, key_nulls, pack):
-    """(bucket id per row, bucket count B) of the two bucket arms: the
+    """(bucket id per row, bucket count B) of the dense arm: the
     statically packed group key as the sort arm packs it (NULL is 0, a
     value shifts by offset + 1), int64, clipped into [0, B)."""
     B = 1 << sum(b for b, _o in pack)
@@ -1123,90 +1092,6 @@ def _packed_bucket(key_cols, key_nulls, pack):
         v = jnp.where(key_nulls[i], jnp.zeros((), dtype=jnp.int64), shifted)
         bucket = (bucket << bits) | v
     return jnp.clip(bucket, 0, B - 1), B
-
-
-def _agg_scatter_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
-                      n_keys, agg_ops, capacity, pack):
-    """Dense-bucket aggregation: bucket id = the statically packed group
-    key; per aggregate ONE scatter-add/min/max over the bucket space, then
-    a compaction scatter into the capacity-sized output slots.
-
-    XLA-CPU-only arm (see agg_arm), for packed key spaces above the dense
-    bound: scatters there are tight O(n) loops (~100x faster than the
-    backend's sort), while on TPU non-unique scatters serialize. Both
-    produce identical group sets; bucket order = packed-key order, and the
-    representative row per group is the scatter-min of kept row positions,
-    so first_row/key decode semantics match the stable-sort path."""
-    n = mask.shape[0]
-    bucket, B = _packed_bucket(key_cols, key_nulls, pack)
-    pos = jnp.arange(n)
-    ones = jnp.where(mask, 1, 0)
-    cnt_rows = jnp.zeros(B, dtype=jnp.int64).at[bucket].add(ones)
-    rep = jnp.full(B, n, dtype=jnp.int64).at[bucket].min(
-        jnp.where(mask, pos, n))
-    live = cnt_rows > 0
-    n_groups = jnp.sum(live)
-    rank = jnp.cumsum(live) - 1
-    tgt = jnp.where(live, rank, capacity)  # dead buckets drop on compact
-
-    def compact(arr_B):
-        out_dt = arr_B.dtype
-        return jnp.zeros(capacity, dtype=out_dt).at[tgt].set(
-            arr_B, mode="drop")
-
-    rep_safe = jnp.clip(rep, 0, jnp.maximum(n - 1, 0))
-    key_out = tuple(compact(k[rep_safe]) for k in key_cols)
-    key_null_out = tuple(compact(kn[rep_safe]) for kn in key_nulls)
-
-    nn_cache = {}
-
-    def nonnull_counts(j):
-        hit = nn_cache.get(id(val_nulls[j]))
-        if hit is None:
-            keep = mask & ~val_nulls[j]
-            hit = jnp.zeros(B, dtype=jnp.int64).at[bucket].add(
-                jnp.where(keep, 1, 0))
-            nn_cache[id(val_nulls[j])] = hit
-        return hit
-
-    results = []
-    result_nulls = []
-    for j, opn in enumerate(agg_ops):
-        v = val_cols[j]
-        vn = val_nulls[j]
-        keep = mask & ~vn
-        if opn == "first":
-            results.append(compact(v[rep_safe]))
-            result_nulls.append(compact(vn[rep_safe]))
-            continue
-        nn = nonnull_counts(j)
-        if opn == "count":
-            results.append(compact(nn))
-            result_nulls.append(jnp.zeros(capacity, dtype=bool))
-            continue
-        if opn == "sum_i":
-            acc = jnp.zeros(B, dtype=jnp.int64).at[bucket].add(
-                jnp.where(keep, v.astype(jnp.int64), 0))
-        elif opn == "sum_f":
-            acc = jnp.zeros(B, dtype=jnp.float64).at[bucket].add(
-                jnp.where(keep, v.astype(jnp.float64), 0.0))
-        elif opn == "min":
-            big = (jnp.inf if jnp.issubdtype(v.dtype, jnp.floating)
-                   else jnp.iinfo(v.dtype).max)
-            acc = jnp.full(B, big, dtype=v.dtype).at[bucket].min(
-                jnp.where(keep, v, big))
-        elif opn == "max":
-            small = (-jnp.inf if jnp.issubdtype(v.dtype, jnp.floating)
-                     else jnp.iinfo(v.dtype).min)
-            acc = jnp.full(B, small, dtype=v.dtype).at[bucket].max(
-                jnp.where(keep, v, small))
-        else:
-            raise ValueError(opn)
-        results.append(compact(acc))
-        result_nulls.append(compact(nn) == 0)
-    valid = jnp.arange(capacity) < n_groups
-    return (key_out, key_null_out, tuple(results), tuple(result_nulls),
-            n_groups, valid)
 
 
 #: dense-arm bound: packed key spaces of at most this many buckets
@@ -1228,21 +1113,19 @@ _DENSE_AGG_OPS = frozenset({"count", "sum_i", "min", "max", "first"})
 
 def agg_arm(pack, agg_ops, gathered=False) -> str:
     """Which arm of _agg_impl aggregates a fragment with this static key
-    packing and these ops: "dense" | "scatter" | "sort". Host-callable
-    (the dispatchers count fragments by it) and what _agg_impl itself
-    asks at trace time.
+    packing and these ops: "dense" | "sort". Host-callable (the
+    dispatchers count fragments by it) and what _agg_impl itself asks at
+    trace time. It reads its arguments only, so every backend traces the
+    program the chip runs.
 
     - dense: the packed key space holds at most _DENSE_AGG_BUCKETS
       buckets, every op is in _DENSE_AGG_OPS and the inputs are not
-      `gathered`. Any backend: tier-1 on XLA:CPU traces the program the
-      chip runs.
-    - scatter: XLA:CPU only, above the dense bound (or gathered), inside
-      the scatter bit and byte budgets.
+      `gathered`.
     - sort: the rest (no static packing, wide keys, sum_f, cnt_dist).
 
     gathered: the aggregate's inputs come out of a join's per-row gather
     chain in the same program (device_join.compile_fragment, the mesh
-    body). Those fragments keep the arm they had: on the v5e TPC-H Q5's
+    body). Those fragments keep the sort arm: on the v5e TPC-H Q5's
     aggregate (32 buckets) fell by 0.23 s under the dense arm and the
     compiler then ran the probe chain's same gathers 1.35 s slower
     (PERF.md section 6, PR 26)."""
@@ -1252,17 +1135,6 @@ def agg_arm(pack, agg_ops, gathered=False) -> str:
     if (not gathered and (1 << bits) <= _DENSE_AGG_BUCKETS
             and all(op in _DENSE_AGG_OPS for op in agg_ops)):
         return "dense"
-    if (bits <= _SCATTER_AGG_BITS
-            # live bucket arrays scale with the aggregate count: cnt +
-            # rep + rank + tgt + live + per-agg acc + nullable nn caches
-            # all stay resident through compaction — bound total BYTES,
-            # not just key bits, or a many-column agg at 25 bits pins
-            # gigabytes of 32M-slot arrays at once
-            and (1 << bits) * (2 * len(agg_ops) + 6) * 8
-            <= _SCATTER_AGG_BUDGET_BYTES
-            and "cnt_dist" not in agg_ops
-            and jax.default_backend() == "cpu"):
-        return "scatter"
     return "sort"
 
 
@@ -1383,14 +1255,11 @@ def _agg_dense_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
 def _agg_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
               n_keys, agg_ops, capacity, pack=None, gathered=False):
     """One fused kernel: filter mask + group-by + aggregate, in one of
-    three arms chosen at trace time by agg_arm(pack, agg_ops, gathered):
+    two arms chosen at trace time by agg_arm(pack, agg_ops, gathered):
 
     - dense (_agg_dense_impl): small packed key spaces whose inputs are
-      not `gathered`, any backend — one masked reduction per bucket, no
-      sort, gather or scatter at the fact length (TPC-H Q6's one group,
-      Q1's four).
-    - scatter (_agg_scatter_impl): XLA:CPU only, above the dense bound
-      or gathered.
+      not `gathered` — one masked reduction per bucket, no sort, gather
+      or scatter at the fact length (TPC-H Q6's one group, Q1's four).
     - sort (below): the rest. Sort-based grouping + boundary arithmetic —
       the XLA/TPU-native answer to the reference's hash tables
       (executor/aggregate.go): static shapes, no data-dependent control
@@ -1421,11 +1290,6 @@ def _agg_impl(key_cols, key_nulls, val_cols, val_nulls, mask,
     if arm == "dense":
         return _agg_dense_impl(key_cols, key_nulls, val_cols, val_nulls,
                                mask, agg_ops, capacity, pack)
-    if arm == "scatter":
-        with jax.named_scope("k_agg_segment"):
-            return _agg_scatter_impl(key_cols, key_nulls, val_cols,
-                                     val_nulls, mask, n_keys, agg_ops,
-                                     capacity, pack)
     n = mask.shape[0]
     with jax.named_scope("k_agg_segment"):
         kept = jnp.sum(mask)

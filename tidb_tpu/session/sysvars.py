@@ -66,7 +66,7 @@ class SysVar:
             if self.choices and v.lower() not in self.choices:
                 raise TiDBError(f"Variable '{self.name}' can't be set to the value of '{v}'")
             # store normalized: every reader compares lowercase literals
-            # (SET tidb_device_compact = OFF must actually disable it)
+            # (SET tidb_executor_engine = HOST must actually select it)
             return v.lower()
         return v
 
@@ -153,9 +153,6 @@ for _v in [
     # factors. 2 = powers of sqrt(2) (<=19% padding), 1 = powers of 2,
     # 0 = exact shapes (recompile per row count)
     SysVar("tidb_device_shape_buckets", SCOPE_BOTH, "2", "int", 0, 8),
-    # post-join compaction in device fragments: auto = CPU backend only
-    SysVar("tidb_device_compact", SCOPE_BOTH, "auto", "enum",
-           choices=("auto", "on", "off")),
     SysVar("tidb_slow_log_threshold", SCOPE_BOTH, "300", "int", 0),
     # query-lifecycle span tracing (session/tracing.py): fraction of
     # statements sampled into a full span trace (0 = off, the default —
