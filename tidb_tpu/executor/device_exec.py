@@ -297,6 +297,11 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # JoinIndex) or by binary search (`sorted`) —
                # note_join_layouts
                "join_direct": 0, "join_search": 0,
+               # column / mask / row-map gathers of dispatched join
+               # fragments' programs, and those the program holds the
+               # result of already (a leaf read in place, a NULL-free
+               # column's mask) — note_join_gathers
+               "join_gathers": 0, "join_gathers_elided": 0,
                # scan-aggregate fragments dispatched, by the path
                # scan_stream_rows (or tidb_device_stream_rows) chose:
                # device_agg over resident columns / device_agg_streaming
@@ -334,7 +339,9 @@ def _tls_stats() -> dict:
                                 "mode_cached": 0, "mode_prewarmed": 0,
                                 "mode_async_pending": 0, "mode_sync": 0,
                                 "agg_dense": 0, "agg_sorted": 0,
-                                "join_direct": 0, "join_search": 0}
+                                "join_direct": 0, "join_search": 0,
+                                "join_gathers": 0,
+                                "join_gathers_elided": 0}
     return st
 
 
@@ -371,6 +378,19 @@ def note_join_layouts(strategies):
         if st is not None and st[2] is not None:
             _bump("join_direct" if st[2].kind == "dense"
                   else "join_search")
+
+
+def note_join_gathers(fn):
+    """Count one dispatched join fragment's gather chain: the column /
+    mask / row-map gathers its program (`fn`, what
+    device_join.compile_fragment returned, traced by now) emits, and
+    those it elides because the leaf's row map is still the identity or
+    the host knows the column holds no NULL.  Once per fragment, whatever
+    its capacity retries and pages; EXPLAIN ANALYZE's ``gathers:``
+    annotation and the benchmark's ``join.elided_gather_share`` read the
+    counters."""
+    _bump("join_gathers", fn.gathers["emitted"])
+    _bump("join_gathers_elided", fn.gathers["elided"])
 
 
 def pipe_cache_stats(thread_local: bool = False) -> dict:

@@ -55,7 +55,7 @@ from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
     _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm,
-    note_join_layouts)
+    note_join_gathers, note_join_layouts)
 from .join_index import build_join_index
 
 
@@ -611,7 +611,7 @@ def _pack_probe(kds, knulls, pvalid, packs):
 
 
 def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
-                     capacity, key_pack, agg_meta, raw_tail=False,
+                     capacity, key_pack, agg_meta, nonnull, raw_tail=False,
                      strategies=None):
     """Build the jitted end-to-end program. caps: per-join static
     capacities aligned with `joins`. Returns jitted fn(env, jidx, n_lives)
@@ -629,6 +629,18 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
     The aggregate runs at the fact length: the fragment's output is
     fact-shaped with a sparse validity mask (the price of the gather-join
     design).
+
+    The gather chain emits a gather only where its result can differ from
+    what the program already holds.  A leaf's row map starts as the
+    identity and stays it until a join re-indexes the leaf: its columns
+    and masks are read in place, and a map composed through the identity
+    is the index itself.  `nonnull` (nonnull_cols: global column indices
+    the host knows hold no NULL) gives a re-indexed column a constant
+    mask.  The facts change the program, so they belong in the caller's
+    pipeline key.  The returned fn carries `gathers`: {"emitted",
+    "elided"} column / mask / row-map gathers of the traced program, one
+    per distinct (source, indices) pair as XLA's CSE leaves them
+    (device_exec.note_join_gathers reads it after a dispatch).
 
     raw_tail: stop BEFORE the in-kernel aggregate and return the evaluated
     (key_cols, key_nulls, val_cols, val_nulls, mask) row arrays instead.
@@ -670,8 +682,16 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                       for k in jn.right_keys]
         jn._oc_fns = [dev.compile_expr(_shift_expr(c, off_o), dcols)
                       for c in jn.other_conds]
+        # what each of them reads: gather_env gathers just that
+        jn._lk_cols = sorted(_expr_cols(jn.left_keys, off_l))
+        jn._rk_cols = sorted(_expr_cols(jn.right_keys, off_r))
+        jn._oc_cols = sorted(_expr_cols(jn.other_conds, off_o))
     cond_fns = [dev.compile_expr(c, dcols) for c in agg_conds]
+    top_cols = sorted(_expr_cols(agg_conds) | _agg_cols(agg_plan))
+    leaf_of = {leaf.offset + i: leaf.leaf_id
+               for leaf in leaves for i in range(leaf.ncols)}
     key_fns, val_plan, agg_ops, slots = agg_meta
+    gathers = {}
 
     def run(env, jidx, n_lives):
         _count_trace()
@@ -694,32 +714,67 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                     mask = mask & (jnp.arange(n) < n_lives[leaf.leaf_id])
                 else:
                     mask = jnp.arange(n) < n_lives[leaf.leaf_id]
-                return {leaf.leaf_id: jnp.arange(n)}, mask
+                return {leaf.leaf_id: ()}, mask
 
         overflows = []
         span_ovfs = []
+        # the fragment's column / mask / row-map gathers, one per distinct
+        # (source, indices): emitted key -> (result, indices), elided key
+        # -> indices.  The indices are held so that their id() stays
+        # theirs for the whole trace.
+        emitted, elided = {}, {}
+
+        def take(key, arr, idx):
+            """`arr[idx]`, traced once per (key, idx): XLA folds repeats
+            anyway, and the counts want the distinct ones."""
+            k = key + (id(idx),)
+            if k not in emitted:
+                emitted[k] = (arr[idx], idx)
+            return emitted[k][0]
+
+        def rows_of(lid, chain):
+            """Leaf `lid`'s row for every row of the relation.  A row map
+            is the chain of indices the joins re-indexed the leaf by:
+            () is the identity (None here: read in place), and one index
+            IS the map (the gather of an arange it stood for is elided).
+            Composed on first use, so a leaf nothing reads costs none."""
+            if not chain:
+                return None
+            if len(chain) == 1:
+                elided[("map", lid, id(chain[0]))] = chain[0]
+                return chain[0]
+            return take(("map", lid) + tuple(id(i) for i in chain[:-1]),
+                        rows_of(lid, chain[:-1]), chain[-1])
 
         @jax.named_scope("k_join_probe")
-        def gather_env(idxmap, valid, node, nullmaps=None):
-            """env of gathered (relation-space) columns for `node`'s
-            subtree, keyed by global column index. Unused columns' gathers
-            are dead code XLA eliminates — laziness here is free.
+        def gather_env(idxmap, cols, nullmaps):
+            """env of gathered (relation-space) columns, keyed by global
+            column index, for the columns `cols` that the expressions
+            about to be evaluated read.
             nullmaps[leaf_id] marks rows where that leaf contributed no
             match (left-join null extension): its columns read as NULL."""
             out = {}
-            for leaf in leaves:
-                if leaf.leaf_id in idxmap and leaf.leaf_id in node.leaf_ids:
-                    idx = idxmap[leaf.leaf_id]
-                    ext = (nullmaps or {}).get(leaf.leaf_id)
-                    for i in range(leaf.ncols):
-                        hit = env.get(leaf.offset + i)
-                        if hit is None:  # pruned (unused) column
-                            continue
-                        d, nl = hit
-                        nli = nl[idx]
-                        if ext is not None:
-                            nli = nli | ext
-                        out[leaf.offset + i] = (d[idx], nli)
+            for g in cols:
+                lid = leaf_of[g]
+                d, nl = env[g]
+                idx = rows_of(lid, idxmap[lid])
+                if idx is None:
+                    # the leaf's own rows: nothing to gather, and padding
+                    # rows keep their null=True
+                    elided[(g, "d")] = elided[(g, "n")] = None
+                else:
+                    d = take((g, "d"), d, idx)
+                    if g in nonnull:
+                        # no live row holds a NULL, and a row the
+                        # relation keeps valid addresses a live row
+                        elided[(g, "n", id(idx))] = idx
+                        nl = jnp.zeros(idx.shape, dtype=bool)
+                    else:
+                        nl = take((g, "n"), nl, idx)
+                ext = nullmaps.get(lid)
+                if ext is not None:
+                    nl = nl | ext
+                out[g] = (d, nl)
             return out
 
         @jax.named_scope("k_join_probe")
@@ -732,16 +787,16 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
             kind, side, idx = strategies[node.pos]
             jkind = node.kind
             if side == "right":
-                pidx_map, pvalid, pside = lidx_map, lvalid, node.left
+                pidx_map, pvalid = lidx_map, lvalid
                 bidx_map, bvalid = ridx_map, rvalid
                 pnull, bnull = lnull, rnull
-                key_fns_p = node._lk_fns
+                key_fns_p, key_cols_p = node._lk_fns, node._lk_cols
             else:
-                pidx_map, pvalid, pside = ridx_map, rvalid, node.right
+                pidx_map, pvalid = ridx_map, rvalid
                 bidx_map, bvalid = lidx_map, lvalid
                 pnull, bnull = rnull, lnull
-                key_fns_p = node._rk_fns
-            penv = gather_env(pidx_map, pvalid, pside, pnull)
+                key_fns_p, key_cols_p = node._rk_fns, node._rk_cols
+            penv = gather_env(pidx_map, key_cols_p, pnull)
             n_probe = pvalid.shape[0]
             kds, knulls = zip(*[
                 dev.broadcast_1d(*f(penv), n_probe) for f in key_fns_p])
@@ -796,11 +851,11 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                     # joins — evaluate on the joined candidate row first
                     cand_idx = dict(pidx_map)
                     for lid, v in bidx_map.items():
-                        cand_idx[lid] = v[bi]
+                        cand_idx[lid] = v + (bi,)
                     cand_null = dict(pnull)
                     for lid, v in bnull.items():
                         cand_null[lid] = v[bi]
-                    jenv = gather_env(cand_idx, hit, node, cand_null)
+                    jenv = gather_env(cand_idx, node._oc_cols, cand_null)
                     for f in node._oc_fns:
                         d, nl = f(jenv)
                         hit = hit & (d != 0) & ~nl
@@ -814,7 +869,7 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                 out = dict(pidx_map)
                 nulls = dict(pnull)
                 for lid, v in bidx_map.items():
-                    out[lid] = v[bi]
+                    out[lid] = v + (bi,)
                 for lid, v in bnull.items():
                     nulls[lid] = v[bi]
                 if jkind == "left":
@@ -846,10 +901,10 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
             else:
                 valid = (posn < total) & hit & pvalid[pi]
             overflows.append(total)
-            out = {k: v[pi] for k, v in pidx_map.items()}
+            out = {k: v + (pi,) for k, v in pidx_map.items()}
             nulls = {k: v[pi] for k, v in pnull.items()}
             for lid, v in bidx_map.items():
-                out[lid] = v[bi]
+                out[lid] = v + (bi,)
             for lid, v in bnull.items():
                 nulls[lid] = v[bi]
             if jkind == "left":
@@ -873,8 +928,8 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                 if node.kind != "inner":
                     raise DeviceUnsupported(
                         f"{node.kind} join needs an indexed build side")
-                lenv = gather_env(lidx, lvalid, node.left, lnull)
-                renv = gather_env(ridx, rvalid, node.right, rnull)
+                lenv = gather_env(lidx, node._lk_cols, lnull)
+                renv = gather_env(ridx, node._rk_cols, rnull)
                 with jax.named_scope("k_join_probe"):
                     lkds, lknulls = zip(*[
                         dev.broadcast_1d(*f(lenv), lvalid.shape[0])
@@ -890,12 +945,12 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                     bk_d, bvalid, pk_d, pvalid, node.cap)
                 overflows.append(total)
                 with jax.named_scope("k_join_probe"):
-                    idxmap = {k: v[pi] for k, v in lidx.items()}
-                    idxmap.update({k: v[bi] for k, v in ridx.items()})
+                    idxmap = {k: v + (pi,) for k, v in lidx.items()}
+                    idxmap.update({k: v + (bi,) for k, v in ridx.items()})
                     nullmaps = {k: v[pi] for k, v in lnull.items()}
                     nullmaps.update({k: v[bi] for k, v in rnull.items()})
             if node._oc_fns and node.kind == "inner":
-                jenv = gather_env(idxmap, valid, node, nullmaps)
+                jenv = gather_env(idxmap, node._oc_cols, nullmaps)
                 with jax.named_scope("k_filter"):
                     for f in node._oc_fns:
                         d, nl = f(jenv)
@@ -903,7 +958,7 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
             return idxmap, valid, nullmaps
 
         idxmap, valid, nullmaps = eval_node(root)
-        fenv = gather_env(idxmap, valid, root, nullmaps)
+        fenv = gather_env(idxmap, top_cols, nullmaps)
         with jax.named_scope("k_filter"):
             mask = valid
             for f in cond_fns:
@@ -930,6 +985,7 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                     d = d.astype(jnp.int64)
                 val_cols.append(d)
                 val_nulls.append(nl)
+        gathers.update(emitted=len(emitted), elided=len(elided))
         if raw_tail:
             raw = (tuple(key_cols), tuple(key_nulls), tuple(val_cols),
                    tuple(val_nulls), mask)
@@ -942,7 +998,9 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                                 gathered=True)
         return agg_out, tuple(overflows), tuple(span_ovfs), kept_total
 
-    return _timed_jit(run)
+    fn = _timed_jit(run)
+    fn.gathers = gathers  # filled by the trace: note_join_gathers
+    return fn
 
 
 def _shift_expr(e, offset):
@@ -1117,6 +1175,8 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     key_fns, key_meta, key_pack, val_plan, agg_ops, slots = agg_meta_full
     agg_meta = (key_fns, val_plan, agg_ops, slots)
     n_lives = tuple(np.int64(leaf.chunk.num_rows) for leaf in leaves)
+    nonnull = nonnull_cols(
+        root, leaves, _fragment_used_cols(leaves, joins, agg_plan, agg_conds))
 
     sig = fragment_sig(leaves, joins, agg_conds, agg_plan)
     dict_refs = tuple(dc.dictionary for dc in dcols.values()
@@ -1138,7 +1198,8 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     note_join_layouts(jn.strategy for jn in joins)
     for _attempt in range(12):
         caps = [jn.cap for jn in joins]
-        key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops))
+        key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
+               nonnull)
         t0 = _time.perf_counter()
 
         def build(caps=tuple(caps), cap=capacity):
@@ -1147,11 +1208,13 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
             # query has already degraded to host, so nothing mutates them
             return compile_fragment(root, leaves, joins, agg_plan,
                                     agg_conds, list(caps), cap, key_pack,
-                                    agg_meta)
+                                    agg_meta, nonnull)
         fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
                               args=(env, jidx, n_lives), shape="join",
                               sig=sig)
         agg_out, ovf_d, sovf_d, kept_d = fn(env, jidx, n_lives)
+        if _attempt == 0:
+            note_join_gathers(fn)
         from .device_exec import AggFetch, resolve_topn
         f = AggFetch(agg_out, extras=(ovf_d, sovf_d, kept_d),
                      topn=resolve_topn(agg_plan, slots))
@@ -1300,45 +1363,65 @@ def _dim_resident_budget() -> int:
     return share if share > 0 else _DIM_RESIDENT_BUDGET_DEFAULT
 
 
+def _inplace_leaf(root):
+    """The leaf whose rows the fragment's output still addresses by
+    position: the probe end of a chain of probe-shaped joins (unique
+    builds, semi / anti), whose row map no join re-indexes.  None once
+    an expansion stands in the way."""
+    node = root
+    while isinstance(node, _JoinNode):
+        st = node.strategy
+        if st is None or (st[0] != "uniq"
+                          and node.kind not in ("semi", "anti")):
+            return None
+        node = node.left if st[1] == "right" else node.right
+    return node
+
+
+def nonnull_cols(root, leaves, used) -> tuple:
+    """Global indices (sorted) of the fragment's `used` columns that the
+    host knows hold no NULL and whose mask the program would otherwise
+    gather: compile_fragment's `nonnull`, and an element of the pipeline
+    key of everyone who calls it (a column's first NULL must find a new
+    program).  The leaf read in place needs no fact, and a paged (memmap)
+    column is never scanned for one."""
+    from ..storage.paged import is_paged
+    inplace = _inplace_leaf(root)
+    return tuple(sorted(
+        leaf.offset + i for leaf in leaves if leaf is not inplace
+        for i, c in enumerate(leaf.chunk.columns)
+        if leaf.offset + i in used and not is_paged(c)
+        and not c.has_nulls()))
+
+
+def _expr_cols(exprs, offset=0) -> set:
+    """Column indices the expressions read, shifted by `offset`."""
+    used = set()
+    for e in exprs:
+        e.columns_used(used)
+    return {offset + i for i in used}
+
+
+def _agg_cols(agg_plan) -> set:
+    """Global column indices the aggregate's keys and inputs read."""
+    return _expr_cols(agg_plan.group_exprs) | _expr_cols(
+        a for d in agg_plan.aggs for a in d.args)
+
+
 def _fragment_used_cols(leaves, joins, agg_plan, agg_conds):
     """Global column indices the fragment actually reads — per-page probe
     transfers and dim uploads carry only these (a 16-wide fact scanned
     for 4 columns must not pay 4x the transfer bytes)."""
-    used = set()
+    used = _expr_cols(agg_conds) | _agg_cols(agg_plan)
     for leaf in leaves:
-        for c in leaf.conds:
-            s = set()
-            c.columns_used(s)
-            used.update(leaf.offset + i for i in s)
+        used |= _expr_cols(leaf.conds, leaf.offset)
     for jn in joins:
         off_l = 0 if jn.global_keys else jn.left.offset
         off_r = 0 if jn.global_keys else jn.right.offset
         off_o = 0 if jn.global_keys else jn.offset
-        for k in jn.left_keys:
-            s = set()
-            k.columns_used(s)
-            used.update(off_l + i for i in s)
-        for k in jn.right_keys:
-            s = set()
-            k.columns_used(s)
-            used.update(off_r + i for i in s)
-        for c in jn.other_conds:
-            s = set()
-            c.columns_used(s)
-            used.update(off_o + i for i in s)
-    for e in agg_plan.group_exprs:
-        s = set()
-        e.columns_used(s)
-        used.update(s)
-    for d in agg_plan.aggs:
-        for a in d.args:
-            s = set()
-            a.columns_used(s)
-            used.update(s)
-    for c in agg_conds:
-        s = set()
-        c.columns_used(s)
-        used.update(s)
+        used |= _expr_cols(jn.left_keys, off_l)
+        used |= _expr_cols(jn.right_keys, off_r)
+        used |= _expr_cols(jn.other_conds, off_o)
     return used
 
 
@@ -1399,6 +1482,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
     agg_meta = (key_fns, val_plan, agg_ops, slots)
 
     used = _fragment_used_cols(leaves, joins, agg_plan, agg_conds)
+    nonnull = nonnull_cols(root, leaves, used)
     # leaf_rel reads each leaf's row count off its first env entry — keep
     # at least one column per leaf alive
     for leaf in leaves:
@@ -1465,12 +1549,12 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
     for _attempt in range(4):
         caps = [page_rows] * len(joins)
         key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
-               "paged")
+               nonnull, "paged")
 
         def build(caps=tuple(caps), cap=capacity):
             return compile_fragment(root, leaves, joins, agg_plan,
                                     agg_conds, list(caps), cap, key_pack,
-                                    agg_meta)
+                                    agg_meta, nonnull)
         # per-page env is assembled inside the loop below, so there is no
         # whole-call arg spec to record: the paged fragment compiles sync
         # (still breaker-guarded + persisted through the compile service)
@@ -1492,6 +1576,8 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
                 env[gidx] = (pad_page(d, lo, hi), pad_page(nl, lo, hi, True))
             t1 = _time.perf_counter()
             agg_out, _ovf, _sovf, _kept = fn(env, jidx, page_lives(hi, lo))
+            if _attempt == 0 and lo == 0:
+                note_join_gathers(fn)
             t2 = _time.perf_counter()
             stats["pages"] += 1
             stats["slice_s"] += t1 - t0

@@ -107,6 +107,13 @@ class QueryExecutor:
                                        ("search", "join_search"))]
             self.annotate(join="+".join(
                 f"{name} x{n}" for name, n in layouts if n > 0) or None)
+            # the join fragment's column / mask / row-map gathers, and
+            # (-n) those its program elides
+            # (device_exec.note_join_gathers): gathers:4 (-15)
+            kept, cut = (st1[k] - st0[k] for k in (
+                "join_gathers", "join_gathers_elided"))
+            self.annotate(
+                gathers=f"{kept} (-{cut})" if kept + cut else None)
             from .supervisor import abandoned_calls
             n_abandoned = abandoned_calls()
             if n_abandoned:

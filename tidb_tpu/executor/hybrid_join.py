@@ -368,7 +368,7 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
     the hybrid language (the caller falls through to the existing
     paths)."""
     from .device_join import (_fragment_used_cols, _leaf_meta,
-                              fragment_sig)
+                              fragment_sig, nonnull_cols)
     from .device_exec import _MERGE_OPS, _plan_agg
     attach(ctx)
     big = next(lf for lf in leaves if lf.leaf_id == big_id)
@@ -569,8 +569,13 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
         stub = _part_index_stub(packs, build_bucket, max_part)
         prev_strategy = big_jn.strategy
         big_jn.strategy = ("uniq", "right", stub)
+        # the NULL-free facts the program is compiled with ride in the
+        # signature, and so in the pipeline key: a partition of a column
+        # holds a NULL only if the column does
+        nonnull = nonnull_cols(root, leaves, used)
         sig = (fragment_sig(leaves, joins, agg_conds, agg_plan)
-               + f"|hyb{n_parts}/{probe_bucket}/{build_bucket}")
+               + f"|hyb{n_parts}/{probe_bucket}/{build_bucket}"
+               + f"|nn{','.join(map(str, nonnull))}")
 
         if n_dev > 0 and _compile_pending(ctx, sig, key_pack, agg_ops,
                                           probe_bucket):
@@ -579,7 +584,7 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
             n_dev, reason = 0, "compile_pending"
             _kick_bg_compile(ctx, sig, key_pack, agg_ops, probe_bucket,
                              root, leaves, joins, agg_plan, agg_conds,
-                             agg_meta, dcols)
+                             agg_meta, dcols, nonnull)
         with _LOCK:
             tp = _THROUGHPUT.get(sig)
         if tp and n_dev > 0:
@@ -642,7 +647,7 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
                     pparts, dev_pids, big_used, probe_used, used,
                     build_key_local, packs, build_bucket, probe_bucket,
                     max_part, agg_meta, agg_conds, key_pack, merge_ops,
-                    n_keys, nvals, sig, dcols, root, agg_plan)
+                    n_keys, nvals, sig, dcols, root, agg_plan, nonnull)
         t_dev = time.perf_counter() - t_dev0
 
         # -- join the host half, merge, assemble ------------------------
@@ -791,7 +796,8 @@ def _hybrid_pipe_key(sig, key_pack, agg_ops, probe_bucket):
 
 
 def _hybrid_pipeline(ctx, sig, key_pack, agg_ops, probe_bucket, root,
-                     leaves, joins, agg_plan, agg_conds, agg_meta, dcols):
+                     leaves, joins, agg_plan, agg_conds, agg_meta, dcols,
+                     nonnull):
     """THE hybrid pipeline resolution: one raw-tail program with every
     join probe-shaped at the common probe bucket and the strategy
     snapshot (the partition stub) bound into the builder — a deferred
@@ -811,21 +817,22 @@ def _hybrid_pipeline(ctx, sig, key_pack, agg_ops, probe_bucket, root,
     def build():
         return compile_fragment(root, leaves, joins, agg_plan, agg_conds,
                                 [probe_bucket] * len(joins), 1, key_pack,
-                                agg_meta, raw_tail=True,
+                                agg_meta, nonnull, raw_tail=True,
                                 strategies=strategies)
     return acquire_pipeline(key, build, dict_refs, ctx=ctx, shape="join",
                             sig=sig)
 
 
 def _kick_bg_compile(ctx, sig, key_pack, agg_ops, probe_bucket, root,
-                     leaves, joins, agg_plan, agg_conds, agg_meta, dcols):
+                     leaves, joins, agg_plan, agg_conds, agg_meta, dcols,
+                     nonnull):
     """Enqueue the hybrid pipeline's background build (compile service)
     without dispatching: acquire_pipeline raises the pending
     DeviceUnsupported by design — here that IS the expected outcome."""
     try:
         _hybrid_pipeline(ctx, sig, key_pack, agg_ops, probe_bucket, root,
                          leaves, joins, agg_plan, agg_conds, agg_meta,
-                         dcols)
+                         dcols, nonnull)
     except DeviceUnsupported:
         pass
 
@@ -884,13 +891,14 @@ def _device_pass(ctx, leaves, joins, probe, big, big_jn, brows, bparts,
                  pparts, dev_pids, big_used, probe_used, used,
                  build_key_local, packs, build_bucket, probe_bucket,
                  max_part, agg_meta, agg_conds, key_pack, merge_ops,
-                 n_keys, nvals, sig, dcols, root, agg_plan):
+                 n_keys, nvals, sig, dcols, root, agg_plan, nonnull):
     """The device half: upload the fitting build partitions as resident
     bucket-padded join indexes + columns, then ONE pipelined probe pass
     dispatching each partition's probe slice through the shared compiled
     raw-tail fragment.  Returns (per-partition compact partial states,
     probed row total)."""
-    from .device_exec import _merge_states_host, page_singleton_state
+    from .device_exec import (_merge_states_host, note_join_gathers,
+                              page_singleton_state)
     key_fns, val_plan, agg_ops, slots = agg_meta
     per_double = dev.shape_buckets(ctx)
 
@@ -944,7 +952,7 @@ def _device_pass(ctx, leaves, joins, probe, big, big_jn, brows, bparts,
     # half's states — same fold, same order-insensitive merge)
     fn = _hybrid_pipeline(ctx, sig, key_pack, agg_ops, probe_bucket, root,
                           leaves, joins, agg_plan, agg_conds, agg_meta,
-                          dcols)
+                          dcols, nonnull)
 
     base_lives = [np.int64(lf.chunk.num_rows) for lf in leaves]
     check = getattr(ctx, "check_killed", None)
@@ -974,6 +982,8 @@ def _device_pass(ctx, leaves, joins, probe, big, big_jn, brows, bparts,
             lives[probe.leaf_id] = np.int64(len(prow))
             lives[big.leaf_id] = n_big
             raw, _ovf, _sovf, _kept = fn(env, jidx, tuple(lives))
+            if not states:
+                note_join_gathers(fn)
             page = page_singleton_state(raw[0], raw[1], raw[2], raw[3],
                                         raw[4], agg_ops)
             st, _ = _merge_states_host([page], 16, n_keys, nvals,

@@ -87,7 +87,8 @@ class Column:
     # weak back-reference per cached device upload so a collected Column
     # releases its bytes from the ledger
     __slots__ = ("ftype", "data", "nulls", "_dict", "_dict_ci", "_device",
-                 "_join_index", "_minmax", "_dict_sig", "__weakref__")
+                 "_join_index", "_minmax", "_has_nulls", "_dict_sig",
+                 "__weakref__")
 
     def __init__(self, ftype: FieldType, data: np.ndarray, nulls: np.ndarray | None = None):
         self.ftype = ftype
@@ -102,6 +103,7 @@ class Column:
         #                      byte-accounted, evictable — AST-linted)
         self._join_index = None  # cached host join index (executor/join_index)
         self._minmax = None  # cached (min, max) over non-null int rows
+        self._has_nulls = None  # cached nulls.any()
         self._dict_sig = None  # cached content hash of the dictionary
 
     def __len__(self):
@@ -122,7 +124,7 @@ class Column:
 
     def __setstate__(self, st):
         for s in ("ftype", "data", "nulls", "_dict", "_dict_ci", "_device",
-                  "_join_index", "_minmax", "_dict_sig"):
+                  "_join_index", "_minmax", "_has_nulls", "_dict_sig"):
             setattr(self, s, st.get(s))
 
     @classmethod
@@ -183,12 +185,21 @@ class Column:
                     or not np.issubdtype(self.data.dtype, np.integer)):
                 self._minmax = (None,)
             else:
-                d = self.data[~self.nulls] if self.nulls.any() else self.data
+                d = self.data[~self.nulls] if self.has_nulls() else self.data
                 if d.size == 0:
                     self._minmax = (None,)
                 else:
                     self._minmax = (int(d.min()), int(d.max()))
         return None if self._minmax[0] is None else self._minmax
+
+    def has_nulls(self) -> bool:
+        """Does any row hold a NULL?  Read from the data (a schema without
+        NOT NULL says nothing) and cached like minmax(): a Column's arrays
+        never change in place, a write installs new Columns.  It scans the
+        whole mask, so a paged (memmap) column is never asked."""
+        if self._has_nulls is None:
+            self._has_nulls = bool(self.nulls.any())
+        return self._has_nulls
 
     # -- string device encodings -------------------------------------------
 
@@ -333,6 +344,7 @@ class LazyDictColumn(Column):
         self._device = None
         self._join_index = None
         self._minmax = (None,)
+        self._has_nulls = None
         self._dict_sig = None
         self._mat = None
 
@@ -355,8 +367,8 @@ class LazyDictColumn(Column):
         self.ftype = st["ftype"]
         self.nulls = st["nulls"]
         self._dict = st["_dict"]
-        for s in ("_dict_ci", "_device", "_join_index", "_dict_sig",
-                  "_mat"):
+        for s in ("_dict_ci", "_device", "_join_index", "_has_nulls",
+                  "_dict_sig", "_mat"):
             setattr(self, s, None)
         self._minmax = (None,)
 
