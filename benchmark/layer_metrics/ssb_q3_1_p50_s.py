@@ -1,0 +1,8 @@
+"""Median client-side latency of SSB Q3.1 in the window."""
+
+from benchmark.harness import stats
+
+
+def read(obs):
+    lat = obs.latencies("ssb_q3_1")
+    return stats.median(lat) if lat else None

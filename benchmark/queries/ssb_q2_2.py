@@ -1,0 +1,44 @@
+"""Star Schema Benchmark Q2.2, flight 2 (O'Neil et al., revision 3, section 3;
+the paper's own literals, cited from memory): the same for a range of eight
+brands, a string BETWEEN."""
+
+from benchmark.datasets.ssb import column_bytes, star, words_where
+
+SQL = """
+select sum(lo_revenue), d_year, p_brand1
+from lineorder, date, part, supplier
+where lo_orderdate = d_datekey
+  and lo_partkey = p_partkey
+  and lo_suppkey = s_suppkey
+  and p_brand1 between 'MFGR#2221' and 'MFGR#2228'
+  and s_region = 'ASIA'
+group by d_year, p_brand1
+order by d_year, p_brand1
+"""
+
+READS = {"lineorder": ["lo_orderdate", "lo_partkey", "lo_suppkey",
+                       "lo_revenue"],
+         "date": ["d_datekey", "d_year"],
+         "part": ["p_partkey", "p_brand1"],
+         "supplier": ["s_suppkey", "s_region"]}
+
+
+def min_bytes(rows: dict) -> int:
+    """Bytes one execution must read: every column in READS, once."""
+    return column_bytes(READS, rows)
+
+
+def reference(t) -> list:
+    p, s = t["part"], t["supplier"]
+    return star(
+        t, t["lineorder"]["lo_revenue"],
+        {"lo_orderdate": ("date", "d_datekey", None),
+         "lo_partkey": ("part", "p_partkey",
+                        words_where(
+                            p["p_brand1"],
+                            lambda w: b"MFGR#2221" <= w <= b"MFGR#2228")),
+         "lo_suppkey": ("supplier", "s_suppkey",
+                        words_where(s["s_region"],
+                                    lambda w: w == b"ASIA"))},
+        group=[("lo_orderdate", "d_year"), ("lo_partkey", "p_brand1")],
+        order=lambda rows: [(rev, y, b) for y, b, rev in sorted(rows)])

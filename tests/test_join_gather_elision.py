@@ -273,6 +273,13 @@ def _first_null(tk, sql, update, spy, ran):
     return facts0, facts1
 
 
+def _page_by(monkeypatch, rows):
+    """Every in-memory probe runs in pages of `rows` rows: the longest
+    input a sorting program takes whole, and the page, lowered."""
+    monkeypatch.setattr(device_exec, "_SORTED_SCAN_MAX_ROWS", 0)
+    monkeypatch.setattr(dj, "_PROBE_PAGE_ROWS", rows)
+
+
 def _g_tables(tk, nf=2600, ng=180):
     tk.must_exec("use test")
     tk.must_exec("create table gf (id int primary key, k bigint, v int)")
@@ -304,8 +311,7 @@ def test_first_null_resident(monkeypatch):
 
 def test_first_null_paged(monkeypatch):
     tk = _g_tables(TestKit())
-    monkeypatch.setattr(dj, "_PAGED_MIN_ROWS", 0)
-    tk.must_exec("set tidb_device_stream_rows = 500")
+    _page_by(monkeypatch, 500)
     spy = _Spy(monkeypatch)
     pages = []
     orig = dj._paged_join_agg
@@ -519,14 +525,10 @@ def test_a_paged_fragment_counts_once(tk_of, monkeypatch):
     g0, e0 = _gather_counts(tk)
     tk.must_query(sql)
     g1, e1 = _gather_counts(tk)
-    monkeypatch.setattr(dj, "_PAGED_MIN_ROWS", 0)
-    tk.must_exec("set tidb_device_stream_rows = 500")
-    try:
-        rows = tk.must_query(sql).rows
-        assert dj.LAST_PAGED_STATS.stats["pages"] == 6
-        g2, e2 = _gather_counts(tk)
-    finally:
-        tk.must_exec("set tidb_device_stream_rows = 0")
+    _page_by(monkeypatch, 500)
+    rows = tk.must_query(sql).rows
+    assert dj.LAST_PAGED_STATS.stats["pages"] == 6
+    g2, e2 = _gather_counts(tk)
     # six pages, one program, one count: the page is the leaf in place
     assert (g2 - g1, e2 - e1) == (g1 - g0, e1 - e0)
     tk.must_exec("set tidb_executor_engine = 'host'")

@@ -308,7 +308,14 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # in blocks; and the bytes the streamed blocks carried to
                # the device, which the residency ledger never sees
                "scan_resident": 0, "scan_streamed": 0,
-               "stream_upload_bytes": 0}
+               "stream_upload_bytes": 0,
+               # join fragments dispatched, by where the probe leaf's
+               # rows came from: columns resident through the residency
+               # ledger (whole, or cut into pages on the device) / pages
+               # the statement cut from the host's columns and sent,
+               # whose bytes count under stream_upload_bytes —
+               # note_join_probe
+               "join_probe_resident": 0, "join_probe_sent": 0}
 _PIPE_LOCK = _threading.Lock()
 _PIPE_TLS = _threading.local()
 
@@ -341,7 +348,9 @@ def _tls_stats() -> dict:
                                 "agg_dense": 0, "agg_sorted": 0,
                                 "join_direct": 0, "join_search": 0,
                                 "join_gathers": 0,
-                                "join_gathers_elided": 0}
+                                "join_gathers_elided": 0,
+                                "join_probe_resident": 0,
+                                "join_probe_sent": 0}
     return st
 
 
@@ -391,6 +400,17 @@ def note_join_gathers(fn):
     counters."""
     _bump("join_gathers", fn.gathers["emitted"])
     _bump("join_gathers_elided", fn.gathers["elided"])
+
+
+def note_join_probe(resident: bool):
+    """Count one dispatched join fragment by where its probe leaf's rows
+    came from: columns resident in HBM through the residency ledger
+    (read whole, or page by page as slices on the device), or pages the
+    statement cut from the host's columns and sent.  Once per fragment,
+    whatever its capacity restarts; EXPLAIN ANALYZE's ``probe:``
+    annotation and the benchmark's ``join.probe_resident_share`` read
+    the counters."""
+    _bump("join_probe_resident" if resident else "join_probe_sent")
 
 
 def pipe_cache_stats(thread_local: bool = False) -> dict:
@@ -605,7 +625,9 @@ def _agg_used_columns(plan, conds) -> set:
 #: resident SF10 lineitem (67,108,864-row bucket) ended its worker at
 #: the v5e host's 40 GiB (PERF.md §6, PR 27).  Beyond this bound such a
 #: scan runs in page-sized blocks, as every long input did before the
-#: choice went from rows to bytes.
+#: choice went from rows to bytes, and a join fragment (whose aggregate
+#: always sorts) runs its probe leaf page by page or not on the device
+#: at all (device_join.probe_pages).
 _SORTED_SCAN_MAX_ROWS = 1 << 24
 
 
@@ -617,28 +639,43 @@ def _scan_arm(plan, chunk: Chunk, used) -> str:
     return dev.agg_arm(key_pack, tuple(agg_ops))
 
 
+def resident_block_rows(cols, num_rows: int, ctx=None) -> int:
+    """THE resident-or-sent rule of an in-memory input, for scans and for
+    a join fragment's probe alike: 0 = `cols` (the used columns) stay
+    resident, because at their row bucket they and the program's working
+    set fit the tenant's share of the residency budget
+    (`residency.scan_fits_resident`); else the length of the blocks the
+    statement sends instead: the largest power of two (at most a page)
+    whose rows fit."""
+    from ..ops import residency
+    from ..storage.paged import DEFAULT_PAGE_ROWS
+    residency.attach(ctx)       # the budget and the tenant of THIS session
+    nb = dev.bucket_rows(num_rows, dev.shape_buckets(ctx))
+    if residency.scan_fits_resident(
+            False, residency.upload_nbytes(cols, nb)):
+        return 0
+    fit = (residency.resident_scan_bytes()
+           // max(residency.upload_nbytes(cols, 1), 1))
+    return min(DEFAULT_PAGE_ROWS, 1 << max(fit.bit_length() - 1, 10))
+
+
 def scan_stream_rows(plan, chunk: Chunk, conds, ctx=None) -> int:
     """Block length for a scan-aggregate when the session sets no
     ``tidb_device_stream_rows``: 0 = the input stays resident and runs
     `device_agg`; else `device_agg_streaming`'s block rows.  Decided by
-    `residency.scan_fits_resident` from the bytes the used columns take
-    at their row bucket against the tenant's share of the residency
-    budget; a paged input streams by pages, one that does not fit in
-    the largest power-of-two blocks (at most a page) that do, and one
-    past `_SORTED_SCAN_MAX_ROWS` whose program would sort by pages too."""
-    from ..ops import residency
+    `resident_block_rows` from the bytes the used columns take at their
+    row bucket against the tenant's share of the residency budget; a
+    paged input streams by pages, one that does not fit in the largest
+    power-of-two blocks (at most a page) that do, and one past
+    `_SORTED_SCAN_MAX_ROWS` whose program would sort by pages too."""
     from ..storage.paged import DEFAULT_PAGE_ROWS, chunk_is_paged
     if chunk_is_paged(chunk):
         return DEFAULT_PAGE_ROWS
-    residency.attach(ctx)       # the budget and the tenant of THIS session
     used = sorted(_agg_used_columns(plan, conds))
-    cols = [chunk.columns[i] for i in used]
-    nb = dev.bucket_rows(chunk.num_rows, dev.shape_buckets(ctx))
-    if not residency.scan_fits_resident(
-            False, residency.upload_nbytes(cols, nb)):
-        fit = (residency.resident_scan_bytes()
-               // max(residency.upload_nbytes(cols, 1), 1))
-        return min(DEFAULT_PAGE_ROWS, 1 << max(fit.bit_length() - 1, 10))
+    block = resident_block_rows([chunk.columns[i] for i in used],
+                                chunk.num_rows, ctx)
+    if block:
+        return block
     if chunk.num_rows > _SORTED_SCAN_MAX_ROWS:
         try:
             if _scan_arm(plan, chunk, used) != "dense":

@@ -42,6 +42,8 @@ Anything else raises DeviceUnsupported and falls back to the host path.
 
 from __future__ import annotations
 
+import collections
+import functools
 import threading
 
 import numpy as np
@@ -55,7 +57,7 @@ from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
     _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm,
-    note_join_gathers, note_join_layouts)
+    note_join_gathers, note_join_layouts, note_join_probe)
 from .join_index import build_join_index
 
 
@@ -1099,9 +1101,11 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
                 raise DeviceUnsupported(
                     "left join residual conds need a unique build")
 
-    # paged-probe dispatch: a disk-backed (or huge) fact side must stream
-    # pages — uploading it whole would exceed HBM (and at SF100, RAM)
-    from ..storage.paged import chunk_is_paged, DEFAULT_PAGE_ROWS
+    # paged-probe dispatch: a disk-backed fact side, one that does not
+    # fit the residency budget, and one too long for a program that sorts
+    # run page by page
+    from ..storage.paged import chunk_is_paged
+    from . import device_exec
     probe = _probe_spine(root)
     any_paged = any(chunk_is_paged(leaf.chunk) for leaf in leaves)
     pageable = (isinstance(probe, _Leaf) and all(
@@ -1128,28 +1132,32 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         # None index — degrade to the host engine instead
         raise DeviceUnsupported(
             "over-budget build side outside the hybrid join language")
+    # past this a leaf never enters the whole-input fragment, whose
+    # aggregate sorts at the fact length
+    too_long = (max(leaf.chunk.num_rows for leaf in leaves)
+                > device_exec._SORTED_SCAN_MAX_ROWS)
     if pageable:
-        paged = chunk_is_paged(probe.chunk)
-        if any_paged and not paged:
+        if any_paged and not chunk_is_paged(probe.chunk):
             raise DeviceUnsupported("paged build-side leaf (resident "
                                     "uploads of a disk table are barred)")
-        try:
-            page_rows = int(ctx.get_sysvar("tidb_device_stream_rows"))
-        except Exception:
-            page_rows = 0
-        stream_off = page_rows < 0  # -1: resident inputs never auto-page
-        if page_rows <= 0:
-            page_rows = DEFAULT_PAGE_ROWS
-        if paged or (not stream_off and probe.chunk.num_rows
-                     > max(_PAGED_MIN_ROWS, page_rows * 4)):
+        page_rows, resident = probe_pages(
+            probe, _fragment_used_cols(leaves, joins, agg_plan, agg_conds),
+            ctx)
+        if page_rows:
             try:
                 return _paged_join_agg(root, leaves, joins, probe, agg_plan,
-                                       agg_conds, ctx, page_rows)
+                                       agg_conds, ctx, page_rows, resident)
             except DeviceUnsupported:
-                if paged:
+                if chunk_is_paged(probe.chunk) or too_long:
                     # whole-table upload of a disk-resident fact is not a
-                    # fallback — let the host path stream it instead
+                    # fallback, and a program that sorts a fact this long
+                    # costs the compiler the host's memory — let the host
+                    # path stream it instead
                     raise
+    if too_long:
+        raise DeviceUnsupported(
+            "a leaf past the longest input a sorting program takes whole, "
+            "outside the paged-probe language")
     # canonical row buckets per leaf: uploads pad to the bucket, the
     # program masks each leaf at its traced live count — a delta append
     # that stays inside the bucket reuses the compiled fragment
@@ -1196,6 +1204,7 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     _dbg = _os.environ.get("TIDB_TPU_DEBUG_JOIN")
     note_agg_arm(key_pack, agg_ops, gathered=True)
     note_join_layouts(jn.strategy for jn in joins)
+    note_join_probe(resident=True)
     for _attempt in range(12):
         caps = [jn.cap for jn in joins]
         key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
@@ -1271,9 +1280,60 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     return _assemble_agg(agg_plan, key_meta, slots, dcols, body, f.out_rows)
 
 
-#: resident probe tables larger than this stream through pages even
-#: without a disk-backed store (bounds HBM at big scale factors)
-_PAGED_MIN_ROWS = 1 << 24
+#: rows of one page of a probe that runs page by page (the session's
+#: ``tidb_device_stream_rows`` when set, for pages the statement sends):
+#: a constant well under `device_exec._SORTED_SCAN_MAX_ROWS`, since every
+#: page runs the fragment's program, which sorts at the page's length
+_PROBE_PAGE_ROWS = 1 << 22
+
+
+def probe_pages(probe, used, ctx) -> "tuple[int, bool]":
+    """How a pageable join fragment reads its probe leaf: (0, True) =
+    whole, from columns resident in HBM; (page_rows, True) = page by page
+    as slices of those resident columns; (page_rows, False) = pages cut
+    from the host's columns and sent by this statement.  Sent when the
+    leaf is paged on disk or its `used` columns at their row bucket do
+    not fit the tenant's share of the residency budget (the rule scans
+    follow, `device_exec.resident_block_rows`); in pages when it is past
+    the longest input a program that sorts takes whole."""
+    from ..storage.paged import chunk_is_paged
+    from . import device_exec
+    try:
+        user_rows = int(ctx.get_sysvar("tidb_device_stream_rows"))
+    except Exception:
+        user_rows = 0
+    if chunk_is_paged(probe.chunk):
+        return (user_rows if user_rows > 0 else _PROBE_PAGE_ROWS), False
+    cols = [probe.chunk.columns[i] for i in range(probe.ncols)
+            if probe.offset + i in used]
+    block = device_exec.resident_block_rows(cols, probe.chunk.num_rows, ctx)
+    if block:
+        return (min(user_rows, block) if user_rows > 0 else block), False
+    if probe.chunk.num_rows > device_exec._SORTED_SCAN_MAX_ROWS:
+        return _PROBE_PAGE_ROWS, True
+    return 0, True
+
+
+def _cut_pages(arrays, rows: int, pages: int):
+    """The first `pages` pages of `rows` rows of every resident probe
+    array (data and masks, all at the leaf's row bucket), cut on the
+    device by ONE program: a program that reads an int64 array first
+    splits the whole of it into 32-bit halves, so a cut a page would
+    pass over the whole column `pages` times (0.21 s a request over
+    the 67,108,864-row bucket; PERF.md §6, PR 31).  Rows past an
+    array's end are zeros; the fragment masks a page at its live
+    count."""
+    _count_trace()
+
+    def cut(a, lo):
+        page = a[lo:lo + rows]
+        return jnp.pad(page, (0, rows - page.shape[0]))
+    return tuple(jax.tree_util.tree_map(
+        functools.partial(cut, lo=p * rows), arrays) for p in range(pages))
+
+
+_resident_pages = dev.observed_jit(_cut_pages,
+                                   static_argnames=("rows", "pages"))
 
 
 def _probe_spine(root):
@@ -1455,17 +1515,26 @@ LAST_PAGED_STATS = _PagedStats()
 
 
 def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
-                    page_rows):
-    """Streamed-probe execution of an all-unique-build join chain: the
+                    page_rows, resident=False):
+    """Paged-probe execution of an all-unique-build join chain: the
     fact leaf is cut into `page_rows` pages; each page runs the SAME
     compiled scan→gather-joins→partial-agg program (dimension tables and
     their join indexes stay HBM-resident across pages); per-page partial
     states buffer on device and fold into one running merged state via
-    the mergeable-agg kernel. Device memory is bounded by
-    page + buffered partials + merge state — never the fact table. This
-    is the engine's cop-paging analog (reference kv/kv.go:349-350: the
-    coprocessor streams a large scan in pages; here each page carries the
-    whole join+agg fragment with it)."""
+    the mergeable-agg kernel.
+
+    Where a page comes from is `probe_pages`' choice.  `resident`: the
+    leaf's used columns are placed through the residency ledger once, at
+    the leaf's row bucket (entries like any scan's: counted in
+    ``device_residency.upload_bytes``, evictable), and a page is a slice
+    of them cut on the device; a second execution sends nothing but the
+    join indexes it rebuilt.  Else a page is cut from the host's columns
+    and sent by this statement (`_stream_block`: under ``upload.h2d``,
+    counted in ``device_pipelines.stream_upload_bytes``), and device
+    memory is bounded by page + buffered partials + merge state — never
+    the fact table. This is the engine's cop-paging analog (reference
+    kv/kv.go:349-350: the coprocessor streams a large scan in pages; here
+    each page carries the whole join+agg fragment with it)."""
     if any(jn.strategy is None or jn.strategy[0] != "uniq" for jn in joins):
         raise DeviceUnsupported("paged probe requires all-unique builds")
     # planning view is metadata-only for EVERY leaf: the only uploads are
@@ -1488,28 +1557,44 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
     for leaf in leaves:
         if not any(leaf.offset + i in used for i in range(leaf.ncols)):
             used.add(leaf.offset)
+    from ..session import tracing
     from ..storage.paged import chunk_is_paged
+    from .device_exec import _fetch, _stream_block, _upload_mark, _upload_tags
     per_double = dev.shape_buckets(ctx)
-    env_dim = {}
-    for leaf in leaves:
-        if leaf.leaf_id == probe.leaf_id:
-            continue
-        lused = [i for i in range(leaf.ncols) if leaf.offset + i in used]
-        if chunk_is_paged(leaf.chunk):
-            est = 8 * leaf.chunk.num_rows * len(lused)
-            if est > _dim_resident_budget():
-                raise DeviceUnsupported(
-                    "paged build-side leaf exceeds resident budget")
-        dim_bucket = dev.bucket_rows(leaf.chunk.num_rows, per_double)
-        for i in lused:
-            dc = dev.to_device_col(leaf.chunk.columns[i],
-                                   bucket=dim_bucket)
-            env_dim[leaf.offset + i] = (dc.data, dc.nulls)
-    probe_arrays = {
-        probe.offset + i: dev.meta_device_col(c)[1]
-        for i, c in enumerate(probe.chunk.columns)
-        if probe.offset + i in used}
-    jidx = tuple(jn.strategy[2].device_arrays() for jn in joins)
+    probe_used = [(probe.offset + i, c)
+                  for i, c in enumerate(probe.chunk.columns)
+                  if probe.offset + i in used]
+    with tracing.span("upload.h2d") as usp:
+        up0 = _upload_mark(usp)
+        env_dim = {}
+        for leaf in leaves:
+            if leaf.leaf_id == probe.leaf_id:
+                continue
+            lused = [i for i in range(leaf.ncols) if leaf.offset + i in used]
+            if chunk_is_paged(leaf.chunk):
+                est = 8 * leaf.chunk.num_rows * len(lused)
+                if est > _dim_resident_budget():
+                    raise DeviceUnsupported(
+                        "paged build-side leaf exceeds resident budget")
+            dim_bucket = dev.bucket_rows(leaf.chunk.num_rows, per_double)
+            for i in lused:
+                dc = dev.to_device_col(leaf.chunk.columns[i],
+                                       bucket=dim_bucket)
+                env_dim[leaf.offset + i] = (dc.data, dc.nulls)
+        if resident:
+            # placed once, kept by the ledger: every page below, and every
+            # later statement, reads these arrays
+            probe_bucket = dev.bucket_rows(probe.chunk.num_rows, per_double)
+            probe_dev = {}
+            for gidx, c in probe_used:
+                dc = dev.to_device_col(c, bucket=probe_bucket)
+                probe_dev[gidx] = (dc.data, dc.nulls)
+        else:
+            probe_host = {gidx: dev.meta_device_col(c)[1]
+                          for gidx, c in probe_used}
+        jidx = tuple(jn.strategy[2].device_arrays() for jn in joins)
+        _upload_tags(usp, up0, len(env_dim) + (len(probe_used)
+                                                if resident else 0))
     sig = fragment_sig(leaves, joins, agg_conds, agg_plan) + f"|pg{page_rows}"
     dict_refs = tuple(dc.dictionary for dc in dcols.values()
                       if dc.dictionary is not None)
@@ -1525,9 +1610,6 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
         capacity = dev.next_pow2(min(page_rows, max(est, 16)))
     learned_total = _CAP_STORE.get((sig, "groups"))
     merge_cap = dev.next_pow2(max(learned_total or capacity, 16))
-
-    def pad_page(arr, lo, hi, null_pad=False):
-        return jnp.asarray(dev.pad_host(arr[lo:hi], page_rows, null_pad))
 
     base_lives = [np.int64(leaf.chunk.num_rows) for leaf in leaves]
 
@@ -1546,6 +1628,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
         jn.cap = page_rows  # every join is a probe-shaped gather
     note_join_layouts(jn.strategy for jn in joins)
     note_agg_arm(key_pack, agg_ops, gathered=True)
+    note_join_probe(resident)
     for _attempt in range(4):
         caps = [page_rows] * len(joins)
         key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
@@ -1568,12 +1651,17 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
         import time as _time
         stats = {"pages": 0, "slice_s": 0.0, "dispatch_s": 0.0,
                  "sync_s": 0.0, "merge_s": 0.0, "capacity": capacity}
+        if resident:
+            # popped as dispatched: a page lives until its program has
+            # read it
+            cut_pages = collections.deque(_resident_pages(
+                probe_dev, rows=page_rows, pages=-(-n // page_rows)))
         for lo in range(0, n, page_rows):
             hi = min(lo + page_rows, n)
-            env = dict(env_dim)
             t0 = _time.perf_counter()
-            for gidx, (d, nl) in probe_arrays.items():
-                env[gidx] = (pad_page(d, lo, hi), pad_page(nl, lo, hi, True))
+            env = {**env_dim, **(
+                cut_pages.popleft() if resident
+                else _stream_block(probe_host, lo, hi, page_rows))}
             t1 = _time.perf_counter()
             agg_out, _ovf, _sovf, _kept = fn(env, jidx, page_lives(hi, lo))
             if _attempt == 0 and lo == 0:
@@ -1586,7 +1674,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
             if len(buffered) >= k_flush:
                 t3 = _time.perf_counter()
                 ngs = [int(g) for g in
-                       jax.device_get([p[4] for p in buffered])]
+                       _fetch(lambda: [p[4] for p in buffered])]
                 stats["sync_s"] += _time.perf_counter() - t3
                 max_ng = max(max_ng, *ngs)
                 if max_ng > capacity:
@@ -1598,7 +1686,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
                 buffered = []
         if not overflow and buffered:
             t3 = _time.perf_counter()
-            ngs = [int(g) for g in jax.device_get([p[4] for p in buffered])]
+            ngs = [int(g) for g in _fetch(lambda: [p[4] for p in buffered])]
             stats["sync_s"] += _time.perf_counter() - t3
             max_ng = max(max_ng, *ngs)
             if max_ng <= capacity:

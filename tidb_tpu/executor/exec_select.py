@@ -114,6 +114,13 @@ class QueryExecutor:
                 "join_gathers", "join_gathers_elided"))
             self.annotate(
                 gathers=f"{kept} (-{cut})" if kept + cut else None)
+            # where the join fragment's probe rows came from
+            # (device_exec.note_join_probe): probe:resident | sent; a
+            # paged run adds pages:<n> below
+            self.annotate(probe=next(
+                (name for name in ("resident", "sent")
+                 if st1["join_probe_" + name]
+                 - st0["join_probe_" + name] > 0), None))
             from .supervisor import abandoned_calls
             n_abandoned = abandoned_calls()
             if n_abandoned:

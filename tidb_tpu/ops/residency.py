@@ -367,9 +367,11 @@ def resident_scan_bytes(budget: "int | None" = None) -> int:
 
 def scan_fits_resident(paged: bool, col_bytes: int,
                        budget: "int | None" = None) -> bool:
-    """Whether a scan's input stays resident (one program over whole
-    columns cached through this ledger) or streams in blocks that nothing
-    keeps.  A paged input always streams: its columns are on disk
+    """Whether a scan's input, or a join fragment's probe leaf, stays
+    resident (programs over columns cached through this ledger) or is
+    sent in blocks that nothing keeps
+    (`executor/device_exec.resident_block_rows`).  A paged input always
+    streams: its columns are on disk
     because they exceed what the host should hold.  An in-memory one
     stays resident when its used columns (`col_bytes`, at their row
     bucket) and the program's working set fit `budget`.  The ledger's

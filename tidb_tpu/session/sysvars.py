@@ -145,7 +145,9 @@ for _v in [
     # for larger-than-memory inputs at the cost of re-transfer per run
     # (0 = auto: an in-memory input whose used columns and working set
     # fit the residency budget stays HBM-resident, whole-table; a paged
-    # one, or one that does not fit, streams — device_exec.scan_stream_rows)
+    # one, or one that does not fit, streams — device_exec.scan_stream_rows;
+    # a join fragment's probe follows the same rule, and a value set here
+    # only bounds the pages it SENDS — device_join.probe_pages)
     SysVar("tidb_device_stream_rows", SCOPE_BOTH, "0", "int", 0),
     # shape-canonicalization granularity: geometric row buckets per
     # doubling that device uploads pad to (ops/device.py bucket_rows) so
