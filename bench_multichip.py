@@ -217,6 +217,9 @@ def phase_skew_exchange():
         f"({i}, {7 if i <= 224 else (i % 64) + 1}, {i})"
         for i in range(1, 321)))
     tk.must_exec("set tidb_broadcast_join_threshold_count = 30")
+    # dim's key is unique: only a build over the size threshold too is
+    # shuffled, anything else takes the indexed broadcast path
+    tk.must_exec("set tidb_broadcast_join_threshold_size = 1")
     q = ("select count(1), sum(fact.v + dim.w) from fact, dim "
          "where fact.k = dim.k")
     host, _ = _round(tk, q, engine="host")

@@ -542,12 +542,19 @@ def test_a_paged_fragment_counts_once(tk_of, monkeypatch):
             "order by l_returnflag"),
 ])
 def test_the_mesh_and_the_scan_bump_neither(tpch_tk, engine, sql):
+    """The mesh's IN-PROGRAM joins (a build over the broadcast size
+    threshold, here lowered to a byte) and a scan; a mesh fragment on the
+    indexed path counts as one chip's does (tests/test_mpp_indexed.py)."""
     tk = tpch_tk
     tk.must_exec(f"set tidb_executor_engine = '{engine}'")
+    tk.must_exec("set tidb_broadcast_join_threshold_size = 1")
     before = _gather_counts(tk)
-    rows = tk.must_query(sql).rows
-    assert _annotations(tk, sql, "engine:") == [f"engine:{engine}"]
-    assert _annotations(tk, sql, "gathers:") == []
+    try:
+        rows = tk.must_query(sql).rows
+        assert _annotations(tk, sql, "engine:") == [f"engine:{engine}"]
+        assert _annotations(tk, sql, "gathers:") == []
+    finally:
+        tk.must_exec("set tidb_broadcast_join_threshold_size = 104857600")
     assert _gather_counts(tk) == before
     tk.must_exec("set tidb_executor_engine = 'host'")
     assert rows == tk.must_query(sql).rows

@@ -129,6 +129,9 @@ class TestZeroRecompile:
         tk.must_exec("insert into bfact values " + ",".join(
             f"({i}, {(i % 100) + 1}, {i})" for i in range(1, 241)))
         tk.must_exec("set tidb_broadcast_join_threshold_count = 50")
+        # bigdim's key is unique and its index direct: its BYTES must
+        # pass the size threshold too, or the indexed path broadcasts it
+        tk.must_exec("set tidb_broadcast_join_threshold_size = 1")
         q = ("select count(1), sum(bfact.v + bigdim.w) from bfact, bigdim "
              "where bfact.k = bigdim.k")
         before_sh = MPP_STATS["shuffle_joins"]
@@ -201,6 +204,7 @@ class TestMppPaddingInvariants:
         tk.must_exec("insert into pb values " + ",".join(
             f"({i}, {i})" for i in range(1, 13)))
         tk.must_exec("set tidb_broadcast_join_threshold_count = 4")
+        tk.must_exec("set tidb_broadcast_join_threshold_size = 1")
         before = MPP_STATS["shuffle_joins"]
         _mpp_parity(tk, "select count(1), sum(pf.v + pb.w) from pf, pb "
                         "where pf.k = pb.k")
@@ -231,6 +235,7 @@ class TestHotKeySkewExchange:
         # the build-skew broadcast guard stays out of the way.
         _make_fact_dim(tk, n_fact=320, n_dim=64, hot_frac=0.7)
         tk.must_exec("set tidb_broadcast_join_threshold_count = 30")
+        tk.must_exec("set tidb_broadcast_join_threshold_size = 1")
 
     Q = ("select count(1), sum(fact.v + dim.w) from fact, dim "
          "where fact.k = dim.k")

@@ -161,12 +161,19 @@ class TestShuffleJoin:
         host = tk.must_query(sql).rows
         before = MPP_STATS["shuffle_joins"]
         tk.must_exec(f"set tidb_broadcast_join_threshold_count = {threshold}")
+        # a unique-keyed build with a direct host index is broadcast
+        # whatever its row count (the indexed mesh path) unless its
+        # BYTES pass the size threshold too: lower both to shuffle
+        tk.must_exec("set tidb_broadcast_join_threshold_size = "
+                     f"{1 if threshold < 10240 else 104857600}")
         tk.must_exec("set tidb_executor_engine = 'tpu-mpp'")
         try:
             mpp = tk.must_query(sql).rows
         finally:
             tk.must_exec("set tidb_executor_engine = 'auto'")
             tk.must_exec("set tidb_broadcast_join_threshold_count = 10240")
+            tk.must_exec(
+                "set tidb_broadcast_join_threshold_size = 104857600")
         ran = MPP_STATS["shuffle_joins"] - before
         assert host == mpp, (f"shuffle/host divergence\nhost({len(host)}): "
                              f"{host[:5]}\nmpp({len(mpp)}): {mpp[:5]}")

@@ -175,9 +175,14 @@ _SCOPES_BY_SHAPE = {
     # order by/limit over more groups than one small fetch holds (at this
     # scale Q3's own groups fit one, and its top-k runs on the host)
     "topn": ("k_agg_sort", "k_topk"),
-    # two devices: radix exchange, in-program sort join, partials merged
+    # two devices, `orders` over both broadcast thresholds: radix
+    # exchange, in-program sort join, partials merged
     "mpp_q3": ("k_filter", "k_exchange", "k_join_build", "k_join_probe",
                "k_agg_sort", "k_agg_segment", "k_agg_gather"),
+    # two devices, as shipped: compile_fragment's body on every shard
+    # (host-built direct indexes: no in-program build), partials merged
+    "mpp_q3_indexed": ("k_filter", "k_exchange", "k_join_probe",
+                       "k_agg_sort", "k_agg_segment", "k_agg_gather"),
 }
 
 
@@ -211,9 +216,14 @@ def lowered(tpch):
                 ("q3", "tpu", bench.QUERIES["q3"]),
                 ("q5", "tpu", bench.QUERIES["q5"]),
                 ("topn", "tpu", topn),
-                ("mpp_q3", "tpu-mpp", bench.QUERIES["q3"])):
+                ("mpp_q3", "tpu-mpp", bench.QUERIES["q3"]),
+                ("mpp_q3_indexed", "tpu-mpp", bench.QUERIES["q3"])):
             tpch.must_exec(f"set tidb_executor_engine = '{engine}'")
             tpch.must_exec("set tidb_mpp_devices = 2")
+            # `orders` (30,000 rows) is over the row threshold; its direct
+            # index keeps it broadcast unless its bytes are over theirs
+            tpch.must_exec("set tidb_broadcast_join_threshold_size = "
+                           f"{1 if shape == 'mpp_q3' else 104857600}")
             del current[:], programs[:]
             rows = tpch.must_query(sql).rows
             assert rows and current, shape

@@ -382,7 +382,8 @@ def note_join_layouts(strategies):
     strategy snapshot: ``_JoinNode.strategy`` per join) by the layout of
     their index; EXPLAIN ANALYZE's ``join:`` annotation and the
     benchmark's ``join.direct_share`` read the counters.  Joins built
-    inside the program (no index) count under neither."""
+    inside the program (no index) count under neither; a mesh fragment on
+    the indexed path (mpp_exec._indexed_chain) counts as one chip's."""
     for st in strategies:
         if st is not None and st[2] is not None:
             _bump("join_direct" if st[2].kind == "dense"
@@ -395,9 +396,10 @@ def note_join_gathers(fn):
     device_join.compile_fragment returned, traced by now) emits, and
     those it elides because the leaf's row map is still the identity or
     the host knows the column holds no NULL.  Once per fragment, whatever
-    its capacity retries and pages; EXPLAIN ANALYZE's ``gathers:``
-    annotation and the benchmark's ``join.elided_gather_share`` read the
-    counters."""
+    its capacity retries, pages and shards (the mesh's indexed path calls
+    this as one chip does; its in-program joins do not); EXPLAIN
+    ANALYZE's ``gathers:`` annotation and the benchmark's
+    ``join.elided_gather_share`` read the counters."""
     _bump("join_gathers", fn.gathers["emitted"])
     _bump("join_gathers_elided", fn.gathers["elided"])
 

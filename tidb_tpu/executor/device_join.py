@@ -614,12 +614,18 @@ def _pack_probe(kds, knulls, pvalid, packs):
 
 def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                      capacity, key_pack, agg_meta, nonnull, raw_tail=False,
-                     strategies=None):
+                     strategies=None, program=None):
     """Build the jitted end-to-end program. caps: per-join static
     capacities aligned with `joins`. Returns jitted fn(env, jidx, n_lives)
     where env is {global_col: (data, nulls)} and jidx is a per-join tuple
     of host-index device arrays (passed as arguments, not baked, so a data
     refresh with unchanged shapes reuses the compiled program).
+
+    program: what turns the body `run(env, jidx, n_lives)` into the
+    program that is dispatched; `_timed_jit` unless given.  Its one other
+    user is the mesh (mpp_exec._shard_program), which runs the body on
+    every shard over that shard's slice of the probe leaf, as the paged
+    probe runs it over a page, and merges the partial states after it.
 
     n_lives: per-leaf traced live-row counts, ordered by leaf_id. Env
     arrays may be padded past them — bucket-padded resident uploads, the
@@ -1000,7 +1006,7 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                                 gathered=True)
         return agg_out, tuple(overflows), tuple(span_ovfs), kept_total
 
-    fn = _timed_jit(run)
+    fn = (program or _timed_jit)(run)
     fn.gathers = gathers  # filled by the trace: note_join_gathers
     return fn
 

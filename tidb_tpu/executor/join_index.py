@@ -152,16 +152,24 @@ class JoinIndex:
         and dropped at a device epoch bump."""
         import jax.numpy as jnp
         from ..ops import residency
-        a0 = (self.slots if self.slots is not None
-              else self.starts if self.kind == "dense" else self.sorted_keys)
+        a0, a1 = self.host_arrays()
         if self._owner is None:
             self._owner = residency.CacheOwner()
         dev = residency.lookup(self._owner, len(a0))
         if dev is None:
             dev = residency.publish(
                 self._owner, jnp.asarray(a0),
-                None if self.slots is not None else jnp.asarray(self.rows))
+                None if a1 is None else jnp.asarray(a1))
         return dev[0], dev[1], np.int64(self.n_valid)
+
+    def host_arrays(self):
+        """The numpy (a0, a1) behind `device_arrays`, for a caller that
+        places them itself (the mesh replicates them over its devices,
+        mpp_exec._place_index)."""
+        if self.slots is not None:
+            return self.slots, None
+        return (self.starts if self.kind == "dense" else self.sorted_keys,
+                self.rows)
 
 
 def _pack_host(datas, valid, packs):

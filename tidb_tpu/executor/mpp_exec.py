@@ -9,9 +9,16 @@ TPU-native translation: one `shard_map`-jitted SPMD program per fragment.
   reference's region sharding, §2.2 DP); every dimension table is
   replicated (broadcast hash join — the PhysicalExchangeSender Broadcast
   type).
-- Each shard runs the SAME fused scan→filter→join→partial-agg body the
-  single-chip path compiles (device_join.compile_fragment), producing a
-  `capacity`-bounded partial aggregate state.
+- Each shard runs a fused scan→filter→join→partial-agg body, producing a
+  `capacity`-bounded partial aggregate state.  Where the fragment is in
+  the paged join's language and its builds have host-built direct indexes
+  (`_indexed_chain`), the body IS the one the single-chip path compiles
+  (device_join.compile_fragment, wrapped by `_shard_program`): indexes
+  read by address, the shard's slice of the probe leaf read in place, a
+  NULL-free column's mask a constant.  Every other fragment runs
+  `_build_mpp_pipeline`'s own body, which joins inside the program
+  (a build lexsort and three searches a join) and can shuffle the bottom
+  join's two sides.
 - Exchange = `all_gather` of the bounded partial states over ICI; the
   final merge is simply a second `_agg_impl` over the gathered partials
   (partial/final parallel hash agg, executor/aggregate.go:85-165),
@@ -49,6 +56,7 @@ retries with grown capacities — one extra compile, never wrong results.
 from __future__ import annotations
 
 import collections
+import functools
 import threading
 
 import numpy as np
@@ -63,10 +71,12 @@ from ..ops.device import DeviceUnsupported
 from ..parallel.mpp import RADIX_SUB, _mix64, _radix_bucket
 from .device_exec import (
     _assemble_agg, _estimate_groups, _plan_agg, acquire_pipeline,
-    engine_mode, note_agg_arm)
+    engine_mode, note_agg_arm, note_join_gathers, note_join_layouts)
 from .device_join import (
     _CAP_STORE, _JoinNode, _Leaf, _cap_store_put, _combined_join_keys,
-    _join_expand, _shift_expr, collect_tree, fragment_sig)
+    _dim_resident_budget, _fragment_used_cols, _join_expand, _leaf_index,
+    _leaf_used_bytes, _probe_spine, _reorder_fact_first, _shift_expr,
+    collect_tree, compile_fragment, fragment_sig, nonnull_cols)
 
 AXIS = "part"
 
@@ -79,10 +89,13 @@ _MERGE_OP = {"count": "sum_i", "sum_i": "sum_i", "sum_f": "sum_f",
 #: exchange_retries = transport faults re-dispatched on the same shapes;
 #: exchange_overflow_retries = radix sub-bucket overflow recompiles at a
 #: larger exchange capacity (the hot-key convergence counter);
-#: retries = all capacity-growth recompiles (joins, agg, exchange).
+#: retries = all capacity-growth recompiles (joins, agg, exchange);
+#: indexed_fragments = the fragments among `fragments` whose shards ran
+#: device_join.compile_fragment's body over host-built direct indexes
+#: (capacity retries count once, as in `fragments`).
 MPP_STATS = {"fragments": 0, "retries": 0, "shuffle_joins": 0,
              "skew_broadcasts": 0, "exchange_retries": 0,
-             "exchange_overflow_retries": 0}
+             "exchange_overflow_retries": 0, "indexed_fragments": 0}
 
 _MESH_CACHE: dict[int, object] = {}
 
@@ -130,14 +143,11 @@ _PLACE_CACHE_MAX = 128
 _PLACE_LOCK = threading.Lock()
 
 
-def _place_col(col, data, nulls, mesh, sharded, total):
-    """Pad `col`'s host arrays to `total` rows and device_put them onto
-    the mesh (row-sharded over AXIS or replicated), cached through the
-    residency ledger.  `total` is a bucket shape (multiple of the mesh
-    size when sharded): a within-bucket delta re-places (new column
-    identity) but re-dispatches the same compiled program."""
+def _placed(key, pin, rows, build):
+    """The arrays cached under `key` through the residency ledger, at
+    least `rows` long, or what `build()` makes, published.  `pin` is the
+    host object whose id() the key holds."""
     from ..ops import residency
-    key = (id(col), id(mesh), sharded, total)
     with _PLACE_LOCK:
         hit = _MPP_PLACE_CACHE.get(key)
         if hit is not None:
@@ -145,19 +155,46 @@ def _place_col(col, data, nulls, mesh, sharded, total):
             owner = hit[0]
         else:
             owner = residency.CacheOwner()
-            _MPP_PLACE_CACHE[key] = (owner, col)
+            _MPP_PLACE_CACHE[key] = (owner, pin)
             while len(_MPP_PLACE_CACHE) > _PLACE_CACHE_MAX:
                 _MPP_PLACE_CACHE.popitem(last=False)
-    cached = residency.lookup(owner, total)
+    cached = residency.lookup(owner, rows)
     if cached is None:
+        # compare-and-keep publish: a racing placement's loser arrays are
+        # accounted as immediately evicted, never leaked off-ledger
+        cached = residency.publish(owner, *build())
+    return cached
+
+
+def _place_col(col, data, nulls, mesh, sharded, total):
+    """Pad `col`'s host arrays to `total` rows and device_put them onto
+    the mesh (row-sharded over AXIS or replicated), cached through the
+    residency ledger.  `total` is a bucket shape (multiple of the mesh
+    size when sharded): a within-bucket delta re-places (new column
+    identity) but re-dispatches the same compiled program."""
+    def build():
         d = dev.pad_host(np.asarray(data), total)
         nl = dev.pad_host(np.asarray(nulls), total, True)
         spec = NamedSharding(mesh, P(AXIS) if sharded else P())
-        built = (jax.device_put(d, spec), jax.device_put(nl, spec))
-        # compare-and-keep publish: a racing placement's loser arrays are
-        # accounted as immediately evicted, never leaked off-ledger
-        cached = residency.publish(owner, *built)
-    return cached
+        return jax.device_put(d, spec), jax.device_put(nl, spec)
+    return _placed((id(col), id(mesh), sharded, total), col, total, build)
+
+
+def _place_index(idx, mesh):
+    """A host-built join index's lookup tuple (a0, a1, n_valid), its
+    arrays replicated over the mesh: placed once an index (one is built
+    a table version and filter), cached through the residency ledger
+    like a replicated column and counted with the columns in
+    ``device_residency.upload_bytes``.  What `JoinIndex.device_arrays`
+    is to one device."""
+    a0, a1 = idx.host_arrays()
+
+    def build():
+        spec = NamedSharding(mesh, P())
+        return (jax.device_put(a0, spec),
+                None if a1 is None else jax.device_put(a1, spec))
+    d0, d1 = _placed((id(idx), id(mesh), "jidx"), idx, len(a0), build)
+    return d0, d1, np.int64(idx.n_valid)
 
 
 def place_cache_bytes() -> int:
@@ -194,7 +231,7 @@ def report_gauges() -> dict:
     out = {"mpp_place_bytes": s["mpp_place_bytes"],
            "mpp_fragments": s["fragments"]}
     for k in ("retries", "exchange_retries", "exchange_overflow_retries",
-              "shuffle_joins", "skew_broadcasts"):
+              "shuffle_joins", "skew_broadcasts", "indexed_fragments"):
         if s[k]:
             out["mpp_" + k] = s[k]
     return out
@@ -265,6 +302,83 @@ def _exchange_leaf(col_pairs, h, valid, n_shards, n_sub, cap):
     return out_cols, x(slot_valid), need
 
 
+
+def _merge_partials(partial, overflows, span_ovfs, xneeds, n_keys,
+                    merge_ops, capacity, key_pack):
+    """The tail of every shard's body: the shards' bounded partial
+    aggregate states (`partial`: what `_agg_impl` returned) gathered to
+    every shard and merged there, the per-shard group count, join totals,
+    span flags and exchange needs reduced to the mesh's maximum."""
+    pk, pkn, pres, presn, png, pvalid = partial
+
+    # exchange: every shard's bounded partial state (capacity rows —
+    # tiny next to N) rides ICI to every shard
+    def g(x):
+        return jax.lax.all_gather(x, AXIS, tiled=True)
+
+    with jax.named_scope("k_exchange"):
+        gk = tuple(g(k) for k in pk)
+        gkn = tuple(g(k) for k in pkn)
+        gres = tuple(g(r) for r in pres)
+        gresn = tuple(g(r) for r in presn)
+        gvalid = g(pvalid)
+
+        # stage 2: replicated final merge — just another _agg_impl
+        # over the gathered partials with partial→merge op mapping;
+        # its own scopes nest under k_exchange, and the outermost
+        # names the kernel: the merge is a cost of the exchange
+        f_out = dev._agg_impl(gk, gkn, gres, gresn, gvalid,
+                              n_keys=n_keys, agg_ops=merge_ops,
+                              capacity=capacity, pack=key_pack,
+                              gathered=True)
+
+    @jax.named_scope("k_exchange")
+    def mesh_max(x):
+        # not lax.pmax: the TPU compiler lowers a 64-bit all-reduce
+        # only for Sum ("UNIMPLEMENTED: Supported lowering only of
+        # Sum all reduce" on a v5e); gathering one scalar per shard
+        # and reducing locally gives every shard the same maximum
+        return jnp.max(jax.lax.all_gather(x, AXIS))
+
+    png_max = mesh_max(png)
+    # exact per-join required totals (the worst shard governs the
+    # static capacity); int64 — totals exceed int32 at TPC-H scale
+    ovfs = tuple(mesh_max(o.astype(jnp.int64)) for o in overflows)
+    sovfs = tuple(mesh_max(o.astype(jnp.int32)) for o in span_ovfs)
+    # exact worst radix sub-bucket counts (not booleans): the retry
+    # jumps straight to next_pow2(need)
+    xneeds_out = tuple(mesh_max(o.astype(jnp.int64)) for o in xneeds)
+    return f_out, png_max, ovfs, sovfs, xneeds_out
+
+
+def _shard_program(run, mesh, probe_id, shard_rows, env_specs, n_keys,
+                   agg_ops, capacity, key_pack):
+    """`device_join.compile_fragment`'s `program` on the mesh: its body
+    `run(env, jidx, n_lives)` on every shard, over that shard's
+    `shard_rows` rows of the probe leaf (`P(AXIS)` in `env_specs`) and
+    the whole of every other leaf and of every join index (replicated),
+    then `_merge_partials`.  A shard is to the mesh what a page is to
+    `device_join._paged_join_agg`: the probe leaf's live count is rebased
+    to the slice, the rule `_build_mpp_pipeline`'s `base_mask` applies,
+    and the body masks the rest.  Returns what `_build_mpp_pipeline`
+    returns, with no exchange needs."""
+    merge_ops = tuple(_MERGE_OP[o] for o in agg_ops)
+
+    def indexed_shard(env, jidx, n_lives):
+        off = jax.lax.axis_index(AXIS).astype(jnp.int64) * shard_rows
+        lives = list(n_lives)
+        lives[probe_id] = jnp.clip(n_lives[probe_id] - off, 0, shard_rows)
+        partial, totals, span_ovfs, _kept = run(env, jidx, tuple(lives))
+        return _merge_partials(partial, totals, span_ovfs, (), n_keys,
+                               merge_ops, capacity, key_pack)
+
+    # no trace marker around it, unlike `_build_mpp_pipeline`'s entry: the
+    # body counts its own trace (device_exec._count_trace)
+    return dev.observed_jit(shard_map(
+        indexed_shard, mesh=mesh, in_specs=(env_specs, P(), P()),
+        out_specs=P(), check_vma=False))
+
+
 # ---------------------------------------------------------------------------
 # the SPMD fragment program
 # ---------------------------------------------------------------------------
@@ -272,9 +386,13 @@ def _exchange_leaf(col_pairs, h, valid, n_shards, n_sub, cap):
 def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
                         cond_fns, key_fns, n_keys, val_plan, agg_ops,
                         capacity, key_pack, env_specs, shuffle=None):
-    """shard_map + jit the whole fragment: per-shard fused body → partial
-    agg → all_gather → replicated final merge. Same body structure as
-    device_join.compile_fragment but per-shard shapes come from the traced
+    """shard_map + jit a fragment OUTSIDE the indexed language (see
+    `_indexed_chain`; inside it `_shard_program` wraps
+    device_join.compile_fragment's body instead): per-shard fused body →
+    partial agg → `_merge_partials`.  The body's structure is
+    compile_fragment's of PR 27, kept for joins built in the program:
+    every leaf starts at `arange(n)` and every column and mask is
+    gathered through its row map.  Per-shard shapes come from the traced
     env and each leaf masks its rows at its TRACED live count (`n_lives`,
     one scalar per leaf): env arrays are bucket-padded past the live rows,
     and padding can never survive a filter, an exchange, a join probe or
@@ -424,50 +542,13 @@ def _build_mpp_pipeline(mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
                 val_nulls.append(nl)
 
         # stage 1: per-shard partial aggregation into bounded state
-        pk, pkn, pres, presn, png, pvalid = dev._agg_impl(
+        partial = dev._agg_impl(
             tuple(key_cols), tuple(key_nulls),
             tuple(val_cols), tuple(val_nulls), mask,
             n_keys=n_keys, agg_ops=agg_ops, capacity=capacity,
             pack=key_pack, gathered=True)
-
-        # exchange: every shard's bounded partial state (capacity rows —
-        # tiny next to N) rides ICI to every shard
-        def g(x):
-            return jax.lax.all_gather(x, AXIS, tiled=True)
-
-        with jax.named_scope("k_exchange"):
-            gk = tuple(g(k) for k in pk)
-            gkn = tuple(g(k) for k in pkn)
-            gres = tuple(g(r) for r in pres)
-            gresn = tuple(g(r) for r in presn)
-            gvalid = g(pvalid)
-
-            # stage 2: replicated final merge — just another _agg_impl
-            # over the gathered partials with partial→merge op mapping;
-            # its own scopes nest under k_exchange, and the outermost
-            # names the kernel: the merge is a cost of the exchange
-            f_out = dev._agg_impl(gk, gkn, gres, gresn, gvalid,
-                                  n_keys=n_keys, agg_ops=merge_ops,
-                                  capacity=capacity, pack=key_pack,
-                                  gathered=True)
-
-        @jax.named_scope("k_exchange")
-        def mesh_max(x):
-            # not lax.pmax: the TPU compiler lowers a 64-bit all-reduce
-            # only for Sum ("UNIMPLEMENTED: Supported lowering only of
-            # Sum all reduce" on a v5e); gathering one scalar per shard
-            # and reducing locally gives every shard the same maximum
-            return jnp.max(jax.lax.all_gather(x, AXIS))
-
-        png_max = mesh_max(png)
-        # exact per-join required totals (the worst shard governs the
-        # static capacity); int64 — totals exceed int32 at TPC-H scale
-        ovfs = tuple(mesh_max(o.astype(jnp.int64)) for o in overflows)
-        sovfs = tuple(mesh_max(o.astype(jnp.int32)) for o in span_ovfs)
-        # exact worst radix sub-bucket counts (not booleans): the retry
-        # jumps straight to next_pow2(need)
-        xneeds_out = tuple(mesh_max(o.astype(jnp.int64)) for o in xneeds)
-        return f_out, png_max, ovfs, sovfs, xneeds_out
+        return _merge_partials(partial, overflows, span_ovfs, xneeds,
+                               n_keys, merge_ops, capacity, key_pack)
 
     n_res = len(val_plan)
     out_specs = (
@@ -508,7 +589,11 @@ def mpp_agg(plan, chunk, conds, ctx, mesh):
 
 def mpp_join_agg(agg_plan, agg_conds, child_exec, ctx, mesh):
     """join-tree→group-by fragment over the mesh: probe spine sharded,
-    build sides broadcast (the broadcast hash join MPP variant)."""
+    build sides broadcast (the broadcast hash join MPP variant) and
+    probed through their host-built direct indexes where the fragment
+    has them (`_indexed_chain`); else joined inside the program, the
+    bottom join's two sides shuffled when its build is over
+    ``tidb_broadcast_join_threshold_count`` rows."""
     root, leaves, joins = collect_tree(child_exec)
     if any(jn.kind != "inner" for jn in joins):
         # the mesh fragment compiler shards/broadcasts inner joins only
@@ -555,6 +640,53 @@ def _build_key_leaf(node, leaves):
     return None
 
 
+def _indexed_chain(root, leaves, joins, plan, agg_conds, ctx):
+    """(root, joins, used columns) of the fragment as a chain the shards
+    can run with `device_join.compile_fragment`'s body, strategies
+    assigned, or None: the in-program joins then take it.  It engages when
+    - the tree, chained fact-first as `device_join_agg` chains it
+      (`_reorder_fact_first`; a single join keeps the oriented tree), is
+      in the paged join's language: the probe spine ends in the sharded
+      leaf and every join is inner with a UNIQUE host index on its right
+      (so no join expands, and a shard's slice of the probe is a page);
+    - every index is direct (`JoinIndex.kind == "dense"`: what
+      `join_index.direct_table_fits` decided from its bytes);
+    - what every chip then holds whole fits: each build leaf's used
+      columns take at most ``tidb_broadcast_join_threshold_size`` bytes
+      (the reference's checkChildFitBC lets the size decide where it is
+      known, planner/core/exhaust_physical_plans.go; 0 or less: no
+      limit) and the resident-build budget.
+    It asks for a join's right index alone, so a fragment it refuses has
+    paid for no index the skew guard would not build."""
+    if not joins:
+        return None
+    chained = _reorder_fact_first(leaves, joins)
+    if chained is not None:
+        root, joins = chained
+        strategies = [jn.strategy for jn in joins]
+    else:
+        strategies = []
+        for jn in joins:
+            idx = _leaf_index(jn.right, jn.right_keys)
+            if idx is None or not idx.unique:
+                return None
+            strategies.append(("uniq", "right", idx))
+    if any(st[2].kind != "dense" for st in strategies):
+        return None
+    bc_bytes = int(ctx.get_sysvar("tidb_broadcast_join_threshold_size"))
+    limit = _dim_resident_budget()
+    if bc_bytes > 0:
+        limit = min(limit, bc_bytes)
+    used = _fragment_used_cols(leaves, joins, plan, agg_conds)
+    probe = _probe_spine(root)
+    if any(_leaf_used_bytes(leaf, used) > limit
+           for leaf in leaves if leaf is not probe):
+        return None
+    for jn, st in zip(joins, strategies):
+        jn.strategy = st
+    return root, joins, used
+
+
 def _run_mpp(plan, agg_conds, root, leaves, joins, ctx, mesh):
     # span tracing (session/tracing.py): one span per MPP fragment
     # dispatch, tagged with the mesh width — per-shard placement, the
@@ -599,6 +731,13 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
         bottom = prev  # the spine join directly over the sharded leaf
     else:
         shard_leaf = root.leaf_id
+    # a fragment in the paged join's language whose builds have direct
+    # host indexes runs compile_fragment's body on every shard
+    chain = _indexed_chain(root, leaves, joins, plan, agg_conds, ctx)
+    indexed = chain is not None
+    if indexed:
+        root, joins, used = chain
+        shard_leaf = _probe_spine(root).leaf_id
     shard_rows = leaves[shard_leaf].chunk.num_rows
     if shard_rows < n_shards:
         raise DeviceUnsupported("too few rows to shard over the mesh")
@@ -612,8 +751,12 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
     # join's right-key columns; any other build-subtree leaves stay
     # replicated, so the subtree's local joins remain co-partitioned
     # by the exchanged key.
+    # An indexed fragment is a broadcast join by construction, whatever
+    # its build's row count: a host-built index addresses the HOST's
+    # rows, not rows an all_to_all has moved, and `_indexed_chain` held
+    # every build it replicates to tidb_broadcast_join_threshold_size.
     shuffle_build = None
-    if bottom is not None:
+    if bottom is not None and not indexed:
         bleaf = _build_key_leaf(bottom, leaves)
         if bleaf is not None:
             try:
@@ -692,19 +835,22 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
         # sets) — single-chip kernel handles distinct
         raise DeviceUnsupported("non-mergeable agg on the mesh path")
 
-    leaf_cond_fns = [
-        [dev.compile_expr(_shift_expr(c, leaf.offset),
-                          {leaf.offset + i: dc
-                           for i, dc in leaf_metas[leaf.leaf_id].items()})
-         for c in leaf.conds] for leaf in leaves]
-    for jn in joins:
-        jn._lk_fns = [dev.compile_expr(_shift_expr(k, jn.left.offset), dcols)
-                      for k in jn.left_keys]
-        jn._rk_fns = [dev.compile_expr(_shift_expr(k, jn.right.offset), dcols)
-                      for k in jn.right_keys]
-        jn._oc_fns = [dev.compile_expr(_shift_expr(c, jn.offset), dcols)
-                      for c in jn.other_conds]
-    cond_fns = [dev.compile_expr(c, dcols) for c in agg_conds]
+    if not indexed:  # compile_fragment compiles its own
+        leaf_cond_fns = [
+            [dev.compile_expr(_shift_expr(c, leaf.offset),
+                              {leaf.offset + i: dc for i, dc
+                               in leaf_metas[leaf.leaf_id].items()})
+             for c in leaf.conds] for leaf in leaves]
+        for jn in joins:
+            jn._lk_fns = [
+                dev.compile_expr(_shift_expr(k, jn.left.offset), dcols)
+                for k in jn.left_keys]
+            jn._rk_fns = [
+                dev.compile_expr(_shift_expr(k, jn.right.offset), dcols)
+                for k in jn.right_keys]
+            jn._oc_fns = [dev.compile_expr(_shift_expr(c, jn.offset), dcols)
+                          for c in jn.other_conds]
+        cond_fns = [dev.compile_expr(c, dcols) for c in agg_conds]
 
     # mesh placement: sharded fact (and shuffled build) columns +
     # replicated dimensions, bucket-padded, residency-ledgered
@@ -721,6 +867,10 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
                 env[leaf.offset + i] = _place_col(
                     c, hd, hn, mesh, sharded, leaf_total[leaf.leaf_id])
                 env_specs[leaf.offset + i] = spec
+        # replicated like a dimension's columns, not through the
+        # one-device JoinIndex.device_arrays()
+        jidx = (tuple(_place_index(jn.strategy[2], mesh) for jn in joins)
+                if indexed else None)
         _upload_tags(usp, up0, len(env))
     # per-leaf LIVE row counts as TRACED scalars (leaf_id order): the
     # program masks padding in-body, so a row-count change inside the
@@ -736,7 +886,10 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
            tuple(leaf_total[leaf.leaf_id] for leaf in leaves))
     dict_refs = tuple(dc.dictionary for dc in dcols.values()
                       if dc.dictionary is not None)
-    bottom_idx = joins.index(bottom) if bottom is not None else -1
+    bottom_idx = joins.index(bottom) if shuffle_build is not None else -1
+    # the facts compile_fragment turns into constant masks: an element of
+    # the indexed program's key (a column's first NULL finds a new one)
+    nonnull = nonnull_cols(root, leaves, used) if indexed else None
 
     # static capacities: per-shard bucketed probe rows bound the bottom
     # join; each join's output bounds the next (FK heuristic, grown on
@@ -781,7 +934,10 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
         return caps
 
     learned_caps = _CAP_STORE.get((sig, "caps"))
-    if learned_caps is not None and len(learned_caps) == len(joins):
+    if indexed:
+        # every join is a probe-shaped gather: nothing to learn or grow
+        caps = [per_shard_b] * len(joins)
+    elif learned_caps is not None and len(learned_caps) == len(joins):
         caps = list(learned_caps)
     else:
         caps = init_caps()
@@ -815,12 +971,26 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
                        xcaps[0], xcaps[1])
         key = (sig, tuple(caps), tuple(xcaps or ()), capacity, key_pack,
                tuple(agg_ops))
+        if indexed:
+            key += (nonnull,)
 
-        def build(shuffle=shuffle, cap=capacity):
-            return _build_mpp_pipeline(
-                mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
-                cond_fns, key_fns, n_keys, val_plan, tuple(agg_ops),
-                cap, key_pack, env_specs, shuffle=shuffle)
+            def build(caps=tuple(caps), cap=capacity):
+                return compile_fragment(
+                    root, leaves, joins, plan, agg_conds, list(caps), cap,
+                    key_pack, (key_fns, val_plan, agg_ops, slots), nonnull,
+                    program=functools.partial(
+                        _shard_program, mesh=mesh, probe_id=shard_leaf,
+                        shard_rows=per_shard_b, env_specs=env_specs,
+                        n_keys=n_keys, agg_ops=tuple(agg_ops), capacity=cap,
+                        key_pack=key_pack))
+            args = (env, jidx, n_lives)
+        else:
+            def build(shuffle=shuffle, cap=capacity):
+                return _build_mpp_pipeline(
+                    mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
+                    cond_fns, key_fns, n_keys, val_plan, tuple(agg_ops),
+                    cap, key_pack, env_specs, shuffle=shuffle)
+            args = (env, n_lives)
         # mesh pipelines compile SYNC through the service (no arg spec):
         # a background warm would dispatch zero-filled HOST arrays against
         # a shard_map program traced for mesh-placed shardings — a
@@ -830,7 +1000,7 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
                               shape="mpp", sig=sig)
         try:
             failpoint.inject("mpp-exchange-send")
-            agg_out, png_d, ovfs_d, sovfs_d, xneeds_d = fn(env, n_lives)
+            agg_out, png_d, ovfs_d, sovfs_d, xneeds_d = fn(*args)
             from .device_exec import AggFetch
             f = AggFetch(agg_out, extras=(png_d, ovfs_d, sovfs_d, xneeds_d))
             failpoint.inject("mpp-exchange-recv")
@@ -907,6 +1077,12 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
     MPP_STATS["fragments"] += 1
     if shuffle_build is not None:
         MPP_STATS["shuffle_joins"] += 1
+    if indexed:
+        # counted as device_join_agg counts its fragment: once, whatever
+        # the retries above (the last program's gathers are every one's)
+        MPP_STATS["indexed_fragments"] += 1
+        note_join_layouts(jn.strategy for jn in joins)
+        note_join_gathers(fn)
     _publish_gauges(ctx)
     key_out, key_null_out, results, result_nulls = f.body()
     return _assemble_agg(plan, key_meta, slots, dcols,
