@@ -315,7 +315,15 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # the statement cut from the host's columns and sent,
                # whose bytes count under stream_upload_bytes —
                # note_join_probe
-               "join_probe_resident": 0, "join_probe_sent": 0}
+               "join_probe_resident": 0, "join_probe_sent": 0,
+               # aggregate subqueries a join fragment materialised and
+               # folded into an in-set filter of its probe side (the
+               # uncorrelated IN -> semi rewrite, Q18) — note_semi_inset
+               "semi_insets": 0,
+               # fragments a PINNED device engine (tpu / tpu-mpp) left
+               # to the host executors because they are outside the
+               # device language — note_unsupported
+               "unsupported": 0}
 _PIPE_LOCK = _threading.Lock()
 _PIPE_TLS = _threading.local()
 
@@ -413,6 +421,29 @@ def note_join_probe(resident: bool):
     annotation and the benchmark's ``join.probe_resident_share`` read
     the counters."""
     _bump("join_probe_resident" if resident else "join_probe_sent")
+
+
+def note_semi_inset():
+    """Count one aggregate subquery that a join fragment's plan walk
+    (device_join.collect_tree) ran through its own executors and folded
+    into an in-set filter on the probe subtree, under the span
+    ``subquery.materialize``.  Once per fragment, whatever its capacity
+    retries (the walk runs before them)."""
+    _bump("semi_insets")
+
+
+def note_unsupported(ctx, reason) -> "str | None":
+    """A fragment raised DeviceUnsupported and its caller is about to run
+    it on the host executors.  Under a PINNED device engine (`tpu`,
+    `tpu-mpp`) that is worth saying: count it, and return the text of the
+    ``device_unsupported:`` note for EXPLAIN ANALYZE.  Under `auto` (or a
+    row floor) leaving a fragment to the host is the engine's own choice:
+    None, nothing counted."""
+    if engine_mode(ctx) not in ("tpu", "tpu-mpp"):
+        return None
+    _bump("unsupported")
+    # one line, no ", " (the separator of EXPLAIN ANALYZE's notes)
+    return " ".join(str(reason).replace(",", ";").split())[:200]
 
 
 def pipe_cache_stats(thread_local: bool = False) -> dict:
@@ -538,6 +569,10 @@ def _expr_sig(e) -> str:
     if isinstance(e, _Const):
         return f"k{e.value!r}:{base}"
     if isinstance(e, _SF):
+        part = dev.date_part(e)
+        if part is not None:
+            # YEAR(d) and EXTRACT(YEAR FROM d): one program, one signature
+            return f"{part[0]}({_expr_sig(part[1])}):{base}"
         extra = f"|{e.extra!r}" if e.extra is not None else ""
         return (f"{e.op}({','.join(_expr_sig(a) for a in e.args)})"
                 f"{extra}:{base}")
@@ -1165,16 +1200,16 @@ def _expr_bounds(e, dcols):
     (multi-sort) agg path."""
     if dcols is None:
         return None
-    from ..expression.core import ScalarFunc as _SF
     if isinstance(e, ExprColumn):
         dc = dcols.get(e.idx)
         if dc is None or dc.host_col is None or dc.dictionary is not None:
             return None
         return dc.host_col.minmax()
-    if (isinstance(e, _SF) and e.op == "year"
-            and isinstance(e.args[0], ExprColumn)
-            and phys_kind(e.args[0].ftype) == K_DATE):
-        b = _expr_bounds(e.args[0], dcols)
+    part = dev.date_part(e)
+    if (part is not None and part[0] == "year"
+            and isinstance(part[1], ExprColumn)
+            and phys_kind(part[1].ftype) == K_DATE):
+        b = _expr_bounds(part[1], dcols)
         if b is None:
             return None
 

@@ -57,7 +57,7 @@ from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
     _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm,
-    note_join_gathers, note_join_layouts, note_join_probe)
+    note_join_gathers, note_join_layouts, note_join_probe, note_semi_inset)
 from .join_index import build_join_index
 
 
@@ -151,14 +151,24 @@ def collect_tree(node):
                     # membership there would null-extend instead of drop
                     raise DeviceUnsupported(
                         "semi membership over a non-inner probe")
-                values_chunk = n.children[1].execute()
-                from .exec_select import eval_expr_to_column
-                col = eval_expr_to_column(p.right_keys[0], values_chunk)
-                vals = [None if col.nulls[i] else col.value_at(i)
-                        for i in range(len(col.data))]
-                from ..expression.builder import build_in_set
-                cond = build_in_set(p.left_keys[0], vals,
-                                    p.right_keys[0].ftype)
+                from ..session import tracing
+                with tracing.span("subquery.materialize") as sp:
+                    # the subquery's own fragments nest under this span;
+                    # what is left of it is the host's: its executors'
+                    # tail (a HAVING), the values as Python objects, the
+                    # in-set
+                    values_chunk = n.children[1].execute()
+                    from .exec_select import eval_expr_to_column
+                    col = eval_expr_to_column(p.right_keys[0], values_chunk)
+                    vals = [None if col.nulls[i] else col.value_at(i)
+                            for i in range(len(col.data))]
+                    from ..expression.builder import build_in_set
+                    cond = build_in_set(p.left_keys[0], vals,
+                                        p.right_keys[0].ftype)
+                    if sp is not None:
+                        sp.tags["rows"] = values_chunk.num_rows
+                        sp.tags["kept"] = len(cond.extra[0])
+                note_semi_inset()
                 if isinstance(lnode, _Leaf):
                     lnode.conds.append(cond)  # left-local schema == leaf's
                 else:

@@ -62,6 +62,14 @@ class QueryExecutor:
         if self.stats is not None:
             self.stats.annotate(self.plan, **kv)
 
+    def _left_to_host(self, why):
+        """This node's device fragment raised `why` (DeviceUnsupported)
+        and runs on the host executors instead.  Under a pinned device
+        engine that is counted (``device_pipelines.unsupported``) and
+        EXPLAIN ANALYZE says so: ``device_unsupported:<reason>``."""
+        from .device_exec import note_unsupported
+        self.annotate(device_unsupported=note_unsupported(self.ctx, why))
+
     def _with_pipe_stats(self, fn, /, *args, **kw):
         """Run a device dispatch and annotate the compiled-fragment cache
         delta — hits/misses, XLA compiles triggered, compile seconds — so
@@ -572,6 +580,7 @@ class HashAggExec(QueryExecutor):
         from .mpp_exec import mpp_mesh, mpp_agg, mpp_join_agg
         from ..storage.paged import chunk_is_paged
         mesh = mpp_mesh(self.ctx)
+        why = None       # the last DeviceUnsupported a device arm raised
         if mesh is not None and raw is not None and chunk_is_paged(raw):
             # paged scans ARE mesh-legal within the residency budget now
             # (placement materializes the pages per shard); a bigger disk
@@ -596,8 +605,8 @@ class HashAggExec(QueryExecutor):
                         shape="join")
                     self._mark_fragment("tpu-mpp", None)
                     return out
-            except DeviceUnsupported:
-                pass
+            except DeviceUnsupported as e:
+                why = e
         want = raw is not None and want_device(self.ctx, raw.num_rows)
         # fragment identity for admission batching AND the shared perf
         # store: computed once here so the device dispatches, the host
@@ -641,8 +650,8 @@ class HashAggExec(QueryExecutor):
                         batch_key=bkey)
                     self._mark_fragment("tpu-stream", raw.num_rows)
                     return out
-                except DeviceUnsupported:
-                    pass
+                except DeviceUnsupported as e:
+                    why = e
             if not paged_in:
                 # a paged chunk must NOT fall through to the whole-input
                 # pipeline: to_device_col would read the entire memmap into
@@ -653,8 +662,8 @@ class HashAggExec(QueryExecutor):
                         conds, ctx=self.ctx, shape="agg", batch_key=bkey)
                     self._mark_fragment("tpu", raw.num_rows)
                     return out
-                except DeviceUnsupported:
-                    pass
+                except DeviceUnsupported as e:
+                    why = e
         # join fragment: HashAgg over an (inner equi-)join tree of scans
         # fuses scans+filters+joins+aggregate into one device program
         if (raw is None and isinstance(join_child, HashJoinExec)
@@ -683,8 +692,10 @@ class HashAggExec(QueryExecutor):
                             hj_spill_bytes=st["hj_spill_bytes"],
                             hj_coproc_host_rows=st["hj_coproc_host_rows"])
                 return out
-            except DeviceUnsupported:
-                pass
+            except DeviceUnsupported as e:
+                why = e
+        if why is not None:
+            self._left_to_host(why)
         import time as _t
         t_host = _t.perf_counter()
         if raw is not None and eff_p is p:
@@ -1070,8 +1081,8 @@ class HashJoinExec(QueryExecutor):
                 return self._with_pipe_stats(
                     run_device, self.ctx, device_join_keys,
                     probe_keys, build_keys, shape="join")
-            except DeviceUnsupported:
-                pass
+            except DeviceUnsupported as e:
+                self._left_to_host(e)
         return self._host_match(build_keys, probe_keys)
 
     def _host_match(self, build_keys, probe_keys):
@@ -1354,8 +1365,8 @@ class WindowExec(QueryExecutor):
                     self.ctx, shape="window")
                 self.annotate(engine="tpu")
                 return out
-            except _DU:
-                pass
+            except _DU as e:
+                self._left_to_host(e)
         if p.partition_exprs:
             pk = [_collate_eval(e, chunk) for e in p.partition_exprs]
             gids, ng, _fi = host.group_ids(pk)

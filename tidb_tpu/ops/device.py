@@ -231,6 +231,33 @@ def meta_device_col(col):
 # expression → jax compiler
 # ---------------------------------------------------------------------------
 
+#: longest IN list (a literal list, or a subquery's values folded by
+#: device_join.collect_tree) that is compared with every row; past it
+#: the list is binary-searched
+_IN_SET_COMPARE_MAX = 256
+
+#: the civil-date fields the device extracts, by their index in
+#: _civil_from_days' result
+_DATE_PARTS = {"year": 0, "month": 1, "day": 2}
+
+
+def date_part(e):
+    """(field, argument) when `e` takes the year, month or day of a
+    temporal value, else None.  THE rule for what the device extracts:
+    YEAR(d), EXTRACT(YEAR FROM d) and their month / day siblings are one
+    expression to the compiler, to the key-bounds rule
+    (device_exec._expr_bounds) and to the pipeline signature
+    (device_exec._expr_sig), so a client's spelling never decides the
+    engine or the program.  EXTRACT's other units (quarter, week, the
+    time fields) have no lowering and stay DeviceUnsupported."""
+    if not isinstance(e, ScalarFunc):
+        return None
+    if e.op == "extract":
+        return (e.extra, e.args[1]) if e.extra in _DATE_PARTS else None
+    op = "day" if e.op == "dayofmonth" else e.op
+    return (op, e.args[0]) if op in _DATE_PARTS else None
+
+
 def compile_expr(expr, cols: dict):
     """Build a traceable fn(env) -> (data, nulls) where env maps column idx
     → (jnp data, jnp nulls). `cols` maps idx → DeviceCol (for dictionaries
@@ -495,12 +522,23 @@ def _compile_func_direct(sf: ScalarFunc, cols):
                 return hit, n | bool(has_null)
             return f
         sorted_vals = jnp.asarray(np.sort(np.asarray(values)))
+        # a short list is compared with every row in one fused pass (no
+        # gather, no loop); a long one is binary-searched, a dependent
+        # gather a step.  The bound keeps the time a smooth function of
+        # the list's length: on the chip the search was 1.4 ms over 56
+        # values and 1,244 ms over 68 (the compiler turns a gather from
+        # at most 64 entries into selects), so Q18's time followed how
+        # many large orders the data held (PERF.md, PR 33)
+        compare_all = len(values) <= _IN_SET_COMPARE_MAX
 
         def f(env):
             d, n = fa(env)
-            pos = jnp.searchsorted(sorted_vals, d)
-            pos = jnp.clip(pos, 0, len(sorted_vals) - 1)
-            hit = sorted_vals[pos] == d
+            if compare_all:
+                hit = (d[..., None] == sorted_vals).any(-1)
+            else:
+                pos = jnp.searchsorted(sorted_vals, d)
+                pos = jnp.clip(pos, 0, len(sorted_vals) - 1)
+                hit = sorted_vals[pos] == d
             nulls = n | (~hit & bool(has_null))
             return hit.astype(jnp.int64), nulls
         return f
@@ -526,20 +564,21 @@ def _compile_func_direct(sf: ScalarFunc, cols):
                 out_n = out_n & n
             return out_d, out_n
         return f
-    if op in ("year", "month", "dayofmonth", "day"):
-        arg = sf.args[0]
+    part = date_part(sf)
+    if part is not None:
+        field, arg = part
         fa = compile_expr(arg, cols)
         ak = phys_kind(arg.ftype)
         is_dt = arg.ftype.tp in (TYPE_DATETIME, TYPE_TIMESTAMP)
         if ak != K_DATE and not is_dt:
-            raise DeviceUnsupported(f"{op}() on non-temporal for device")
-        part = {"year": 0, "month": 1, "dayofmonth": 2, "day": 2}[op]
+            raise DeviceUnsupported(f"{field}() on non-temporal for device")
+        which = _DATE_PARTS[field]
 
         def f(env):
             d, n = fa(env)
             days = (jnp.floor_divide(d.astype(jnp.int64), 86_400_000_000)
                     if is_dt else d.astype(jnp.int64))
-            return _civil_from_days(days)[part], n
+            return _civil_from_days(days)[which], n
         return f
     if op == "abs":
         fa = compile_expr(sf.args[0], cols)
