@@ -18,8 +18,8 @@ import json
 import pytest
 
 from test_join_gather_elision import _iota_gathers
-from benchmark.datasets import tpch
-from benchmark.queries import q3, q5
+from benchmark.datasets import ssb, tpch
+from benchmark.queries import q1, q3, q5, q6, q18, ssb_q2_1
 from tidb_tpu.executor import device_exec, device_join, join_index, mpp_exec
 from tidb_tpu.executor.mpp_exec import MPP_STATS
 from tidb_tpu.ops import device as dev
@@ -392,3 +392,45 @@ def test_exposing_the_body_moved_no_one_chip_program(tpch_tk, monkeypatch,
     _drop_compiled()
     assert bodies and bodies[-1].__name__ == "run"
     assert _sha(wrapped) == _sha(default) == _SETTLED[name]
+
+
+# -- a prefix before a searched index moved no program that searches nothing --
+
+#: SHA-1 (12 digits) of every program the THIRD execution dispatches (the
+#: learned capacities settled by then), over the benchmark's data at SF0.02
+#: seed 7 (SSB: SF0.01, seed 3100200341), whole schema: equal at PR 33's
+#: commit 1e23e2c and after ISSUE 34 gave a `sorted` join index its prefix
+#: table.  Every join here is `dense` and passes no new argument; Q18 is
+#: its inner scan aggregate at the first capacity and at the learned one
+#: (`device_agg` forgets it: ROADMAP S7), then its outer join fragment.
+_UNSEARCHED = {
+    "q1": ("tpu", q1.SQL, ["ea0e7b39e1e9"]),
+    "q6": ("tpu", q6.SQL, ["de2b533191b0"]),
+    "q18": ("tpu", q18.SQL,
+            ["a66673799df8", "73101b935985", "bd1b6c50d898"]),
+    "mesh_q3": ("tpu-mpp", q3.SQL, ["6b2a6791d773"]),
+    "ssb_q2_1": ("tpu", ssb_q2_1.SQL, ["99d230717088"]),
+}
+
+
+@pytest.fixture(scope="module")
+def ssb_tk():
+    want = {t: list(cols) for t, cols in ssb.SCHEMA.items()}
+    tk = _tk()
+    ssb.load(tk, ssb.generate(3100200341, 0.01, want), want, False,
+             "test_mpp_indexed/ssb")
+    return tk
+
+
+@pytest.mark.parametrize("name", list(_UNSEARCHED))
+def test_a_program_that_searches_nothing_kept_its_text(
+        tpch_tk, ssb_tk, monkeypatch, name):
+    engine, sql, settled = _UNSEARCHED[name]
+    tk = ssb_tk if name.startswith("ssb") else tpch_tk
+    want = _rows(tk, sql, "host")
+    low = _Lowered(monkeypatch)
+    for _ in range(3):
+        low.take()
+        assert _rows(tk, sql, engine) == want
+    _drop_compiled()
+    assert [_sha(t) for t in low.take()] == settled

@@ -294,9 +294,12 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                "agg_scatter": 0,
                # host-indexed joins of dispatched join fragments, by how
                # a probe key finds its build rows: by address (a `dense`
-               # JoinIndex) or by binary search (`sorted`) —
+               # JoinIndex) or by binary search (`sorted`); of the
+               # searched, those whose index carries a prefix table, so
+               # that the search starts from the key's bucket —
                # note_join_layouts
                "join_direct": 0, "join_search": 0,
+               "join_search_prefixed": 0,
                # column / mask / row-map gathers of dispatched join
                # fragments' programs, and those the program holds the
                # result of already (a leaf read in place, a NULL-free
@@ -355,6 +358,7 @@ def _tls_stats() -> dict:
                                 "mode_async_pending": 0, "mode_sync": 0,
                                 "agg_dense": 0, "agg_sorted": 0,
                                 "join_direct": 0, "join_search": 0,
+                                "join_search_prefixed": 0,
                                 "join_gathers": 0,
                                 "join_gathers_elided": 0,
                                 "join_probe_resident": 0,
@@ -389,13 +393,18 @@ def note_join_layouts(strategies):
     """Count the host-indexed joins of one dispatched join fragment (its
     strategy snapshot: ``_JoinNode.strategy`` per join) by the layout of
     their index; EXPLAIN ANALYZE's ``join:`` annotation and the
-    benchmark's ``join.direct_share`` read the counters.  Joins built
-    inside the program (no index) count under neither; a mesh fragment on
-    the indexed path (mpp_exec._indexed_chain) counts as one chip's."""
+    benchmark's ``join.direct_share`` read the counters.  A searched
+    join whose index carries a prefix table (join_index._bucket_prefix)
+    counts under ``join_search_prefixed`` too
+    (``join.prefixed_search_share``).  Joins built inside the program (no
+    index) count under none; a mesh fragment on the indexed path
+    (mpp_exec._indexed_chain) counts as one chip's."""
     for st in strategies:
         if st is not None and st[2] is not None:
             _bump("join_direct" if st[2].kind == "dense"
                   else "join_search")
+            if st[2].prefix is not None:
+                _bump("join_search_prefixed")
 
 
 def note_join_gathers(fn):

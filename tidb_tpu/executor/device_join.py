@@ -9,7 +9,10 @@ compiles into ONE jitted XLA program over HBM-resident base tables:
   in numpy and the compiled program only gathers: a probe key addresses
   a table over the key span directly whenever that table's bytes are
   affordable, and binary-searches the host-sorted keys only when they
-  are not (a `sorted` build: log2(n) dependent gathers a probe row). A
+  are not (a `sorted` build): from the two ends of the key's bucket
+  where the index carries a prefix table over the key's high bits (a
+  step or two a probe row), over the whole array where it does not
+  (log2(n) dependent gathers). A
   UNIQUE build side (every TPC-H fact⋈dim join) adds nothing to the
   output shape — the join is one gather of a row id with the probe
   side's exact capacity, no expansion pass and no overflow retry at all.
@@ -622,6 +625,28 @@ def _pack_probe(kds, knulls, pvalid, packs):
     return key, ok
 
 
+def _bucket_search(a0, probe, blo, bhi, steps, right=False):
+    """`jnp.searchsorted(a0, probe, side)` for rows whose answer is known
+    to lie in [blo, bhi] with bhi - blo < 2**steps: `steps` unrolled
+    bisection steps, one gather of `a0` each.  Returns (pos, eq); eq says
+    that a0[pos] == probe INSIDE the interval, read from the value of the
+    step that last drew the upper end in (position bhi is the next
+    bucket's, and is never compared)."""
+    lo, hi = blo, bhi
+    eq = jnp.zeros(probe.shape, dtype=bool)
+    for _ in range(steps):
+        mid = (lo + hi) >> 1
+        # lo <= mid < hi while the interval is open; a closed one reads
+        # an entry it ignores (past the array only when every row is live)
+        v = a0[jnp.minimum(mid, a0.shape[0] - 1)]
+        up = (lo < hi) & ((v <= probe) if right else (v < probe))
+        down = (lo < hi) & ~up
+        eq = jnp.where(down, v == probe, eq)
+        lo = jnp.where(up, mid + 1, lo)
+        hi = jnp.where(down, mid, hi)
+    return lo, eq
+
+
 def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                      capacity, key_pack, agg_meta, nonnull, raw_tail=False,
                      strategies=None, program=None):
@@ -821,7 +846,7 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
             key, ok = _pack_probe(kds, knulls, pvalid, idx.packs)
             # nv is TRACED (a same-bucket index refresh re-dispatches
             # without retracing); every bound derived from it is traced
-            a0, a1, nv = jidx[node.pos]
+            a0, a1, nv = jidx[node.pos][:3]
             safe_hi = jnp.maximum(nv - 1, 0)
             if idx.slots is not None:
                 # unique dense build: the slot holds the row id, or -1
@@ -833,13 +858,26 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                 cnt = jnp.where(ok, (a0[k_c + 1] - a0[k_c]).astype(jnp.int64),
                                 0)
             else:
-                lo = jnp.searchsorted(a0, key, side="left")
-                lo_c = jnp.clip(lo, 0, a0.shape[0] - 1)
+                if idx.prefix is None:
+                    lo = jnp.searchsorted(a0, key, side="left")
+                    if kind == "uniq":
+                        lo_c = jnp.clip(lo, 0, a0.shape[0] - 1)
+                        found = a0[lo_c] == key
+                    else:
+                        hi = jnp.searchsorted(a0, key, side="right")
+                else:
+                    # the key's high bits address its bucket, whose keys
+                    # differ in their low bits only: a0 holds those
+                    ends = jidx[node.pos][3][key >> idx.shift]
+                    bucket = ends[:, 0], ends[:, 1], idx.steps
+                    low = (key & ((1 << idx.shift) - 1)).astype(a0.dtype)
+                    lo, found = _bucket_search(a0, low, *bucket)
+                    if kind != "uniq":
+                        hi, _ = _bucket_search(a0, low, *bucket, right=True)
                 pos0 = jnp.minimum(lo, nv).astype(jnp.int64)
                 if kind == "uniq":
-                    cnt = jnp.where(ok & (lo < nv) & (a0[lo_c] == key), 1, 0)
+                    cnt = jnp.where(ok & (lo < nv) & found, 1, 0)
                 else:
-                    hi = jnp.searchsorted(a0, key, side="right")
                     cnt = jnp.where(
                         ok, jnp.minimum(hi, nv) - jnp.minimum(lo, nv), 0)
 

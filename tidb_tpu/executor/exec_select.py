@@ -109,12 +109,18 @@ class QueryExecutor:
                 (arm for arm, k in AGG_ARM_STATS.items()
                  if st1[k] - st0[k] > 0), None))
             # how the fragment's host-indexed joins look a key up
-            # (device_exec.note_join_layouts): join:direct x5
+            # (device_exec.note_join_layouts): join:direct x5, or
+            # join:direct x4+search x1 (prefix x1) where a searched
+            # join starts from its key's bucket
             layouts = [(name, st1[k] - st0[k])
                        for name, k in (("direct", "join_direct"),
                                        ("search", "join_search"))]
-            self.annotate(join="+".join(
-                f"{name} x{n}" for name, n in layouts if n > 0) or None)
+            note = "+".join(f"{name} x{n}" for name, n in layouts if n > 0)
+            prefixed = (st1["join_search_prefixed"]
+                        - st0["join_search_prefixed"])
+            if prefixed:
+                note += f" (prefix x{prefixed})"
+            self.annotate(join=note or None)
             # the join fragment's column / mask / row-map gathers, and
             # (-n) those its program elides
             # (device_exec.note_join_gathers): gathers:4 (-15)

@@ -20,8 +20,20 @@ Two layouts, told apart by how a probe key finds its build rows:
   CSR (``starts`` of span + 1 entries, ``rows`` listing row ids in key
   order): two gathers and an expansion.
 - ``sorted``: row ids argsorted by packed key + the sorted key array;
-  a lookup is a binary search, ceil(log2(n)) dependent gathers per probe
-  row, each an emulated 64-bit compare on the TPU.
+  a lookup is a binary search.  Where the host sees that it pays
+  (`_bucket_prefix`), the search starts from an address too: a
+  ``prefix`` table over the key's HIGH bits (``packed >> shift``, about
+  as many buckets as the index has padded rows, admitted by
+  `direct_table_fits` like any direct table) holds where each bucket's
+  keys start and end in the sorted array, side by side, so a probe row
+  reads its bucket's two ends in ONE gather and bisects the few keys
+  between them: ``steps`` dependent gathers, from the largest bucket's
+  population, instead of ceil(log2(n)).  All keys of one bucket share
+  their high bits, so the device holds and compares only the low
+  ``shift`` bits (``low_keys``, one 32-bit gather a step).  Without a
+  prefix (a hot key that fills a bucket, a span whose buckets are wider
+  than 32 bits, a partitioned build) the device holds the int64 keys and
+  every step is an emulated 64-bit compare over the whole array.
 
 The layout is chosen from the table's BYTES (`direct_table_fits`): the
 table is direct-addressed when it fits the device budget the residency
@@ -33,7 +45,9 @@ and 243 MB at SF10; Q5's (``c_nationkey``, ``c_custkey``) pair spans 26x
 its rows in 16 MB), and a search at fact length cost the chip 18-20
 dependent gathers where an address costs one (PERF.md §6, PR 28). What stays
 ``sorted`` is a composite key space like partsupp's (``ps_partkey``,
-``ps_suppkey``): 2e9 slots, 8 GB at SF1.
+``ps_suppkey``): 2e9 slots, 8 GB at SF1, its 800,000 rows spread evenly
+over them: a prefix of 1,064,960 buckets leaves one key a bucket and one
+step of the twenty-one (PERF.md §6, PR 34).
 
 Either layout knows whether the (non-null) build keys are UNIQUE. A
 unique build side makes the join output shape the PROBE side's shape —
@@ -55,8 +69,13 @@ signature and forced a full XLA recompile.  With ~1/16-of-magnitude
 slack on each end, a delta that stays inside the widened range rebuilds
 only the (cheap, numpy) host index and re-uses the compiled fragment:
 the lookup arrays are passed as runtime arguments, so same shapes ⇒ same
-program.  Correctness is unaffected — probe keys in the slack region
-simply find zero matches, exactly like any other unmatched key.
+program.  A prefixed search bakes in two more numbers, both quantized:
+the shift (from the packed span and the padded row count) and the number
+of bisection steps (the largest bucket's population up to the next
+2^steps - 1): a build-side INSERT that keeps every bucket under that
+bound compiles nothing, one past it recompiles once.  Correctness is
+unaffected — probe keys in the slack region simply find zero matches,
+exactly like any other unmatched key.
 
 Bucketed shapes + traced n_valid (ROADMAP item 1, the LAST recompile
 trigger): the row-id array (and the sorted-key array) pads to a
@@ -76,6 +95,8 @@ sentinel region).
 """
 
 from __future__ import annotations
+
+import math
 
 import numpy as np
 
@@ -120,12 +141,17 @@ class JoinIndex:
 
     __slots__ = ("kind", "packs", "unique", "filtered", "n_rows",
                  "n_valid", "span", "slots", "starts", "rows",
-                 "sorted_keys", "avg_cnt", "max_cnt", "rows_len", "_owner")
+                 "sorted_keys", "avg_cnt", "max_cnt", "rows_len", "_owner",
+                 "prefix", "shift", "steps", "low_keys", "_prefix_owner")
 
     def __init__(self):
         self.slots = None
         self.filtered = False
         self._owner = None
+        # a `sorted` index's direct-address front (_bucket_prefix)
+        self.prefix = self.low_keys = None
+        self.shift = self.steps = 0
+        self._prefix_owner = None
 
     def sig(self) -> str:
         """What a compiled fragment bakes in of this index: the layout,
@@ -135,32 +161,37 @@ class JoinIndex:
         rebuilds the cheap numpy index and reuses the compiled program;
         a slot table has no row-id array (rows_len 0), and whether it
         was built under the leaf's filter decides whether the program
-        still reads the build mask."""
+        still reads the build mask.  A prefixed search adds its shift
+        and its number of steps (`_bucket_prefix`)."""
         ids = self.slots if self.slots is not None else self.rows
-        return (f"{self.kind}/{self.packs}/{int(self.unique)}/"
-                f"{int(self.filtered)}/{self.rows_len}/{ids.dtype}")
+        sig = (f"{self.kind}/{self.packs}/{int(self.unique)}/"
+               f"{int(self.filtered)}/{self.rows_len}/{ids.dtype}")
+        if self.prefix is not None:
+            # the prefix's length follows from packs and rows_len
+            sig += f"/prefix{self.shift}.{self.steps}"
+        return sig
 
     def device_arrays(self):
         """The (a0, a1, n_valid) lookup tuple the compiled fragment takes
         as runtime arguments: the slot table (a1 None) / the CSR starts /
-        the sorted keys, the bucket-padded row ids, and the live entry
-        count as a TRACED scalar (np.int64, the n_lives convention) — a
-        same-shape index refresh re-dispatches the compiled program
-        without retracing.  The arrays are uploaded once and cached
+        the sorted keys (their low bits under a prefix), the
+        bucket-padded row ids, and the live entry count as a TRACED
+        scalar (np.int64, the n_lives convention) — a same-shape index
+        refresh re-dispatches the compiled program without retracing.  A
+        prefixed `sorted` index, and no other, passes a fourth element:
+        the prefix table.  The arrays are uploaded once and cached
         through the residency ledger like a column's (`direct_table_fits`
         admitted their bytes against its budget): counted, evictable,
         and dropped at a device epoch bump."""
-        import jax.numpy as jnp
         from ..ops import residency
-        a0, a1 = self.host_arrays()
         if self._owner is None:
             self._owner = residency.CacheOwner()
-        dev = residency.lookup(self._owner, len(a0))
-        if dev is None:
-            dev = residency.publish(
-                self._owner, jnp.asarray(a0),
-                None if a1 is None else jnp.asarray(a1))
-        return dev[0], dev[1], np.int64(self.n_valid)
+            self._prefix_owner = residency.CacheOwner()
+        a0, a1 = _resident(self._owner, *self.host_arrays())
+        if self.prefix is None:
+            return a0, a1, np.int64(self.n_valid)
+        return (a0, a1, np.int64(self.n_valid),
+                _resident(self._prefix_owner, self.prefix, None)[0])
 
     def host_arrays(self):
         """The numpy (a0, a1) behind `device_arrays`, for a caller that
@@ -168,8 +199,62 @@ class JoinIndex:
         mpp_exec._place_index)."""
         if self.slots is not None:
             return self.slots, None
-        return (self.starts if self.kind == "dense" else self.sorted_keys,
+        if self.kind == "dense":
+            return self.starts, self.rows
+        return (self.sorted_keys if self.prefix is None else self.low_keys,
                 self.rows)
+
+
+def _resident(owner, a0, a1):
+    """`owner`'s (a0, a1) on the device, through the residency ledger."""
+    import jax.numpy as jnp
+    from ..ops import residency
+    dev = residency.lookup(owner, len(a0))
+    if dev is None:
+        dev = residency.publish(owner, jnp.asarray(a0),
+                                None if a1 is None else jnp.asarray(a1))
+    return dev
+
+
+def _bucket_prefix(sk, span, pad_len, row_dt):
+    """The direct-address front of a `sorted` index over the valid sorted
+    keys `sk`: (shift, steps, prefix), or None where a prefixed search
+    would not pay.  Everything is what the host observes:
+
+    - ``shift``: log2 of the packed span over the padded row count,
+      rounded, so the table has as many entries as the index has rows to
+      within a factor of sqrt(2) and is no larger than the arrays the
+      index uploads anyway (partsupp at SF1: span 2,181,038,080 over
+      1,048,576 rows, shift 11, 1,064,960 buckets);
+    - ``prefix``: CSR starts over ``sk >> shift`` and the same starts one
+      bucket on, as the two columns of one (buckets, 2) table: bucket
+      b's keys are ``sk[prefix[b, 0]:prefix[b, 1]]``, the last end reads
+      n_valid.  Each start is stored twice so that a probe row reads
+      both ends in one gather of an 8-byte row: on the chip the lookup
+      at Q9's shapes took 159 ms against 254 ms with two gathers of a
+      (buckets + 1) array (PERF.md §6, PR 34);
+    - ``steps``: bit_length of the largest bucket's population, the
+      STATIC number of bisection steps that finds any position among a
+      bucket's at most 2^steps - 1 keys (every population up to that
+      bound shares one compiled program).
+
+    None when the prefix's two values and the steps would not come to
+    less than the full search's ``pad_len.bit_length()`` steps (a hot key
+    with thousands of rows, a table of a few rows), when a bucket's low
+    bits do not fit the 32 the device compares, or when
+    `direct_table_fits` refuses the table."""
+    shift = max(round(math.log2(span / pad_len)), 0)
+    n_buckets = -(-span // (1 << shift))
+    if shift > 32 or not direct_table_fits(
+            2 * n_buckets * np.dtype(row_dt).itemsize):
+        return None
+    counts = np.bincount(sk >> shift, minlength=n_buckets)
+    steps = max(int(counts.max()), 1).bit_length()
+    if steps + 2 >= pad_len.bit_length():
+        return None
+    starts = np.zeros(n_buckets + 1, dtype=row_dt)
+    np.cumsum(counts, out=starts[1:])
+    return shift, steps, np.stack([starts[:-1], starts[1:]], axis=1)
 
 
 def _pack_host(datas, valid, packs):
@@ -206,7 +291,8 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
     packs / force_sorted / pad_rows override the shape-determining
     choices for PARTITIONED builds (executor/hybrid_join.py): every radix
     partition of one hybrid join must carry the SAME per-column (min,
-    span) packs, the same layout kind and the same padded array length —
+    span) packs, the same layout kind (with no prefix: `_bucket_prefix`'s
+    bound is one build's own) and the same padded array length —
     otherwise each partition would bake its own shapes into the fragment
     signature and the zero-recompile invariant would die P ways.  `packs`
     are the whole-table quantized ranges (probe keys outside a
@@ -237,6 +323,9 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
     nb = len(datas[0])
     n_valid = int(valid.sum())
 
+    # a partitioned build keeps the plain search: its partitions share
+    # one signature, and a bound of its own each would end that
+    partitioned = packs is not None or force_sorted
     if packs is not None:
         total_span = 1.0
         for _mn, span in packs:
@@ -326,6 +415,14 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
                          dtype=np.int64)])
         idx.rows = _pad_rows(order[:n_valid])
         idx.starts = None
+        front = (None if partitioned
+                 else _bucket_prefix(sk, span_total, pad_len, row_dt))
+        if front is not None:
+            idx.shift, idx.steps, idx.prefix = front
+            # one bucket's keys differ in their low `shift` bits only; the
+            # pad tail's sentinels lie past every bucket
+            idx.low_keys = (idx.sorted_keys
+                            & ((1 << idx.shift) - 1)).astype(np.uint32)
         idx.unique = bool(n_valid <= 1 or not np.any(sk[1:] == sk[:-1]))
         n_distinct = (1 + int(np.count_nonzero(sk[1:] != sk[:-1]))
                       if n_valid else 1)
