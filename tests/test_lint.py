@@ -640,12 +640,41 @@ def _stream_block(col_arrays, lo, hi, batch_rows):
         return {}
 
 def _fetch(make_tree):
-    with tracing.span("fetch.d2h"):
-        return make_tree()
+    with tracing.span("fetch.d2h") as sp:
+        if sp is None:
+            return make_tree()
+        with tracing.span("device.wait"):
+            tree = make_tree()
+        with tracing.span("fetch.copy", arrays=1, bytes=8):
+            return tree
 
 def _assemble_agg(plan):
     with tracing.span("host.assemble", rows=1):
         return 1
+"""
+
+#: the wait and the copy back under one name again: two of three missing
+SPAN_CHOKE_FETCH_WHOLE = SPAN_CHOKE_OK.replace(
+    'with tracing.span("device.wait"):', 'if True:').replace(
+    'with tracing.span("fetch.copy", arrays=1, bytes=8):', 'if True:')
+
+JOIN_INDEX_OK = """
+from ..session import tracing
+
+def build_join_index(columns, mask_fn=None):
+    with tracing.span("join.index_build") as sp:
+        return _build_index(columns, mask_fn)
+"""
+
+JOIN_INDEX_BAD = """
+from ..session import tracing
+
+def build_join_index(columns, mask_fn=None):
+    return _build_index(columns, mask_fn)
+
+def _build_index(columns, mask_fn):
+    with tracing.span("join.index_build"):   # a hit would open it too
+        return None
 """
 
 SPAN_CHOKE_BAD = """
@@ -701,8 +730,24 @@ class TestSpanChokepoints:
         out = run_one("span-chokepoints",
                       {"executor/device_exec.py": SPAN_CHOKE_BAD})
         assert sorted(f.ident for f in out) == [
-            "span@_assemble_agg:host.assemble", "span@_fetch:fetch.d2h",
+            "span@_assemble_agg:host.assemble", "span@_fetch:device.wait",
+            "span@_fetch:fetch.copy", "span@_fetch:fetch.d2h",
             "span@_stream_block:upload.h2d", "span@device_agg:upload.h2d"]
+
+    def test_every_span_of_a_function_is_wanted(self):
+        out = run_one("span-chokepoints",
+                      {"executor/device_exec.py": SPAN_CHOKE_FETCH_WHOLE})
+        assert sorted(f.ident for f in out) == [
+            "span@_fetch:device.wait", "span@_fetch:fetch.copy"]
+
+    @pytest.mark.parametrize("text,want", [
+        (JOIN_INDEX_OK, []),
+        (JOIN_INDEX_BAD, ["span@build_join_index:join.index_build"]),
+    ])
+    def test_join_index_build_opens_its_span(self, text, want):
+        out = run_one("span-chokepoints",
+                      {"executor/join_index.py": text})
+        assert [f.ident for f in out] == want
 
     def test_tree_without_the_layer_is_skipped(self):
         assert run_one("span-chokepoints",

@@ -326,7 +326,16 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # fragments a PINNED device engine (tpu / tpu-mpp) left
                # to the host executors because they are outside the
                # device language — note_unsupported
-               "unsupported": 0}
+               "unsupported": 0,
+               # host join indexes built in numpy (a miss of the one
+               # index a key column caches: join_index.build_join_index,
+               # under the span join.index_build) — note_join_index_build
+               "join_index_builds": 0,
+               # programs a fragment ran AGAIN at another capacity
+               # because the run before it overflowed (or was far too
+               # wide): every turn of a fragment's capacity loop after a
+               # program has run — note_rerun
+               "capacity_reruns": 0}
 _PIPE_LOCK = _threading.Lock()
 _PIPE_TLS = _threading.local()
 
@@ -439,6 +448,29 @@ def note_semi_inset():
     ``subquery.materialize``.  Once per fragment, whatever its capacity
     retries (the walk runs before them)."""
     _bump("semi_insets")
+
+
+def note_join_index_build():
+    """Count one host join index built in numpy: a miss of the one index
+    a key column caches (join_index.build_join_index, which runs the
+    build under the span ``join.index_build``).  The benchmark's
+    ``join.index_builds_per_query`` reads the counter."""
+    _bump("join_index_builds")
+
+
+def note_rerun(shape, capacity, groups, **tags):
+    """A fragment's program has RUN and its loop goes round again at
+    another capacity (an aggregate that found more groups than it had
+    slots for, a join that expanded past its output, a learned capacity
+    that was far too wide): count it, and mark the statement's trace
+    with ``fragment.rerun`` (`capacity` = what the next program gets,
+    `groups` = what this one counted).  A turn that follows a deferred
+    compile or a transport fault is not one.  The benchmark's
+    ``fragment.reruns_per_query`` reads the counter."""
+    _bump("capacity_reruns")
+    from ..session import tracing
+    tracing.event("fragment.rerun", shape=shape, capacity=int(capacity),
+                  groups=int(groups), **tags)
 
 
 def note_unsupported(ctx, reason) -> "str | None":
@@ -828,6 +860,7 @@ def device_agg(plan, chunk: Chunk, conds, ctx=None) -> Chunk:
         if ng <= capacity:
             break
         capacity = dev.next_pow2(ng)
+        note_rerun("agg", capacity, ng)
     if ng == 0 and not plan.group_exprs:
         # global aggregate over zero kept rows still yields ONE row
         # (count=0, sum/min/max NULL) — host path has the special case
@@ -881,13 +914,22 @@ def _fetch(make_tree):
     """``jax.device_get(make_tree())`` under a ``fetch.d2h`` span: the
     slices `make_tree` dispatches (each a device program of its own), the
     wait for the device to finish what they depend on, and the copy
-    back."""
+    back.  Under a live span the last two are told apart: ``device.wait``
+    blocks until the tree is ready (the programs' own run time),
+    ``fetch.copy`` is the ``device_get`` of arrays that are (tags
+    `arrays`, `bytes`).  Without one it is the one call."""
     from ..session import tracing
     with tracing.span("fetch.d2h") as sp:
-        out = jax.device_get(make_tree())
-        if sp is not None:
-            sp.tags["bytes"] = sum(
-                a.nbytes for a in jax.tree_util.tree_leaves(out))
+        if sp is None:
+            return jax.device_get(make_tree())
+        tree = make_tree()
+        with tracing.span("device.wait"):
+            jax.block_until_ready(tree)
+        leaves = jax.tree_util.tree_leaves(tree)
+        nbytes = sum(a.nbytes for a in leaves)
+        with tracing.span("fetch.copy", arrays=len(leaves), bytes=nbytes):
+            out = jax.device_get(tree)
+        sp.tags["bytes"] = nbytes
     return out
 
 
@@ -1402,6 +1444,7 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
                 buffered = []
         if overflow or max_ng > capacity:
             capacity = dev.next_pow2(max_ng)
+            note_rerun("agg.stream", capacity, max_ng)
             continue
         break
     else:
@@ -1477,6 +1520,7 @@ def _stream_count_distinct(plan, conds, chunk, col_arrays, dcols, cond_fns,
         if max(counts) <= capacity:
             break
         capacity = dev.next_pow2(max(counts))
+        note_rerun("agg.stream", capacity, max(counts))
     else:
         raise DeviceUnsupported("distinct pair capacity did not converge")
 
@@ -1508,6 +1552,7 @@ def _stream_count_distinct(plan, conds, chunk, col_arrays, dcols, cond_fns,
         if ng <= final_cap:
             break
         final_cap = dev.next_pow2(ng)
+        note_rerun("agg.stream", final_cap, ng)
     if ng == 0 and not plan.group_exprs:
         raise DeviceUnsupported("empty global aggregate")
     return _assemble_agg(plan, key_meta, slots, dcols,
@@ -1541,6 +1586,7 @@ def merge_partial_states(state, parts, merge_cap, n_keys, nvals, merge_ops,
         if ng <= merge_cap:
             return out, merge_cap
         merge_cap = dev.next_pow2(ng)
+        note_rerun("merge", merge_cap, ng)
 
 
 def page_singleton_state(key_cols, key_nulls, val_cols, val_nulls, mask,

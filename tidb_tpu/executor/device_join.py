@@ -60,7 +60,8 @@ from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
     _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm,
-    note_join_gathers, note_join_layouts, note_join_probe, note_semi_inset)
+    note_join_gathers, note_join_layouts, note_join_probe, note_rerun,
+    note_semi_inset)
 from .join_index import build_join_index
 
 
@@ -1252,10 +1253,6 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         est = _estimate_groups(agg_plan, n_frag, ctx)
         capacity = dev.next_pow2(min(n_frag, max(est, 16)))
 
-    import os as _os
-    import sys as _sys
-    import time as _time
-    _dbg = _os.environ.get("TIDB_TPU_DEBUG_JOIN")
     note_agg_arm(key_pack, agg_ops, gathered=True)
     note_join_layouts(jn.strategy for jn in joins)
     note_join_probe(resident=True)
@@ -1263,7 +1260,6 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         caps = [jn.cap for jn in joins]
         key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
                nonnull)
-        t0 = _time.perf_counter()
 
         def build(caps=tuple(caps), cap=capacity):
             # the leaves/joins/plan objects are OWNED by this execution;
@@ -1284,12 +1280,6 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         overflows, span_ovfs, kept = f.extras
         kept = int(kept)
         ng = f.ng
-        if _dbg:
-            print(f"[device_join] attempt {_attempt}: caps={caps} "
-                  f"agg_cap={capacity} kept={kept} "
-                  f"totals={[int(o) for o in overflows]} "
-                  f"{_time.perf_counter() - t0:.2f}s",
-                  file=_sys.stderr, flush=True)
         if any(bool(s) for s in span_ovfs):
             raise DeviceUnsupported(
                 "multi-key join value ranges exceed int64 packing")
@@ -1324,6 +1314,9 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         _cap_store_put((sig, "agg"), ng)
         if retry:
             _fill_caps(root, sig)
+            note_rerun("join", capacity, ng,
+                       caps=[int(jn.cap) for jn in joins], kept=kept,
+                       totals=[int(o) for o in overflows])
             continue
         break
     else:
@@ -1540,14 +1533,14 @@ def _fragment_used_cols(leaves, joins, agg_plan, agg_conds):
 
 
 class _PagedStats(threading.local):
-    """Stage timing of the thread's most recent paged fragment run —
-    EXPLAIN ANALYZE surfaces it on the HashAgg line (reference: executor
-    runtime stats, util/execdetails), so "where do the seconds go" is
-    answerable without a profiler: slice_s = host page slicing + transfer
-    enqueue, sync_s = device compute drained at merge barriers, merge_s =
-    partial-state folds, fetch_s = final TopN-candidate fetch + host
-    assembly. Thread-local: concurrent sessions each annotate their own
-    run, never a neighbor's."""
+    """Facts of the thread's most recent paged fragment run — EXPLAIN
+    ANALYZE surfaces them on the HashAgg line (reference: executor
+    runtime stats, util/execdetails): `pages` of the last pass,
+    the partial `capacity` it ran at, the `groups` it merged to (the
+    hybrid join adds its ``hj_*`` keys).  Where the seconds go is the
+    spans' to say (``upload.h2d`` / ``fetch.d2h`` / ``host.assemble``,
+    ``TRACE <stmt>``).  Thread-local: concurrent sessions each annotate
+    their own run, never a neighbor's."""
 
     def __init__(self):
         self.stats = {}
@@ -1702,9 +1695,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
         buffered = []
         max_ng = 0
         overflow = False
-        import time as _time
-        stats = {"pages": 0, "slice_s": 0.0, "dispatch_s": 0.0,
-                 "sync_s": 0.0, "merge_s": 0.0, "capacity": capacity}
+        pages = 0
         if resident:
             # popped as dispatched: a page lives until its program has
             # read it
@@ -1712,41 +1703,28 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
                 probe_dev, rows=page_rows, pages=-(-n // page_rows)))
         for lo in range(0, n, page_rows):
             hi = min(lo + page_rows, n)
-            t0 = _time.perf_counter()
             env = {**env_dim, **(
                 cut_pages.popleft() if resident
                 else _stream_block(probe_host, lo, hi, page_rows))}
-            t1 = _time.perf_counter()
             agg_out, _ovf, _sovf, _kept = fn(env, jidx, page_lives(hi, lo))
             if _attempt == 0 and lo == 0:
                 note_join_gathers(fn)
-            t2 = _time.perf_counter()
-            stats["pages"] += 1
-            stats["slice_s"] += t1 - t0
-            stats["dispatch_s"] += t2 - t1
+            pages += 1
             buffered.append(agg_out)
             if len(buffered) >= k_flush:
-                t3 = _time.perf_counter()
                 ngs = [int(g) for g in
                        _fetch(lambda: [p[4] for p in buffered])]
-                stats["sync_s"] += _time.perf_counter() - t3
                 max_ng = max(max_ng, *ngs)
                 if max_ng > capacity:
                     overflow = True
                     break
-                t4 = _time.perf_counter()
                 state, merge_cap = merge_flush(state, buffered, merge_cap)
-                stats["merge_s"] += _time.perf_counter() - t4
                 buffered = []
         if not overflow and buffered:
-            t3 = _time.perf_counter()
             ngs = [int(g) for g in _fetch(lambda: [p[4] for p in buffered])]
-            stats["sync_s"] += _time.perf_counter() - t3
             max_ng = max(max_ng, *ngs)
             if max_ng <= capacity:
-                t4 = _time.perf_counter()
                 state, merge_cap = merge_flush(state, buffered, merge_cap)
-                stats["merge_s"] += _time.perf_counter() - t4
                 buffered = []
         if overflow or max_ng > capacity:
             # a page's group count exceeded the partial capacity: restart
@@ -1754,6 +1732,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
             # restart happens once per fragment ever)
             capacity = dev.next_pow2(max_ng)
             _cap_store_put((sig, "agg"), max_ng)
+            note_rerun("join.paged", capacity, max_ng, pages=pages)
             continue
         _cap_store_put((sig, "agg"), max(max_ng, 1))
         break
@@ -1761,7 +1740,6 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
         raise DeviceUnsupported("paged fragment capacity did not converge")
     if state is None:
         raise DeviceUnsupported("empty paged fragment input")
-    t5 = _time.perf_counter()
     f = AggFetch(state, topn=resolve_topn(agg_plan, slots))
     ng = f.ng
     _cap_store_put((sig, "groups"), ng)
@@ -1769,12 +1747,9 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
         raise DeviceUnsupported("empty global aggregate")
     body = f.body()
     out = _assemble_agg(agg_plan, key_meta, slots, dcols, body, f.out_rows)
-    stats["fetch_s"] = _time.perf_counter() - t5
-    stats["groups"] = ng
     LAST_PAGED_STATS.clear()
-    LAST_PAGED_STATS.update(
-        {k: (round(v, 2) if isinstance(v, float) else v)
-         for k, v in stats.items()})
+    LAST_PAGED_STATS.update({"pages": pages, "capacity": capacity,
+                             "groups": ng})
     return out
 
 

@@ -204,6 +204,12 @@ class JoinIndex:
         return (self.sorted_keys if self.prefix is None else self.low_keys,
                 self.rows)
 
+    def host_bytes(self) -> int:
+        """Bytes of the arrays `device_arrays` places: what the next
+        ``upload.h2d`` sends of this index."""
+        return sum(a.nbytes for a in (*self.host_arrays(), self.prefix)
+                   if a is not None)
+
 
 def _resident(owner, a0, a1):
     """`owner`'s (a0, a1) on the device, through the residency ledger."""
@@ -309,8 +315,30 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
                  force_sorted, pad_rows)
     cached = getattr(host, "_join_index", None)
     if cached is not None and cached[0] == cache_key:
+        # a hit says nothing: the plan walk may ask for one index more
+        # than once a fragment, and a count of hits would measure the walk
         return cached[1]
+    from ..session import tracing
+    from .device_exec import note_join_index_build
+    note_join_index_build()
+    with tracing.span("join.index_build") as sp:
+        idx, nb, n_valid = _build_index(columns, mask_fn, packs,
+                                        force_sorted, pad_rows)
+        # the negative entry must pin the columns too — id() keys are
+        # only sound while the referenced objects stay alive
+        host._join_index = (cache_key, idx, tuple(columns))
+        if sp is not None:
+            sp.tags.update(
+                layout="none" if idx is None else idx.kind, rows=nb,
+                kept=n_valid, bytes=0 if idx is None else idx.host_bytes(),
+                filtered=mask_fn is not None,
+                prefix=idx is not None and idx.prefix is not None)
+    return idx
 
+
+def _build_index(columns, mask_fn, packs, force_sorted, pad_rows):
+    """`build_join_index`'s miss: (the index or None, the build side's
+    rows, the rows its keys' NULLs and `mask_fn` keep), all in numpy."""
     datas = [c.data for c in columns]
     nulls = columns[0].nulls
     for c in columns[1:]:
@@ -348,10 +376,7 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
             total_span *= span
             packs.append((mn, span))
     if total_span > 2.0**62:
-        # the negative entry must pin the columns too — id() keys are
-        # only sound while the referenced objects stay alive
-        host._join_index = (cache_key, None, tuple(columns))
-        return None
+        return None, nb, n_valid
 
     idx = JoinIndex()
     idx.filtered = mask_fn is not None
@@ -434,5 +459,4 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
             idx.max_cnt = int(np.diff(bounds).max())
         else:
             idx.max_cnt = 0
-    host._join_index = (cache_key, idx, tuple(columns))
-    return idx
+    return idx, nb, n_valid

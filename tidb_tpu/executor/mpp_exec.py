@@ -71,7 +71,8 @@ from ..ops.device import DeviceUnsupported
 from ..parallel.mpp import RADIX_SUB, _mix64, _radix_bucket
 from .device_exec import (
     _assemble_agg, _estimate_groups, _plan_agg, acquire_pipeline,
-    engine_mode, note_agg_arm, note_join_gathers, note_join_layouts)
+    engine_mode, note_agg_arm, note_join_gathers, note_join_layouts,
+    note_rerun)
 from .device_join import (
     _CAP_STORE, _JoinNode, _Leaf, _cap_store_put, _combined_join_keys,
     _dim_resident_budget, _fragment_used_cols, _join_expand, _leaf_index,
@@ -1059,6 +1060,7 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
         if not retry:
             break
         MPP_STATS["retries"] += 1
+        note_rerun("mpp", capacity, max_ng, caps=[int(c) for c in caps])
         try:
             bo.backoff("exchangeGrow")
         except BackoffExhaustedError as e:

@@ -45,18 +45,21 @@ AUDITED = {
 #: leaf-name is one of these
 DEGRADE_EXCEPTIONS = ("DeviceUnsupported",)
 
-#: rel-path -> {function: span it must open}: the host<->device
+#: rel-path -> {function: the spans it must open}: the host<->device
 #: boundaries the benchmark reads by span name (``upload.h2d_ms``,
-#: ``fetch.d2h_ms``, ``assemble.host_ms`` and the ``idle.*`` owners).  A
-#: refactor that moves the work out from under its span would leave the
-#: metric reading an empty span, not failing.
+#: ``fetch.d2h_ms`` and its two parts ``fetch.device_wait_ms`` /
+#: ``fetch.copy_ms``, ``assemble.host_ms``, ``join.index_build_ms`` and
+#: the ``idle.*`` owners).  A refactor that moves the work out from under
+#: its span would leave the metric reading an empty span, not failing.
 SPAN_CHOKEPOINTS = {
-    "executor/device_exec.py": {"device_agg": "upload.h2d",
-                                "_stream_block": "upload.h2d",
-                                "_fetch": "fetch.d2h",
-                                "_assemble_agg": "host.assemble"},
-    "executor/device_join.py": {"device_join_agg": "upload.h2d"},
-    "executor/mpp_exec.py": {"_run_mpp_impl": "upload.h2d"},
+    "executor/device_exec.py": {"device_agg": ("upload.h2d",),
+                                "_stream_block": ("upload.h2d",),
+                                "_fetch": ("fetch.d2h", "device.wait",
+                                           "fetch.copy"),
+                                "_assemble_agg": ("host.assemble",)},
+    "executor/device_join.py": {"device_join_agg": ("upload.h2d",)},
+    "executor/join_index.py": {"build_join_index": ("join.index_build",)},
+    "executor/mpp_exec.py": {"_run_mpp_impl": ("upload.h2d",)},
 }
 
 #: where the kernel vocabulary is defined, and under which name
@@ -215,8 +218,8 @@ class CodecRpcTrace(Rule):
 
 @register
 class SpanChokepoints(Rule):
-    """The functions in SPAN_CHOKEPOINTS each open their span by its
-    literal name (``tracing.span("upload.h2d")``)."""
+    """The functions in SPAN_CHOKEPOINTS each open every one of their
+    spans by its literal name (``tracing.span("upload.h2d")``)."""
 
     name = "span-chokepoints"
     title = "host<->device boundaries open the span the benchmark reads"
@@ -232,19 +235,19 @@ class SpanChokepoints(Rule):
                 if isinstance(top, (ast.FunctionDef, ast.AsyncFunctionDef)) \
                         and top.name in wanted:
                     found[top.name] = top
-            for fn, span in sorted(wanted.items()):
+            for fn, spans in sorted(wanted.items()):
                 top = found.get(fn)
-                opened = top is not None and any(
-                    _is_trace_call(n, ("span",)) and n.args
-                    and const_str(n.args[0]) == span
-                    for n in ast.walk(top))
-                if not opened:
-                    out.append(self.finding(
-                        rel, top.lineno if top is not None else 1,
-                        f"span@{fn}:{span}",
-                        f"{fn} must open tracing.span({span!r}): the "
-                        "benchmark's per-layer metrics read that boundary "
-                        "by the span's name"))
+                opened = set() if top is None else {
+                    const_str(n.args[0]) for n in ast.walk(top)
+                    if _is_trace_call(n, ("span",)) and n.args}
+                for span in spans:
+                    if span not in opened:
+                        out.append(self.finding(
+                            rel, top.lineno if top is not None else 1,
+                            f"span@{fn}:{span}",
+                            f"{fn} must open tracing.span({span!r}): the "
+                            "benchmark's per-layer metrics read that "
+                            "boundary by the span's name"))
         return out
 
 
