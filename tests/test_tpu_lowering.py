@@ -113,6 +113,74 @@ def test_join_aggregate_without_compaction(tpch, monkeypatch):
     assert all(n >= n_fact for n, _g in lengths), (lengths, n_fact)
 
 
+# -- the sort arm's group starts: one sort or a search a slot (ISSUE 36) ------
+
+_ORDER_SUMS = ("select l_orderkey, sum(l_quantity) from lineitem "
+               "group by l_orderkey having sum(l_quantity) > 250 "
+               "order by l_orderkey")
+_A_GROUP_A_ROW = ("select l_orderkey, l_partkey, l_suppkey, l_shipdate, "
+                  "count(*), sum(l_quantity) from lineitem group by "
+                  "l_orderkey, l_partkey, l_suppkey, l_shipdate "
+                  "order by l_orderkey, l_partkey, l_suppkey, l_shipdate")
+
+
+def test_learned_capacity_program_finds_its_groups_without_a_loop(
+        tpch, monkeypatch):
+    """Q18's subquery shape at SF0.02 (30,000 groups over the 131,072-row
+    bucket): the program at the estimated capacity keeps the per-slot
+    search (a `while` of dependent gathers; its text is the parent's,
+    held by hash in tests/test_mpp_indexed.py), the one at the learned
+    capacity of 32,768 sorts the flagged positions once and holds no
+    `while` at all."""
+    programs = []
+    orig = dev.observed_jit
+
+    def spy(fn, **jit_kw):
+        run = orig(fn, **jit_kw)
+
+        def call(*a, **k):
+            programs.append(run.lower(*a, **k).as_text(debug_info=True))
+            return run(*a, **k)
+        return call
+    monkeypatch.setattr(dev, "observed_jit", spy)
+    _drop_compiled()
+    tpch.must_exec("set tidb_result_cache = 'OFF'")
+    tpch.must_exec("set tidb_executor_engine = 'tpu'")
+    got = tpch.must_query(_ORDER_SUMS).rows
+    _drop_compiled()
+    tpch.must_exec("set tidb_executor_engine = 'host'")
+    assert got and got == tpch.must_query(_ORDER_SUMS).rows
+    first, learned = programs
+    assert "tensor<32768xi64>" in learned and "tensor<32768xi64>" not in first
+    assert "stablehlo.while" in first and "searchsorted" in first
+    assert "stablehlo.while" not in learned and "searchsorted" not in learned
+    # the one sort more is k_agg_segment's: positions as int32
+    assert "k_agg_segment/jit(sort)" in learned
+    assert "k_agg_segment/jit(sort)" not in first
+
+
+@pytest.mark.parametrize("shape,sql,one_pass", [
+    ("q18", bench.QUERIES["q18"], 1),          # its inner aggregate
+    ("a_group_a_row", _A_GROUP_A_ROW, 1),      # capacity = the row bucket
+    ("q5", bench.QUERIES["q5"], 0),            # 16 slots: the search
+])
+def test_one_pass_programs_answer_as_the_host_and_are_counted(
+        tpch, shape, sql, one_pass):
+    """Parity with the host engine on either side of dev.spans_one_pass,
+    and DIAG STATUS device_pipelines.agg_spans_one_pass: one per settled
+    execution of a fragment whose learned capacity sorts, none for a
+    fragment that searches."""
+    _drop_compiled()
+    assert set(_parity(tpch, sql)) == {"engine:tpu"}
+    tpch.must_exec("set tidb_executor_engine = 'tpu'")
+    before = _device_pipelines(tpch)
+    assert tpch.must_query(sql).rows
+    after = _device_pipelines(tpch)
+    tpch.must_exec("set tidb_executor_engine = 'host'")
+    assert (after["agg_spans_one_pass"]
+            - before["agg_spans_one_pass"]) == one_pass, shape
+
+
 def test_streamed_aggregate_merges_in_kernel(monkeypatch):
     """A streamed scan-aggregate with a packable key folds its partial
     states through merge_partial_states' concat + sort kernel, not the

@@ -289,6 +289,12 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # ops/device._agg_impl that dev.agg_arm named for them —
                # note_agg_arm
                "agg_dense": 0, "agg_sorted": 0,
+               # sort-arm programs dispatched whose group starts come
+               # from ONE sort at input length and not from a binary
+               # search per output slot (dev.spans_one_pass names the
+               # side from the capacity and the input length) —
+               # note_agg_spans
+               "agg_spans_one_pass": 0,
                # never bumped: benchmark/layer_metrics/agg.dense_share.py
                # reads the key
                "agg_scatter": 0,
@@ -396,6 +402,20 @@ def note_agg_arm(pack, agg_ops, gathered=False):
     EXPLAIN ANALYZE's ``agg:`` annotation and the benchmark's
     ``agg.dense_share`` read the counters."""
     _bump(AGG_ARM_STATS[dev.agg_arm(pack, tuple(agg_ops), gathered)])
+
+
+def note_agg_spans(pack, agg_ops, capacity, n, gathered=False):
+    """Count one dispatched sort-arm program whose group starts come from
+    the one-pass side of ops/device._group_spans: the side
+    dev.spans_one_pass names for the program's static `capacity` and the
+    `n` rows its aggregate reads.  Called on every turn of a fragment's
+    capacity loop (a turn's pages, blocks or shards run one program text
+    and count once); the dense arm and the searched side count nothing.
+    The benchmark's ``agg.one_pass_spans_share`` reads the counter over
+    ``agg_sorted``."""
+    if (dev.agg_arm(pack, tuple(agg_ops), gathered) == "sort"
+            and dev.spans_one_pass(capacity, n)):
+        _bump("agg_spans_one_pass")
 
 
 def note_join_layouts(strategies):
@@ -856,6 +876,7 @@ def device_agg(plan, chunk: Chunk, conds, ctx=None) -> Chunk:
                               args=(env, np.int64(n)), shape="agg",
                               sig=sig_exprs)
         f = AggFetch(fn(env, np.int64(n)), topn=resolve_topn(plan, slots))
+        note_agg_spans(key_pack, agg_ops, capacity, nb)
         ng = f.ng
         if ng <= capacity:
             break
@@ -1412,6 +1433,7 @@ def device_agg_streaming(plan, chunk: Chunk, conds, batch_rows: int,
         fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
                               spec=_stream_spec(col_arrays, batch_rows),
                               shape="agg", sig=sig_exprs, ladder=False)
+        note_agg_spans(key_pack, agg_ops, capacity, batch_rows)
         k_flush = max(1, _MERGE_BUDGET_ROWS // capacity)
         state = None
         buffered = []

@@ -59,7 +59,7 @@ from ..ops import device as dev
 from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
-    _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm,
+    _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm, note_agg_spans,
     note_join_gathers, note_join_layouts, note_join_probe, note_rerun,
     note_semi_inset)
 from .join_index import build_join_index
@@ -1272,6 +1272,7 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
                               args=(env, jidx, n_lives), shape="join",
                               sig=sig)
         agg_out, ovf_d, sovf_d, kept_d = fn(env, jidx, n_lives)
+        note_agg_spans(key_pack, agg_ops, capacity, n_frag, gathered=True)
         if _attempt == 0:
             note_join_gathers(fn)
         from .device_exec import AggFetch, resolve_topn
@@ -1313,7 +1314,7 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
             retry = True
         _cap_store_put((sig, "agg"), ng)
         if retry:
-            _fill_caps(root, sig)
+            n_frag = _fill_caps(root, sig)
             note_rerun("join", capacity, ng,
                        caps=[int(jn.cap) for jn in joins], kept=kept,
                        totals=[int(o) for o in overflows])
@@ -1690,6 +1691,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
         # (still breaker-guarded + persisted through the compile service)
         fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
                               shape="join", sig=sig)
+        note_agg_spans(key_pack, agg_ops, capacity, page_rows, gathered=True)
         k_flush = max(1, _MERGE_BUDGET_ROWS // capacity)
         state = None
         buffered = []

@@ -71,8 +71,8 @@ from ..ops.device import DeviceUnsupported
 from ..parallel.mpp import RADIX_SUB, _mix64, _radix_bucket
 from .device_exec import (
     _assemble_agg, _estimate_groups, _plan_agg, acquire_pipeline,
-    engine_mode, note_agg_arm, note_join_gathers, note_join_layouts,
-    note_rerun)
+    engine_mode, note_agg_arm, note_agg_spans, note_join_gathers,
+    note_join_layouts, note_rerun)
 from .device_join import (
     _CAP_STORE, _JoinNode, _Leaf, _cap_store_put, _combined_join_keys,
     _dim_resident_budget, _fragment_used_cols, _join_expand, _leaf_index,
@@ -1002,6 +1002,8 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
         try:
             failpoint.inject("mpp-exchange-send")
             agg_out, png_d, ovfs_d, sovfs_d, xneeds_d = fn(*args)
+            note_agg_spans(key_pack, agg_ops, capacity,
+                           caps[-1] if caps else per_shard_b, gathered=True)
             from .device_exec import AggFetch
             f = AggFetch(agg_out, extras=(png_d, ovfs_d, sovfs_d, xneeds_d))
             failpoint.inject("mpp-exchange-recv")

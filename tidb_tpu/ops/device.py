@@ -1089,25 +1089,96 @@ def _seg_running(comb_val, is_new, z):
     return run
 
 
+#: spans_one_pass's one price: what a searched slot-step (one dependent
+#: gather into the input-length group-id array) is charged, in sorted
+#: rows.  Set from a sweep on the v5e (standalone, n = 8,388,608 flags;
+#: PERF.md section 6, PR 36): the search 1,454.4 ms at 2,097,152 slots,
+#: 189.3 at 262,144, 8.4 at 16,384 (30 ns a slot-step of its 23, 22 at
+#: the small end); the sort of int32 positions 5.7 ms at any capacity
+#: (0.7 ns a row): the two are even at a price of 45.  At 4 the sort is
+#: taken where it wins by eleven times or more: every shape that flips is
+#: a new program text (a cold compile of tens of seconds for a whole
+#: fragment), so a program within that margin (Q3's 16,384 slots: 8.4
+#: against 5.7 ms; the mesh's 2,097,152-row shard) keeps its own until a
+#: change claims it in its cell.
+_SPANS_SEARCH_PRICE = 4
+
+#: inputs shorter than this keep the search whatever their capacity: the
+#: sort saves 8.7 ms at 131,072 rows and 32,768 slots (9.6 against 0.9)
+#: and 4.1 ms at 65,536 and 16,384 (5.0 against 0.9; same sweep), and the
+#: folds of partial states (device_exec.merge_partial_states, the mesh's
+#: _merge_partials: pages x capacity rows at the same capacity) keep the
+#: program texts they had.
+_SPANS_ONE_PASS_MIN_ROWS = 1 << 17
+
+
+def spans_one_pass(capacity, n) -> bool:
+    """Which side of _group_spans turns the boundary flags of an `n`-row
+    sort-arm aggregate into its `capacity` group starts: True = one sort
+    at input length, False = a binary search per output slot.
+    Host-callable (the dispatchers count programs by it:
+    device_exec.note_agg_spans) and what _group_spans itself asks at
+    trace time; it reads its two arguments only, both static shapes, so
+    every backend traces the program the chip runs.
+
+    The search costs capacity x ceil(log2 n) gathered rows, the sort n
+    sorted rows; the sort is taken from _SPANS_ONE_PASS_MIN_ROWS rows up
+    where a gathered row at _SPANS_SEARCH_PRICE sorted ones makes the
+    search the dearer: TPC-H Q3's 16,384 slots over 8,388,608 rows
+    search, Q18's 2,097,152 slots over the same rows sort."""
+    n = int(n)
+    steps = max(n - 1, 1).bit_length()          # ceil(log2 n)
+    return (n >= _SPANS_ONE_PASS_MIN_ROWS
+            and int(capacity) * steps * _SPANS_SEARCH_PRICE >= n)
+
+
 def _group_spans(is_new, kept, n, capacity):
     """Group boundary arithmetic shared by the single-chip kernel and the
-    MPP partial/final stages: starts from a top-k selection, end_g = next
-    start (or kept for the last group). Returns (starts, ends, end_idx,
-    span_sum) where span_sum(z) = per-group sums of z via exclusive prefix
-    sums (exact for ints — two's-complement differences cancel; float sums
-    must use _seg_running instead to keep rounding error group-local).
+    MPP partial/final stages: starts[g] = the position of the g-th set
+    flag of `is_new` (n past the last group), end_g = next start (or kept
+    for the last group). Returns (starts, ends, end_idx, span_sum) where
+    span_sum(z) = per-group sums of z via exclusive prefix sums (exact
+    for ints — two's-complement differences cancel; float sums must use
+    _seg_running instead to keep rounding error group-local).
 
-    Boundary positions come from a searchsorted over the running group id
-    (cumsum of is_new), NOT jnp.nonzero(size=...) nor top_k: nonzero
-    lowers to a serialized path on TPU (~500ms at 6M rows), and top_k is a
-    partial sort (measured 188ms at 600k/262k-capacity on the CPU backend
-    vs 43ms for the two binary searches). gid is non-decreasing by
-    construction, so `starts[g] = first row with gid ≥ g` is exact, and
-    rows past the last group (g ≥ n_groups) return n — the same fill
-    nonzero's fill_value produced."""
-    gid = jnp.cumsum(is_new) - 1
-    starts = jnp.searchsorted(gid, jnp.arange(capacity), side="left"
-                              ).astype(jnp.int64)
+    Two ways to the same `starts`, chosen by spans_one_pass(capacity, n)
+    from the static shapes alone:
+
+    - one pass: the first `capacity` entries of the ascending sort of
+      where(is_new, position, n), positions as int32 while n fits (a
+      capacity above n pads with n). One single-operand sort at input
+      length, no group id, no per-slot search.
+    - search: searchsorted over the running group id (cumsum of is_new),
+      one binary search per output slot: ceil(log2 n) DEPENDENT gathers
+      into an int64 array of n rows each. gid is non-decreasing by
+      construction, so `starts[g] = first row with gid ≥ g` is exact, and
+      slots past the last group return n.
+
+    On the v5e at n = 8,388,608 and 2,097,152 slots (PERF.md section 6,
+    PR 36) the search is 1,454 ms (in TPC-H Q18's inner aggregate: two
+    fusions of its `while` body, the `u32` halves of the int64 group id,
+    1,031 + 433 ms of a 1,868 ms program) and the sort 5.7 ms (13.7 ms
+    as a stable sort, which carries a second operand). Neither a scatter
+    nor jnp.nonzero(size=...) comes near: scattering the flagged
+    positions to their group id is 41.7 ms at any capacity,
+    `unique_indices` / `indices_are_sorted` or not, a scatter-min over
+    the sorted group id 76.2 ms, nonzero 573-577 ms."""
+    if spans_one_pass(capacity, n):
+        pos_dt = jnp.int32 if n < (1 << 31) else jnp.int64
+        # not stable: equal entries are all the fill n, and a stable sort
+        # carries a second operand (13.7 ms against 5.7, 15-20 s of
+        # compile against 4-6)
+        flagged = jnp.sort(jnp.where(is_new, jnp.arange(n, dtype=pos_dt),
+                                     jnp.asarray(n, dtype=pos_dt)),
+                           stable=False)
+        starts = flagged[:capacity].astype(jnp.int64)
+        if capacity > n:
+            starts = jnp.concatenate(
+                [starts, jnp.full(capacity - n, n, dtype=jnp.int64)])
+    else:
+        gid = jnp.cumsum(is_new) - 1
+        starts = jnp.searchsorted(gid, jnp.arange(capacity), side="left"
+                                  ).astype(jnp.int64)
     ends = jnp.minimum(jnp.concatenate(
         [starts[1:], jnp.full(1, n, dtype=starts.dtype)]), kept)
     end_idx = jnp.clip(ends - 1, 0, jnp.maximum(n - 1, 0))
