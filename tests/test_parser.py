@@ -293,3 +293,48 @@ def test_tpch_queries_parse(q):
     # restore must itself re-parse to the same restored text (fixpoint)
     r1 = s.restore()
     assert parse_one(r1).restore() == r1
+
+
+# -- a derived table's alias with a column list (ISSUE 37) --------------------
+
+@pytest.mark.parametrize("sql", [
+    "select a from (select 1, 2) as t (a, b)",
+    "select a from (select 1, 2) t (a, b)",
+    "select a from (select 1, 2) AS `t` (`a`, `b`)",
+])
+def test_derived_table_column_list(sql):
+    s = parse_one(sql)
+    assert isinstance(s.from_, ast.SubqueryTable)
+    assert s.from_.as_name == "t" and s.from_.col_names == ["a", "b"]
+
+
+def test_derived_table_column_list_round_trips():
+    s = parse_one("select c_count, count(*) from (select k, count(v) from u "
+                  "group by k) as c_orders (c_custkey, c_count) "
+                  "group by c_count")
+    text = s.restore()
+    assert "AS `c_orders` (`c_custkey`, `c_count`)" in text
+    again = parse_one(text)
+    assert again.from_.col_names == ["c_custkey", "c_count"]
+    assert again.restore() == text
+    # without a list, restore() prints what it printed before
+    bare = parse_one("select a from (select 1 as a) as t")
+    assert bare.from_.col_names == [] and bare.restore().endswith("AS `t`")
+
+
+def test_derived_table_column_list_inside_a_join():
+    s = parse_one("select * from t1 join (select 1, 2) d (x, y) on t1.a = d.x "
+                  "left join (select 3) as e (z) on e.z = d.y")
+    assert s.from_.right.col_names == ["z"]
+    assert s.from_.left.right.col_names == ["x", "y"]
+
+
+@pytest.mark.parametrize("sql", [
+    "select a from (select 1) as t ()",
+    "select a from (select 1) as t (a,)",
+    "select a from (select 1) as t (a b)",
+    "select a from (select 1) (a)",      # a column list needs an alias
+])
+def test_derived_table_column_list_errors(sql):
+    with pytest.raises(ParseError):
+        parse(sql)

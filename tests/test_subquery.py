@@ -78,3 +78,53 @@ def test_uncorrelated_still_works(tk):
         "select a from t1 where exists (select * from t2 where b > 2.9) "
         "order by a")
     r.check([("1",), ("2",), ("3",)])
+
+
+# -- a derived table named with a column list (ISSUE 37) ----------------------
+
+def test_derived_table_column_list_names_the_columns(tk):
+    tk.must_query(
+        "select k2, n from (select k, count(a) from t1 group by k) "
+        "as d (k2, n) order by k2").check([("1", "2"), ("2", "1")])
+    # without AS, and the names reach the result set's header
+    r = tk.must_query("select * from (select a, k from t1) d (x, y) "
+                      "where x = 3")
+    r.check([("3", "2")])
+    assert list(r.result.names) == ["x", "y"]
+
+
+def test_derived_table_column_list_shadows_select_aliases(tk):
+    # the list wins over the select list's own aliases: `n` is gone
+    tk.must_query("select m from (select a as n from t1) as d (m) "
+                  "order by m").check([("1",), ("2",), ("3",)])
+    e = tk.exec_error("select n from (select a as n from t1) as d (m)")
+    assert e.code == 1054 and "n" in str(e)
+    tk.must_query("select d.m from (select a as n from t1) as d (m) "
+                  "where d.m = 2").check([("2",)])
+
+
+def test_derived_table_column_list_arity(tk):
+    for sql in ("select * from (select a, k from t1) as d (x)",
+                "select * from (select a from t1) as d (x, y)"):
+        e = tk.exec_error(sql)
+        assert e.code == 1353, sql
+        assert "different column counts" in str(e)
+    # the CTE form of the same refusal carries the same code now
+    assert tk.exec_error(
+        "with c (x, y) as (select 1) select x from c").code == 1353
+
+
+@pytest.mark.parametrize("engine", ["host", "tpu"])
+def test_derived_table_column_list_inside_a_join(tk, engine):
+    tk.must_exec(f"set tidb_executor_engine = '{engine}'")
+    try:
+        tk.must_query(
+            "select t1.a, d.n from t1 join (select k, count(*) from t2 "
+            "group by k) as d (kk, n) on t1.k = d.kk order by t1.a"
+        ).check([("1", "2"), ("2", "2"), ("3", "1")])
+        tk.must_query(
+            "select n, count(*) from (select t1.k, count(b) from t1 "
+            "left join t2 on t1.a = t2.k group by t1.k) as d (kk, n) "
+            "group by n order by n").check([("0", "1"), ("3", "1")])
+    finally:
+        tk.must_exec("set tidb_executor_engine = 'auto'")

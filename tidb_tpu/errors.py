@@ -101,6 +101,7 @@ class ErrCode:
     WrongObject = 1347
     ViewRecursive = 1462
     ViewInvalid = 1356
+    ViewWrongList = 1353
     NonInsertableTable = 1471
     NonUpdatableTable = 1288
     DupFieldName = 1060

@@ -306,6 +306,19 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # note_join_layouts
                "join_direct": 0, "join_search": 0,
                "join_search_prefixed": 0,
+               # the same joins by kind (an inner join counts under
+               # none), and those whose OUTPUT is CSR-expanded: an inner
+               # or left join over a non-unique build (a semi / anti
+               # existence count over one is probe-shaped and is not) —
+               # note_join_layouts
+               "join_left": 0, "join_semi": 0, "join_anti": 0,
+               "join_expand": 0,
+               # per expanded join of a dispatched join fragment: the
+               # rows its expansion emitted (the `total` the program
+               # counts beside the overflow check) and the static
+               # capacity the program ran at; rows over capacity is how
+               # full the learned capacity is — note_join_expansion
+               "join_expand_rows": 0, "join_expand_capacity": 0,
                # column / mask / row-map gathers of dispatched join
                # fragments' programs, and those the program holds the
                # result of already (a leaf read in place, a NULL-free
@@ -374,6 +387,8 @@ def _tls_stats() -> dict:
                                 "agg_dense": 0, "agg_sorted": 0,
                                 "join_direct": 0, "join_search": 0,
                                 "join_search_prefixed": 0,
+                                "join_left": 0, "join_semi": 0,
+                                "join_anti": 0, "join_expand": 0,
                                 "join_gathers": 0,
                                 "join_gathers_elided": 0,
                                 "join_probe_resident": 0,
@@ -418,22 +433,50 @@ def note_agg_spans(pack, agg_ops, capacity, n, gathered=False):
         _bump("agg_spans_one_pass")
 
 
-def note_join_layouts(strategies):
+def join_expands(jn) -> bool:
+    """Does this host-indexed join (a device_join._JoinNode with its
+    strategy planned) emit a CSR-expanded output?  An inner or left join
+    over a non-unique build does; a unique build is one gather at the
+    probe's capacity, a semi / anti join an existence count."""
+    return (jn.kind in ("inner", "left") and jn.strategy is not None
+            and jn.strategy[2] is not None and jn.strategy[0] != "uniq")
+
+
+def note_join_layouts(joins):
     """Count the host-indexed joins of one dispatched join fragment (its
-    strategy snapshot: ``_JoinNode.strategy`` per join) by the layout of
+    ``_JoinNode``s with their strategies planned) by the layout of
     their index; EXPLAIN ANALYZE's ``join:`` annotation and the
     benchmark's ``join.direct_share`` read the counters.  A searched
     join whose index carries a prefix table (join_index._bucket_prefix)
     counts under ``join_search_prefixed`` too
-    (``join.prefixed_search_share``).  Joins built inside the program (no
-    index) count under none; a mesh fragment on the indexed path
-    (mpp_exec._indexed_chain) counts as one chip's."""
-    for st in strategies:
-        if st is not None and st[2] is not None:
-            _bump("join_direct" if st[2].kind == "dense"
-                  else "join_search")
-            if st[2].prefix is not None:
-                _bump("join_search_prefixed")
+    (``join.prefixed_search_share``).  The same joins count by kind
+    (``join_left`` / ``join_semi`` / ``join_anti``; an inner join under
+    none) and, where their output is CSR-expanded, under
+    ``join_expand`` (``join.non_inner_share``, ``join.expanded_share``).
+    Joins built inside the program (no index) count under none; a mesh
+    fragment on the indexed path (mpp_exec._indexed_chain) counts as one
+    chip's."""
+    for jn in joins:
+        st = jn.strategy
+        if st is None or st[2] is None:
+            continue
+        _bump("join_direct" if st[2].kind == "dense" else "join_search")
+        if st[2].prefix is not None:
+            _bump("join_search_prefixed")
+        if jn.kind != "inner":
+            _bump("join_" + jn.kind)
+        if join_expands(jn):
+            _bump("join_expand")
+
+
+def note_join_expansion(rows, capacity):
+    """Count one expanded join of a dispatched join fragment: the `rows`
+    its expansion emitted and the static `capacity` of the program that
+    emitted them.  Once per fragment, for the run whose result is kept
+    (a capacity retry's totals are not counted); the benchmark's
+    ``join.expand_fill`` reads rows over capacity."""
+    _bump("join_expand_rows", int(rows))
+    _bump("join_expand_capacity", int(capacity))
 
 
 def note_join_gathers(fn):

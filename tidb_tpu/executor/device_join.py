@@ -59,9 +59,9 @@ from ..ops import device as dev
 from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
-    _plan_agg, _timed_jit, acquire_pipeline, note_agg_arm, note_agg_spans,
-    note_join_gathers, note_join_layouts, note_join_probe, note_rerun,
-    note_semi_inset)
+    _plan_agg, _timed_jit, acquire_pipeline, join_expands, note_agg_arm,
+    note_agg_spans, note_join_expansion, note_join_gathers,
+    note_join_layouts, note_join_probe, note_rerun, note_semi_inset)
 from .join_index import build_join_index
 
 
@@ -1254,7 +1254,7 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         capacity = dev.next_pow2(min(n_frag, max(est, 16)))
 
     note_agg_arm(key_pack, agg_ops, gathered=True)
-    note_join_layouts(jn.strategy for jn in joins)
+    note_join_layouts(joins)
     note_join_probe(resident=True)
     for _attempt in range(12):
         caps = [jn.cap for jn in joins]
@@ -1322,6 +1322,9 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         break
     else:
         raise DeviceUnsupported("join fragment capacities did not converge")
+    for jn, total in zip(joins, overflows):
+        if join_expands(jn):
+            note_join_expansion(total, jn.cap)
     if ng == 0 and not agg_plan.group_exprs:
         raise DeviceUnsupported("empty global aggregate")
     body = f.body()
@@ -1674,7 +1677,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
 
     for jn in joins:
         jn.cap = page_rows  # every join is a probe-shaped gather
-    note_join_layouts(jn.strategy for jn in joins)
+    note_join_layouts(joins)
     note_agg_arm(key_pack, agg_ops, gathered=True)
     note_join_probe(resident)
     for _attempt in range(4):

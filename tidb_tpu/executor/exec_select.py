@@ -111,15 +111,19 @@ class QueryExecutor:
             # how the fragment's host-indexed joins look a key up
             # (device_exec.note_join_layouts): join:direct x5, or
             # join:direct x4+search x1 (prefix x1) where a searched
-            # join starts from its key's bucket
-            layouts = [(name, st1[k] - st0[k])
-                       for name, k in (("direct", "join_direct"),
-                                       ("search", "join_search"))]
-            note = "+".join(f"{name} x{n}" for name, n in layouts if n > 0)
-            prefixed = (st1["join_search_prefixed"]
-                        - st0["join_search_prefixed"])
-            if prefixed:
-                note += f" (prefix x{prefixed})"
+            # join starts from its key's bucket; the kinds other than
+            # inner and the CSR expansions beside them: join:direct x1
+            # (left x1, expand x1), join:direct x1 (semi x1)
+            def grew(names, sep):
+                found = [(name, st1["join_" + k] - st0["join_" + k])
+                         for name, k in names]
+                return sep.join(f"{name} x{n}" for name, n in found if n)
+            note = grew((("direct", "direct"), ("search", "search")), "+")
+            beside = grew((("prefix", "search_prefixed"), ("left", "left"),
+                           ("semi", "semi"), ("anti", "anti"),
+                           ("expand", "expand")), ", ")
+            if beside:
+                note += f" ({beside})"
             self.annotate(join=note or None)
             # the join fragment's column / mask / row-map gathers, and
             # (-n) those its program elides
@@ -714,7 +718,17 @@ class HashAggExec(QueryExecutor):
                 chunk = chunk.filter(eval_conds_mask(conds, chunk))
         else:
             chunk = self.children[0].execute()
-        out = self._execute_host_spillable(chunk)
+        # an aggregate over another operator's output (a derived table's:
+        # Q13's group by c_count), neither a scan nor a join fragment
+        if raw is None and not isinstance(join_child, HashJoinExec):
+            from ..session import tracing
+            with tracing.span("derived.aggregate") as sp:
+                out = self._execute_host_spillable(chunk)
+                if sp is not None:
+                    sp.tags.update(rows_in=chunk.num_rows,
+                                   groups=out.num_rows)
+        else:
+            out = self._execute_host_spillable(chunk)
         if bkey is not None:
             # the host-side dispatch row for this fragment: the same
             # (sig, bucket) key as its device dispatches, so the perf

@@ -1085,7 +1085,7 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
         # counted as device_join_agg counts its fragment: once, whatever
         # the retries above (the last program's gathers are every one's)
         MPP_STATS["indexed_fragments"] += 1
-        note_join_layouts(jn.strategy for jn in joins)
+        note_join_layouts(joins)
         note_join_gathers(fn)
     _publish_gauges(ctx)
     key_out, key_null_out, results, result_nulls = f.body()

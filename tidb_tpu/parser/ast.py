@@ -335,9 +335,14 @@ class TableName(Node):
 class SubqueryTable(Node):
     query: "SelectStmt"
     as_name: str = ""
+    #: the alias's column list, `(select ...) as t (a, b)`; a CTE's too
+    col_names: list = field(default_factory=list)
 
     def restore(self):
-        return f"({self.query.restore()}) AS `{self.as_name}`"
+        s = f"({self.query.restore()}) AS `{self.as_name}`"
+        if self.col_names:
+            s += " (" + ", ".join(f"`{c}`" for c in self.col_names) + ")"
+        return s
 
 
 @dataclass(repr=False)

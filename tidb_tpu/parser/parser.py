@@ -644,10 +644,13 @@ class Parser:
                 if t.kind in (IDENT, QIDENT) and (t.kind == QIDENT or t.val.lower() not in RESERVED_STOP):
                     as_name = t.val
                     self.pos += 1
-                if isinstance(sub, ast.SetOprStmt):
-                    st = ast.SubqueryTable(query=sub, as_name=as_name)
-                else:
-                    st = ast.SubqueryTable(query=sub, as_name=as_name)
+                st = ast.SubqueryTable(query=sub, as_name=as_name)
+                # the alias's column list: (select ...) as t (a, b)
+                if as_name and self._accept_op("("):
+                    st.col_names.append(self._ident())
+                    while self._accept_op(","):
+                        st.col_names.append(self._ident())
+                    self._expect_op(")")
                 return st
             refs = self._parse_table_refs()
             self._expect_op(")")

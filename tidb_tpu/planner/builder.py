@@ -240,10 +240,9 @@ def _subst_from(node, ctes, _copy):
                     query=body, as_name=node.as_name or node.name)
             cols, stmt = entry
             body = _copy.deepcopy(stmt)
-            sub = ast.SubqueryTable(query=body,
-                                    as_name=node.as_name or node.name)
-            sub.col_renames = list(cols)
-            return sub
+            return ast.SubqueryTable(query=body,
+                                     as_name=node.as_name or node.name,
+                                     col_names=list(cols))
         return node
     if isinstance(node, ast.Join):
         node.left = _subst_from(node.left, ctes, _copy)
@@ -379,12 +378,12 @@ class PlanBuilder:
         if isinstance(node, ast.SubqueryTable):
             sub = self.build(node.query)
             alias = node.as_name or ""
-            renames = getattr(node, "col_renames", None) or []
+            renames = node.col_names
             if renames and len(renames) != len(sub.schema.refs):
                 raise TiDBError(
-                    f"In definition of view, derived table or common table "
-                    f"expression, SELECT list and column names list have "
-                    f"different column counts")
+                    "In definition of view, derived table or common table "
+                    "expression, SELECT list and column names list have "
+                    "different column counts", code=ErrCode.ViewWrongList)
             refs = []
             for i, r in enumerate(sub.schema.refs):
                 name = renames[i] if i < len(renames) else r.name
