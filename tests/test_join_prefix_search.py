@@ -484,6 +484,7 @@ def test_the_counter_and_the_note(searched):
     assert [after[k] - before[k] for k in (
         "join_direct", "join_search", "join_search_prefixed")] == [0, 1, 0]
     # ... and, its build no longer unique, CSR-expanded (PR 37's note)
-    assert _annotations(hot, _SQL, "join:") == ["join:search x1 (expand x1)"]
+    assert _annotations(hot, _SQL, "join:") == [
+        "join:search x1 (expand x1 one-pass)"]
     assert after["join_expand"] - before["join_expand"] == 1
     _drop_compiled()

@@ -160,7 +160,8 @@ def test_q13_is_one_fragment_that_expands_and_null_extends(seed):
     assert rows <= slots == dev.next_pow2(rows)
     notes = _notes(tk, q13.SQL)
     assert [n for n in notes if n.startswith("engine:")] == ["engine:tpu"]
-    assert "join:direct x1 (left x1" in notes and "expand x1)" in notes
+    assert "join:direct x1 (left x1" in notes
+    assert "expand x1 one-pass)" in notes
     assert "agg:sort" in notes and "probe:resident" in notes
     assert notes.count("fused:into tpu fragment") == 3
     assert not [n for n in notes if n.startswith("device_unsupported:")]

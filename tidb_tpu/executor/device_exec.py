@@ -319,6 +319,11 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # capacity the program ran at; rows over capacity is how
                # full the learned capacity is — note_join_expansion
                "join_expand_rows": 0, "join_expand_capacity": 0,
+               # of join_expand, the expansions whose KEPT program maps
+               # its output slots to probe rows in one pass and not by a
+               # binary search per slot (device_join.expand_one_pass) —
+               # note_join_expansion
+               "join_expand_one_pass": 0,
                # column / mask / row-map gathers of dispatched join
                # fragments' programs, and those the program holds the
                # result of already (a leaf read in place, a NULL-free
@@ -389,6 +394,7 @@ def _tls_stats() -> dict:
                                 "join_search_prefixed": 0,
                                 "join_left": 0, "join_semi": 0,
                                 "join_anti": 0, "join_expand": 0,
+                                "join_expand_one_pass": 0,
                                 "join_gathers": 0,
                                 "join_gathers_elided": 0,
                                 "join_probe_resident": 0,
@@ -469,14 +475,22 @@ def note_join_layouts(joins):
             _bump("join_expand")
 
 
-def note_join_expansion(rows, capacity):
+def note_join_expansion(rows, capacity, one_pass):
     """Count one expanded join of a dispatched join fragment: the `rows`
-    its expansion emitted and the static `capacity` of the program that
-    emitted them.  Once per fragment, for the run whose result is kept
-    (a capacity retry's totals are not counted); the benchmark's
-    ``join.expand_fill`` reads rows over capacity."""
+    its expansion emitted, the static `capacity` of the program that
+    emitted them and whether that program took the one-pass side of
+    device_join._expand_rows (`one_pass`: device_join.expand_one_pass
+    asked with the capacity and the probe rows the trace asked it with).
+    Once per fragment, for the run whose result is kept (a capacity
+    retry's totals are not counted); the benchmark's
+    ``join.expand_fill`` reads rows over capacity,
+    ``join.one_pass_expand_share`` ``join_expand_one_pass`` over
+    ``join_expand``, and EXPLAIN ANALYZE's ``join:`` annotation says
+    ``expand x1 one-pass``."""
     _bump("join_expand_rows", int(rows))
     _bump("join_expand_capacity", int(capacity))
+    if one_pass:
+        _bump("join_expand_one_pass")
 
 
 def note_join_gathers(fn):

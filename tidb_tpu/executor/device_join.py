@@ -17,9 +17,12 @@ compiles into ONE jitted XLA program over HBM-resident base tables:
   output shape — the join is one gather of a row id with the probe
   side's exact capacity, no expansion pass and no overflow retry at all.
 - non-unique indexed builds expand through a static-capacity CSR walk
-  (cnt → cumsum → searchsorted), still sort-free on device.
+  (cnt → cumsum → the row map of _expand_rows: one scatter and one
+  running sum, or a search per output slot where the slots are few
+  against the probe rows), still sort-free on device.
 - joins outside the index language (bushy subtrees, computed keys) fall
-  back to the in-program lexsort + searchsorted expansion.
+  back to the in-program lexsort + searchsorted build, expanded through
+  the same row map.
 - intermediate results are row-index vectors into the base tables, not
   materialized rows: each join composes gathers lazily, and only the
   aggregate at the top reads actual column values.
@@ -98,6 +101,7 @@ class _JoinNode:
         self.pos = 0            # index into the fragment's join list
         self.strategy = None    # None | (kind, side, JoinIndex)
         self.exp_cap = None     # requested capacity for expansion joins
+        self.probe_cap = 0      # an expansion's probe rows (_fill_caps)
         self.global_keys = False  # keys/conds already in global indices
 
 
@@ -528,6 +532,91 @@ def _null_extend(nulls, bidx_map, hit):
         nulls[lid] = ~hit if prev is None else (prev | ~hit)
 
 
+#: one slot-step of the per-slot search priced in scattered probe rows of
+#: the one pass (expand_one_pass): 15-27 ns against 8.8 ns on the v5e
+_EXPAND_SEARCH_PRICE = 2
+
+
+def expand_one_pass(cap, n_probe) -> bool:
+    """Which side of _expand_rows maps an expansion's `cap` output slots
+    to its `n_probe` probe rows: True = one scatter at probe length and
+    two running scans at output length, False = a binary search per
+    output slot.  Host-callable (the dispatcher counts kept programs by
+    it: device_exec.note_join_expansion) and what _expand_rows itself
+    asks at trace time; it reads its two arguments only, both static
+    shapes, so every backend traces the program the chip runs.
+
+    The search costs cap x ceil(log2(n_probe + 1)) gathered slot-steps,
+    the pass n_probe scattered rows (its scans over the slots are a
+    fiftieth of a slot-step each); the pass is taken where a slot-step at
+    _EXPAND_SEARCH_PRICE scattered rows makes the search the dearer:
+    TPC-H Q13's 2,097,152 slots over the 185,364-row customer bucket
+    pass, a learned capacity of 16,384 slots over 8,388,608 probe rows
+    searches."""
+    n_probe = int(n_probe)
+    steps = max(n_probe, 1).bit_length()        # ceil(log2(n_probe + 1))
+    return int(cap) * steps * _EXPAND_SEARCH_PRICE >= n_probe
+
+
+def _expand_rows(cnt, cap):
+    """The row map of a static-capacity expansion: probe row i emits
+    cnt[i] consecutive output slots, in row order.  Returns (pi, within,
+    total): pi[s] = the probe row of slot s, clipped to [0, n_probe - 1]
+    (slots at and past `total` read the last row); within[s] = s less the
+    first slot of pi[s]'s run; total = sum(cnt), exact in cnt's dtype
+    whatever `cap` (the overflow the capacity retry reads).
+
+    pi[s] = #{i : cum[i + 1] <= s} is a count, not a search.  Two ways to
+    the same arrays, slot for slot past `total` too, chosen by
+    expand_one_pass(cap, n_probe) from the static shapes alone:
+
+    - one pass: a one scattered at the end of every row but the last
+      (ends non-decreasing, equal where a row emits nothing, those past
+      `cap` dropped), then a running sum over the `cap` slots: the count
+      of ends at or before a slot, at most n_probe - 1 by construction.
+      A marked slot starts a run, so a running max of the marked
+      positions is every slot's run start.  Positions are int32 while
+      cap and n_probe fit; no gather, no loop.
+    - search: searchsorted(cum, arange(cap)), one binary search per
+      output slot: ceil(log2(n_probe + 1)) DEPENDENT gathers into the
+      int64 `cum`, two `u32` halves each, then cum[pi].
+
+    Standalone on the v5e (PERF.md section 6, PR 38; ms, the cumsum of
+    `cnt` included: 0.9 / 10.3 / 10.2 / 3.4 / 1.0 by itself), at
+    (n_probe, cap) = (262,144, 2,097,152: Q13's, whose customer bucket
+    is 185,364 rows) / (8,388,608, 16,384) /
+    (8,388,608, 2,097,152) / (2,097,152, 2,097,152) / (65,536, 1,024):
+    search 1,148.7 / 16.2 / 1,532.4 / 1,719.1 / 1.2 (over an int32 `cum`
+    302.0 / 13.3 / 385.4 / 349.3 / 1.1); this pass 4.6 / 84.0 / 85.0 /
+    23.1 / 1.6 (18.95 / 84.1 / 99.4 / 37.4 / 1.6 with `within` gathered
+    as start[pi]); a scatter-max of row ids at the starts and two
+    running maxes 4.9 / 84.0 / 85.3 / 23.4 / 1.6; jnp.repeat 34.1 / 93.9
+    / 123.9 / 54.7 / 1.6; two single-operand sorts of the ends merged
+    with the slots (`pi` alone) 5.1 / 28.9 / 31.7 / 9.8 / 1.0.  The
+    scatter is 8.8 ns a probe row, a scan 0.3 ns a slot, the search 15-27
+    ns a slot-step, the sorts 2 ns a row of n_probe + cap: cheapest where
+    the probe is as long as the output, 1.3 ms behind this pass at Q13's
+    shape, and not taken: no cell has such a shape (PERF.md section 7)."""
+    n_probe = cnt.shape[0]
+    cum = jnp.concatenate([jnp.zeros(1, dtype=cnt.dtype), jnp.cumsum(cnt)])
+    total = cum[-1]
+    if expand_one_pass(cap, n_probe):
+        pos_dt = jnp.int32 if max(cap, n_probe) < (1 << 31) else jnp.int64
+        # an end past `cap` is past every slot: clipped to `cap`, dropped
+        ends = jnp.minimum(cum[1:n_probe], cap).astype(pos_dt)
+        marks = jnp.zeros(cap, dtype=pos_dt).at[ends].add(
+            1, mode="drop", indices_are_sorted=True)
+        pi = jnp.cumsum(marks)
+        posn = jnp.arange(cap, dtype=pos_dt)
+        within = posn - jax.lax.cummax(jnp.where(marks > 0, posn, 0))
+    else:
+        posn = jnp.arange(cap)
+        pi = jnp.clip(jnp.searchsorted(cum, posn, side="right") - 1,
+                      0, n_probe - 1)
+        within = posn - cum[pi]
+    return pi, within, total
+
+
 def _join_expand(bk, bvalid, pk, pvalid, cap):
     """Static-capacity inner equi-join expansion (device-sort fallback).
     Returns (probe_slot, build_slot, valid, total): slot arrays index the
@@ -538,7 +627,6 @@ def _join_expand(bk, bvalid, pk, pvalid, cap):
     bounds are clamped to the valid prefix — a plain int64.max sentinel
     would interleave genuine max-valued keys with padding and overcount."""
     nb = bk.shape[0]
-    npr = pk.shape[0]
     with jax.named_scope("k_join_build"):
         nb_valid = jnp.sum(bvalid)
         order = jnp.lexsort((bk, ~bvalid))  # valid-first, then key-sorted
@@ -548,14 +636,8 @@ def _join_expand(bk, bvalid, pk, pvalid, cap):
         lo = jnp.minimum(jnp.searchsorted(sb, pk, side="left"), nb_valid)
         hi = jnp.minimum(jnp.searchsorted(sb, pk, side="right"), nb_valid)
         cnt = jnp.where(pvalid, hi - lo, 0)
-        cum = jnp.concatenate([jnp.zeros(1, dtype=cnt.dtype),
-                               jnp.cumsum(cnt)])
-        total = cum[-1]
-        pos = jnp.arange(cap)
-        pi = jnp.clip(jnp.searchsorted(cum, pos, side="right") - 1,
-                      0, npr - 1)
-        valid = pos < total
-        within = pos - cum[pi]
+        pi, within, total = _expand_rows(cnt, cap)
+        valid = jnp.arange(cap) < total
         bpos = lo[pi] + within
         bi = order[jnp.clip(bpos, 0, jnp.maximum(nb - 1, 0))]
         valid = valid & bvalid[bi] & pvalid[pi]
@@ -941,13 +1023,8 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                 cnt_eff = jnp.where(pvalid, jnp.maximum(cnt, 1), 0)
             else:
                 cnt_eff = cnt
-            cum = jnp.concatenate(
-                [jnp.zeros(1, dtype=jnp.int64), jnp.cumsum(cnt_eff)])
-            total = cum[-1]
+            pi, within, total = _expand_rows(cnt_eff, cap)
             posn = jnp.arange(cap)
-            pi = jnp.clip(jnp.searchsorted(cum, posn, side="right") - 1,
-                          0, n_probe - 1)
-            within = posn - cum[pi]
             real = within < cnt[pi]  # False on a left join's null emission
             bpos = pos0[pi] + jnp.minimum(within,
                                           jnp.maximum(cnt[pi] - 1, 0))
@@ -1093,15 +1170,16 @@ def _fill_caps(node, sig):
         node.cap = lc if (node.kind != "inner"
                           or st[1] == "right") else rc
         return node.cap
+    # the in-program expansion probes with its left side
+    node.probe_cap = rc if st is not None and st[1] != "right" else lc
     if node.exp_cap is None:
         learned = _CAP_STORE.get((sig, node.pos))
         if learned is not None:
             node.exp_cap = dev.next_pow2(max(learned, 8))
         elif st is not None:
-            probe_cap = lc if st[1] == "right" else rc
-            est = int(probe_cap * st[2].avg_cnt * 1.5)
+            est = int(node.probe_cap * st[2].avg_cnt * 1.5)
             if node.kind == "left":
-                est += probe_cap  # every unmatched probe row still emits
+                est += node.probe_cap  # every unmatched probe row still emits
             node.exp_cap = dev.next_pow2(max(est, 1024))
         else:
             def fk_est(nd):
@@ -1324,7 +1402,8 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         raise DeviceUnsupported("join fragment capacities did not converge")
     for jn, total in zip(joins, overflows):
         if join_expands(jn):
-            note_join_expansion(total, jn.cap)
+            note_join_expansion(total, jn.cap,
+                                expand_one_pass(jn.cap, jn.probe_cap))
     if ng == 0 and not agg_plan.group_exprs:
         raise DeviceUnsupported("empty global aggregate")
     body = f.body()

@@ -113,7 +113,10 @@ class QueryExecutor:
             # join:direct x4+search x1 (prefix x1) where a searched
             # join starts from its key's bucket; the kinds other than
             # inner and the CSR expansions beside them: join:direct x1
-            # (left x1, expand x1), join:direct x1 (semi x1)
+            # (left x1, expand x1 one-pass), join:direct x1 (semi x1);
+            # `one-pass` where every expansion's kept program maps its
+            # slots to probe rows without a search per slot
+            # (note_join_expansion), `one-pass x1` where only some do
             def grew(names, sep):
                 found = [(name, st1["join_" + k] - st0["join_" + k])
                          for name, k in names]
@@ -122,6 +125,11 @@ class QueryExecutor:
             beside = grew((("prefix", "search_prefixed"), ("left", "left"),
                            ("semi", "semi"), ("anti", "anti"),
                            ("expand", "expand")), ", ")
+            n_pass, n_expand = (st1[k] - st0[k] for k in (
+                "join_expand_one_pass", "join_expand"))
+            if n_pass:
+                beside += (" one-pass" if n_pass == n_expand
+                           else f" one-pass x{n_pass}")
             if beside:
                 note += f" ({beside})"
             self.annotate(join=note or None)
