@@ -39,6 +39,17 @@ def tk():
     tk.must_exec("insert into orders_d values " + ",".join(rows_o))
     tk.must_exec("insert into lineitem_d values " + ",".join(rows_l))
     tk.must_exec("insert into customer_d values " + ",".join(rows_c))
+    # Q21's shape: orders of 1..5 lines from 1..4 suppliers, late or not
+    tk.must_exec("create table li21 (l_orderkey bigint, l_suppkey bigint, "
+                 "l_commitdate date, l_receiptdate date)")
+    rows_21 = []
+    for ok in range(1, 151):
+        for _ in range(int(rng.integers(1, 6))):
+            c = int(rng.integers(1, 28))
+            r = int(rng.integers(1, 28))
+            rows_21.append(f"({ok}, {int(rng.integers(1, 5))}, "
+                           f"'1995-03-{c:02d}', '1995-03-{r:02d}')")
+    tk.must_exec("insert into li21 values " + ",".join(rows_21))
     return tk
 
 
@@ -66,6 +77,17 @@ class TestDecorrelatePlans:
         p = _plan(tk, sql)
         assert "semi" in p and "anti" in p and "apply" not in p
 
+    def test_q21_real_shape_keeps_the_non_equality_as_residual(self, tk):
+        """TPC-H Q21's correlation: `=` on the order is the key, `<>` on
+        the supplier the semi / anti join's other condition."""
+        p = _plan(tk, Q21_SHAPE)
+        assert "apply" not in p
+        lines = p.splitlines()
+        semi = next(ln for ln in lines if "semi, equal:" in ln)
+        anti = next(ln for ln in lines if "anti, equal:" in ln)
+        for ln in (semi, anti):
+            assert "other:ne(" in ln and "l_suppkey" in ln.split("other:")[1]
+
     def test_q22_shape_not_exists(self, tk):
         """TPC-H Q22 inner: NOT EXISTS orders per customer."""
         sql = ("select c_custkey from customer_d where c_acctbal > 0 and "
@@ -91,6 +113,17 @@ class TestDecorrelatePlans:
                "select o_custkey from orders_d where o_custkey = c_custkey "
                "group by o_custkey having count(*) > 1)")
         assert "apply" in _plan(tk, sql)
+
+
+Q21_SHAPE = (
+    "select l1.l_suppkey, count(*) from li21 l1 "
+    "where l1.l_receiptdate > l1.l_commitdate "
+    "and exists (select * from li21 l2 "
+    "where l2.l_orderkey = l1.l_orderkey and l2.l_suppkey <> l1.l_suppkey) "
+    "and not exists (select * from li21 l3 "
+    "where l3.l_orderkey = l1.l_orderkey and l3.l_suppkey <> l1.l_suppkey "
+    "and l3.l_receiptdate > l3.l_commitdate) "
+    "group by l1.l_suppkey order by l1.l_suppkey")
 
 
 class TestDecorrelateResults:
@@ -139,6 +172,57 @@ class TestDecorrelateResults:
         p = _plan(tk, "select a from tn where a not in (select b from sn "
                       "where sn.g = tn.a)")
         assert "anti" in p and "apply" not in p
+
+    def test_q21_shape_parity_with_apply_fallback(self, tk):
+        """The residual reads the right columns through the probe's
+        projection and the chain of two existence joins: the same rows
+        as the Apply fallback (`+ 0` defeats the key pattern)."""
+        app = Q21_SHAPE.replace("l2.l_orderkey = l1.l_orderkey",
+                                "l2.l_orderkey = l1.l_orderkey + 0") \
+            .replace("l3.l_orderkey = l1.l_orderkey",
+                     "l3.l_orderkey = l1.l_orderkey + 0")
+        assert "apply" in _plan(tk, app)
+        rows = self._parity(tk, Q21_SHAPE, app)
+        assert len(rows) > 0
+
+    def test_not_in_with_a_residual_stays_null_aware(self, tk):
+        """NOT IN with a `<>` beside its `=` correlation: the null-aware
+        residual and the `<>` are both the anti join's other conditions,
+        and the rows are the Apply fallback's."""
+        tk.must_exec("create table tr (a bigint, k bigint)")
+        tk.must_exec("create table sr (g bigint, b bigint, k bigint)")
+        tk.must_exec("insert into tr values (1, 1), (2, 1), (null, 2), "
+                     "(3, 3), (4, 4)")
+        tk.must_exec("insert into sr values (1, 1, 1), (1, null, 1), "
+                     "(2, 5, 2), (3, 3, 3), (3, 9, 4), (4, 4, 5)")
+        dec = ("select a from tr where a not in (select b from sr where "
+               "sr.g = tr.a and sr.k <> tr.k) order by a")
+        app = dec.replace("sr.g = tr.a", "sr.g = tr.a + 0")
+        p = _plan(tk, dec)
+        assert "anti" in p and "apply" not in p
+        assert "isnull(" in p and "ne(" in p
+        assert "apply" in _plan(tk, app)
+        rows = self._parity(tk, dec, app)
+        # a=1: {b : g=1, k<>1} = {} -> keep; a=2: {} -> keep; NULL: empty
+        # set -> keep; a=3: {9} -> keep; a=4: {4} -> drop
+        assert rows == [(None,), ("1",), ("2",), ("3",)]
+
+    def test_two_stacked_residual_joins(self, tk):
+        """Two correlated NOT IN: the upper anti join's residual reads its
+        build's columns right after the lower one's probe schema (a pruned
+        semi / anti join outputs its probe's columns only; the parent read
+        past the end and failed with IndexError)."""
+        tk.must_exec("create table t2 (a bigint, b bigint, k bigint)")
+        tk.must_exec("create table s2 (g bigint, x bigint)")
+        tk.must_exec("insert into t2 values (1, 10, 1), (2, 20, 2), "
+                     "(3, 30, 3)")
+        tk.must_exec("insert into s2 values (1, 1), (2, 7), (3, 30)")
+        dec = ("select a from t2 where a not in (select x from s2 where "
+               "s2.g = t2.k) and b not in (select x from s2 where "
+               "s2.g = t2.k) order by a")
+        app = dec.replace("s2.g = t2.k)", "s2.g = t2.k + 0)")
+        assert "apply" not in _plan(tk, dec)
+        assert self._parity(tk, dec, app) == [("2",)]
 
     def test_q17_shape_scalar_avg_cmp(self, tk):
         """x < (SELECT 0.2*avg(...) WHERE k = outer.k) → semi join against

@@ -313,6 +313,14 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # note_join_layouts
                "join_left": 0, "join_semi": 0, "join_anti": 0,
                "join_expand": 0,
+               # of the semi / anti joins, those whose residual (a
+               # correlated conjunct that is not a key: Q21's `<>`) ran
+               # in the program — note_join_layouts; and per such join
+               # over a non-unique build, the pairs its kept program
+               # tested through a CSR expansion of the probe's live rows
+               # and the static capacity it ran at — note_join_residual
+               "join_residual": 0,
+               "join_residual_rows": 0, "join_residual_capacity": 0,
                # per expanded join of a dispatched join fragment: the
                # rows its expansion emitted (the `total` the program
                # counts beside the overflow check) and the static
@@ -394,6 +402,7 @@ def _tls_stats() -> dict:
                                 "join_search_prefixed": 0,
                                 "join_left": 0, "join_semi": 0,
                                 "join_anti": 0, "join_expand": 0,
+                                "join_residual": 0,
                                 "join_expand_one_pass": 0,
                                 "join_gathers": 0,
                                 "join_gathers_elided": 0,
@@ -448,6 +457,17 @@ def join_expands(jn) -> bool:
             and jn.strategy[2] is not None and jn.strategy[0] != "uniq")
 
 
+def exists_expands(jn) -> bool:
+    """Does this host-indexed semi / anti join test its residual pair by
+    pair over a CSR expansion of the probe's live rows (a non-unique
+    build)?  Its output stays probe-shaped (the pairs reduce back to
+    their probe row); a unique build tests the residual on its one
+    gathered row, a join with no residual counts matches."""
+    return (jn.kind in ("semi", "anti") and bool(jn.other_conds)
+            and jn.strategy is not None and jn.strategy[2] is not None
+            and jn.strategy[0] != "uniq")
+
+
 def note_join_layouts(joins):
     """Count the host-indexed joins of one dispatched join fragment (its
     ``_JoinNode``s with their strategies planned) by the layout of
@@ -458,7 +478,9 @@ def note_join_layouts(joins):
     (``join.prefixed_search_share``).  The same joins count by kind
     (``join_left`` / ``join_semi`` / ``join_anti``; an inner join under
     none) and, where their output is CSR-expanded, under
-    ``join_expand`` (``join.non_inner_share``, ``join.expanded_share``).
+    ``join_expand`` (``join.non_inner_share``, ``join.expanded_share``);
+    a semi / anti join with a residual under ``join_residual`` too
+    (``join.residual_share``).
     Joins built inside the program (no index) count under none; a mesh
     fragment on the indexed path (mpp_exec._indexed_chain) counts as one
     chip's."""
@@ -471,6 +493,8 @@ def note_join_layouts(joins):
             _bump("join_search_prefixed")
         if jn.kind != "inner":
             _bump("join_" + jn.kind)
+        if jn.kind in ("semi", "anti") and jn.other_conds:
+            _bump("join_residual")
         if join_expands(jn):
             _bump("join_expand")
 
@@ -491,6 +515,16 @@ def note_join_expansion(rows, capacity, one_pass):
     _bump("join_expand_capacity", int(capacity))
     if one_pass:
         _bump("join_expand_one_pass")
+
+
+def note_join_residual(rows, capacity):
+    """Count one semi / anti join whose residual its program tested over
+    a CSR expansion (exists_expands): the pairs (probe row, build row)
+    the expansion held and the static capacity it ran at.  Once per
+    fragment, for the run whose result is kept; the benchmark's
+    ``join.residual_fill`` reads rows over capacity."""
+    _bump("join_residual_rows", int(rows))
+    _bump("join_residual_capacity", int(capacity))
 
 
 def note_join_gathers(fn):

@@ -41,8 +41,13 @@ filters, topped by a group-by aggregate. Join kinds:
 - left outer: anywhere, with an indexed build side — the build side
   null-extends in-program (nullmaps thread the ~matched flags through the
   gathers), ON-residuals fold into the match on the unique-gather path;
-- semi / anti: at the fragment ROOT only (probe-shaped existence counts —
-  exactly the decorrelated EXISTS/IN plans), no residual conds.
+- semi / anti: at the fragment ROOT, or a chain of them there, each the
+  probe of the one above (the decorrelated EXISTS / IN conjuncts of one
+  WHERE; the inner joins under the chain reorder fact-first). Without a
+  residual an existence is a match count; with one (Q21's `<>`) a
+  unique build tests it on its gathered row and a non-unique build on
+  every pair of a CSR expansion of the probe's live rows, reduced back
+  to the probe row (probe-shaped either way).
 Anything else raises DeviceUnsupported and falls back to the host path.
 """
 
@@ -62,9 +67,10 @@ from ..ops import device as dev
 from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
-    _plan_agg, _timed_jit, acquire_pipeline, join_expands, note_agg_arm,
-    note_agg_spans, note_join_expansion, note_join_gathers,
-    note_join_layouts, note_join_probe, note_rerun, note_semi_inset)
+    _plan_agg, _timed_jit, acquire_pipeline, exists_expands, join_expands,
+    note_agg_arm, note_agg_spans, note_join_expansion, note_join_gathers,
+    note_join_layouts, note_join_probe, note_join_residual, note_rerun,
+    note_semi_inset)
 from .join_index import build_join_index
 
 
@@ -186,6 +192,19 @@ def collect_tree(node):
                 return lnode
             left = walk(n.children[0], offset)
             right = walk(n.children[1], offset + left.ncols)
+            other_conds = list(p.other_conds)
+            gap = left.ncols - _width(left)
+            if p.kind in ("semi", "anti") and gap:
+                # a residual reads [probe | build] in the plan's schema,
+                # where the probe (a semi / anti join's rows) is narrower
+                # than the columns its subtree holds: the build half
+                # moves to the build leaf's own columns
+                lw = _width(left)
+                other_conds = [c.transform_columns(
+                    lambda col, lw=lw, gap=gap: col if col.idx < lw
+                    else ExprColumn(col.idx + gap, col.ftype,
+                                    name=col.name))
+                    for c in other_conds]
             for lk, rk in zip(p.left_keys, p.right_keys):
                 kl, kr = phys_kind(lk.ftype), phys_kind(rk.ftype)
                 if K_STR in (kl, kr) or K_FLOAT in (kl, kr):
@@ -193,7 +212,7 @@ def collect_tree(node):
                 if (lk.ftype.scale or 0) != (rk.ftype.scale or 0):
                     raise DeviceUnsupported("mismatched decimal key scales")
             jn = _JoinNode(left, right, list(p.left_keys),
-                           list(p.right_keys), list(p.other_conds), offset,
+                           list(p.right_keys), other_conds, offset,
                            kind=p.kind)
             jn.pos = len(joins)
             joins.append(jn)
@@ -205,17 +224,36 @@ def collect_tree(node):
     if not joins:
         raise DeviceUnsupported("no joins in fragment")
     # semi/anti joins expose only their probe (left) schema, so upstream
-    # column indices stay valid only when such a join is the fragment ROOT
-    # (the aggregate's direct child — exactly the decorrelated-subquery
-    # shape); anywhere deeper, sibling offsets would collide
-    for jn in joins:
-        if jn.kind in ("semi", "anti") and jn is not root:
-            raise DeviceUnsupported("semi/anti join below fragment root")
-        if jn.kind in ("semi", "anti") and jn.other_conds:
-            # probe-shaped existence checks cannot evaluate residuals over
-            # build columns (null-aware NOT IN etc.) — host path instead
-            raise DeviceUnsupported("semi/anti join with residual conds")
+    # column indices stay valid only on the chain of such joins at the
+    # fragment ROOT, each the probe of the one above (the decorrelated
+    # EXISTS / NOT EXISTS conjuncts of one WHERE: Q21's): nothing is
+    # joined beside them, so no sibling's offsets can collide
+    chain = {id(jn) for jn in _exists_chain(root)}
+    if any(jn.kind in ("semi", "anti") and id(jn) not in chain
+           for jn in joins):
+        raise DeviceUnsupported("semi/anti join below fragment root")
     return root, leaves, joins
+
+
+def _width(node) -> int:
+    """Columns of a tree node's schema in the plan: a semi / anti join's
+    is its probe's, though its subtree holds its build's columns too."""
+    if isinstance(node, _Leaf):
+        return node.ncols
+    if node.kind in ("semi", "anti"):
+        return _width(node.left)
+    return _width(node.left) + _width(node.right)
+
+
+def _exists_chain(root) -> list:
+    """The semi / anti joins stacked at a fragment's root, top first,
+    each the probe (left side) of the one above."""
+    chain = []
+    node = root
+    while isinstance(node, _JoinNode) and node.kind in ("semi", "anti"):
+        chain.append(node)
+        node = node.left
+    return chain
 
 
 def _leaf_env(leaf, bucket=None):
@@ -293,17 +331,19 @@ def _leaf_key_cols(side, keys):
     return cols
 
 
-def _leaf_index(side, keys):
+def _leaf_index(side, keys, filtered=True):
     """Host join index for `side` (a leaf with bare int keys), built over
     the rows passing the leaf's pushed-down filters — evaluated host-side
     with the host engine's own predicate path, so index membership matches
-    the device mask exactly. None when out of the index language."""
+    the device mask exactly — or over all its rows where not `filtered`
+    (the program then tests the leaf's mask on every row it reads
+    through the index).  None when out of the index language."""
     cols = _leaf_key_cols(side, keys)
     if cols is None:
         return None
     tag = ""
     mask_fn = None
-    if side.conds:
+    if side.conds and filtered:
         try:
             tag = ";".join(_expr_sig(c) for c in side.conds)
         except DeviceUnsupported:
@@ -324,8 +364,14 @@ def _plan_strategy(jn):
     argsort the (typically huge) fact table for nothing.
 
     Non-inner kinds (left/semi/anti) preserve their LEFT side: the probe
-    must be the left relation, so only right-side builds qualify."""
-    ridx = _leaf_index(jn.right, jn.right_keys)
+    must be the left relation, so only right-side builds qualify.  A
+    semi / anti join with a residual tests its build leaf's filter pair by
+    pair beside the residual, so it takes the leaf's UNFILTERED index: two
+    existence tests on one key column under different filters (Q21's l2
+    and l3) then share the one index the column caches, and neither
+    rebuilds it for the other."""
+    ridx = _leaf_index(jn.right, jn.right_keys, filtered=not (
+        jn.kind in ("semi", "anti") and jn.other_conds))
     if jn.kind != "inner":
         if ridx is None:
             return None
@@ -495,6 +541,34 @@ def _reorder_fact_first(leaves, joins, assume_unique=frozenset()):
     if pend_pairs or pend_others:
         return None  # anything unplaced means the rewrite lost a predicate
     return cur, new_joins
+
+
+def _reorder_below_chain(leaves, joins):
+    """_reorder_fact_first for the inner joins under a fragment's chain of
+    semi / anti joins (_exists_chain), the existence chain put back on
+    top of the fact-first one: the probe of every existence test is then
+    the fact leaf's rows, read in place, whose mask says which the inner
+    joins keep.  -> (root, joins) in postorder with the inner joins' strategies
+    assigned, or None where the chain's builds are not leaves or what is
+    under it is not all inner joins that chain expansion-free."""
+    chain = _exists_chain(joins[-1])
+    if not chain or any(not isinstance(jn.right, _Leaf) for jn in chain):
+        return None
+    below = chain[-1].left
+    inner = [jn for jn in joins if jn.leaf_ids <= below.leaf_ids]
+    if (not isinstance(below, _JoinNode) or len(inner) + len(chain)
+            != len(joins) or any(jn.kind != "inner" for jn in inner)):
+        return None
+    got = _reorder_fact_first(
+        [leaf for leaf in leaves if leaf.leaf_id in below.leaf_ids], inner)
+    if got is None:
+        return None
+    sub, new_joins = got
+    chain[-1].left = sub
+    for jn in reversed(chain):
+        jn.pos = len(new_joins)
+        new_joins.append(jn)
+    return chain[0], new_joins
 
 
 def _strategy_sig(jn):
@@ -966,9 +1040,15 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
 
             if jkind in ("semi", "anti") and kind != "uniq":
                 # existence only: probe-shaped regardless of match counts
-                hit = cnt > 0
+                if node._oc_fns:
+                    hit, total = exists_pairs(node, cnt, pos0, a1, safe_hi,
+                                              pidx_map, pnull, bidx_map,
+                                              bnull, bvalid)
+                else:
+                    hit = cnt > 0
                 valid = pvalid & (hit if jkind == "semi" else ~hit)
-                overflows.append(jnp.sum(valid))
+                # a residual's expansion reports its pairs: the capacity
+                overflows.append(total if node._oc_fns else jnp.sum(valid))
                 return dict(pidx_map), valid, dict(pnull)
 
             if kind == "uniq":
@@ -985,9 +1065,10 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                     idx.slots is not None and isinstance(bside, _Leaf)
                     and (idx.filtered or not bside.conds))
                 hit = cnt > 0 if slots_hold_mask else (cnt > 0) & bvalid[bi]
-                if node._oc_fns and jkind == "left":
+                if node._oc_fns and jkind != "inner":
                     # ON-clause residuals are part of the MATCH for outer
-                    # joins — evaluate on the joined candidate row first
+                    # joins, a semi / anti join's residual part of what
+                    # exists — evaluate on the joined candidate row first
                     cand_idx = dict(pidx_map)
                     for lid, v in bidx_map.items():
                         cand_idx[lid] = v + (bi,)
@@ -1044,6 +1125,34 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
             if jkind == "left":
                 _null_extend(nulls, bidx_map, hit)
             return out, valid, nulls
+
+        @jax.named_scope("k_join_exists")
+        def exists_pairs(node, cnt, pos0, a1, safe_hi, pidx_map, pnull,
+                         bidx_map, bnull, bvalid):
+            """Which probe rows have a build row that passes the build
+            leaf's mask and the join's residual: the CSR expansion of the
+            probe's LIVE rows (a dead row's cnt is 0 and emits nothing)
+            into node.cap pairs, the mask and the residual tested on each
+            pair, the pairs reduced back to their probe row by a
+            scatter-max.  -> (hit at probe length, the pairs' total)."""
+            cap = node.cap
+            pi, within, total = _expand_rows(cnt, cap)
+            bi = a1[jnp.clip(pos0[pi] + within, 0, safe_hi)].astype(
+                jnp.int64)
+            pair = (jnp.arange(cap) < total) & bvalid[bi]
+            cand_idx = {k: v + (pi,) for k, v in pidx_map.items()}
+            cand_null = {k: v[pi] for k, v in pnull.items()}
+            for lid, v in bidx_map.items():
+                cand_idx[lid] = v + (bi,)
+            for lid, v in bnull.items():
+                cand_null[lid] = v[bi]
+            jenv = gather_env(cand_idx, node._oc_cols, cand_null)
+            for f in node._oc_fns:
+                d, nl = f(jenv)
+                pair = pair & (d != 0) & ~nl
+            hit = jnp.zeros(cnt.shape[0], dtype=jnp.int32).at[pi].max(
+                pair.astype(jnp.int32), indices_are_sorted=True)
+            return hit > 0, total
 
         def eval_node(node):
             if isinstance(node, _Leaf):
@@ -1164,8 +1273,9 @@ def _fill_caps(node, sig):
     lc = _fill_caps(node.left, sig)
     rc = _fill_caps(node.right, sig)
     st = node.strategy
-    if node.kind in ("semi", "anti") or (
-            st is not None and st[0] == "uniq"):
+    exists = exists_expands(node)
+    if not exists and (node.kind in ("semi", "anti") or (
+            st is not None and st[0] == "uniq")):
         # probe-shaped: semi/anti are existence counts; uniq is a gather
         node.cap = lc if (node.kind != "inner"
                           or st[1] == "right") else rc
@@ -1180,6 +1290,11 @@ def _fill_caps(node, sig):
             est = int(node.probe_cap * st[2].avg_cnt * 1.5)
             if node.kind == "left":
                 est += node.probe_cap  # every unmatched probe row still emits
+            elif exists:
+                # the probe of an existence test is what its WHERE keeps,
+                # a few rows of its bucket: the bucket is room enough to
+                # start from, and an overflow reports the exact total
+                est = min(est, node.probe_cap)
             node.exp_cap = dev.next_pow2(max(est, 1024))
         else:
             def fk_est(nd):
@@ -1188,7 +1303,8 @@ def _fill_caps(node, sig):
                 return max(fk_est(nd.left), fk_est(nd.right))
             node.exp_cap = dev.next_pow2(fk_est(node))
     node.cap = node.exp_cap
-    return node.cap
+    # an existence test's pairs reduce back to its probe's rows
+    return lc if exists else node.cap
 
 
 def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
@@ -1204,7 +1320,8 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     if not want_device(ctx, max(leaf.chunk.num_rows for leaf in leaves)):
         raise DeviceUnsupported("below device threshold")
     all_inner = all(jn.kind == "inner" for jn in joins)
-    reordered = _reorder_fact_first(leaves, joins) if all_inner else None
+    reordered = (_reorder_fact_first(leaves, joins) if all_inner
+                 else _reorder_below_chain(leaves, joins))
     hybrid_deferred = None
     if reordered is None and all_inner:
         # a build side too big to index whole (the paged-budget guard in
@@ -1217,22 +1334,22 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
             if reordered is not None:
                 hybrid_deferred = next(iter(over))
     if reordered is not None:
-        root, joins = reordered  # strategies assigned (all uniq)
-    else:
-        for jn in joins:
+        root, joins = reordered  # inner strategies assigned (all uniq)
+    for jn in joins:
+        if jn.strategy is None:
             jn.strategy = _plan_strategy(jn)
-        for jn in joins:
-            if jn.kind == "inner":
-                continue
-            if jn.strategy is None:
-                raise DeviceUnsupported(
-                    f"{jn.kind} join needs an indexed build side")
-            if (jn.kind == "left" and jn.other_conds
-                    and jn.strategy[0] != "uniq"):
-                # ON-residuals fold into the match only on the gather
-                # path; dropping them on the CSR path would change results
-                raise DeviceUnsupported(
-                    "left join residual conds need a unique build")
+    for jn in joins:
+        if jn.kind == "inner":
+            continue
+        if jn.strategy is None:
+            raise DeviceUnsupported(
+                f"{jn.kind} join needs an indexed build side")
+        if (jn.kind == "left" and jn.other_conds
+                and jn.strategy[0] != "uniq"):
+            # ON-residuals fold into the match only on the gather
+            # path; dropping them on the CSR path would change results
+            raise DeviceUnsupported(
+                "left join residual conds need a unique build")
 
     # paged-probe dispatch: a disk-backed fact side, one that does not
     # fit the residency budget, and one too long for a program that sorts
@@ -1364,8 +1481,10 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
                 "multi-key join value ranges exceed int64 packing")
         retry = False
         for jn, total in zip(joins, overflows):
-            if jn.kind in ("semi", "anti") or (
-                    jn.strategy is not None and jn.strategy[0] == "uniq"):
+            if not exists_expands(jn) and (
+                    jn.kind in ("semi", "anti") or (
+                        jn.strategy is not None
+                        and jn.strategy[0] == "uniq")):
                 continue  # probe-shaped: total ≤ probe cap by construction
             total = int(total)
             tight = dev.next_pow2(max(total, 8))
@@ -1404,6 +1523,8 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         if join_expands(jn):
             note_join_expansion(total, jn.cap,
                                 expand_one_pass(jn.cap, jn.probe_cap))
+        elif exists_expands(jn):
+            note_join_residual(total, jn.cap)
     if ng == 0 and not agg_plan.group_exprs:
         raise DeviceUnsupported("empty global aggregate")
     body = f.body()

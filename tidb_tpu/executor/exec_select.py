@@ -113,7 +113,8 @@ class QueryExecutor:
             # join:direct x4+search x1 (prefix x1) where a searched
             # join starts from its key's bucket; the kinds other than
             # inner and the CSR expansions beside them: join:direct x1
-            # (left x1, expand x1 one-pass), join:direct x1 (semi x1);
+            # (left x1, expand x1 one-pass), join:direct x1 (semi x1),
+            # join:direct x5 (semi x1, anti x1, residual x2);
             # `one-pass` where every expansion's kept program maps its
             # slots to probe rows without a search per slot
             # (note_join_expansion), `one-pass x1` where only some do
@@ -124,7 +125,8 @@ class QueryExecutor:
             note = grew((("direct", "direct"), ("search", "search")), "+")
             beside = grew((("prefix", "search_prefixed"), ("left", "left"),
                            ("semi", "semi"), ("anti", "anti"),
-                           ("expand", "expand")), ", ")
+                           ("residual", "residual"), ("expand", "expand")),
+                          ", ")
             n_pass, n_expand = (st1[k] - st0[k] for k in (
                 "join_expand_one_pass", "join_expand"))
             if n_pass:

@@ -675,10 +675,14 @@ class PlanBuilder:
         The subquery is analyzed once with outer refs surfacing as OuterRef
         markers; the rewrite accepts the canonical shape — [Sort] [Limit≥1,
         EXISTS only] [Projection] Selection(from-tree) — where every
-        OuterRef sits in a top-Selection conjunct of the form
-        eq(OuterRef, inner_expr). Anything else (correlation under an
-        aggregate, non-equality correlation, nested Apply) bails to the
-        SubqueryApply fallback. NOT IN compiles to a NULL-AWARE anti join:
+        OuterRef sits in a top-Selection conjunct and at least one of them
+        has the form eq(OuterRef, inner_expr): those are the join keys,
+        every other correlated conjunct (TPC-H Q21's `l2.l_suppkey <>
+        l1.l_suppkey`) the join's other condition, over the joined schema
+        (reference: TiDB keeps it as the semi join's OtherConditions).
+        Anything else (correlation under an aggregate, a correlation with
+        no equality, nested Apply) bails to the SubqueryApply fallback.
+        NOT IN compiles to a NULL-AWARE anti join:
         the membership key matches when equal OR either side is NULL
         (reference: null-aware anti join, planner/core/
         expression_rewriter.go handleInSubquery)."""
@@ -773,33 +777,29 @@ class PlanBuilder:
                 if acc:
                     return None
 
-        residual, lkeys, rkeys = [], [], []
+        residual, lkeys, rkeys, oconds = [], [], [], []
+        nl = len(from_schema)
         for c in sel_node.conds:
             acc = []
             _collect_outer_refs(c, acc)
             if not acc:
                 residual.append(c)
                 continue
-            if not (isinstance(c, ScalarFunc) and c.op == "eq"
-                    and len(c.args) == 2):
-                return None
-            a, b2 = c.args
-            a_acc, b_acc = [], []
-            _collect_outer_refs(a, a_acc)
-            _collect_outer_refs(b2, b_acc)
-            if isinstance(a, OuterRef) and not b_acc:
-                outer_ref, inner = a, b2
-            elif isinstance(b2, OuterRef) and not a_acc:
-                outer_ref, inner = b2, a
-            else:
-                return None
-            if phys_kind(outer_ref.ftype) != phys_kind(inner.ftype):
-                return None
-            lkeys.append(Column(outer_ref.idx, outer_ref.ftype,
-                                name=outer_ref.name))
-            rkeys.append(inner)
+            if not all(isinstance(r, OuterRef) for r in acc):
+                return None  # a nested Apply pins the conjunct to Apply
+            key = _outer_eq_key(c)
+            if key is None:
+                # any other correlated conjunct (Q21's l2.l_suppkey <>
+                # l1.l_suppkey) tests each matched pair: the join's
+                # other condition over [outer | inner]
+                oconds.append(bind_outer_refs(c, nl))
+                continue
+            lkeys.append(key[0])
+            rkeys.append(key[1])
+        if not lkeys:
+            return None  # no equi keys: a cartesian semi join would be
+            #              worse than the memoized Apply
 
-        oconds = []
         if target_ast is not None:
             out_len = len(proj.exprs) if proj else len(base.schema)
             if out_len != 1:
@@ -821,7 +821,6 @@ class PlanBuilder:
             else:
                 # NOT IN: null-aware residual — a build row "blocks" the
                 # probe row when the values match OR either side is NULL
-                nl = len(from_schema)
                 ys = _shift(y, nl)
                 oconds.append(ScalarFunc("or", [
                     ScalarFunc("or", [
@@ -830,9 +829,6 @@ class PlanBuilder:
                     ], _BOOL_FT.clone()),
                     ScalarFunc("isnull", [x], _BOOL_FT.clone()),
                 ], _BOOL_FT.clone()))
-        if not lkeys:
-            return None  # no equi keys: a cartesian semi join would be
-            #              worse than the memoized Apply
         right_child = Selection(base, residual) if residual else base
         return kind, right_child, lkeys, rkeys, oconds
 
@@ -877,8 +873,6 @@ class PlanBuilder:
         empty-group scalar is 0, not NULL, and a semi join would wrongly
         drop the row."""
         from ..expression.builder import OuterScope, _OP_MAP
-        from ..expression.core import OuterRef
-        from ..expression import phys_kind
         if isinstance(conj.left, ast.SubqueryExpr):
             sub_ast, target_ast = conj.left.query, conj.right
             op = self._MIRROR_OP[conj.op]
@@ -930,24 +924,11 @@ class PlanBuilder:
             if not acc:
                 residual.append(c)
                 continue
-            if not (isinstance(c, ScalarFunc) and c.op == "eq"
-                    and len(c.args) == 2):
+            key = _outer_eq_key(c)
+            if key is None:
                 return None
-            a, b2 = c.args
-            a_acc, b_acc = [], []
-            _collect_outer_refs(a, a_acc)
-            _collect_outer_refs(b2, b_acc)
-            if isinstance(a, OuterRef) and not b_acc:
-                outer_ref, inner = a, b2
-            elif isinstance(b2, OuterRef) and not a_acc:
-                outer_ref, inner = b2, a
-            else:
-                return None
-            if phys_kind(outer_ref.ftype) != phys_kind(inner.ftype):
-                return None
-            lkeys.append(Column(outer_ref.idx, outer_ref.ftype,
-                                name=outer_ref.name))
-            ikeys.append(inner)
+            lkeys.append(key[0])
+            ikeys.append(key[1])
         if not lkeys:
             return None
 
@@ -1266,6 +1247,42 @@ class PlanBuilder:
 def _shift(expr, delta):
     return expr.transform_columns(
         lambda c: Column(c.idx + delta, c.ftype, name=c.name))
+
+
+def _outer_eq_key(c):
+    """(outer key Column, inner key expr) of a correlated conjunct of the
+    form eq(OuterRef, inner) with both sides of one physical kind; None
+    for any other shape."""
+    from ..expression import phys_kind
+    from ..expression.core import OuterRef
+    if not (isinstance(c, ScalarFunc) and c.op == "eq"
+            and len(c.args) == 2):
+        return None
+    for outer_ref, inner in (c.args, c.args[::-1]):
+        acc = []
+        _collect_outer_refs(inner, acc)
+        if (isinstance(outer_ref, OuterRef) and not acc
+                and phys_kind(outer_ref.ftype) == phys_kind(inner.ftype)):
+            return (Column(outer_ref.idx, outer_ref.ftype,
+                           name=outer_ref.name), inner)
+    return None
+
+
+def bind_outer_refs(e, nl):
+    """A correlated conjunct over a semi / anti join's joined schema
+    [outer | inner]: an OuterRef reads the outer column at its index, an
+    inner column shifts past the `nl` outer ones.  (benchmark/queries/
+    q21.py asks for this name to tell a planner that keeps such a
+    conjunct as the join's residual from one that leaves it to Apply.)"""
+    from ..expression.core import OuterRef
+    if isinstance(e, OuterRef):
+        return Column(e.idx, e.ftype, name=e.name)
+    if isinstance(e, Column):
+        return Column(e.idx + nl, e.ftype, name=e.name)
+    if isinstance(e, ScalarFunc):
+        return ScalarFunc(e.op, [bind_outer_refs(a, nl) for a in e.args],
+                          e.ftype, e.extra)
+    return e
 
 
 def _split_ast_and(e, out):

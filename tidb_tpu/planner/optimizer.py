@@ -1058,17 +1058,21 @@ def pull_proj_through_semi(plan):
     for i, c in enumerate(plan.children):
         plan.children[i] = pull_proj_through_semi(c)
     if (isinstance(plan, Join) and plan.kind in ("semi", "anti")
-            and not plan.other_conds  # residuals index the concat schema
-            #                           whose left half IS the projection's
-            #                           output — rotating would misalign
-            #                           them (null-aware NOT IN, Q17/Q20)
             and isinstance(plan.left, Projection)
             and all(isinstance(e, Column) for e in plan.left.exprs)):
         proj = plan.left
+        nl, gap = len(proj.exprs), len(proj.child.schema) - len(proj.exprs)
         plan.children[0] = proj.child
         plan.left_keys = [
             e.transform_columns(lambda c: proj.exprs[c.idx])
             for e in plan.left_keys]
+        # residuals index [probe | build]: the probe half reads through
+        # the projection, the build half moves past the wider probe
+        plan.other_conds = [
+            e.transform_columns(
+                lambda c: proj.exprs[c.idx] if c.idx < nl
+                else Column(c.idx + gap, c.ftype, name=c.name))
+            for e in plan.other_conds]
         plan.schema = proj.child.schema
         proj.children[0] = plan
         return proj
@@ -1160,6 +1164,11 @@ def _prune(plan, needed):
         plan.left_keys = [_remap_cols(e, lmap) for e in plan.left_keys]
         plan.right_keys = [_remap_cols(e, rmap) for e in plan.right_keys]
         plan.other_conds = [_remap_cols(e, mapping) for e in plan.other_conds]
+        if plan.kind in ("semi", "anti"):
+            # the output is the probe's rows alone: a join above reads
+            # its right side's columns right after them
+            plan.schema = plan.left.schema
+            return plan, lmap
         plan.schema = plan.left.schema.concat(plan.right.schema)
         return plan, mapping
     if isinstance(plan, (Sort, TopN)):
