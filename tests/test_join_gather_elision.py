@@ -461,6 +461,9 @@ _PARENT_PROBE_GATHERS = {"q3": 15, "q5": 23}
     ("q3", q3.SQL, 2, 3, 10), ("q5", q5.SQL, 5, 4, 15)])
 def test_q3_q5_programs_hold_no_identity_gather(
         tpch_tk, monkeypatch, name, sql, n_joins, emitted, elided):
+    # the program that keeps its probe leaf whole: a cut to the live rows
+    # gathers that leaf too (tests/test_join_compaction.py)
+    monkeypatch.setattr(dj, "compact_to", lambda _live, _n: None)
     spy = _Spy(monkeypatch)
     assert _parity(tpch_tk, sql) == ["engine:tpu"]
     fn, env, jidx, n_lives = spy.calls[-1]
@@ -482,8 +485,9 @@ def test_q3_q5_programs_hold_no_identity_gather(
 
 # -- (e) the counters and EXPLAIN ANALYZE --------------------------------------
 
-def test_one_bump_per_dispatched_fragment(tpch_tk):
+def test_one_bump_per_dispatched_fragment(tpch_tk, monkeypatch):
     tk = tpch_tk
+    monkeypatch.setattr(dj, "compact_to", lambda _live, _n: None)
     tk.must_exec("set tidb_executor_engine = 'tpu'")
     tk.must_query(q5.SQL)
     tk.must_query(q3.SQL)                      # warm: no retry below

@@ -174,6 +174,7 @@ def test_both_existence_tests_share_one_unfiltered_index():
 
 def test_expand_rows_is_asked_with_the_programs_own_shapes(monkeypatch):
     tables, tk = _loaded(SEEDS[0])
+    dj._CAP_STORE.clear()
     _rows(tk, "tpu", q21.SQL)              # capacities learned
     asked = []
     orig = dj.expand_one_pass
@@ -190,11 +191,17 @@ def test_expand_rows_is_asked_with_the_programs_own_shapes(monkeypatch):
     semi, anti = _pairs(tables)
     n_lines = len(tables["lineitem"]["l_orderkey"])
     # one trace: the EXISTS test, then the NOT EXISTS test, both over
-    # l1's bucket; the capacities are the ones the counter holds
+    # l1's live rows as the cuts past its filter and the inner gathers
+    # left them; the capacities are the ones the counter holds
     assert [cap for cap, _n in asked] == [dev.next_pow2(semi),
                                          dev.next_pow2(anti)]
-    (bucket,) = {n for _cap, n in asked}
-    assert n_lines <= bucket < 2 * n_lines
+    (n_probe,) = {n for _cap, n in asked}
+    live = {k[1][1]: v for k, v in dj._CAP_STORE.items()
+            if isinstance(k[1], tuple) and k[1][0] == "live"}
+    n = dev.bucket_rows(n_lines)
+    for pos in (-1, 0, 1, 2):                  # the leaf, three gathers
+        n = dj.compact_to(live[pos], n) or n
+    assert n_probe == n < n_lines
     assert _grew(before, after, "join_residual_capacity") == [
         sum(cap for cap, _n in asked)]
 

@@ -92,13 +92,10 @@ def test_small_packed_key_aggregate_reduces_densely(tpch, monkeypatch):
     assert arms and set(arms) == {"dense"}
 
 
-def test_join_aggregate_without_compaction(tpch, monkeypatch):
-    """Q3: the join fragment aggregates at the fact length — the mask
-    the aggregate takes is as long as the probe leaf's row bucket — and
-    compile_fragment has no parameter to shorten it."""
-    import inspect
-    assert "compact_cap" not in inspect.signature(
-        device_join.compile_fragment).parameters
+def test_join_aggregate_at_the_length_its_cuts_leave(tpch, monkeypatch):
+    """Q3: the join fragment aggregates at the probe leaf's row bucket
+    until its live counts are learned, then at the length the cuts of its
+    probe path leave (device_join.compact_to: past `orders` at SF0.02)."""
     lengths = []
     orig = dev._agg_impl
 
@@ -110,7 +107,8 @@ def test_join_aggregate_without_compaction(tpch, monkeypatch):
     assert _parity(tpch, bench.QUERIES["q3"]) == ["engine:tpu"]
     n_fact = int(tpch.must_query("select count(*) from lineitem").rows[0][0])
     assert lengths and all(g for _n, g in lengths)
-    assert all(n >= n_fact for n, _g in lengths), (lengths, n_fact)
+    assert lengths[0][0] >= n_fact
+    assert lengths[-1][0] * 4 <= lengths[0][0], (lengths, n_fact)
 
 
 # -- the sort arm's group starts: one sort or a search a slot (ISSUE 36) ------

@@ -332,6 +332,10 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # binary search per slot (device_join.expand_one_pass) —
                # note_join_expansion
                "join_expand_one_pass": 0,
+               # compaction points of dispatched join fragments whose
+               # KEPT program cut the probe path's relation to its live
+               # rows (device_join.compact_to) — note_join_compactions
+               "join_compactions": 0,
                # column / mask / row-map gathers of dispatched join
                # fragments' programs, and those the program holds the
                # result of already (a leaf read in place, a NULL-free
@@ -404,6 +408,7 @@ def _tls_stats() -> dict:
                                 "join_anti": 0, "join_expand": 0,
                                 "join_residual": 0,
                                 "join_expand_one_pass": 0,
+                                "join_compactions": 0,
                                 "join_gathers": 0,
                                 "join_gathers_elided": 0,
                                 "join_probe_resident": 0,
@@ -515,6 +520,17 @@ def note_join_expansion(rows, capacity, one_pass):
     _bump("join_expand_capacity", int(capacity))
     if one_pass:
         _bump("join_expand_one_pass")
+
+
+def note_join_compactions(cuts):
+    """Count the `cuts` of one dispatched join fragment's KEPT program:
+    its compaction points whose relation the program cut to the live
+    rows (device_join.compact_to answered a capacity for them, and the
+    program was built with it).  Once per fragment, after its capacity
+    loop, traced or not; the benchmark's ``join.compactions_per_query``
+    reads the counter, and EXPLAIN ANALYZE prints ``compact:x2`` beside
+    the ``join:`` annotation."""
+    _bump("join_compactions", int(cuts))
 
 
 def note_join_residual(rows, capacity):

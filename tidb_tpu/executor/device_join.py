@@ -68,9 +68,9 @@ from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
     _plan_agg, _timed_jit, acquire_pipeline, exists_expands, join_expands,
-    note_agg_arm, note_agg_spans, note_join_expansion, note_join_gathers,
-    note_join_layouts, note_join_probe, note_join_residual, note_rerun,
-    note_semi_inset)
+    note_agg_arm, note_agg_spans, note_join_compactions, note_join_expansion,
+    note_join_gathers, note_join_layouts, note_join_probe,
+    note_join_residual, note_rerun, note_semi_inset)
 from .join_index import build_join_index
 
 
@@ -804,9 +804,68 @@ def _bucket_search(a0, probe, blo, bhi, steps, right=False):
     return lo, eq
 
 
+#: compaction's floor: a relation shorter than this keeps its length
+_COMPACT_MIN_ROWS = 1 << 16
+#: ... and one whose live rows would fill more than a quarter of it
+_COMPACT_FACTOR = 4
+
+
+def compact_to(live, n) -> "int | None":
+    """The static length a probe-path relation of `n` rows whose last run
+    held `live` live rows is cut to at a compaction point
+    (compaction_points), or None where it keeps its `n`.  Host-callable:
+    _fill_caps asks it with the learned count and the relation's static
+    length, and the dispatcher counts the kept program's cuts by what it
+    answered (device_exec.note_join_compactions).
+
+    A cut is one unstable single-operand sort of the rows' positions at
+    length `n` (5.7 ms at 8,388,608 rows on the v5e, ops/device
+    _group_spans' one-pass side) and one gather a surviving row map or
+    null map at the cut's length; every later lookup, column gather,
+    existence test and the aggregate then run at that length, where each
+    was a gather of 60-72 ms at `n`.  So a cut is taken from
+    _COMPACT_MIN_ROWS rows up wherever next_pow2(live) is at most
+    1 / _COMPACT_FACTOR of `n`: TPC-H Q3's 151,000 rows after `orders`
+    in the 8,388,608-row bucket go to 262,144, Q4's 57,000 orders to
+    65,536 of 2,097,152; Q13's 150,000 customers of 262,144 stay."""
+    n = int(n)
+    if live is None or n < _COMPACT_MIN_ROWS:
+        return None
+    cap = dev.next_pow2(max(int(live), 1))
+    return cap if cap * _COMPACT_FACTOR <= n else None
+
+
+def _probe_child(node):
+    """The child whose rows a join's output is indexed by: its probe, and
+    never a host-indexed build (a build leaf's index addresses its own
+    rows, so its relation must keep them)."""
+    st = node.strategy
+    return node.right if st is not None and st[1] == "left" else node.left
+
+
+def compaction_points(root) -> dict:
+    """{id(node): pos} of the relations a fragment may compact, in the
+    order the program evaluates them: the probe leaf after its filter
+    (pos -1), then every probe-shaped step on the probe path up to the
+    root (pos = the join's): an inner or left join on the `uniq` arm, a
+    semi or anti join (a residual existence test among them).  An
+    expansion, a build side and a subtree beside the probe path are
+    never one."""
+    path = [root]
+    while isinstance(path[-1], _JoinNode):
+        path.append(_probe_child(path[-1]))
+    points = {id(path[-1]): -1}
+    for jn in reversed(path[:-1]):
+        st = jn.strategy
+        if st is not None and (st[0] == "uniq"
+                               or jn.kind in ("semi", "anti")):
+            points[id(jn)] = jn.pos
+    return points
+
+
 def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                      capacity, key_pack, agg_meta, nonnull, raw_tail=False,
-                     strategies=None, program=None):
+                     strategies=None, program=None, compact=None):
     """Build the jitted end-to-end program. caps: per-join static
     capacities aligned with `joins`. Returns jitted fn(env, jidx, n_lives)
     where env is {global_col: (data, nulls)} and jidx is a per-join tuple
@@ -826,9 +885,9 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
     filter, probe a join, or reach the aggregate. Traced scalars: a
     within-bucket row-count change re-dispatches without recompiling.
 
-    The aggregate runs at the fact length: the fragment's output is
-    fact-shaped with a sparse validity mask (the price of the gather-join
-    design).
+    The aggregate runs at the fact length unless the relation was cut
+    (`compact`, below): the fragment's output is fact-shaped with a
+    sparse validity mask (the price of the gather-join design).
 
     The gather chain emits a gather only where its result can differ from
     what the program already holds.  A leaf's row map starts as the
@@ -847,7 +906,20 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
     Its one user is the hybrid join (hybrid_join.py), which aggregates
     the rows of its device partitions and of its host partitions together
     in numpy on every backend; the join/filter/expression work stays
-    fused in the program."""
+    fused in the program.
+
+    compact: ((pos, cap or None), ...) for every compaction point
+    (compaction_points, in its order), as _fill_caps decided them; only
+    device_join_agg passes it.  At each point the program counts the
+    relation's live rows, and where `cap` is given it cuts the relation
+    to its first `cap` live rows: their positions, in ascending order,
+    become one more index of every row map (rows_of composes it as an
+    expansion's `pi`), the null maps are gathered through them, and the
+    rows past the live count are invalid.  Everything after the point
+    runs at `cap`.  A cut never drops a row unseen: the live counts
+    follow the joins' totals in the returned overflows, and a count past
+    its `cap` makes the caller run again.  The returned fn carries
+    `compacted`: the positions of the points the trace cut."""
     for jn, cap in zip(joins, caps):
         jn.cap = cap
     if strategies is None:
@@ -892,9 +964,13 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                for leaf in leaves for i in range(leaf.ncols)}
     key_fns, val_plan, agg_ops, slots = agg_meta
     gathers = {}
+    points = compaction_points(root) if compact is not None else {}
+    cut_at = dict(compact or ())
+    compacted = []
 
     def run(env, jidx, n_lives):
         _count_trace()
+        compacted.clear()
 
         # env keyed by global column index → (data, nulls) on device
         def leaf_rel(leaf):
@@ -1154,19 +1230,45 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                 pair.astype(jnp.int32), indices_are_sorted=True)
             return hit > 0, total
 
+        lives = {}
+
+        @jax.named_scope("k_join_probe")
+        def at_point(node, idxmap, valid, nullmaps):
+            """The relation past a compaction point: counted, and cut to
+            the point's capacity where the caller gave one."""
+            pos = points.get(id(node))
+            if pos is None:
+                return idxmap, valid, nullmaps
+            with jax.named_scope("k_join_compact"):
+                lives[pos] = total = jnp.sum(valid)
+                cap = cut_at[pos]
+                if cap is None:
+                    return idxmap, valid, nullmaps
+                compacted.append(pos)
+                n = valid.shape[0]
+                pos_dt = jnp.int32 if n < (1 << 31) else jnp.int64
+                # the live rows' positions, ascending, the dead ones' n
+                # behind them: one single-operand sort, unstable as
+                # _group_spans' (the equal entries are all n)
+                keyed = jnp.where(valid, jnp.arange(n, dtype=pos_dt),
+                                  jnp.asarray(n, dtype=pos_dt))
+                p = jnp.minimum(jnp.sort(keyed, stable=False)[:cap], n - 1)
+                return ({lid: v + (p,) for lid, v in idxmap.items()},
+                        jnp.arange(cap) < total,
+                        {lid: nl[p] for lid, nl in nullmaps.items()})
+
         def eval_node(node):
             if isinstance(node, _Leaf):
                 idxmap, mask = leaf_rel(node)
-                return idxmap, mask, {}
+                return at_point(node, idxmap, mask, {})
             # children always evaluate left-then-right so the overflow
             # list order matches the `joins` list (postorder walk)
             lidx, lvalid, lnull = eval_node(node.left)
             ridx, rvalid, rnull = eval_node(node.right)
             if strategies[node.pos] is not None:
+                # a left join's residual is folded into its match already
                 idxmap, valid, nullmaps = eval_indexed(
                     node, lidx, lvalid, lnull, ridx, rvalid, rnull)
-                if node.kind == "left":
-                    return idxmap, valid, nullmaps  # conds folded already
             else:
                 if node.kind != "inner":
                     raise DeviceUnsupported(
@@ -1198,9 +1300,11 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
                     for f in node._oc_fns:
                         d, nl = f(jenv)
                         valid = valid & (d != 0) & ~nl
-            return idxmap, valid, nullmaps
+            return at_point(node, idxmap, valid, nullmaps)
 
         idxmap, valid, nullmaps = eval_node(root)
+        # the points' live counts ride behind the joins' totals
+        overflows.extend(lives[pos] for pos, _cap in compact or ())
         fenv = gather_env(idxmap, top_cols, nullmaps)
         with jax.named_scope("k_filter"):
             mask = valid
@@ -1243,6 +1347,7 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
 
     fn = (program or _timed_jit)(run)
     fn.gathers = gathers  # filled by the trace: note_join_gathers
+    fn.compacted = compacted
     return fn
 
 
@@ -1254,7 +1359,7 @@ def _shift_expr(e, offset):
         lambda c: ExprColumn(c.idx + offset, c.ftype, name=c.name))
 
 
-def _fill_caps(node, sig):
+def _fill_caps(node, sig, points=None, cuts=None):
     """Bottom-up static output capacities. Unique-indexed joins inherit
     the probe side's capacity exactly. Expansion joins take (in order):
     the retry-adjusted/learned size, a stats-free estimate from the build
@@ -1263,15 +1368,22 @@ def _fill_caps(node, sig):
     LARGER input, composed bottom-up over RAW leaf sizes. Estimates
     deliberately overshoot: undershoot costs a full recompile (minutes
     for a deep fragment), overshoot only pads the kernels; the learned
-    store tightens the shapes from the second compile on."""
+    store tightens the shapes from the second compile on.
+
+    points / cuts: compaction_points(root), and a dict this fills with
+    each point's cut (compact_to over the live count the store learned
+    for it and the relation's length there; None = kept whole).  A node
+    returns the length its relation has after its cut: what the node
+    above it, an expansion's probe and the aggregate read."""
     if isinstance(node, _Leaf):
         # BUCKET space, not the live row count: probe-shaped capacities
         # flow into the compiled program's static shapes and the pipeline
         # cache key, and must stay stable across within-bucket deltas
-        return node.bucket or node.chunk.num_rows
+        return _cut(node, node.bucket or node.chunk.num_rows, sig, points,
+                    cuts)
 
-    lc = _fill_caps(node.left, sig)
-    rc = _fill_caps(node.right, sig)
+    lc = _fill_caps(node.left, sig, points, cuts)
+    rc = _fill_caps(node.right, sig, points, cuts)
     st = node.strategy
     exists = exists_expands(node)
     if not exists and (node.kind in ("semi", "anti") or (
@@ -1279,7 +1391,7 @@ def _fill_caps(node, sig):
         # probe-shaped: semi/anti are existence counts; uniq is a gather
         node.cap = lc if (node.kind != "inner"
                           or st[1] == "right") else rc
-        return node.cap
+        return _cut(node, node.cap, sig, points, cuts)
     # the in-program expansion probes with its left side
     node.probe_cap = rc if st is not None and st[1] != "right" else lc
     if node.exp_cap is None:
@@ -1304,7 +1416,17 @@ def _fill_caps(node, sig):
             node.exp_cap = dev.next_pow2(fk_est(node))
     node.cap = node.exp_cap
     # an existence test's pairs reduce back to its probe's rows
-    return lc if exists else node.cap
+    return _cut(node, lc, sig, points, cuts) if exists else node.cap
+
+
+def _cut(node, n, sig, points, cuts):
+    """_fill_caps' length of `node`'s relation past its compaction point,
+    where it is one; `n` before it."""
+    pos = (points or {}).get(id(node))
+    if pos is None:
+        return n
+    cuts[pos] = compact_to(_CAP_STORE.get((sig, ("live", pos))), n)
+    return cuts[pos] or n
 
 
 def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
@@ -1433,14 +1555,17 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     key_fns, key_meta, key_pack, val_plan, agg_ops, slots = agg_meta_full
     agg_meta = (key_fns, val_plan, agg_ops, slots)
     n_lives = tuple(np.int64(leaf.chunk.num_rows) for leaf in leaves)
-    nonnull = nonnull_cols(
-        root, leaves, _fragment_used_cols(leaves, joins, agg_plan, agg_conds))
+    used = _fragment_used_cols(leaves, joins, agg_plan, agg_conds)
 
     sig = fragment_sig(leaves, joins, agg_conds, agg_plan)
     dict_refs = tuple(dc.dictionary for dc in dcols.values()
                       if dc.dictionary is not None)
 
-    n_frag = _fill_caps(root, sig)
+    # the probe path's compaction points: each cut to the live count its
+    # last run learned (_fill_caps), counted by every run
+    points = compaction_points(root)
+    cuts = {}
+    n_frag = _fill_caps(root, sig, points, cuts)
     learned_ng = _CAP_STORE.get((sig, "agg"))
     if learned_ng is not None:
         capacity = dev.next_pow2(max(learned_ng, 16))
@@ -1453,16 +1578,21 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     note_join_probe(resident=True)
     for _attempt in range(12):
         caps = [jn.cap for jn in joins]
+        compact = tuple((pos, cuts[pos]) for pos in points.values())
+        # a program that cuts gathers the leaf it would read in place
+        nonnull = nonnull_cols(root, leaves, used, compacts=any(
+            cut for _pos, cut in compact))
         key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
-               nonnull)
+               nonnull, compact)
 
-        def build(caps=tuple(caps), cap=capacity):
+        def build(caps=tuple(caps), cap=capacity, compact=compact,
+                  nonnull=nonnull):
             # the leaves/joins/plan objects are OWNED by this execution;
             # when the compile service defers this builder to a worker the
             # query has already degraded to host, so nothing mutates them
             return compile_fragment(root, leaves, joins, agg_plan,
                                     agg_conds, list(caps), cap, key_pack,
-                                    agg_meta, nonnull)
+                                    agg_meta, nonnull, compact=compact)
         fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
                               args=(env, jidx, n_lives), shape="join",
                               sig=sig)
@@ -1474,12 +1604,21 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         f = AggFetch(agg_out, extras=(ovf_d, sovf_d, kept_d),
                      topn=resolve_topn(agg_plan, slots))
         overflows, span_ovfs, kept = f.extras
+        lives = [int(v) for v in overflows[len(joins):]]
+        overflows = overflows[:len(joins)]
         kept = int(kept)
         ng = f.ng
         if any(bool(s) for s in span_ovfs):
             raise DeviceUnsupported(
                 "multi-key join value ranges exceed int64 packing")
         retry = False
+        for (pos, cut), live in zip(compact, lives):
+            # a cut that held fewer rows than were live dropped some (a
+            # count past it downstream is a lower bound): run again at the
+            # size the count asks; a point that could cut and did not
+            # learns it for the next execution, which recompiles anyway
+            retry |= cut is not None and live > cut
+            _cap_store_put((sig, ("live", pos)), live)
         for jn, total in zip(joins, overflows):
             if not exists_expands(jn) and (
                     jn.kind in ("semi", "anti") or (
@@ -1511,14 +1650,15 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
             retry = True
         _cap_store_put((sig, "agg"), ng)
         if retry:
-            n_frag = _fill_caps(root, sig)
+            n_frag = _fill_caps(root, sig, points, cuts)
             note_rerun("join", capacity, ng,
                        caps=[int(jn.cap) for jn in joins], kept=kept,
-                       totals=[int(o) for o in overflows])
+                       totals=[int(o) for o in overflows], lives=lives)
             continue
         break
     else:
         raise DeviceUnsupported("join fragment capacities did not converge")
+    note_join_compactions(sum(cut is not None for _pos, cut in compact))
     for jn, total in zip(joins, overflows):
         if join_expands(jn):
             note_join_expansion(total, jn.cap,
@@ -1685,19 +1825,20 @@ def _inplace_leaf(root):
         if st is None or (st[0] != "uniq"
                           and node.kind not in ("semi", "anti")):
             return None
-        node = node.left if st[1] == "right" else node.right
+        node = _probe_child(node)
     return node
 
 
-def nonnull_cols(root, leaves, used) -> tuple:
+def nonnull_cols(root, leaves, used, compacts=False) -> tuple:
     """Global indices (sorted) of the fragment's `used` columns that the
     host knows hold no NULL and whose mask the program would otherwise
     gather: compile_fragment's `nonnull`, and an element of the pipeline
     key of everyone who calls it (a column's first NULL must find a new
-    program).  The leaf read in place needs no fact, and a paged (memmap)
-    column is never scanned for one."""
+    program).  The leaf read in place needs no fact unless the caller
+    `compacts` its probe path (a cut gathers that leaf too), and a paged
+    (memmap) column is never scanned for one."""
     from ..storage.paged import is_paged
-    inplace = _inplace_leaf(root)
+    inplace = None if compacts else _inplace_leaf(root)
     return tuple(sorted(
         leaf.offset + i for leaf in leaves if leaf is not inplace
         for i, c in enumerate(leaf.chunk.columns)

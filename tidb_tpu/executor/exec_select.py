@@ -135,6 +135,11 @@ class QueryExecutor:
             if beside:
                 note += f" ({beside})"
             self.annotate(join=note or None)
+            # beside them, the cuts of the probe path's relation to its
+            # live rows in the kept program (note_join_compactions):
+            # compact:x2
+            n_cut = st1["join_compactions"] - st0["join_compactions"]
+            self.annotate(compact=f"x{n_cut}" if n_cut else None)
             # the join fragment's column / mask / row-map gathers, and
             # (-n) those its program elides
             # (device_exec.note_join_gathers): gathers:4 (-15)
