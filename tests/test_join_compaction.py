@@ -10,9 +10,14 @@ expansion after a cut) the cut program answers as the uncut one and the
 host engine; a cut exactly at the live count, no live row at all and a
 within-bucket append that overflows the learned cut (the fragment runs
 again, exactly); the rule at the benchmark's shapes; the counter, the
-``EXPLAIN ANALYZE`` note and the trace agree.  The tables are a few
-thousand rows, so the tests lower the rule's floor; the benchmark's own
-shapes go through the rule unpatched.
+``EXPLAIN ANALYZE`` note and the trace agree.  The paged join cuts its
+one program's probe path where the largest live count of any page
+leaves few rows: a star with a filtered leaf and one with a filtered
+dimension by resident and by sent pages, pages with no live row, a page
+past its cut (the turn restarts, exactly), the aggregate at the cut's
+length, the counter once a fragment.  The tables are a few thousand
+rows, so the tests lower the rule's floor and the whole-input bound; the
+benchmark's own shapes go through the rule unpatched.
 """
 
 import json
@@ -228,6 +233,11 @@ def test_an_append_past_the_cut_runs_again_exactly(low_floor):
     (100, 65_535, None),               # under the floor
     (100, 65_536, 128),
     (None, 8_388_608, None),           # nothing learned yet
+    # SSB SF10 by pages of 4,194,304 lineorder rows
+    (549_000, 4_194_304, 1_048_576),   # Q1.1's filter on lineorder
+    (83_500, 1_048_576, 131_072),      # ... then d_year = 1993
+    (33_600, 1_048_576, 65_536),       # Q2.1's p_category past s_region
+    (67_000, 262_144, None),           # Q4.1's p_mfgr past both regions
 ])
 def test_the_rule_at_the_benchmarks_shapes(live, n, cut):
     assert dj.compact_to(live, n) == cut
@@ -280,6 +290,198 @@ def test_the_counter_the_note_and_the_trace_agree(tk, monkeypatch,
     tk.must_exec("set tidb_executor_engine = 'host'")
     assert notes == ["join:direct x1 (semi x1, residual x1)",
                      f"compact:x{cuts}"]
+
+
+# -- the paged join: every page runs one program, cut where the pages were ----
+
+_PAGE = 1024                        # four pages of f's 4,000 rows
+#: above d's columns and its slot table, below the probe's at its bucket:
+#: the pages are cut from the host's columns and sent
+_SMALL_BUDGET = 60_000
+
+#: (sql, cuts of the settled program), each a star by pages
+_STARS = {
+    # f's filter keeps 1 row in 15 (69 of a page at most: a cut of 128),
+    # d.w < 3 about a third of them again (21: 32)
+    "filtered_leaf": ("select d.c, count(*), sum(f.v), sum(d.w) from f "
+                      "join d on f.k = d.k where f.s = 0 and f.g = 1 "
+                      "and d.w < 3 group by d.c order by d.c", 2),
+    # every row of f is live until d.w = 3 keeps 7 keys of 100
+    "filtered_dim": ("select d.c, count(*), sum(f.v) from f join d "
+                     "on f.k = d.k where d.w = 3 group by d.c order by d.c",
+                     1),
+    # Q1.x's shape: no group key, and only the last page holds a live row
+    "global": ("select sum(f.v * d.w), count(*) from f join d "
+               "on f.k = d.k where f.v >= 3900 and d.c = 1", 2),
+}
+
+
+@pytest.fixture()
+def paged(monkeypatch):
+    """A probe past the whole-input bound (lowered to 1,000 rows) runs
+    by pages of `_PAGE` rows."""
+    monkeypatch.setattr(device_exec, "_SORTED_SCAN_MAX_ROWS", 1000)
+    monkeypatch.setattr(dj, "_PROBE_PAGE_ROWS", _PAGE)
+
+
+@pytest.fixture(params=["resident", "sent"])
+def pages_from(request, tk):
+    """Where the pages come from: the leaf's resident columns, or the
+    host's, under a budget the probe does not fit."""
+    from tidb_tpu.ops import residency
+    if request.param == "sent":
+        tk.must_exec(f"set global tidb_device_mem_budget = {_SMALL_BUDGET}")
+    yield request.param
+    tk.must_exec("set global tidb_device_mem_budget = 0")
+    residency.set_budget(0)
+
+
+@pytest.mark.parametrize("star", list(_STARS))
+def test_a_paged_star_answers_as_uncut_and_host(tk, monkeypatch, low_floor,
+                                                paged, pages_from, star):
+    sql, cuts = _STARS[star]
+    want = _rows(tk, "host", sql)
+    assert want and want != [(None, "0")], "an empty answer proves nothing"
+    # the first execution learns the pages' largest live counts, the
+    # second cuts every page at them
+    assert _rows(tk, "tpu", sql) == want
+    before = _pipelines(tk)
+    assert _rows(tk, "tpu", sql) == want
+    after = _pipelines(tk)
+    assert _grew(before, after, "join_compactions", "capacity_reruns",
+                 "compiles", "join_probe_" + pages_from) == [cuts, 0, 1, 1]
+    assert dj.LAST_PAGED_STATS.stats["pages"] > 1
+    # uncut: the rule answers "keep" everywhere, the program counts only
+    monkeypatch.setattr(dj, "compact_to", lambda _live, _n: None)
+    _drop_compiled()
+    assert _rows(tk, "tpu", sql) == want
+    before = _pipelines(tk)
+    assert _rows(tk, "tpu", sql) == want
+    assert _grew(before, _pipelines(tk), "join_compactions") == [0]
+
+
+def test_a_paged_global_aggregate_with_whole_pages_dead(tk, low_floor,
+                                                        paged):
+    """Three of the four pages hold no live row: the largest count is
+    the last page's, and the empty pages' states merge to nothing."""
+    sql, cuts = _STARS["global"]
+    assert _rows(tk, "tpu", sql) == _rows(tk, "host", sql)
+    sig = next(k[0] for k in dj._CAP_STORE if k[1] == ("live", -1))
+    assert dj._CAP_STORE[(sig, ("live", -1))] == 100
+    assert dj.compact_to(100, _PAGE) == 128
+    before = _pipelines(tk)
+    assert _rows(tk, "tpu", sql) == _rows(tk, "host", sql)
+    assert _grew(before, _pipelines(tk), "join_compactions") == [cuts]
+
+
+@pytest.mark.parametrize("sent", [False, True])
+def test_a_page_past_its_cut_restarts_the_turn_exactly(low_floor, paged,
+                                                       sent):
+    """An append gives one page more live rows than the learned cut: the
+    turn stops at that page's fetch, restarts from the first page at the
+    count's size, and the answer is the host's."""
+    from tidb_tpu.ops import residency
+    tk = TestKit()
+    tk.must_exec("create table a (id bigint, k bigint, v bigint)")
+    tk.must_exec("create table b (k bigint, c bigint)")
+    i = np.arange(3000)
+    tk.must_exec("insert into a values " + _values(zip(i, i % 50, i)))
+    tk.must_exec("insert into b values " + _values(
+        (k, k % 3) for k in range(50)))
+    tk.must_exec("set tidb_device_dispatch_rows = 1")
+    tk.must_exec("set tidb_result_cache = 'OFF'")
+    if sent:
+        tk.must_exec(f"set global tidb_device_mem_budget = {_SMALL_BUDGET}")
+    sql = ("select b.c, count(*), sum(a.v) from a join b on a.k = b.k "
+           "where a.v < 100 group by b.c order by b.c")
+    try:
+        # the first page holds all 100 live rows: a cut of 128
+        assert _rows(tk, "tpu", sql) == _rows(tk, "host", sql)
+        before = _pipelines(tk)
+        assert _rows(tk, "tpu", sql) == _rows(tk, "host", sql)
+        assert _grew(before, _pipelines(tk), "join_compactions",
+                     "join_probe_" + ("sent" if sent else "resident")) == [
+                         1, 1]
+        # 250 more live rows: 178 of them on the last 1,024-row page, all
+        # on the last 2,048-row one (a sent page here), a cut of 256
+        tk.must_exec("insert into a values " + _values(
+            (3000 + j, j % 50, j % 100) for j in range(250)))
+        want = _rows(tk, "host", sql)
+        before = _pipelines(tk)
+        assert _rows(tk, "tpu", sql) == want
+        after = _pipelines(tk)
+        assert _grew(before, after, "capacity_reruns",
+                     "join_compactions") == [1, 1]
+        assert _rows(tk, "tpu", sql) == want
+        assert _grew(after, _pipelines(tk), "capacity_reruns") == [0]
+    finally:
+        tk.must_exec("set global tidb_device_mem_budget = 0")
+        residency.set_budget(0)
+        _drop_compiled()
+
+
+def test_the_paged_aggregate_reads_the_cut_length(tk, monkeypatch,
+                                                  low_floor, paged):
+    """The partial aggregate's input, its capacity and the one-pass
+    question (note_agg_spans) all read the length the last cut leaves,
+    and an estimated capacity is capped at it."""
+    sql = ("select f.id, sum(d.w) from f join d on f.k = d.k "
+           "where f.s = 0 and f.g = 1 and d.w < 3 group by f.id "
+           "order by f.id")
+    want = _rows(tk, "host", sql)
+    assert _rows(tk, "tpu", sql) == want
+    aggs, spans = [], []
+    orig_agg, orig_spans = dj.dev._agg_impl, dj.note_agg_spans
+
+    def agg(*a, **kw):
+        if kw.get("gathered"):
+            aggs.append((a[4].shape[0], kw["capacity"]))
+        return orig_agg(*a, **kw)
+
+    def note(pack, agg_ops, capacity, n, **kw):
+        spans.append((capacity, n))
+        return orig_spans(pack, agg_ops, capacity, n, **kw)
+    monkeypatch.setattr(dj.dev, "_agg_impl", agg)
+    monkeypatch.setattr(dj, "note_agg_spans", note)
+    assert _rows(tk, "tpu", sql) == want
+    sig = next(k[0] for k in dj._CAP_STORE if k[1] == ("live", 0))
+    cut = dj.compact_to(dj._CAP_STORE[(sig, ("live", 0))], 128)
+    assert cut == 32
+    assert aggs == [(cut, cut)] and spans == [(cut, cut)]
+    # the group count forgotten, the estimate (f.id: a group a row) is
+    # capped at the cut and not at the page
+    del dj._CAP_STORE[(sig, "agg")]
+    spans.clear()
+    assert _rows(tk, "tpu", sql) == want
+    assert spans == [(cut, cut)]
+
+
+def test_the_paged_counter_the_note_and_the_trace_agree(tk, monkeypatch,
+                                                        low_floor, paged):
+    """`device_pipelines.join_compactions` counts the KEPT program's cuts
+    (`fn.compacted`) once a fragment, whatever its pages, and EXPLAIN
+    ANALYZE prints them beside the pages."""
+    sql, cuts = _STARS["filtered_leaf"]
+    built = []
+    orig = dj.compile_fragment
+
+    def spy(*a, **kw):
+        fn = orig(*a, **kw)
+        built.append(fn)
+        return fn
+    monkeypatch.setattr(dj, "compile_fragment", spy)
+    _rows(tk, "tpu", sql)
+    before = _pipelines(tk)
+    _rows(tk, "tpu", sql)
+    grew, = _grew(before, _pipelines(tk), "join_compactions")
+    assert grew == cuts == len(built[-1].compacted)
+    assert built[-1].compacted == [-1, 0]
+    assert dj.LAST_PAGED_STATS.stats["pages"] == 4
+    tk.must_exec("set tidb_executor_engine = 'tpu'")
+    notes = (_notes(tk, sql, "compact:") + _notes(tk, sql, "pages:")
+             + _notes(tk, sql, "probe:"))
+    tk.must_exec("set tidb_executor_engine = 'host'")
+    assert notes == [f"compact:x{cuts}", "pages:4", "probe:resident"]
 
 
 # -- join.compactions_per_query: the reader and its entry --------------------

@@ -75,6 +75,44 @@ def test_reference_host_and_tpu_agree(template, seed):
     assert scans == ["fused:into tpu fragment"] * (n_dims + 1)
 
 
+#: the cell's four flights by pages of 8,192 rows, as SF10's 60M rows run
+#: by pages of 4,194,304, and the cuts of each one's settled program at
+#: SF0.01 (the compaction rule's floor lowered to 1,024 rows): Q1.1 past
+#: lineorder's filter and past `d_year`, Q2.1 past `part`, Q3.1 past
+#: `supplier` and `customer`, Q4.1 past `customer`
+_FLIGHTS = {"ssb_q1_1": 2, "ssb_q2_1": 1, "ssb_q3_1": 2, "ssb_q4_1": 1}
+
+
+@pytest.mark.parametrize("template", list(_FLIGHTS))
+def test_the_flights_by_pages_cut_where_the_pages_were(template,
+                                                       monkeypatch):
+    import json
+    import tidb_tpu.executor.device_join as dj
+    from tidb_tpu.executor import device_exec
+    monkeypatch.setattr(device_exec, "_SORTED_SCAN_MAX_ROWS", 10_000)
+    monkeypatch.setattr(dj, "_PROBE_PAGE_ROWS", 8192)
+    monkeypatch.setattr(dj, "_COMPACT_MIN_ROWS", 1024)
+    mod = MODS[template]
+    tables, tk = _loaded(SEEDS[0], 0.01)
+    want = mod.reference(tables)
+
+    def cuts():
+        return json.loads(tk.must_query("DIAG STATUS").rows[0][0])[
+            "device_pipelines"]["join_compactions"]
+    try:
+        # the first execution learns the pages' live counts, the second
+        # cuts every page's program at the largest of them
+        assert _rows(tk, "tpu", mod.SQL) == want
+        before = cuts()
+        assert _rows(tk, "tpu", mod.SQL) == want
+        assert cuts() - before == _FLIGHTS[template]
+        assert dj.LAST_PAGED_STATS.stats["pages"] == -(
+            -len(tables["lineorder"]["lo_orderkey"]) // 8192)
+    finally:
+        dj._CAP_STORE.clear()
+        device_exec._PIPE_CACHE.clear()
+
+
 @pytest.mark.parametrize("template", TEMPLATES)
 def test_another_seed_gives_other_answers(template):
     mod = MODS[template]

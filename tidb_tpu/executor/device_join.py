@@ -909,9 +909,10 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
     fused in the program.
 
     compact: ((pos, cap or None), ...) for every compaction point
-    (compaction_points, in its order), as _fill_caps decided them; only
-    device_join_agg passes it.  At each point the program counts the
-    relation's live rows, and where `cap` is given it cuts the relation
+    (compaction_points, in its order), as _fill_caps decided them;
+    device_join_agg and _paged_join_agg pass it.  At each point the
+    program counts the relation's live rows, and where `cap` is given it
+    cuts the relation
     to its first `cap` live rows: their positions, in ascending order,
     become one more index of every row map (rows_of composes it as an
     expansion's `pi`), the null maps are gathered through them, and the
@@ -1926,7 +1927,15 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
     memory is bounded by page + buffered partials + merge state — never
     the fact table. This is the engine's cop-paging analog (reference
     kv/kv.go:349-350: the coprocessor streams a large scan in pages; here
-    each page carries the whole join+agg fragment with it)."""
+    each page carries the whole join+agg fragment with it).
+
+    The program cuts its probe path as the whole-input fragment's does
+    (compaction_points, compact_to), at the page's static length: where
+    the largest live count any page of the last kept turn had at a point
+    leaves few rows, every page is cut there.  Each page's live counts
+    come back with the pages' group counts, in the same fetch; a page
+    whose count passes its cut restarts the turn from the first page at
+    the new size, as a group count past the capacity does."""
     if any(jn.strategy is None or jn.strategy[0] != "uniq" for jn in joins):
         raise DeviceUnsupported("paged probe requires all-unique builds")
     # planning view is metadata-only for EVERY leaf: the only uploads are
@@ -1943,7 +1952,6 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
     agg_meta = (key_fns, val_plan, agg_ops, slots)
 
     used = _fragment_used_cols(leaves, joins, agg_plan, agg_conds)
-    nonnull = nonnull_cols(root, leaves, used)
     # leaf_rel reads each leaf's row count off its first env entry — keep
     # at least one column per leaf alive
     for leaf in leaves:
@@ -1994,12 +2002,19 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
     n = probe.chunk.num_rows
     n_keys = max(len(key_fns), 1)
     nvals = len(val_plan)
+    # every page runs one program at the page's static length, and its
+    # probe path is cut where the live counts the pages of the last kept
+    # turn learned (the largest of any page) leave few rows
+    probe.bucket = page_rows
+    points = compaction_points(root)
+    cuts = {}
+    n_frag = _fill_caps(root, sig, points, cuts)
     learned = _CAP_STORE.get((sig, "agg"))
     if learned is not None:
         capacity = dev.next_pow2(max(learned, 16))
     else:
-        est = _estimate_groups(agg_plan, min(n, page_rows), ctx)
-        capacity = dev.next_pow2(min(page_rows, max(est, 16)))
+        est = _estimate_groups(agg_plan, min(n, n_frag), ctx)
+        capacity = dev.next_pow2(min(n_frag, max(est, 16)))
     learned_total = _CAP_STORE.get((sig, "groups"))
     merge_cap = dev.next_pow2(max(learned_total or capacity, 16))
 
@@ -2016,30 +2031,37 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
         return merge_partial_states(state, buffered, merge_cap, n_keys,
                                     nvals, merge_ops, key_pack)
 
-    for jn in joins:
-        jn.cap = page_rows  # every join is a probe-shaped gather
     note_join_layouts(joins)
     note_agg_arm(key_pack, agg_ops, gathered=True)
     note_join_probe(resident)
-    for _attempt in range(4):
-        caps = [page_rows] * len(joins)
+    for _attempt in range(12):
+        # every join is a probe-shaped gather at its probe's length
+        caps = [jn.cap for jn in joins]
+        compact = tuple((pos, cuts[pos]) for pos in points.values())
+        # a program that cuts gathers the leaf it would read in place
+        nonnull = nonnull_cols(root, leaves, used, compacts=any(
+            cut for _pos, cut in compact))
         key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
-               nonnull, "paged")
+               nonnull, "paged", compact)
 
-        def build(caps=tuple(caps), cap=capacity):
+        def build(caps=tuple(caps), cap=capacity, compact=compact,
+                  nonnull=nonnull):
             return compile_fragment(root, leaves, joins, agg_plan,
                                     agg_conds, list(caps), cap, key_pack,
-                                    agg_meta, nonnull)
+                                    agg_meta, nonnull, compact=compact)
         # per-page env is assembled inside the loop below, so there is no
         # whole-call arg spec to record: the paged fragment compiles sync
         # (still breaker-guarded + persisted through the compile service)
         fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
                               shape="join", sig=sig)
-        note_agg_spans(key_pack, agg_ops, capacity, page_rows, gathered=True)
+        note_agg_spans(key_pack, agg_ops, capacity, n_frag, gathered=True)
         k_flush = max(1, _MERGE_BUDGET_ROWS // capacity)
         state = None
         buffered = []
+        # each page's live counts at the points, beside its partial state
+        buffered_lives = []
         max_ng = 0
+        max_lives = [0] * len(compact)
         overflow = False
         pages = 0
         if resident:
@@ -2052,38 +2074,55 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
             env = {**env_dim, **(
                 cut_pages.popleft() if resident
                 else _stream_block(probe_host, lo, hi, page_rows))}
-            agg_out, _ovf, _sovf, _kept = fn(env, jidx, page_lives(hi, lo))
+            agg_out, ovf, _sovf, _kept = fn(env, jidx, page_lives(hi, lo))
             if _attempt == 0 and lo == 0:
                 note_join_gathers(fn)
             pages += 1
             buffered.append(agg_out)
-            if len(buffered) >= k_flush:
-                ngs = [int(g) for g in
-                       _fetch(lambda: [p[4] for p in buffered])]
-                max_ng = max(max_ng, *ngs)
-                if max_ng > capacity:
-                    overflow = True
-                    break
-                state, merge_cap = merge_flush(state, buffered, merge_cap)
-                buffered = []
-        if not overflow and buffered:
-            ngs = [int(g) for g in _fetch(lambda: [p[4] for p in buffered])]
-            max_ng = max(max_ng, *ngs)
-            if max_ng <= capacity:
-                state, merge_cap = merge_flush(state, buffered, merge_cap)
-                buffered = []
-        if overflow or max_ng > capacity:
-            # a page's group count exceeded the partial capacity: restart
-            # the pass at the observed size (remembered, so the discovery
-            # restart happens once per fragment ever)
-            capacity = dev.next_pow2(max_ng)
-            _cap_store_put((sig, "agg"), max_ng)
-            note_rerun("join.paged", capacity, max_ng, pages=pages)
+            # the points' live counts ride behind the joins' totals
+            buffered_lives.append(ovf[len(joins):])
+            if len(buffered) < k_flush and hi < n:
+                continue
+            # the group counts and the live counts in one round trip
+            ngs, lives = _fetch(
+                lambda: ([p[4] for p in buffered], buffered_lives))
+            max_ng = max(max_ng, *(int(g) for g in ngs))
+            for page in lives:
+                max_lives = [max(m, int(v)) for m, v in zip(max_lives, page)]
+            # a cut that held fewer rows than a page had live dropped some
+            # (a count past it downstream is a lower bound)
+            overflow = max_ng > capacity or any(
+                cut is not None and live > cut
+                for (_pos, cut), live in zip(compact, max_lives))
+            if overflow:
+                break
+            state, merge_cap = merge_flush(state, buffered, merge_cap)
+            buffered, buffered_lives = [], []
+        if overflow:
+            # a page's group count exceeded the partial capacity, or its
+            # live rows a cut: restart the pass from the first page at the
+            # observed sizes (remembered, so the discovery restart happens
+            # once per fragment ever); the pages not run keep what the
+            # last kept turn learned
+            for (pos, _cut), live in zip(compact, max_lives):
+                _cap_store_put((sig, ("live", pos)), max(
+                    live, _CAP_STORE.get((sig, ("live", pos))) or 0))
+            if max_ng > capacity:
+                capacity = dev.next_pow2(max_ng)
+                _cap_store_put((sig, "agg"), max_ng)
+            n_frag = _fill_caps(root, sig, points, cuts)
+            note_rerun("join.paged", capacity, max_ng, pages=pages,
+                       lives=max_lives)
             continue
+        # the largest live count of any page: the cut every page's
+        # program can take on the next execution
+        for (pos, _cut), live in zip(compact, max_lives):
+            _cap_store_put((sig, ("live", pos)), live)
         _cap_store_put((sig, "agg"), max(max_ng, 1))
         break
     else:
         raise DeviceUnsupported("paged fragment capacity did not converge")
+    note_join_compactions(sum(cut is not None for _pos, cut in compact))
     if state is None:
         raise DeviceUnsupported("empty paged fragment input")
     f = AggFetch(state, topn=resolve_topn(agg_plan, slots))
