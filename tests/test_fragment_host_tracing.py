@@ -373,11 +373,13 @@ def test_no_environment_variable_beside_the_tracing():
     assert hits == []
 
 
-@pytest.mark.parametrize("fn", ["_paged_join_agg", "device_join_agg"])
+@pytest.mark.parametrize("fn", ["_paged_join_agg", "device_join_agg",
+                                "FragmentRunner"])
 def test_no_second_stopwatch_in_the_join_fragments(fn):
     tree = ast.parse(pathlib.Path(dj.__file__).read_text())
     (top,) = [n for n in ast.walk(tree)
-              if isinstance(n, ast.FunctionDef) and n.name == fn]
+              if isinstance(n, (ast.FunctionDef, ast.ClassDef))
+              and n.name == fn]
     names = {n.attr for n in ast.walk(top) if isinstance(n, ast.Attribute)}
     names |= {n.id for n in ast.walk(top) if isinstance(n, ast.Name)}
     assert not names & {"perf_counter", "environ", "stderr"}

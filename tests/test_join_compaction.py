@@ -165,7 +165,7 @@ def test_a_cut_exactly_at_the_live_count(tk, low_floor):
     want = _rows(tk, "host", sql)
     assert _rows(tk, "tpu", sql) == want
     sig = next(k[0] for k in dj._CAP_STORE if k[1] == ("live", -1))
-    assert dj._CAP_STORE[(sig, ("live", -1))] == 512
+    assert dj.learned(sig, ("live", -1)) == 512
     assert dj.compact_to(512, 4096) == 512
     before = _pipelines(tk)
     assert _rows(tk, "tpu", sql) == want
@@ -367,7 +367,7 @@ def test_a_paged_global_aggregate_with_whole_pages_dead(tk, low_floor,
     sql, cuts = _STARS["global"]
     assert _rows(tk, "tpu", sql) == _rows(tk, "host", sql)
     sig = next(k[0] for k in dj._CAP_STORE if k[1] == ("live", -1))
-    assert dj._CAP_STORE[(sig, ("live", -1))] == 100
+    assert dj.learned(sig, ("live", -1)) == 100
     assert dj.compact_to(100, _PAGE) == 128
     before = _pipelines(tk)
     assert _rows(tk, "tpu", sql) == _rows(tk, "host", sql)
@@ -445,7 +445,7 @@ def test_the_paged_aggregate_reads_the_cut_length(tk, monkeypatch,
     monkeypatch.setattr(dj, "note_agg_spans", note)
     assert _rows(tk, "tpu", sql) == want
     sig = next(k[0] for k in dj._CAP_STORE if k[1] == ("live", 0))
-    cut = dj.compact_to(dj._CAP_STORE[(sig, ("live", 0))], 128)
+    cut = dj.compact_to(dj.learned(sig, ("live", 0)), 128)
     assert cut == 32
     assert aggs == [(cut, cut)] and spans == [(cut, cut)]
     # the group count forgotten, the estimate (f.id: a group a row) is

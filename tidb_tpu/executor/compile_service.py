@@ -55,7 +55,7 @@ pipelines to this module + ops/device.py):
   above the seen shape), so the shapes growing traffic will hit are
   traced before traffic arrives — a delta that crosses a bucket boundary
   re-dispatches a prewarmed program instead of paying a sync compile.
-  Fragment signatures with learned capacities (device_join._CAP_STORE)
+  Fragment signatures with learned capacities (device_join.learned_sigs)
   are prewarm-priority: they are the shapes real traffic converged on.
 
 * **Resilient remote compile**: a compile worker runs under the PR 3
@@ -921,19 +921,14 @@ def prewarm(ctx=None, ladder_up: int = 2, max_recipes: int = 32,
             wait: bool = False, timeout_s: float = 120.0) -> dict:
     """Background-compile the bucket ladder for the hot recipes: for each
     registered fragment signature (most-used first; signatures with
-    learned capacities in device_join._CAP_STORE rank hottest — they are
+    learned capacities (device_join.learned_sigs) rank hottest — they are
     the shapes traffic converged on), warm the next `ladder_up` row
     buckets above the seen shape, plus rebuild any signature an off-CPU
     fence evicted.  `wait` blocks until the submitted warms finish
     (ADMIN COMPILE uses this so the statement returns a final count)."""
     _refresh_cfg(ctx)
-    from .device_join import _CAP_STORE
-    # snapshot: concurrent queries mutate the cap store un-locked, and a
-    # mid-sort resize would raise out of the priority key function
-    try:
-        hot_sigs = {k[0] for k in list(_CAP_STORE)}
-    except RuntimeError:  # resized mid-snapshot: lose the priority boost
-        hot_sigs = set()
+    from .device_join import learned_sigs
+    hot_sigs = learned_sigs()
     with _LOCK:
         warm0 = STATS["compile_prewarmed"]
         fail0 = STATS["bg_failed"]

@@ -579,22 +579,41 @@ def _strategy_sig(jn):
     return f"S{jn.pos}:{kind}/{side}/{idx.sig()}"
 
 
-#: learned exact sizes per fragment: (sig, join_pos) → last observed match
-#: total; (sig, "agg") → last observed group count. In-process, LRU-bounded
-#: like _PIPE_CACHE (sig strings embed data-dependent packs, so stale data
-#: versions must age out); repeat fragments (bench steady state, plan-cache
-#: hits) start tight and never pay a discovery recompile again.
-import collections as _collections
-
-_CAP_STORE: "_collections.OrderedDict" = _collections.OrderedDict()
+#: learned exact sizes per fragment, through `learned` / `learn` alone:
+#: (sig, join_pos) → last observed match total, (sig, ("live", pos)) → a
+#: compaction point's live count, (sig, "agg") → group count ("groups":
+#: the pages' merged one); the mesh's: converged "caps", "xcaps" and
+#: aggregate capacity. In-process, LRU-bounded like _PIPE_CACHE (sig
+#: strings embed data-dependent packs, so stale data versions must age
+#: out); repeat fragments (bench steady state, plan-cache hits) start
+#: tight and never pay a discovery recompile again.
+_CAP_STORE: "collections.OrderedDict" = collections.OrderedDict()
 _CAP_STORE_MAX = 4096
 
 
-def _cap_store_put(key, val):
-    _CAP_STORE[key] = val
+def learned(sig, what):
+    """What the last run of fragment `sig` stored under `what`, or None."""
+    return _CAP_STORE.get((sig, what))
+
+
+def learn(sig, what, value):
+    """Store `value` under `what` for the next run of fragment `sig`."""
+    key = (sig, what)
+    _CAP_STORE[key] = value
     _CAP_STORE.move_to_end(key)
     if len(_CAP_STORE) > _CAP_STORE_MAX:
         _CAP_STORE.popitem(last=False)
+
+
+def learned_sigs() -> set:
+    """The signatures the store holds something for: the shapes traffic
+    converged on."""
+    # snapshot: concurrent queries mutate the store un-locked, and a
+    # resize mid-iteration raises
+    try:
+        return {k[0] for k in list(_CAP_STORE)}
+    except RuntimeError:
+        return set()
 
 
 def _null_extend(nulls, bidx_map, hit):
@@ -864,19 +883,28 @@ def compaction_points(root) -> dict:
 
 
 def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
-                     capacity, key_pack, agg_meta, nonnull, raw_tail=False,
-                     strategies=None, program=None, compact=None):
-    """Build the jitted end-to-end program. caps: per-join static
-    capacities aligned with `joins`. Returns jitted fn(env, jidx, n_lives)
+                     capacity, key_pack, agg_meta, nonnull, *, strategies,
+                     raw_tail=False, program=None, compact=None):
+    """Build the jitted end-to-end program. caps / strategies: per-join
+    static capacities and strategies aligned with `joins`, as the builder
+    was made with them (the traced body never reads a node's mutable
+    `.strategy`: a deferred background build can trace long after its
+    execution restored or replaced it, as the hybrid join swaps a
+    partition-shaped stub in and out around its run). Returns jitted
+    fn(env, jidx, n_lives)
     where env is {global_col: (data, nulls)} and jidx is a per-join tuple
     of host-index device arrays (passed as arguments, not baked, so a data
     refresh with unchanged shapes reuses the compiled program).
 
+    Its one caller is FragmentRunner.build.  The program returns (the
+    aggregate, the joins' totals and the points' live counts, the
+    multi-key span flags).
+
     program: what turns the body `run(env, jidx, n_lives)` into the
-    program that is dispatched; `_timed_jit` unless given.  Its one other
-    user is the mesh (mpp_exec._shard_program), which runs the body on
-    every shard over that shard's slice of the probe leaf, as the paged
-    probe runs it over a page, and merges the partial states after it.
+    program that is dispatched; `_timed_jit` unless given.  The mesh
+    gives mpp_exec._shard_program, which runs the body on every shard
+    over that shard's slice of the probe leaf, as the paged probe runs
+    it over a page, and merges the partial states after it.
 
     n_lives: per-leaf traced live-row counts, ordered by leaf_id. Env
     arrays may be padded past them — bucket-padded resident uploads, the
@@ -903,16 +931,16 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
 
     raw_tail: stop BEFORE the in-kernel aggregate and return the evaluated
     (key_cols, key_nulls, val_cols, val_nulls, mask) row arrays instead.
-    Its one user is the hybrid join (hybrid_join.py), which aggregates
-    the rows of its device partitions and of its host partitions together
-    in numpy on every backend; the join/filter/expression work stays
-    fused in the program.
+    The hybrid join (hybrid_join.py) asks for it: it aggregates the rows
+    of its device partitions and of its host partitions together in
+    numpy on every backend; the join/filter/expression work stays fused
+    in the program.
 
     compact: ((pos, cap or None), ...) for every compaction point
-    (compaction_points, in its order), as _fill_caps decided them;
-    device_join_agg and _paged_join_agg pass it.  At each point the
-    program counts the relation's live rows, and where `cap` is given it
-    cuts the relation
+    (compaction_points, in its order), as _fill_caps decided them; the
+    runner passes it where the fragment compacts (FragmentRunner.plan).
+    At each point the program counts the relation's live rows, and where
+    `cap` is given it cuts the relation
     to its first `cap` live rows: their positions, in ascending order,
     become one more index of every row map (rows_of composes it as an
     expansion's `pi`), the null maps are gathered through them, and the
@@ -923,13 +951,6 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
     `compacted`: the positions of the points the trace cut."""
     for jn, cap in zip(joins, caps):
         jn.cap = cap
-    if strategies is None:
-        # snapshot NOW: the traced body must never read the mutable
-        # .strategy slot at dispatch/trace time — a deferred background
-        # build (compile service) can trace long after the originating
-        # execution restored or replaced it (the hybrid join swaps a
-        # partition-shaped stub in and out around its run)
-        strategies = tuple(jn.strategy for jn in joins)
 
     # metadata-only planning view: compiling expressions must not upload
     # any column (the paged probe's columns never transfer whole)
@@ -1312,7 +1333,6 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
             for f in cond_fns:
                 d, nl = f(fenv)
                 mask = mask & (d != 0) & ~nl
-            kept_total = jnp.sum(mask)
         n_out = mask.shape[0]
         # as in the scan pipeline: key expressions are k_agg_sort,
         # aggregate inputs k_agg_gather
@@ -1337,14 +1357,14 @@ def compile_fragment(root, leaves, joins, agg_plan, agg_conds, caps,
         if raw_tail:
             raw = (tuple(key_cols), tuple(key_nulls), tuple(val_cols),
                    tuple(val_nulls), mask)
-            return raw, tuple(overflows), tuple(span_ovfs), kept_total
+            return raw, tuple(overflows), tuple(span_ovfs)
         agg_out = dev._agg_impl(tuple(key_cols), tuple(key_nulls),
                                 tuple(val_cols), tuple(val_nulls), mask,
                                 n_keys=len(key_cols),
                                 agg_ops=tuple(agg_ops),
                                 capacity=capacity, pack=key_pack,
                                 gathered=True)
-        return agg_out, tuple(overflows), tuple(span_ovfs), kept_total
+        return agg_out, tuple(overflows), tuple(span_ovfs)
 
     fn = (program or _timed_jit)(run)
     fn.gathers = gathers  # filled by the trace: note_join_gathers
@@ -1396,9 +1416,9 @@ def _fill_caps(node, sig, points=None, cuts=None):
     # the in-program expansion probes with its left side
     node.probe_cap = rc if st is not None and st[1] != "right" else lc
     if node.exp_cap is None:
-        learned = _CAP_STORE.get((sig, node.pos))
-        if learned is not None:
-            node.exp_cap = dev.next_pow2(max(learned, 8))
+        total = learned(sig, node.pos)
+        if total is not None:
+            node.exp_cap = dev.next_pow2(max(total, 8))
         elif st is not None:
             est = int(node.probe_cap * st[2].avg_cnt * 1.5)
             if node.kind == "left":
@@ -1426,8 +1446,153 @@ def _cut(node, n, sig, points, cuts):
     pos = (points or {}).get(id(node))
     if pos is None:
         return n
-    cuts[pos] = compact_to(_CAP_STORE.get((sig, ("live", pos))), n)
+    cuts[pos] = compact_to(learned(sig, ("live", pos)), n)
     return cuts[pos] or n
+
+
+class FragmentRunner:
+    """The turn around compile_fragment, for every path that runs a join
+    fragment's program: the whole input (device_join_agg), the probe page
+    by page (_paged_join_agg), the mesh's indexed path (mpp_exec, a shard
+    a page) and the hybrid join (its raw-tail program).  A turn plans the
+    fragment's capacities, gets its program, runs it, learns from the
+    counts that come back and decides whether to run again; the callers
+    keep how a turn is dispatched and fetched, and what only their loop
+    learns.  Construction plans the aggregate (`_plan_agg`'s six parts
+    are attributes)."""
+
+    def __init__(self, root, leaves, joins, agg_plan, agg_conds, dcols):
+        self.root, self.leaves, self.joins = root, leaves, joins
+        self.agg_plan, self.agg_conds = agg_plan, agg_conds
+        (self.key_fns, self.key_meta, self.key_pack, self.val_plan,
+         self.agg_ops, self.slots) = _plan_agg(agg_plan, dcols)
+        self.dict_refs = tuple(dc.dictionary for dc in dcols.values()
+                               if dc.dictionary is not None)
+        self.sig = self.used = self.points = None
+        self._gathers_noted = False
+
+    def plan(self, sig, used, compacts=True):
+        """Every join's static capacity (_fill_caps) and, where the
+        fragment `compacts`, every compaction point's cut, from what the
+        store learned for `sig`.  `used`: the fragment's used columns."""
+        self.sig, self.used, self.cuts = sig, used, {}
+        self.points = compaction_points(self.root) if compacts else None
+        self.n_frag = _fill_caps(self.root, sig, self.points, self.cuts)
+
+    @property
+    def compact(self) -> tuple:
+        """((pos, cut or None), ...) of the points, in their order."""
+        return tuple((pos, self.cuts[pos])
+                     for pos in (self.points or {}).values())
+
+    def start_capacity(self, ctx, rows=None):
+        """The aggregate's first capacity: from the group count the store
+        learned, else estimated over the fragment's length, or over
+        `rows` where fewer (a page's probe is at most its table)."""
+        ng = learned(self.sig, "agg")
+        if ng is not None:
+            return dev.next_pow2(max(ng, 16))
+        n = self.n_frag if rows is None else min(rows, self.n_frag)
+        est = _estimate_groups(self.agg_plan, n, ctx)
+        return dev.next_pow2(min(self.n_frag, max(est, 16)))
+
+    def build(self, capacity, nonnull, **kw):
+        """The builder acquire_pipeline calls on a miss: the program at the
+        joins' capacities and strategies as they are now, and `capacity`;
+        `kw` goes to compile_fragment (the mesh's `program`, the hybrid
+        join's `raw_tail`)."""
+        caps = [jn.cap for jn in self.joins]
+        strategies = tuple(jn.strategy for jn in self.joins)
+        agg_meta = (self.key_fns, self.val_plan, self.agg_ops, self.slots)
+
+        def build():
+            # the leaves/joins/plan objects are OWNED by this execution;
+            # when the compile service defers this builder to a worker the
+            # query has already degraded to host, so nothing mutates them
+            return compile_fragment(self.root, self.leaves, self.joins,
+                                    self.agg_plan, self.agg_conds, caps,
+                                    capacity, self.key_pack, agg_meta,
+                                    nonnull, strategies=strategies, **kw)
+        return build
+
+    def program(self, ctx, capacity, *, args=None, shape="join", lead=(),
+                tag=(), **kw):
+        """A turn's program, cached or compiled, under the key: signature,
+        joins' capacities, `lead`, the aggregate's capacity and plan, the
+        NULL-free columns, `tag` and, where the fragment compacts, the
+        cuts (`lead` / `tag`: the mesh's exchange capacities, the paged
+        probe's mark).  `args`: the call's arguments where one call is
+        the turn."""
+        compact = self.compact
+        # a program that cuts gathers the leaf it would read in place
+        nonnull = nonnull_cols(self.root, self.leaves, self.used, compacts=any(
+            cut for _pos, cut in compact))
+        key = (self.sig, tuple(jn.cap for jn in self.joins), *lead, capacity,
+               self.key_pack, tuple(self.agg_ops), nonnull, *tag)
+        if self.points is not None:
+            key += (compact,)
+            kw["compact"] = compact
+        return acquire_pipeline(key, self.build(capacity, nonnull, **kw),
+                                self.dict_refs, ctx=ctx, args=args,
+                                shape=shape, sig=self.sig)
+
+    def dispatched(self, fn, capacity):
+        """Count a turn's program once it ran: its aggregate's side of
+        _group_spans and, the fragment's first, its gathers."""
+        note_agg_spans(self.key_pack, self.agg_ops, capacity, self.n_frag,
+                       gathered=True)
+        if not self._gathers_noted:
+            self._gathers_noted = True
+            note_join_gathers(fn)
+
+    def split(self, outs):
+        """(the joins' totals, the points' live counts) of the program's
+        second output: the counts ride behind the totals."""
+        return outs[:len(self.joins)], outs[len(self.joins):]
+
+    def passed_cut(self, lives) -> bool:
+        """Did a live count pass its point's cut?  The cut dropped rows
+        (a count past it downstream is a lower bound): run the turn again
+        at the size the count asks."""
+        return any(cut is not None and live > cut
+                   for (_pos, cut), live in zip(self.compact, lives))
+
+    def learned_lives(self) -> list:
+        """The live counts the store holds for the points (or None)."""
+        return [learned(self.sig, ("live", pos))
+                for pos in (self.points or {}).values()]
+
+    def learn_lives(self, lives):
+        """Store the points' live counts, which the next plan cuts by."""
+        for pos, live in zip((self.points or {}).values(), lives):
+            learn(self.sig, ("live", pos), live)
+
+    def rerun(self, shape, capacity, groups, **tags):
+        """Plan the next turn from the store, and count the rerun."""
+        self.n_frag = _fill_caps(self.root, self.sig, self.points, self.cuts)
+        note_rerun(shape, capacity, groups,
+                   caps=[int(jn.cap) for jn in self.joins], **tags)
+
+    def begin(self, resident=None):
+        """Count the fragment once: its aggregate's arm, its joins'
+        layouts and, on one chip, whether its probe is `resident`."""
+        note_agg_arm(self.key_pack, self.agg_ops, gathered=True)
+        note_join_layouts(self.joins)
+        if resident is not None:
+            note_join_probe(resident)
+
+    def end(self, totals=()):
+        """Count what the kept turn did, once a fragment: its cuts, and
+        each expansion's and residual test's rows (`totals`) and
+        capacity."""
+        note_join_compactions(sum(cut is not None
+                                  for _pos, cut in self.compact))
+        for jn, total in zip(self.joins, totals):
+            if join_expands(jn):
+                note_join_expansion(total, jn.cap,
+                                    expand_one_pass(jn.cap, jn.probe_cap))
+            elif exists_expands(jn):
+                note_join_residual(total, jn.cap)
 
 
 def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
@@ -1552,74 +1717,28 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         jidx = tuple(jn.strategy[2].device_arrays()
                      if jn.strategy is not None else () for jn in joins)
         _upload_tags(usp, up0, len(env))
-    agg_meta_full = _plan_agg(agg_plan, dcols)
-    key_fns, key_meta, key_pack, val_plan, agg_ops, slots = agg_meta_full
-    agg_meta = (key_fns, val_plan, agg_ops, slots)
+    run = FragmentRunner(root, leaves, joins, agg_plan, agg_conds, dcols)
     n_lives = tuple(np.int64(leaf.chunk.num_rows) for leaf in leaves)
-    used = _fragment_used_cols(leaves, joins, agg_plan, agg_conds)
-
     sig = fragment_sig(leaves, joins, agg_conds, agg_plan)
-    dict_refs = tuple(dc.dictionary for dc in dcols.values()
-                      if dc.dictionary is not None)
-
-    # the probe path's compaction points: each cut to the live count its
-    # last run learned (_fill_caps), counted by every run
-    points = compaction_points(root)
-    cuts = {}
-    n_frag = _fill_caps(root, sig, points, cuts)
-    learned_ng = _CAP_STORE.get((sig, "agg"))
-    if learned_ng is not None:
-        capacity = dev.next_pow2(max(learned_ng, 16))
-    else:
-        est = _estimate_groups(agg_plan, n_frag, ctx)
-        capacity = dev.next_pow2(min(n_frag, max(est, 16)))
-
-    note_agg_arm(key_pack, agg_ops, gathered=True)
-    note_join_layouts(joins)
-    note_join_probe(resident=True)
+    run.plan(sig, _fragment_used_cols(leaves, joins, agg_plan, agg_conds))
+    capacity = run.start_capacity(ctx)
+    run.begin(resident=True)
+    from .device_exec import AggFetch, resolve_topn
     for _attempt in range(12):
-        caps = [jn.cap for jn in joins]
-        compact = tuple((pos, cuts[pos]) for pos in points.values())
-        # a program that cuts gathers the leaf it would read in place
-        nonnull = nonnull_cols(root, leaves, used, compacts=any(
-            cut for _pos, cut in compact))
-        key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
-               nonnull, compact)
-
-        def build(caps=tuple(caps), cap=capacity, compact=compact,
-                  nonnull=nonnull):
-            # the leaves/joins/plan objects are OWNED by this execution;
-            # when the compile service defers this builder to a worker the
-            # query has already degraded to host, so nothing mutates them
-            return compile_fragment(root, leaves, joins, agg_plan,
-                                    agg_conds, list(caps), cap, key_pack,
-                                    agg_meta, nonnull, compact=compact)
-        fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
-                              args=(env, jidx, n_lives), shape="join",
-                              sig=sig)
-        agg_out, ovf_d, sovf_d, kept_d = fn(env, jidx, n_lives)
-        note_agg_spans(key_pack, agg_ops, capacity, n_frag, gathered=True)
-        if _attempt == 0:
-            note_join_gathers(fn)
-        from .device_exec import AggFetch, resolve_topn
-        f = AggFetch(agg_out, extras=(ovf_d, sovf_d, kept_d),
-                     topn=resolve_topn(agg_plan, slots))
-        overflows, span_ovfs, kept = f.extras
-        lives = [int(v) for v in overflows[len(joins):]]
-        overflows = overflows[:len(joins)]
-        kept = int(kept)
+        fn = run.program(ctx, capacity, args=(env, jidx, n_lives))
+        agg_out, ovf_d, sovf_d = fn(env, jidx, n_lives)
+        run.dispatched(fn, capacity)
+        f = AggFetch(agg_out, extras=(ovf_d, sovf_d),
+                     topn=resolve_topn(agg_plan, run.slots))
+        outs, span_ovfs = f.extras
+        overflows, lives = run.split(outs)
+        lives = [int(v) for v in lives]
         ng = f.ng
         if any(bool(s) for s in span_ovfs):
             raise DeviceUnsupported(
                 "multi-key join value ranges exceed int64 packing")
-        retry = False
-        for (pos, cut), live in zip(compact, lives):
-            # a cut that held fewer rows than were live dropped some (a
-            # count past it downstream is a lower bound): run again at the
-            # size the count asks; a point that could cut and did not
-            # learns it for the next execution, which recompiles anyway
-            retry |= cut is not None and live > cut
-            _cap_store_put((sig, ("live", pos)), live)
+        retry = run.passed_cut(lives)
+        run.learn_lives(lives)
         for jn, total in zip(joins, overflows):
             if not exists_expands(jn) and (
                     jn.kind in ("semi", "anti") or (
@@ -1641,7 +1760,7 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
                 # compile now buys tight steady-state shapes forever
                 jn.exp_cap = tight
                 retry = True
-            _cap_store_put((sig, jn.pos), total)
+            learn(sig, jn.pos, total)
         tight_ng = dev.next_pow2(max(ng, 16))
         if ng > capacity:
             capacity = tight_ng
@@ -1649,27 +1768,20 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         elif capacity > 4 * tight_ng and capacity > 8192:
             capacity = tight_ng
             retry = True
-        _cap_store_put((sig, "agg"), ng)
+        learn(sig, "agg", ng)
         if retry:
-            n_frag = _fill_caps(root, sig, points, cuts)
-            note_rerun("join", capacity, ng,
-                       caps=[int(jn.cap) for jn in joins], kept=kept,
-                       totals=[int(o) for o in overflows], lives=lives)
+            run.rerun("join", capacity, ng,
+                      totals=[int(o) for o in overflows], lives=lives)
             continue
         break
     else:
         raise DeviceUnsupported("join fragment capacities did not converge")
-    note_join_compactions(sum(cut is not None for _pos, cut in compact))
-    for jn, total in zip(joins, overflows):
-        if join_expands(jn):
-            note_join_expansion(total, jn.cap,
-                                expand_one_pass(jn.cap, jn.probe_cap))
-        elif exists_expands(jn):
-            note_join_residual(total, jn.cap)
+    run.end(overflows)
     if ng == 0 and not agg_plan.group_exprs:
         raise DeviceUnsupported("empty global aggregate")
     body = f.body()
-    return _assemble_agg(agg_plan, key_meta, slots, dcols, body, f.out_rows)
+    return _assemble_agg(agg_plan, run.key_meta, run.slots, dcols, body,
+                         f.out_rows)
 
 
 #: rows of one page of a probe that runs page by page (the session's
@@ -1942,14 +2054,12 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
     # the pruned env_dim ones below, AFTER the resident-budget check
     dcols = _global_dcols(leaves, meta_leaf_ids=frozenset(
         leaf.leaf_id for leaf in leaves))
-    agg_meta_full = _plan_agg(agg_plan, dcols)
-    key_fns, key_meta, key_pack, val_plan, agg_ops, slots = agg_meta_full
+    run = FragmentRunner(root, leaves, joins, agg_plan, agg_conds, dcols)
     from .device_exec import (
         _MERGE_BUDGET_ROWS, _MERGE_OPS, AggFetch, resolve_topn)
-    if any(op not in _MERGE_OPS for op in agg_ops):
+    if any(op not in _MERGE_OPS for op in run.agg_ops):
         raise DeviceUnsupported("non-mergeable agg in paged fragment")
-    merge_ops = tuple(_MERGE_OPS[op] for op in agg_ops)
-    agg_meta = (key_fns, val_plan, agg_ops, slots)
+    merge_ops = tuple(_MERGE_OPS[op] for op in run.agg_ops)
 
     used = _fragment_used_cols(leaves, joins, agg_plan, agg_conds)
     # leaf_rel reads each leaf's row count off its first env entry — keep
@@ -1996,27 +2106,16 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
         _upload_tags(usp, up0, len(env_dim) + (len(probe_used)
                                                 if resident else 0))
     sig = fragment_sig(leaves, joins, agg_conds, agg_plan) + f"|pg{page_rows}"
-    dict_refs = tuple(dc.dictionary for dc in dcols.values()
-                      if dc.dictionary is not None)
-
     n = probe.chunk.num_rows
-    n_keys = max(len(key_fns), 1)
-    nvals = len(val_plan)
+    n_keys = max(len(run.key_fns), 1)
+    nvals = len(run.val_plan)
     # every page runs one program at the page's static length, and its
     # probe path is cut where the live counts the pages of the last kept
     # turn learned (the largest of any page) leave few rows
     probe.bucket = page_rows
-    points = compaction_points(root)
-    cuts = {}
-    n_frag = _fill_caps(root, sig, points, cuts)
-    learned = _CAP_STORE.get((sig, "agg"))
-    if learned is not None:
-        capacity = dev.next_pow2(max(learned, 16))
-    else:
-        est = _estimate_groups(agg_plan, min(n, n_frag), ctx)
-        capacity = dev.next_pow2(min(n_frag, max(est, 16)))
-    learned_total = _CAP_STORE.get((sig, "groups"))
-    merge_cap = dev.next_pow2(max(learned_total or capacity, 16))
+    run.plan(sig, used)
+    capacity = run.start_capacity(ctx, rows=n)
+    merge_cap = dev.next_pow2(max(learned(sig, "groups") or capacity, 16))
 
     base_lives = [np.int64(leaf.chunk.num_rows) for leaf in leaves]
 
@@ -2029,39 +2128,21 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
 
     def merge_flush(state, buffered, merge_cap):
         return merge_partial_states(state, buffered, merge_cap, n_keys,
-                                    nvals, merge_ops, key_pack)
+                                    nvals, merge_ops, run.key_pack)
 
-    note_join_layouts(joins)
-    note_agg_arm(key_pack, agg_ops, gathered=True)
-    note_join_probe(resident)
+    run.begin(resident)
     for _attempt in range(12):
-        # every join is a probe-shaped gather at its probe's length
-        caps = [jn.cap for jn in joins]
-        compact = tuple((pos, cuts[pos]) for pos in points.values())
-        # a program that cuts gathers the leaf it would read in place
-        nonnull = nonnull_cols(root, leaves, used, compacts=any(
-            cut for _pos, cut in compact))
-        key = (sig, tuple(caps), capacity, key_pack, tuple(agg_ops),
-               nonnull, "paged", compact)
-
-        def build(caps=tuple(caps), cap=capacity, compact=compact,
-                  nonnull=nonnull):
-            return compile_fragment(root, leaves, joins, agg_plan,
-                                    agg_conds, list(caps), cap, key_pack,
-                                    agg_meta, nonnull, compact=compact)
         # per-page env is assembled inside the loop below, so there is no
         # whole-call arg spec to record: the paged fragment compiles sync
         # (still breaker-guarded + persisted through the compile service)
-        fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
-                              shape="join", sig=sig)
-        note_agg_spans(key_pack, agg_ops, capacity, n_frag, gathered=True)
+        fn = run.program(ctx, capacity, tag=("paged",))
         k_flush = max(1, _MERGE_BUDGET_ROWS // capacity)
         state = None
         buffered = []
         # each page's live counts at the points, beside its partial state
         buffered_lives = []
         max_ng = 0
-        max_lives = [0] * len(compact)
+        max_lives = [0] * len(run.compact)
         overflow = False
         pages = 0
         if resident:
@@ -2074,13 +2155,12 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
             env = {**env_dim, **(
                 cut_pages.popleft() if resident
                 else _stream_block(probe_host, lo, hi, page_rows))}
-            agg_out, ovf, _sovf, _kept = fn(env, jidx, page_lives(hi, lo))
-            if _attempt == 0 and lo == 0:
-                note_join_gathers(fn)
+            agg_out, ovf, _sovf = fn(env, jidx, page_lives(hi, lo))
+            if lo == 0:
+                run.dispatched(fn, capacity)
             pages += 1
             buffered.append(agg_out)
-            # the points' live counts ride behind the joins' totals
-            buffered_lives.append(ovf[len(joins):])
+            buffered_lives.append(run.split(ovf)[1])
             if len(buffered) < k_flush and hi < n:
                 continue
             # the group counts and the live counts in one round trip
@@ -2089,11 +2169,7 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
             max_ng = max(max_ng, *(int(g) for g in ngs))
             for page in lives:
                 max_lives = [max(m, int(v)) for m, v in zip(max_lives, page)]
-            # a cut that held fewer rows than a page had live dropped some
-            # (a count past it downstream is a lower bound)
-            overflow = max_ng > capacity or any(
-                cut is not None and live > cut
-                for (_pos, cut), live in zip(compact, max_lives))
+            overflow = max_ng > capacity or run.passed_cut(max_lives)
             if overflow:
                 break
             state, merge_cap = merge_flush(state, buffered, merge_cap)
@@ -2104,34 +2180,32 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
             # observed sizes (remembered, so the discovery restart happens
             # once per fragment ever); the pages not run keep what the
             # last kept turn learned
-            for (pos, _cut), live in zip(compact, max_lives):
-                _cap_store_put((sig, ("live", pos)), max(
-                    live, _CAP_STORE.get((sig, ("live", pos))) or 0))
+            run.learn_lives([max(live, old or 0) for live, old
+                             in zip(max_lives, run.learned_lives())])
             if max_ng > capacity:
                 capacity = dev.next_pow2(max_ng)
-                _cap_store_put((sig, "agg"), max_ng)
-            n_frag = _fill_caps(root, sig, points, cuts)
-            note_rerun("join.paged", capacity, max_ng, pages=pages,
-                       lives=max_lives)
+                learn(sig, "agg", max_ng)
+            run.rerun("join.paged", capacity, max_ng, pages=pages,
+                      lives=max_lives)
             continue
         # the largest live count of any page: the cut every page's
         # program can take on the next execution
-        for (pos, _cut), live in zip(compact, max_lives):
-            _cap_store_put((sig, ("live", pos)), live)
-        _cap_store_put((sig, "agg"), max(max_ng, 1))
+        run.learn_lives(max_lives)
+        learn(sig, "agg", max(max_ng, 1))
         break
     else:
         raise DeviceUnsupported("paged fragment capacity did not converge")
-    note_join_compactions(sum(cut is not None for _pos, cut in compact))
+    run.end()
     if state is None:
         raise DeviceUnsupported("empty paged fragment input")
-    f = AggFetch(state, topn=resolve_topn(agg_plan, slots))
+    f = AggFetch(state, topn=resolve_topn(agg_plan, run.slots))
     ng = f.ng
-    _cap_store_put((sig, "groups"), ng)
+    learn(sig, "groups", ng)
     if ng == 0 and not agg_plan.group_exprs:
         raise DeviceUnsupported("empty global aggregate")
     body = f.body()
-    out = _assemble_agg(agg_plan, key_meta, slots, dcols, body, f.out_rows)
+    out = _assemble_agg(agg_plan, run.key_meta, run.slots, dcols, body,
+                        f.out_rows)
     LAST_PAGED_STATS.clear()
     LAST_PAGED_STATS.update({"pages": pages, "capacity": capacity,
                              "groups": ng})

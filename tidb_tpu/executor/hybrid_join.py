@@ -367,9 +367,9 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
     concurrently.  Raises DeviceUnsupported when the fragment is outside
     the hybrid language (the caller falls through to the existing
     paths)."""
-    from .device_join import (_fragment_used_cols, _leaf_meta,
-                              fragment_sig, nonnull_cols)
-    from .device_exec import _MERGE_OPS, _plan_agg
+    from .device_join import (FragmentRunner, _fragment_used_cols,
+                              _leaf_meta, fragment_sig, nonnull_cols)
+    from .device_exec import _MERGE_OPS
     attach(ctx)
     big = next(lf for lf in leaves if lf.leaf_id == big_id)
     t_all = time.perf_counter()
@@ -418,8 +418,8 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
         # agg planning against metadata-only device columns (no uploads)
         dcols = {lf.offset + i: dc
                  for lf in leaves for i, dc in _leaf_meta(lf).items()}
-        agg_meta_full = _plan_agg(agg_plan, dcols)
-        key_fns, key_meta, key_pack, val_plan, agg_ops, slots = agg_meta_full
+        run = FragmentRunner(root, leaves, joins, agg_plan, agg_conds, dcols)
+        key_pack, agg_ops = run.key_pack, run.agg_ops
         if any(op not in _MERGE_OPS for op in agg_ops):
             raise DeviceUnsupported("non-mergeable agg in hybrid fragment")
         if key_pack is None:
@@ -431,9 +431,8 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
                     "hybrid host pass needs bare string group keys")
         host_vals = _host_val_plan(agg_plan)
         merge_ops = tuple(_MERGE_OPS[op] for op in agg_ops)
-        agg_meta = (key_fns, val_plan, agg_ops, slots)
-        n_keys = max(len(key_fns), 1)
-        nvals = len(val_plan)
+        n_keys = max(len(run.key_fns), 1)
+        nvals = len(run.val_plan)
 
         used = _fragment_used_cols(leaves, joins, agg_plan, agg_conds)
         for lf in leaves:
@@ -582,9 +581,11 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
             # shift everything host-ward for THIS run, but still kick the
             # background build so the next run takes the device share back
             n_dev, reason = 0, "compile_pending"
-            _kick_bg_compile(ctx, sig, key_pack, agg_ops, probe_bucket,
-                             root, leaves, joins, agg_plan, agg_conds,
-                             agg_meta, dcols, nonnull)
+            try:
+                # acquire_pipeline queues the build and refuses, as meant
+                _hybrid_pipeline(ctx, run, sig, probe_bucket, nonnull)
+            except DeviceUnsupported:
+                pass
         with _LOCK:
             tp = _THROUGHPUT.get(sig)
         if tp and n_dev > 0:
@@ -643,11 +644,10 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
             with tracing.span("join.probe_device", parts=len(dev_pids),
                               bucket=probe_bucket):
                 states, dev_rows = _device_pass(
-                    ctx, leaves, joins, probe, big, big_jn, brows, bparts,
-                    pparts, dev_pids, big_used, probe_used, used,
-                    build_key_local, packs, build_bucket, probe_bucket,
-                    max_part, agg_meta, agg_conds, key_pack, merge_ops,
-                    n_keys, nvals, sig, dcols, root, agg_plan, nonnull)
+                    ctx, run, probe, big, big_jn, brows, bparts, pparts,
+                    dev_pids, big_used, probe_used, used, build_key_local,
+                    packs, build_bucket, probe_bucket, max_part, merge_ops,
+                    n_keys, nvals, sig, nonnull)
         t_dev = time.perf_counter() - t_dev0
 
         # -- join the host half, merge, assemble ------------------------
@@ -674,14 +674,14 @@ def hybrid_join_agg(root, leaves, joins, probe, big_id, agg_plan,
         state, _cap = (_merge_states_host(states, 16, n_keys, nvals,
                                           merge_ops, key_pack)
                        if len(states) > 1 else (states[0], 0))
-        f = AggFetch(state, topn=resolve_topn(agg_plan, slots))
+        f = AggFetch(state, topn=resolve_topn(agg_plan, run.slots))
         ng = f.ng
         if ng == 0 and not agg_plan.group_exprs:
             tracing.event("host_degraded", reason="hybrid_empty",
                           shape="join")
             raise DeviceUnsupported("empty global aggregate")
         body = f.body()
-        out = _assemble_agg(agg_plan, key_meta, slots, dcols, body,
+        out = _assemble_agg(agg_plan, run.key_meta, run.slots, dcols, body,
                             f.out_rows)
 
         # -- stats / gauges / throughput memory -------------------------
@@ -795,46 +795,23 @@ def _hybrid_pipe_key(sig, key_pack, agg_ops, probe_bucket):
     return (sig, probe_bucket, key_pack, tuple(agg_ops), "hybrid-rawtail")
 
 
-def _hybrid_pipeline(ctx, sig, key_pack, agg_ops, probe_bucket, root,
-                     leaves, joins, agg_plan, agg_conds, agg_meta, dcols,
-                     nonnull):
-    """THE hybrid pipeline resolution: one raw-tail program with every
-    join probe-shaped at the common probe bucket and the strategy
-    snapshot (the partition stub) bound into the builder — a deferred
-    background build must see the stub even after this run's exit path
-    restores the join node's original strategy.  Shared by the device
-    pass and the compile-pending kick so key and shape can never
-    diverge between them."""
+def _hybrid_pipeline(ctx, run, sig, probe_bucket, nonnull):
+    """THE hybrid pipeline resolution: the runner's (device_join.
+    FragmentRunner) one raw-tail program with every join probe-shaped at
+    the common probe bucket and the strategy snapshot (the partition
+    stub) bound into the builder — a deferred background build must see
+    the stub even after this run's exit path restores the join node's
+    original strategy.  Shared by the device pass and the
+    compile-pending kick so key and shape can never diverge between
+    them."""
     from .device_exec import acquire_pipeline
-    from .device_join import compile_fragment
-    for jn in joins:
+    for jn in run.joins:
         jn.cap = probe_bucket
-    key = _hybrid_pipe_key(sig, key_pack, tuple(agg_ops), probe_bucket)
-    dict_refs = tuple(dc.dictionary for dc in dcols.values()
-                      if dc.dictionary is not None)
-    strategies = tuple(jn.strategy for jn in joins)
-
-    def build():
-        return compile_fragment(root, leaves, joins, agg_plan, agg_conds,
-                                [probe_bucket] * len(joins), 1, key_pack,
-                                agg_meta, nonnull, raw_tail=True,
-                                strategies=strategies)
-    return acquire_pipeline(key, build, dict_refs, ctx=ctx, shape="join",
-                            sig=sig)
-
-
-def _kick_bg_compile(ctx, sig, key_pack, agg_ops, probe_bucket, root,
-                     leaves, joins, agg_plan, agg_conds, agg_meta, dcols,
-                     nonnull):
-    """Enqueue the hybrid pipeline's background build (compile service)
-    without dispatching: acquire_pipeline raises the pending
-    DeviceUnsupported by design — here that IS the expected outcome."""
-    try:
-        _hybrid_pipeline(ctx, sig, key_pack, agg_ops, probe_bucket, root,
-                         leaves, joins, agg_plan, agg_conds, agg_meta,
-                         dcols, nonnull)
-    except DeviceUnsupported:
-        pass
+    key = _hybrid_pipe_key(sig, run.key_pack, tuple(run.agg_ops),
+                           probe_bucket)
+    build = run.build(1, nonnull, raw_tail=True)
+    return acquire_pipeline(key, build, run.dict_refs, ctx=ctx,
+                            shape="join", sig=sig)
 
 
 def _balance_split(n_dev, n_parts, pparts, tp) -> int:
@@ -887,11 +864,10 @@ def _update_throughput(sig, dev_rows, t_dev, host_fed, t_host):
 # device half
 # ---------------------------------------------------------------------------
 
-def _device_pass(ctx, leaves, joins, probe, big, big_jn, brows, bparts,
-                 pparts, dev_pids, big_used, probe_used, used,
-                 build_key_local, packs, build_bucket, probe_bucket,
-                 max_part, agg_meta, agg_conds, key_pack, merge_ops,
-                 n_keys, nvals, sig, dcols, root, agg_plan, nonnull):
+def _device_pass(ctx, run, probe, big, big_jn, brows, bparts, pparts,
+                 dev_pids, big_used, probe_used, used, build_key_local,
+                 packs, build_bucket, probe_bucket, max_part, merge_ops,
+                 n_keys, nvals, sig, nonnull):
     """The device half: upload the fitting build partitions as resident
     bucket-padded join indexes + columns, then ONE pipelined probe pass
     dispatching each partition's probe slice through the shared compiled
@@ -899,7 +875,7 @@ def _device_pass(ctx, leaves, joins, probe, big, big_jn, brows, bparts,
     probed row total)."""
     from .device_exec import (_merge_states_host, note_join_gathers,
                               page_singleton_state)
-    key_fns, val_plan, agg_ops, slots = agg_meta
+    leaves, joins = run.leaves, run.joins
     per_double = dev.shape_buckets(ctx)
 
     # resident dimensions (shared by every partition), pruned to used
@@ -950,9 +926,7 @@ def _device_pass(ctx, leaves, joins, probe, big, big_jn, brows, bparts,
     # the shared compiled program: every join probe-shaped at the common
     # probe bucket, raw tail (the group-by folds host-side with the host
     # half's states — same fold, same order-insensitive merge)
-    fn = _hybrid_pipeline(ctx, sig, key_pack, agg_ops, probe_bucket, root,
-                          leaves, joins, agg_plan, agg_conds, agg_meta,
-                          dcols, nonnull)
+    fn = _hybrid_pipeline(ctx, run, sig, probe_bucket, nonnull)
 
     base_lives = [np.int64(lf.chunk.num_rows) for lf in leaves]
     check = getattr(ctx, "check_killed", None)
@@ -981,13 +955,13 @@ def _device_pass(ctx, leaves, joins, probe, big, big_jn, brows, bparts,
             lives = list(base_lives)
             lives[probe.leaf_id] = np.int64(len(prow))
             lives[big.leaf_id] = n_big
-            raw, _ovf, _sovf, _kept = fn(env, jidx, tuple(lives))
+            raw, _ovf, _sovf = fn(env, jidx, tuple(lives))
             if not states:
                 note_join_gathers(fn)
             page = page_singleton_state(raw[0], raw[1], raw[2], raw[3],
-                                        raw[4], agg_ops)
+                                        raw[4], run.agg_ops)
             st, _ = _merge_states_host([page], 16, n_keys, nvals,
-                                       merge_ops, key_pack)
+                                       merge_ops, run.key_pack)
             states.append(st)
     return states, total_rows
 

@@ -13,7 +13,8 @@ TPU-native translation: one `shard_map`-jitted SPMD program per fragment.
   `capacity`-bounded partial aggregate state.  Where the fragment is in
   the paged join's language and its builds have host-built direct indexes
   (`_indexed_chain`), the body IS the one the single-chip path compiles
-  (device_join.compile_fragment, wrapped by `_shard_program`): indexes
+  (device_join.compile_fragment, wrapped by `_shard_program`), and its
+  turn is the single-chip one (device_join.FragmentRunner): indexes
   read by address, the shard's slice of the probe leaf read in place, a
   NULL-free column's mask a constant.  Every other fragment runs
   `_build_mpp_pipeline`'s own body, which joins inside the program
@@ -36,7 +37,8 @@ The single-chip compile-amortization stack carries across the mesh
   bucket tuple, fragment signature incl. dictionary-CONTENT sigs,
   capacities) and flow through the shared _PIPE_CACHE with its
   hit/miss/compile_s stats; converged capacities are LEARNED per
-  signature (device_join._CAP_STORE) so repeat executions start tight.
+  signature (the join fragment's store: device_join.learned / learn) so
+  repeat executions start tight.
 - **Residency + epoch fencing**: every mesh placement registers its
   bytes in the ops/residency.py ledger via a CacheOwner (per-group
   charging, LRU eviction, OOM evict-all) and carries the device epoch —
@@ -70,14 +72,13 @@ from ..ops import device as dev
 from ..ops.device import DeviceUnsupported
 from ..parallel.mpp import RADIX_SUB, _mix64, _radix_bucket
 from .device_exec import (
-    _assemble_agg, _estimate_groups, _plan_agg, acquire_pipeline,
-    engine_mode, note_agg_arm, note_agg_spans, note_join_gathers,
-    note_join_layouts, note_rerun)
+    _assemble_agg, _estimate_groups, acquire_pipeline, engine_mode,
+    note_agg_spans, note_join_gathers, note_rerun)
 from .device_join import (
-    _CAP_STORE, _JoinNode, _Leaf, _cap_store_put, _combined_join_keys,
+    FragmentRunner, _JoinNode, _Leaf, _combined_join_keys,
     _dim_resident_budget, _fragment_used_cols, _join_expand, _leaf_index,
     _leaf_used_bytes, _probe_spine, _reorder_fact_first, _shift_expr,
-    collect_tree, compile_fragment, fragment_sig, nonnull_cols)
+    collect_tree, fragment_sig, learn, learned)
 
 AXIS = "part"
 
@@ -369,7 +370,7 @@ def _shard_program(run, mesh, probe_id, shard_rows, env_specs, n_keys,
         off = jax.lax.axis_index(AXIS).astype(jnp.int64) * shard_rows
         lives = list(n_lives)
         lives[probe_id] = jnp.clip(n_lives[probe_id] - off, 0, shard_rows)
-        partial, totals, span_ovfs, _kept = run(env, jidx, tuple(lives))
+        partial, totals, span_ovfs = run(env, jidx, tuple(lives))
         return _merge_partials(partial, totals, span_ovfs, (), n_keys,
                                merge_ops, capacity, key_pack)
 
@@ -828,9 +829,9 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
             host_cols[leaf.offset + i] = (c, hd, hn)
         leaf_metas.append(metas)
 
-    key_fns, key_meta, key_pack, val_plan, agg_ops, slots = _plan_agg(
-        plan, dcols)
-    n_keys = max(len(key_fns), 1)
+    run = FragmentRunner(root, leaves, joins, plan, agg_conds, dcols)
+    n_keys = max(len(run.key_fns), 1)
+    agg_ops = tuple(run.agg_ops)
     if any(op not in _MERGE_OP for op in agg_ops):
         # cnt_dist partial states don't merge across shards (counts, not
         # sets) — single-chip kernel handles distinct
@@ -885,12 +886,7 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
            fragment_sig(leaves, joins, agg_conds, plan),
            tuple(sharded_ids),
            tuple(leaf_total[leaf.leaf_id] for leaf in leaves))
-    dict_refs = tuple(dc.dictionary for dc in dcols.values()
-                      if dc.dictionary is not None)
     bottom_idx = joins.index(bottom) if shuffle_build is not None else -1
-    # the facts compile_fragment turns into constant masks: an element of
-    # the indexed program's key (a column's first NULL finds a new one)
-    nonnull = nonnull_cols(root, leaves, used) if indexed else None
 
     # static capacities: per-shard bucketed probe rows bound the bottom
     # join; each join's output bounds the next (FK heuristic, grown on
@@ -898,12 +894,12 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
     # capacity (~2x the uniform share), and the bottom join's probe side
     # becomes the post-exchange n_shards*RADIX_SUB*cap_l rows.  All of
     # them start from the LEARNED converged values when this signature
-    # has run before (device_join._CAP_STORE): a repeat execution reuses
+    # has run before (device_join.learned): a repeat execution reuses
     # the cached compiled pipeline with zero discovery retries.
     per_shard_b = leaf_psb[shard_leaf]  # always sharded: filled above
     xcaps = None
     if shuffle_build is not None:
-        learned_x = _CAP_STORE.get((sig, "xcaps"))
+        learned_x = learned(sig, "xcaps")
         if learned_x is not None:
             xcaps = list(learned_x)
         else:
@@ -934,16 +930,19 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
             caps.append(jn.cap)
         return caps
 
-    learned_caps = _CAP_STORE.get((sig, "caps"))
+    learned_caps = learned(sig, "caps")
     if indexed:
-        # every join is a probe-shaped gather: nothing to learn or grow
-        caps = [per_shard_b] * len(joins)
+        # every join is a probe-shaped gather at a shard's rows, which the
+        # body reads as a page: nothing to learn or grow
+        leaves[shard_leaf].bucket = per_shard_b
+        run.plan(sig, used, compacts=False)
+        caps = [jn.cap for jn in joins]
     elif learned_caps is not None and len(learned_caps) == len(joins):
         caps = list(learned_caps)
     else:
         caps = init_caps()
     n_frag = caps[-1] if caps else per_shard_b
-    learned_cap = _CAP_STORE.get((sig, "agg"))
+    learned_cap = learned(sig, "agg")
     if learned_cap is not None:
         capacity = learned_cap
     else:
@@ -962,7 +961,7 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
     from ..utils.failpoint import FailpointError
     from ..errors import BackoffExhaustedError
     bo = Backoffer.for_session(ctx)
-    note_agg_arm(key_pack, agg_ops, gathered=True)
+    run.begin()
     while True:
         for jn, cap in zip(joins, caps):
             jn.cap = cap
@@ -970,39 +969,35 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
         if shuffle_build is not None:
             shuffle = (bottom, shard_leaf, shuffle_build,
                        xcaps[0], xcaps[1])
-        key = (sig, tuple(caps), tuple(xcaps or ()), capacity, key_pack,
-               tuple(agg_ops))
-        if indexed:
-            key += (nonnull,)
-
-            def build(caps=tuple(caps), cap=capacity):
-                return compile_fragment(
-                    root, leaves, joins, plan, agg_conds, list(caps), cap,
-                    key_pack, (key_fns, val_plan, agg_ops, slots), nonnull,
-                    program=functools.partial(
-                        _shard_program, mesh=mesh, probe_id=shard_leaf,
-                        shard_rows=per_shard_b, env_specs=env_specs,
-                        n_keys=n_keys, agg_ops=tuple(agg_ops), capacity=cap,
-                        key_pack=key_pack))
-            args = (env, jidx, n_lives)
-        else:
-            def build(shuffle=shuffle, cap=capacity):
-                return _build_mpp_pipeline(
-                    mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
-                    cond_fns, key_fns, n_keys, val_plan, tuple(agg_ops),
-                    cap, key_pack, env_specs, shuffle=shuffle)
-            args = (env, n_lives)
         # mesh pipelines compile SYNC through the service (no arg spec):
         # a background warm would dispatch zero-filled HOST arrays against
         # a shard_map program traced for mesh-placed shardings — a
         # different program than the one traffic dispatches.  The compile
         # still gets the breaker/persist/failpoint ladder.
-        fn = acquire_pipeline(key, build, dict_refs, ctx=ctx,
-                              shape="mpp", sig=sig)
+        if indexed:
+            fn = run.program(
+                ctx, capacity, shape="mpp", lead=(tuple(xcaps or ()),),
+                program=functools.partial(
+                    _shard_program, mesh=mesh, probe_id=shard_leaf,
+                    shard_rows=per_shard_b, env_specs=env_specs,
+                    n_keys=n_keys, agg_ops=agg_ops, capacity=capacity,
+                    key_pack=run.key_pack))
+            args = (env, jidx, n_lives)
+        else:
+            def build(shuffle=shuffle, cap=capacity):
+                return _build_mpp_pipeline(
+                    mesh, leaves, joins, root, sharded_ids, leaf_cond_fns,
+                    cond_fns, run.key_fns, n_keys, run.val_plan, agg_ops,
+                    cap, run.key_pack, env_specs, shuffle=shuffle)
+            key = (sig, tuple(caps), tuple(xcaps or ()), capacity,
+                   run.key_pack, agg_ops)
+            fn = acquire_pipeline(key, build, run.dict_refs, ctx=ctx,
+                                  shape="mpp", sig=sig)
+            args = (env, n_lives)
         try:
             failpoint.inject("mpp-exchange-send")
             agg_out, png_d, ovfs_d, sovfs_d, xneeds_d = fn(*args)
-            note_agg_spans(key_pack, agg_ops, capacity,
+            note_agg_spans(run.key_pack, agg_ops, capacity,
                            caps[-1] if caps else per_shard_b, gathered=True)
             from .device_exec import AggFetch
             f = AggFetch(agg_out, extras=(png_d, ovfs_d, sovfs_d, xneeds_d))
@@ -1071,10 +1066,10 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
     # remember the converged shapes per signature: the next execution —
     # another session, the warm bench round, the post-INSERT re-run —
     # starts at these exact capacities and hits the compiled pipeline
-    _cap_store_put((sig, "caps"), tuple(caps))
+    learn(sig, "caps", tuple(caps))
     if xcaps is not None:
-        _cap_store_put((sig, "xcaps"), tuple(xcaps))
-    _cap_store_put((sig, "agg"), capacity)
+        learn(sig, "xcaps", tuple(xcaps))
+    learn(sig, "agg", capacity)
     ng = int(fng)
     if ng == 0 and not plan.group_exprs:
         raise DeviceUnsupported("empty global aggregate")
@@ -1085,9 +1080,8 @@ def _run_mpp_impl(plan, agg_conds, root, leaves, joins, ctx, mesh):
         # counted as device_join_agg counts its fragment: once, whatever
         # the retries above (the last program's gathers are every one's)
         MPP_STATS["indexed_fragments"] += 1
-        note_join_layouts(joins)
         note_join_gathers(fn)
     _publish_gauges(ctx)
     key_out, key_null_out, results, result_nulls = f.body()
-    return _assemble_agg(plan, key_meta, slots, dcols,
+    return _assemble_agg(plan, run.key_meta, run.slots, dcols,
                          (key_out, key_null_out, results, result_nulls), ng)
