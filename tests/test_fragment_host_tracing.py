@@ -374,7 +374,7 @@ def test_no_environment_variable_beside_the_tracing():
 
 
 @pytest.mark.parametrize("fn", ["_paged_join_agg", "device_join_agg",
-                                "FragmentRunner"])
+                                "_join_agg", "FragmentRunner"])
 def test_no_second_stopwatch_in_the_join_fragments(fn):
     tree = ast.parse(pathlib.Path(dj.__file__).read_text())
     (top,) = [n for n in ast.walk(tree)

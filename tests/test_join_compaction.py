@@ -525,7 +525,7 @@ def test_the_entry_and_the_cells_that_report_it():
         "better": "higher", "source": "program_counter",
         "layer": "XLA programs", "moves": "query_geomean_s"}
     joins = {"tpch-sf1.q3q5", "ssb-sf10.flights", "tpch-sf1.q9q18",
-             "tpch-sf1.q13q4", "tpch-sf1.q21"}
+             "tpch-sf1.q13q4", "tpch-sf1.q21", "tpch-sf1.q17"}
     assert set(entry["workloads"]) == joins
     for w in spec["workloads"]:
         names = {m["name"] for m, _mod in Cell(w["name"]).per_layer}

@@ -359,6 +359,11 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # folded into an in-set filter of its probe side (the
                # uncorrelated IN -> semi rewrite, Q18) — note_semi_inset
                "semi_insets": 0,
+               # build leaves of dispatched join fragments that are
+               # another operator's result (a derived aggregate: Q17's
+               # lineitem group by l_partkey), and the rows they held —
+               # note_join_derived
+               "join_derived": 0, "join_derived_rows": 0,
                # fragments a PINNED device engine (tpu / tpu-mpp) left
                # to the host executors because they are outside the
                # device language — note_unsupported
@@ -412,7 +417,9 @@ def _tls_stats() -> dict:
                                 "join_gathers": 0,
                                 "join_gathers_elided": 0,
                                 "join_probe_resident": 0,
-                                "join_probe_sent": 0}
+                                "join_probe_sent": 0,
+                                "join_derived": 0,
+                                "join_derived_rows": 0}
     return st
 
 
@@ -575,6 +582,17 @@ def note_semi_inset():
     ``subquery.materialize``.  Once per fragment, whatever its capacity
     retries (the walk runs before them)."""
     _bump("semi_insets")
+
+
+def note_join_derived(rows):
+    """Count one build leaf of a dispatched join fragment that is another
+    operator's result, run through its own executors under the span
+    ``join.derived_build`` (device_join.collect_tree), and the `rows` it
+    held.  Once per fragment, whatever its capacity retries, traced or
+    not; EXPLAIN ANALYZE prints ``derived:x1 (rows 200000)`` and the
+    benchmark's ``join.derived_rows_per_query`` reads the rows."""
+    _bump("join_derived")
+    _bump("join_derived_rows", int(rows))
 
 
 def note_join_index_build():

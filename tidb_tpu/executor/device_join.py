@@ -69,16 +69,17 @@ from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
     _plan_agg, _timed_jit, acquire_pipeline, exists_expands, join_expands,
     note_agg_arm, note_agg_spans, note_join_compactions, note_join_expansion,
-    note_join_gathers, note_join_layouts, note_join_probe,
+    note_join_derived, note_join_gathers, note_join_layouts, note_join_probe,
     note_join_residual, note_rerun, note_semi_inset)
 from .join_index import build_join_index
 
 
 class _Leaf:
     __slots__ = ("leaf_id", "chunk", "conds", "offset", "ncols", "dcols",
-                 "dcols_bucket", "dcols_epoch", "leaf_ids", "bucket")
+                 "dcols_bucket", "dcols_epoch", "leaf_ids", "bucket",
+                 "derived")
 
-    def __init__(self, leaf_id, chunk, conds, offset):
+    def __init__(self, leaf_id, chunk, conds, offset, derived=False):
         self.leaf_id = leaf_id
         self.chunk = chunk
         self.conds = conds
@@ -89,6 +90,10 @@ class _Leaf:
         self.dcols_epoch = None  # device epoch the dcols were built under
         self.leaf_ids = frozenset((leaf_id,))
         self.bucket = None  # padded upload rows (ops/device.py bucket_rows)
+        # the chunk is another operator's result, made by this statement
+        # (collect_tree's derived build): its uploads and index are the
+        # statement's own, released when the fragment ends
+        self.derived = derived
 
 
 class _JoinNode:
@@ -111,9 +116,27 @@ class _JoinNode:
         self.global_keys = False  # keys/conds already in global indices
 
 
-def collect_tree(node):
+def _run_build(build, span, finish):
+    """Run a join's build subtree through its own executors (their device
+    fragments nest under `span`) and hand its chunk to `finish`, which
+    returns (what the caller keeps, the span's tags).  THE one way a
+    fragment's plan walk materialises a build: the in-set fold
+    (``subquery.materialize``) and the derived leaf
+    (``join.derived_build``)."""
+    from ..session import tracing
+    with tracing.span(span) as sp:
+        out, tags = finish(build.execute())
+        if sp is not None:
+            sp.tags.update(tags)
+    return out
+
+
+def collect_tree(node, derived=False):
     """executor node → (_Leaf | _JoinNode) tree; DeviceUnsupported if the
-    shape is outside the fragment language."""
+    shape is outside the fragment language.  `derived`: a join's build
+    child that is neither scan-shaped nor a join (and not folded into an
+    in-set) runs through its own executors and becomes a derived leaf;
+    without it such a build is DeviceUnsupported."""
     from .exec_select import HashJoinExec, SelectionExec, TableScanExec
 
     leaves = []
@@ -165,13 +188,11 @@ def collect_tree(node):
                     # membership there would null-extend instead of drop
                     raise DeviceUnsupported(
                         "semi membership over a non-inner probe")
-                from ..session import tracing
-                with tracing.span("subquery.materialize") as sp:
-                    # the subquery's own fragments nest under this span;
+                def in_set(values_chunk):
+                    # the subquery's own fragments nest under the span;
                     # what is left of it is the host's: its executors'
                     # tail (a HAVING), the values as Python objects, the
                     # in-set
-                    values_chunk = n.children[1].execute()
                     from .exec_select import eval_expr_to_column
                     col = eval_expr_to_column(p.right_keys[0], values_chunk)
                     vals = [None if col.nulls[i] else col.value_at(i)
@@ -179,9 +200,10 @@ def collect_tree(node):
                     from ..expression.builder import build_in_set
                     cond = build_in_set(p.left_keys[0], vals,
                                         p.right_keys[0].ftype)
-                    if sp is not None:
-                        sp.tags["rows"] = values_chunk.num_rows
-                        sp.tags["kept"] = len(cond.extra[0])
+                    return cond, {"rows": values_chunk.num_rows,
+                                  "kept": len(cond.extra[0])}
+                cond = _run_build(n.children[1], "subquery.materialize",
+                                  in_set)
                 note_semi_inset()
                 if isinstance(lnode, _Leaf):
                     lnode.conds.append(cond)  # left-local schema == leaf's
@@ -191,7 +213,19 @@ def collect_tree(node):
                     lnode.other_conds.append(cond)
                 return lnode
             left = walk(n.children[0], offset)
-            right = walk(n.children[1], offset + left.ncols)
+            for lk, rk in zip(p.left_keys, p.right_keys):
+                kl, kr = phys_kind(lk.ftype), phys_kind(rk.ftype)
+                if K_STR in (kl, kr) or K_FLOAT in (kl, kr):
+                    raise DeviceUnsupported("string/float join keys")
+                if (lk.ftype.scale or 0) != (rk.ftype.scale or 0):
+                    raise DeviceUnsupported("mismatched decimal key scales")
+            if (derived and not _scan_shaped
+                    and not isinstance(n.children[1], HashJoinExec)):
+                right = _derived_leaf(n.children[1], len(leaves),
+                                      offset + left.ncols)
+                leaves.append(right)
+            else:
+                right = walk(n.children[1], offset + left.ncols)
             other_conds = list(p.other_conds)
             gap = left.ncols - _width(left)
             if p.kind in ("semi", "anti") and gap:
@@ -205,12 +239,6 @@ def collect_tree(node):
                     else ExprColumn(col.idx + gap, col.ftype,
                                     name=col.name))
                     for c in other_conds]
-            for lk, rk in zip(p.left_keys, p.right_keys):
-                kl, kr = phys_kind(lk.ftype), phys_kind(rk.ftype)
-                if K_STR in (kl, kr) or K_FLOAT in (kl, kr):
-                    raise DeviceUnsupported("string/float join keys")
-                if (lk.ftype.scale or 0) != (rk.ftype.scale or 0):
-                    raise DeviceUnsupported("mismatched decimal key scales")
             jn = _JoinNode(left, right, list(p.left_keys),
                            list(p.right_keys), other_conds, offset,
                            kind=p.kind)
@@ -233,6 +261,43 @@ def collect_tree(node):
            for jn in joins):
         raise DeviceUnsupported("semi/anti join below fragment root")
     return root, leaves, joins
+
+
+def _derived_leaf(build, leaf_id, offset):
+    """A join's build child that is another operator (Q17's ``lineitem
+    group by l_partkey``), run once under the span ``join.derived_build``
+    (tags ``rows``, ``cols``, ``bytes``) and taken as a leaf like a
+    table's: indexed by join_index.build_join_index, its columns passed
+    to the program as arguments.  Only the keys were checked before it
+    runs; a column the device cannot hold as an argument (a string, whose
+    dictionary the program would bake in) is refused before it runs
+    too."""
+    for ref in build.plan.schema.refs:
+        if phys_kind(ref.ftype) == K_STR:
+            raise DeviceUnsupported(
+                f"derived build column {ref.name or '?'} is a string")
+
+    def take(chunk):
+        return chunk, {"rows": chunk.num_rows, "cols": chunk.num_cols,
+                       "bytes": sum(c.data.nbytes + c.nulls.nbytes
+                                    for c in chunk.columns)}
+    chunk = _run_build(build, "join.derived_build", take)
+    return _Leaf(leaf_id, chunk, [], offset, derived=True)
+
+
+def release_derived(leaves):
+    """What a fragment's derived leaves placed is the statement's: drop
+    their columns' uploads and their indexes (host and device) from the
+    caches, so that the ledger does not grow over requests and no later
+    statement is served them."""
+    from ..ops import residency
+    from . import join_index
+    for leaf in leaves:
+        if leaf.derived:
+            for c in leaf.chunk.columns:
+                join_index.release(c)
+            residency.release(leaf.chunk.columns)
+            leaf.dcols = None
 
 
 def _width(node) -> int:
@@ -273,7 +338,8 @@ def _leaf_env(leaf, bucket=None):
     epoch = residency.device_epoch()
     if (leaf.dcols is None or leaf.dcols_bucket != bucket
             or leaf.dcols_epoch != epoch):
-        leaf.dcols = {i: dev.to_device_col(c, bucket=bucket)
+        leaf.dcols = {i: dev.to_device_col(c, bucket=bucket,
+                                           scoped=leaf.derived)
                       for i, c in enumerate(leaf.chunk.columns)}
         leaf.dcols_bucket = bucket
         leaf.dcols_epoch = epoch
@@ -352,7 +418,8 @@ def _leaf_index(side, keys, filtered=True):
             def mask_fn():
                 from .exec_select import eval_conds_mask
                 return eval_conds_mask(side.conds, side.chunk)
-    return build_join_index(cols, mask_fn=mask_fn, cache_tag=tag)
+    return build_join_index(cols, mask_fn=mask_fn, cache_tag=tag,
+                            scoped=side.derived)
 
 
 def _plan_strategy(jn):
@@ -1578,6 +1645,9 @@ class FragmentRunner:
         layouts and, on one chip, whether its probe is `resident`."""
         note_agg_arm(self.key_pack, self.agg_ops, gathered=True)
         note_join_layouts(self.joins)
+        for leaf in self.leaves:
+            if leaf.derived:
+                note_join_derived(leaf.chunk.num_rows)
         if resident is not None:
             note_join_probe(resident)
 
@@ -1603,8 +1673,16 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
     # chaos/supervisor hook: a `sleep(...)` here models a backend hang at
     # the join-fragment boundary, `panic` a runtime failure
     failpoint.inject("device-join-exec")
+    root, leaves, joins = collect_tree(child_exec, derived=True)
+    try:
+        return _join_agg(root, leaves, joins, agg_plan, agg_conds, ctx)
+    finally:
+        release_derived(leaves)
+
+
+def _join_agg(root, leaves, joins, agg_plan, agg_conds, ctx):
+    """device_join_agg past the plan walk."""
     from .device_exec import want_device
-    root, leaves, joins = collect_tree(child_exec)
     if not want_device(ctx, max(leaf.chunk.num_rows for leaf in leaves)):
         raise DeviceUnsupported("below device threshold")
     all_inner = all(jn.kind == "inner" for jn in joins)
@@ -1623,6 +1701,10 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
                 hybrid_deferred = next(iter(over))
     if reordered is not None:
         root, joins = reordered  # inner strategies assigned (all uniq)
+    if _probe_spine(root).derived:
+        raise DeviceUnsupported("a derived probe (only a build may be "
+                                "another operator's result)")
+    derived = any(leaf.derived for leaf in leaves)
     for jn in joins:
         if jn.strategy is None:
             jn.strategy = _plan_strategy(jn)
@@ -1655,11 +1737,12 @@ def device_join_agg(agg_plan, agg_conds, child_exec, ctx):
         # fragment shape outside the paged language goes to the host
         # executors, which stream
         raise DeviceUnsupported("paged leaf outside streamed-probe language")
-    if pageable:
+    if pageable and not derived:
         # hybrid hash join: a build side larger than the residency budget
         # radix-partitions — fitting partitions stay device-resident,
         # overflow spills to host pages and probes CONCURRENTLY on a
         # supervisor worker — instead of surrendering the whole fragment
+        # (a derived leaf's uploads are the statement's, never partitioned)
         hj = _maybe_hybrid(root, leaves, joins, probe, agg_plan,
                            agg_conds, ctx, deferred=hybrid_deferred)
         if hj is not None:
@@ -2089,7 +2172,8 @@ def _paged_join_agg(root, leaves, joins, probe, agg_plan, agg_conds, ctx,
             dim_bucket = dev.bucket_rows(leaf.chunk.num_rows, per_double)
             for i in lused:
                 dc = dev.to_device_col(leaf.chunk.columns[i],
-                                       bucket=dim_bucket)
+                                       bucket=dim_bucket,
+                                       scoped=leaf.derived)
                 env_dim[leaf.offset + i] = (dc.data, dc.nulls)
         if resident:
             # placed once, kept by the ledger: every page below, and every

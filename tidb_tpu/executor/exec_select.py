@@ -140,6 +140,13 @@ class QueryExecutor:
             # compact:x2
             n_cut = st1["join_compactions"] - st0["join_compactions"]
             self.annotate(compact=f"x{n_cut}" if n_cut else None)
+            # the build leaves that are another operator's result, and
+            # their rows (device_exec.note_join_derived):
+            # derived:x1 (rows 200000)
+            n_der, der_rows = (st1[k] - st0[k] for k in (
+                "join_derived", "join_derived_rows"))
+            self.annotate(derived=f"x{n_der} (rows {der_rows})"
+                          if n_der else None)
             # the join fragment's column / mask / row-map gathers, and
             # (-n) those its program elides
             # (device_exec.note_join_gathers): gathers:4 (-15)

@@ -142,12 +142,16 @@ class JoinIndex:
     __slots__ = ("kind", "packs", "unique", "filtered", "n_rows",
                  "n_valid", "span", "slots", "starts", "rows",
                  "sorted_keys", "avg_cnt", "max_cnt", "rows_len", "_owner",
-                 "prefix", "shift", "steps", "low_keys", "_prefix_owner")
+                 "prefix", "shift", "steps", "low_keys", "_prefix_owner",
+                 "scoped")
 
     def __init__(self):
         self.slots = None
         self.filtered = False
         self._owner = None
+        # over a statement's own build (a derived leaf): its device
+        # arrays are published scoped and released with the statement
+        self.scoped = False
         # a `sorted` index's direct-address front (_bucket_prefix)
         self.prefix = self.low_keys = None
         self.shift = self.steps = 0
@@ -187,11 +191,12 @@ class JoinIndex:
         if self._owner is None:
             self._owner = residency.CacheOwner()
             self._prefix_owner = residency.CacheOwner()
-        a0, a1 = _resident(self._owner, *self.host_arrays())
+        a0, a1 = _resident(self._owner, *self.host_arrays(), self.scoped)
         if self.prefix is None:
             return a0, a1, np.int64(self.n_valid)
         return (a0, a1, np.int64(self.n_valid),
-                _resident(self._prefix_owner, self.prefix, None)[0])
+                _resident(self._prefix_owner, self.prefix, None,
+                          self.scoped)[0])
 
     def host_arrays(self):
         """The numpy (a0, a1) behind `device_arrays`, for a caller that
@@ -211,15 +216,27 @@ class JoinIndex:
                    if a is not None)
 
 
-def _resident(owner, a0, a1):
+def _resident(owner, a0, a1, scoped=False):
     """`owner`'s (a0, a1) on the device, through the residency ledger."""
     import jax.numpy as jnp
     from ..ops import residency
     dev = residency.lookup(owner, len(a0))
     if dev is None:
         dev = residency.publish(owner, jnp.asarray(a0),
-                                None if a1 is None else jnp.asarray(a1))
+                                None if a1 is None else jnp.asarray(a1),
+                                scoped=scoped)
     return dev
+
+
+def release(col) -> None:
+    """Forget the index `col` caches and drop its device arrays from the
+    residency ledger: a statement's own build (a derived leaf) is done
+    with it, and no later statement may be served it."""
+    cached, col._join_index = col._join_index, None
+    idx = cached[1] if cached is not None else None
+    if idx is not None and idx._owner is not None:
+        from ..ops import residency
+        residency.release((idx._owner, idx._prefix_owner))
 
 
 def _bucket_prefix(sk, span, pad_len, row_dt):
@@ -275,8 +292,8 @@ def _pack_host(datas, valid, packs):
 
 
 def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
-                     force_sorted=False,
-                     pad_rows=None) -> "JoinIndex | None":
+                     force_sorted=False, pad_rows=None,
+                     scoped=False) -> "JoinIndex | None":
     """Index over `columns` (utils.chunk.Column tuple, int-kinded numpy
     data), cached on columns[0]. None when the keys can't range-pack into
     int64 (caller falls back to the device-side sort join).
@@ -305,7 +322,11 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
     partition's narrower true range simply find no match); force_sorted
     skips the dense layout (a per-partition table spans the WHOLE key
     range — P copies of it would dwarf the data); `pad_rows`
-    floors the bucket so all partitions pad to the largest one's."""
+    floors the bucket so all partitions pad to the largest one's.
+
+    scoped: the columns are a statement's own (a derived build leaf):
+    the index's device arrays are published for the statement to
+    `release` (`JoinIndex.scoped`)."""
     host = columns[0]
     # the cached tuple PINS the column objects: a live reference can never
     # share its id with a newly allocated Column, which is what makes the
@@ -324,6 +345,8 @@ def build_join_index(columns, mask_fn=None, cache_tag="", packs=None,
     with tracing.span("join.index_build") as sp:
         idx, nb, n_valid = _build_index(columns, mask_fn, packs,
                                         force_sorted, pad_rows)
+        if idx is not None:
+            idx.scoped = scoped
         # the negative entry must pin the columns too — id() keys are
         # only sound while the referenced objects stay alive
         host._join_index = (cache_key, idx, tuple(columns))

@@ -57,7 +57,7 @@ SPAN_CHOKEPOINTS = {
                                 "_fetch": ("fetch.d2h", "device.wait",
                                            "fetch.copy"),
                                 "_assemble_agg": ("host.assemble",)},
-    "executor/device_join.py": {"device_join_agg": ("upload.h2d",)},
+    "executor/device_join.py": {"_join_agg": ("upload.h2d",)},
     "executor/join_index.py": {"build_join_index": ("join.index_build",)},
     "executor/mpp_exec.py": {"_run_mpp_impl": ("upload.h2d",)},
 }

@@ -133,7 +133,8 @@ class DeviceCol:
         return self.reps if self.reps is not None else self.dictionary
 
 
-def to_device_col(col, bucket: int | None = None) -> DeviceCol:
+def to_device_col(col, bucket: int | None = None,
+                  scoped: bool = False) -> DeviceCol:
     """utils.chunk.Column → DeviceCol. Strings are dict-encoded host-side.
 
     The device arrays are cached on the Column THROUGH the residency
@@ -151,7 +152,11 @@ def to_device_col(col, bucket: int | None = None) -> DeviceCol:
     length is cached per column: a LONGER cached upload serves shorter
     requests as a device-side slice (no host re-transfer — an
     exact-shape consumer like the mpp path must not thrash a bucketed
-    HBM-resident cache); only a grow evicts and re-uploads."""
+    HBM-resident cache); only a grow evicts and re-uploads.
+
+    `scoped`: the column is the running statement's own (a derived join
+    build's, executor/device_join.py), published as such
+    (`residency.publish`) for the statement to release."""
     from . import residency
     want = bucket if bucket is not None and bucket > len(col) else len(col)
     cached = residency.lookup(col, want)
@@ -185,7 +190,7 @@ def to_device_col(col, bucket: int | None = None) -> DeviceCol:
         # compare-and-keep publish under the residency lock: a racing
         # builder's loser arrays are accounted as immediately evicted,
         # never leaked outside the ledger
-        cached = residency.publish(col, *built)
+        cached = residency.publish(col, *built, scoped=scoped)
     data, nulls = cached
     if int(data.shape[0]) > want:
         # cached at a larger bucket: on-device slice (HBM-local, cheap)

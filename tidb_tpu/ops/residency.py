@@ -111,6 +111,10 @@ STATS = {
     "epoch_bumps": 0,
     "publish_races": 0,    # racing publish lost to an existing entry
     "gc_releases": 0,      # owners collected with their entry still live
+    # statement-scoped entries (publish(scoped=True): a derived join
+    # build's columns and index) dropped by release() when the statement
+    # was done with them: not evictions, nothing pressed them out
+    "statement_releases": 0,
 }
 
 #: Observability sinks (session/observe.py) mirroring the gauges —
@@ -176,13 +180,17 @@ class _Entry:
     the owner is garbage-collected) plus the byte charge and the resource
     group it is charged to."""
 
-    __slots__ = ("ref", "nbytes", "token", "group")
+    __slots__ = ("ref", "nbytes", "token", "group", "scoped")
 
-    def __init__(self, ref, nbytes, token, group=DEFAULT_GROUP):
+    def __init__(self, ref, nbytes, token, group=DEFAULT_GROUP,
+                 scoped=False):
         self.ref = ref
         self.nbytes = nbytes
         self.token = token
         self.group = group
+        # the running statement's own upload (publish(scoped=True)):
+        # never a victim of the budget, released by release()
+        self.scoped = scoped
 
 
 class CacheOwner:
@@ -411,7 +419,7 @@ def lookup(col, want_rows: int):
         return res.data, res.nulls
 
 
-def publish(col, data, nulls):
+def publish(col, data, nulls, scoped=False):
     """Install a freshly built upload as `col`'s cached device value and
     charge its bytes; returns the arrays to use.
 
@@ -420,7 +428,12 @@ def publish(col, data, nulls):
     WINS and this caller's arrays are discarded — the loser's bytes are
     counted as immediately evicted, never silently leaked outside the
     ledger (the pre-residency "last wins" publish leaked the loser's HBM
-    buffer untracked until GC)."""
+    buffer untracked until GC).
+
+    `scoped`: the upload belongs to the running statement alone (a
+    derived join build, executor/device_join.py): it is charged like any
+    other, but it neither evicts anything on arrival nor is ever evicted
+    for the budget, and the statement drops it with `release`."""
     nbytes = _nbytes(data) + _nbytes(nulls)
     rows = int(data.shape[0])
     budget_evicted = 0
@@ -445,7 +458,7 @@ def publish(col, data, nulls):
                 ref = weakref.ref(col, _make_gc_cb(token))
             except TypeError:
                 ref = None  # owner not weakref-able: entry lives forever
-            _ENTRIES[token] = _Entry(ref, nbytes, token, group)
+            _ENTRIES[token] = _Entry(ref, nbytes, token, group, scoped)
             _BYTES[0] += nbytes
             _GROUP_BYTES[group] += nbytes
             _fleet_charge_locked(group, nbytes)
@@ -453,7 +466,8 @@ def publish(col, data, nulls):
             STATS["upload_bytes"] += nbytes
             _TLS.upload_bytes = thread_upload_bytes() + nbytes
             ev0 = STATS["hbm_evictions"]
-            _enforce_budget_locked(keep_token=token, group=group)
+            if not scoped:
+                _enforce_budget_locked(keep_token=token, group=group)
             budget_evicted = STATS["hbm_evictions"] - ev0
             out = data, nulls
     _publish_gauges()
@@ -565,7 +579,8 @@ def _enforce_budget_locked(keep_token: int, group: str = DEFAULT_GROUP):
            and _GROUP_BYTES.get(group, 0) + remote > share):
         victim = None
         for token, ent in _ENTRIES.items():  # oldest first
-            if token != keep_token and ent.group == group:
+            if (token != keep_token and ent.group == group
+                    and not ent.scoped):
                 victim = token
                 break
         if victim is None:
@@ -576,7 +591,7 @@ def _enforce_budget_locked(keep_token: int, group: str = DEFAULT_GROUP):
         victim = None
         fallback = None
         for token, ent in _ENTRIES.items():  # oldest first
-            if token == keep_token:
+            if token == keep_token or ent.scoped:
                 continue
             if fallback is None:
                 fallback = token
@@ -598,6 +613,32 @@ def _evict_all_locked() -> int:
     n = len(_ENTRIES)
     for token in list(_ENTRIES):
         _evict_token_locked(token)
+    return n
+
+
+def release(owners) -> int:
+    """Drop the cached device values of `owners` (Columns or CacheOwners)
+    that a statement published `scoped` and is done with: their bytes
+    leave the ledger now, not when a collector finds the owners, and no
+    later statement can be served them.  Counted under
+    ``statement_releases``, never under ``hbm_evictions``.  Returns the
+    number of entries dropped."""
+    n = 0
+    with _LOCK:
+        for owner in owners:
+            res = owner._device
+            if res is None:
+                continue
+            owner._device = None
+            ent = _ENTRIES.pop(res.token, None)
+            if ent is None:
+                continue
+            _BYTES[0] -= ent.nbytes
+            _drop_group_bytes_locked(ent.group, ent.nbytes)
+            n += 1
+        STATS["statement_releases"] += n
+    if n:
+        _publish_gauges()
     return n
 
 
