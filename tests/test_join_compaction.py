@@ -222,6 +222,9 @@ def test_an_append_past_the_cut_runs_again_exactly(low_floor):
     (151_000, 8_388_608, 262_144),     # Q3 past orders
     (1_191_000, 8_388_608, 2_097_152),  # Q5 past region
     (180_000, 2_097_152, 262_144),     # ... then past orders
+    (910_000, 8_388_608, 1_048_576),   # Q5 past orders, attached first
+    (182_000, 1_048_576, 262_144),     # ... then past region
+    (324_000, 8_388_608, 524_288),     # Q9 past part, attached first
     (72_444, 8_388_608, 131_072),      # Q21's live rows
     (57_000, 2_097_152, 65_536),       # Q4's orders filter
     (400, 8_388_608, 512),             # Q18's in-set
@@ -238,6 +241,8 @@ def test_an_append_past_the_cut_runs_again_exactly(low_floor):
     (83_500, 1_048_576, 131_072),      # ... then d_year = 1993
     (33_600, 1_048_576, 65_536),       # Q2.1's p_category past s_region
     (67_000, 262_144, None),           # Q4.1's p_mfgr past both regions
+    (167_800, 4_194_304, 262_144),     # Q2.1's p_category, attached first
+    (839_000, 4_194_304, 1_048_576),   # Q3.1 / Q4.1 past s_region first
 ])
 def test_the_rule_at_the_benchmarks_shapes(live, n, cut):
     assert dj.compact_to(live, n) == cut

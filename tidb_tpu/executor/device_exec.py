@@ -336,6 +336,12 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # KEPT program cut the probe path's relation to its live
                # rows (device_join.compact_to) — note_join_compactions
                "join_compactions": 0,
+               # dispatched join fragments whose inner joins
+               # device_join._reorder_fact_first chained, and of them
+               # those where a step attached a build whose filter lets
+               # the probe path cut ahead of a smaller candidate —
+               # note_join_chain
+               "join_chains": 0, "join_chains_selective": 0,
                # column / mask / row-map gathers of dispatched join
                # fragments' programs, and those the program holds the
                # result of already (a leaf read in place, a NULL-free
@@ -414,6 +420,7 @@ def _tls_stats() -> dict:
                                 "join_residual": 0,
                                 "join_expand_one_pass": 0,
                                 "join_compactions": 0,
+                                "join_chains_selective": 0,
                                 "join_gathers": 0,
                                 "join_gathers_elided": 0,
                                 "join_probe_resident": 0,
@@ -538,6 +545,24 @@ def note_join_compactions(cuts):
     reads the counter, and EXPLAIN ANALYZE prints ``compact:x2`` beside
     the ``join:`` annotation."""
     _bump("join_compactions", int(cuts))
+
+
+def note_join_chain(joins):
+    """Count one dispatched join fragment whose inner joins
+    device_join._reorder_fact_first chained (its nodes carry global
+    keys) under ``join_chains``, and under ``join_chains_selective`` too
+    where a step of the chain attached a build that lets the probe path
+    cut ahead of a smaller candidate (a node marked ``selective``: the
+    order the size rule alone gives changed).  Once per fragment,
+    whatever its capacity retries and pages, traced or not; the mesh's
+    indexed path counts as one chip's.  The benchmark's
+    ``join.selective_first_share`` reads the two, and EXPLAIN ANALYZE
+    prints ``order:selective`` beside ``join:``."""
+    if not any(jn.global_keys for jn in joins):
+        return
+    _bump("join_chains")
+    if any(jn.selective for jn in joins):
+        _bump("join_chains_selective")
 
 
 def note_join_residual(rows, capacity):

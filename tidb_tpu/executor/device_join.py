@@ -68,9 +68,10 @@ from ..ops.device import DeviceUnsupported
 from .device_exec import (
     _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
     _plan_agg, _timed_jit, acquire_pipeline, exists_expands, join_expands,
-    note_agg_arm, note_agg_spans, note_join_compactions, note_join_expansion,
-    note_join_derived, note_join_gathers, note_join_layouts, note_join_probe,
-    note_join_residual, note_rerun, note_semi_inset)
+    note_agg_arm, note_agg_spans, note_join_chain, note_join_compactions,
+    note_join_derived, note_join_expansion, note_join_gathers,
+    note_join_layouts, note_join_probe, note_join_residual, note_rerun,
+    note_semi_inset)
 from .join_index import build_join_index
 
 
@@ -114,6 +115,9 @@ class _JoinNode:
         self.exp_cap = None     # requested capacity for expansion joins
         self.probe_cap = 0      # an expansion's probe rows (_fill_caps)
         self.global_keys = False  # keys/conds already in global indices
+        # _reorder_fact_first attached this build ahead of a smaller
+        # candidate, because its filter lets the probe path cut
+        self.selective = False
 
 
 def _run_build(build, span, finish):
@@ -474,14 +478,26 @@ def _reorder_fact_first(leaves, joins, assume_unique=frozenset()):
     equi-joins reorder freely, so this is pure engine-side physical
     planning.
 
+    Which candidate a step attaches is _attach_rank's: first a build
+    whose filter keeps at most 1 / _COMPACT_FACTOR of its rows (the share
+    at which compact_to cuts the probe path after its join), the one whose
+    cut would leave the fewest rows first; then the rest by size.  Every
+    lookup and gather before the first cut runs at the fact's length, so
+    the filtered build that cuts goes ahead of a smaller one that does
+    not (SSB Q2.1's `part` ahead of `date` and `supplier`, TPC-H Q9's
+    `part` ahead of `supplier`, Q5's `orders` ahead of `supplier`); a
+    chain with no such build keeps the size order and its program.  A
+    step that took a cutting build ahead of a smaller candidate marks
+    its node `selective` (device_exec.note_join_chain counts it).
+
     assume_unique: leaf ids whose whole-table index must NOT be built at
     plan time (it would exceed the residency budget — exactly the hybrid
     hash join's partitioned build, executor/hybrid_join.py).  Such a leaf
     joins the chain with a DEFERRED strategy ``("uniq", "right", None)``
-    on bare-integer-column keys; the hybrid path builds per-partition
-    indexes at execution and verifies uniqueness there.  A deferred node
-    must never reach the resident/paged dispatch paths — device_join_agg
-    raises if the hybrid attempt falls through.
+    on bare-integer-column keys, ranked by its size; the hybrid path
+    builds per-partition indexes at execution and verifies uniqueness
+    there.  A deferred node must never reach the resident/paged dispatch
+    paths — device_join_agg raises if the hybrid attempt falls through.
 
     Returns (root, new_joins) with strategies assigned, or None when the
     chain can't be built expansion-free (multi-leaf key exprs, a
@@ -522,6 +538,7 @@ def _reorder_fact_first(leaves, joins, assume_unique=frozenset()):
 
     remaining = set(by_id)
     start = max(remaining, key=lambda i: by_id[i].chunk.num_rows)
+    fact_rows = by_id[start].chunk.num_rows
     remaining.discard(start)
     spine_ids = {start}
     cur = by_id[start]
@@ -539,7 +556,7 @@ def _reorder_fact_first(leaves, joins, assume_unique=frozenset()):
                 cands.setdefault(cl, []).append((p, gr, gl))
         if not cands:
             return None
-        best = None
+        ranked = []  # (rank, leaf_id, key pairs, index)
         for lid, kps in cands.items():
             leaf = by_id[lid]
             if lid in assume_unique:
@@ -555,29 +572,28 @@ def _reorder_fact_first(leaves, joins, assume_unique=frozenset()):
                            np.integer)
                        for e in local):
                     continue
-                key = (leaf.chunk.num_rows, lid)
-                if best is None or key < best[0]:
-                    best = (key, lid, kps, None)
-                continue
-            # the index builder addresses the leaf's LOCAL schema; the
-            # chain's key exprs are global — rebase before the lookup
-            idx = _leaf_index(leaf, [_shift_expr(lx, -leaf.offset)
-                                     for _p, _s, lx in kps])
-            if idx is None or not idx.unique:
-                continue
-            key = (leaf.chunk.num_rows, lid)
-            if best is None or key < best[0]:
-                best = (key, lid, kps, idx)
-        if best is None:
+                idx = None
+            else:
+                # the index builder addresses the leaf's LOCAL schema; the
+                # chain's key exprs are global — rebase before the lookup
+                idx = _leaf_index(leaf, [_shift_expr(lx, -leaf.offset)
+                                         for _p, _s, lx in kps])
+                if idx is None or not idx.unique:
+                    continue
+            ranked.append((_attach_rank(leaf, idx, fact_rows), lid, kps,
+                           idx))
+        if not ranked:
             return None  # a non-unique build would expand: keep the
             #              planner's tree instead
-        _key, lid, kps, idx = best
+        _rank, lid, kps, idx = min(ranked, key=lambda r: r[0])
+        smallest = min((by_id[r[1]].chunk.num_rows, r[1]) for r in ranked)[1]
         leaf = by_id[lid]
         jn = _JoinNode(cur, leaf,
                        [s for _p, s, _l in kps], [l for _p, _s, l in kps],
                        [], 0)
         jn.global_keys = True
         jn.strategy = ("uniq", "right", idx)
+        jn.selective = lid != smallest
         spine_ids.add(lid)
         remaining.discard(lid)
         consumed = {id(p) for p, _s, _l in kps}
@@ -608,6 +624,23 @@ def _reorder_fact_first(leaves, joins, assume_unique=frozenset()):
     if pend_pairs or pend_others:
         return None  # anything unplaced means the rewrite lost a predicate
     return cur, new_joins
+
+
+def _attach_rank(leaf, idx, fact_rows):
+    """Where a candidate build stands in _reorder_fact_first's step:
+    (0, cut, rows, leaf id) for one whose index was built under the
+    leaf's filter and keeps at most 1 / _COMPACT_FACTOR of its rows —
+    past its join compact_to would cut the probe path, to `cut` =
+    next_pow2 of its kept share of the fact's `fact_rows` — and (1, 0,
+    rows, leaf id) for the rest (no filter, a wider share, a deferred
+    build with no index), which keep the size order.  The smaller build
+    breaks a tie."""
+    size = (leaf.chunk.num_rows, leaf.leaf_id)
+    if (idx is not None and idx.filtered
+            and idx.n_valid * _COMPACT_FACTOR <= idx.n_rows):
+        live = -(-idx.n_valid * fact_rows // max(idx.n_rows, 1))
+        return (0, dev.next_pow2(max(live, 1))) + size
+    return (1, 0) + size
 
 
 def _reorder_below_chain(leaves, joins):
@@ -1645,6 +1678,7 @@ class FragmentRunner:
         layouts and, on one chip, whether its probe is `resident`."""
         note_agg_arm(self.key_pack, self.agg_ops, gathered=True)
         note_join_layouts(self.joins)
+        note_join_chain(self.joins)
         for leaf in self.leaves:
             if leaf.derived:
                 note_join_derived(leaf.chunk.num_rows)

@@ -135,6 +135,11 @@ class QueryExecutor:
             if beside:
                 note += f" ({beside})"
             self.annotate(join=note or None)
+            # beside it, a fact-first chain that attached a build whose
+            # filter cuts ahead of a smaller one
+            # (device_exec.note_join_chain): order:selective
+            self.annotate(order="selective" if st1["join_chains_selective"]
+                          - st0["join_chains_selective"] else None)
             # beside them, the cuts of the probe path's relation to its
             # live rows in the kept program (note_join_compactions):
             # compact:x2
