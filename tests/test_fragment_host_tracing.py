@@ -3,7 +3,9 @@ counter on every join-index build (a miss of the one index a key column
 caches; a hit says nothing), ``fetch.d2h`` split into ``device.wait`` and
 ``fetch.copy`` under a live trace and left as its one ``device_get``
 without one, and a counter and an event on every program a fragment runs
-again at another capacity.  No environment variable, no second
+again at another capacity.  A scan aggregate starts at the capacity it
+knows: the dense arm at its bucket count, the sort arm at the group
+count its last run stored.  No environment variable, no second
 stopwatch."""
 
 import ast
@@ -244,6 +246,9 @@ def test_fetch_whose_span_was_dropped_is_the_one_device_get(
 # -- C: capacity_reruns and fragment.rerun ------------------------------------
 
 N_GROUPS = 500          # a key column without statistics estimates 64
+#: `wide.g` holds every third value of [0, 1,497]: 2,048 packed buckets,
+#: past the dense arm's 1,024, so `group by g` takes the sort arm
+SPREAD = 3
 
 
 @pytest.fixture(scope="module")
@@ -252,7 +257,7 @@ def tk():
     tk.must_exec("use test")
     tk.must_exec("create table wide (g bigint, v bigint)")
     tk.must_exec("insert into wide values " + ",".join(
-        f"({i % N_GROUPS}, {i})" for i in range(2 * N_GROUPS)))
+        f"({i % N_GROUPS * SPREAD}, {i})" for i in range(2 * N_GROUPS)))
     tk.must_exec("create table t (a bigint primary key, b bigint)")
     tk.must_exec("create table u (k bigint primary key, v bigint)")
     tk.must_exec("insert into t values " + ",".join(
@@ -299,23 +304,132 @@ def _events(node, name):
 
 
 def test_a_scan_aggregate_past_its_estimate_reruns_on_every_execution(tk):
-    """`device_agg` forgets the capacity it learned (ROADMAP S7): the
-    counter and the event say so on each of three executions."""
+    """A sort-arm `device_agg` past its estimate reruns on its FIRST
+    execution only (ROADMAP S7(2)): the kept run's group count is learned
+    and the next executions start at its capacity, one program and one
+    round trip each, traced or not."""
+    dj._CAP_STORE.clear()
     sql = "select g, sum(v) from wide group by g"
-    for _ in range(3):
+    for i in range(3):
         rows, tr, grew = _traced_query(tk, sql)
-        assert len(rows) == N_GROUPS and grew == 1
-        assert tr["dropped"] == 0
-        (ev,) = _events(tr["root"], "fragment.rerun")
-        assert ev["tags"] == {"shape": "agg", "capacity": 512,
-                              "groups": N_GROUPS}
-        # two programs ran: two round trips before the body's
-        assert len(find_spans(tr["root"], "device.wait")) == \
-            len(find_spans(tr["root"], "fetch.d2h")) >= 2
-    # without a trace the counter still counts
+        assert len(rows) == N_GROUPS and tr["dropped"] == 0
+        reruns = _events(tr["root"], "fragment.rerun")
+        waits = find_spans(tr["root"], "device.wait")
+        d2h = find_spans(tr["root"], "fetch.d2h")
+        if i == 0:
+            assert grew == 1
+            (ev,) = reruns
+            assert ev["tags"] == {"shape": "agg", "capacity": 512,
+                                  "groups": N_GROUPS}
+            # two programs ran, each fetched in one round trip
+            assert len(waits) == len(d2h) == 2
+        else:
+            assert grew == 0 and not reruns
+            assert len(waits) == len(d2h) == 1
+    # without a trace the counter stays where it was
     before = _status(tk)["capacity_reruns"]
     assert len(tk.must_query(sql).rows) == N_GROUPS
-    assert _status(tk)["capacity_reruns"] == before + 1
+    assert _status(tk)["capacity_reruns"] == before
+
+
+def _host_rows(tk, sql):
+    tk.must_exec("set tidb_executor_engine = 'host'")
+    try:
+        return sorted(tk.must_query(sql).rows)
+    finally:
+        tk.must_exec("set tidb_executor_engine = 'tpu'")
+
+
+def _agg_capacities(monkeypatch):
+    """The capacities of the scan-aggregate programs `device_agg` asks
+    for from now on, in order (the second element of the pipeline key)."""
+    caps = []
+    real = device_exec.acquire_pipeline
+
+    def spy(key, *a, **kw):
+        if kw.get("shape") == "agg":
+            caps.append(key[1])
+        return real(key, *a, **kw)
+    monkeypatch.setattr(device_exec, "acquire_pipeline", spy)
+    return caps
+
+
+def test_a_learned_scan_aggregate_that_gains_groups_relearns_once(tk):
+    """An insert that takes a learned sort-arm aggregate past its
+    capacity costs one rerun, once: the kept run's count replaces the
+    stored one and the next execution starts at it."""
+    tk.must_exec("create table grow (g bigint, v bigint)")
+    tk.must_exec("insert into grow values " + ",".join(
+        f"({i % N_GROUPS * SPREAD}, {i})" for i in range(2 * N_GROUPS)))
+    dj._CAP_STORE.clear()
+    sql = "select g, count(*), sum(v) from grow group by g"
+
+    def run(want_reruns):
+        before = _status(tk)["capacity_reruns"]
+        assert sorted(tk.must_query(sql).rows) == _host_rows(tk, sql)
+        assert _status(tk)["capacity_reruns"] - before == want_reruns
+
+    run(1)                      # 64 slots, then 512
+    run(0)
+    # 100 new groups inside the key's range: the packing, and so the
+    # store's key, stay as they were
+    tk.must_exec("insert into grow values " + ",".join(
+        f"({j * SPREAD + 1}, {j})" for j in range(100)))
+    rows, tr, grew = _traced_query(tk, sql)
+    assert sorted(rows) == _host_rows(tk, sql) and grew == 1
+    (ev,) = _events(tr["root"], "fragment.rerun")
+    assert ev["tags"] == {"shape": "agg", "capacity": 1024,
+                          "groups": N_GROUPS + 100}
+    run(0)
+
+
+def test_a_dense_aggregate_starts_at_its_bucket_count(tk, monkeypatch):
+    """Two coded keys without statistics estimate 64 x 64 = 4,096 groups;
+    their packing holds 8 x 4 = 32 buckets, which the dense arm cannot
+    outgrow: the FIRST execution runs at 32 slots and fetches its groups
+    in one round trip, where 4,096 slots of these aggregates would take
+    two (past the small fetch's 256 KiB)."""
+    rows = 4200
+    tk.must_exec("create table coded (rf varchar(1), ls varchar(1), "
+                 "a bigint, b bigint, c bigint, d bigint)")
+    tk.must_exec("insert into coded values " + ",".join(
+        f"('{'ANR'[i % 3]}', '{'FO'[i % 2]}', {i}, {i % 7}, {i % 11}, "
+        f"{i % 13})" for i in range(rows)))
+    sql = ("select rf, ls, sum(a), sum(b), sum(c), sum(d), avg(a), avg(b), "
+           "avg(c), count(*) from coded group by rf, ls")
+    caps = _agg_capacities(monkeypatch)
+    got, tr, grew = _traced_query(tk, sql)
+    assert caps == [32] and grew == 0
+    assert not _events(tr["root"], "fragment.rerun")
+    assert len(find_spans(tr["root"], "fetch.d2h")) == 1
+    assert sorted(got) == _host_rows(tk, sql) and len(got) == 6
+
+
+def test_a_global_aggregate_keeps_its_sixteen_slots(tk, monkeypatch):
+    caps = _agg_capacities(monkeypatch)
+    (row,) = tk.must_query(
+        "select count(*), sum(v) from wide where v >= 10").rows
+    assert row == ("990", str(sum(range(10, 2 * N_GROUPS))))
+    assert caps == [16]
+
+
+def test_the_sort_arm_counts_where_its_start_came_from(tk):
+    """`agg_start_estimated` on a sort-arm aggregate's first execution,
+    `agg_start_learned` on the next; a dense aggregate counts in
+    neither."""
+    dj._CAP_STORE.clear()
+    keys = ("agg_start_learned", "agg_start_estimated", "agg_dense")
+
+    def grew(sql):
+        before = _status(tk)
+        tk.must_query(sql)
+        after = _status(tk)
+        return [after[k] - before[k] for k in keys]
+
+    sql = "select g, max(v) from wide group by g"
+    assert grew(sql) == [0, 1, 0]
+    assert grew(sql) == [1, 0, 0]
+    assert grew("select count(*), sum(v) from wide") == [0, 0, 1]
 
 
 def test_a_warm_join_fragment_reruns_nothing(tk):

@@ -295,6 +295,10 @@ _PIPE_STATS = {"hits": 0, "misses": 0, "traces": 0, "compiles": 0,
                # side from the capacity and the input length) —
                # note_agg_spans
                "agg_spans_one_pass": 0,
+               # sort-arm scan aggregates dispatched whose first capacity
+               # came from the group count their last kept run stored /
+               # from _estimate_groups (device_agg's _scan_agg_start)
+               "agg_start_learned": 0, "agg_start_estimated": 0,
                # never bumped: benchmark/layer_metrics/agg.dense_share.py
                # reads the key
                "agg_scatter": 0,
@@ -977,6 +981,45 @@ def agg_batch_key(plan, conds, n_rows: int, ctx=None):
         return None
 
 
+#: learned exact sizes per fragment, through `learned` / `learn` alone:
+#: the join fragments' (device_join) (sig, join_pos) → last observed
+#: match total, (sig, ("live", pos)) → a compaction point's live count,
+#: (sig, "agg") → group count ("groups": the pages' merged one), the
+#: mesh's converged "caps", "xcaps" and aggregate capacity; and the
+#: sort-arm scan aggregate's (sig, "scan_agg") → group count of its kept
+#: run (device_agg). In-process, LRU-bounded like _PIPE_CACHE (sig
+#: strings embed data-dependent packs, so stale data versions must age
+#: out); repeat fragments (bench steady state, plan-cache hits) start
+#: tight and never pay a discovery recompile again.
+_CAP_STORE: "collections.OrderedDict" = collections.OrderedDict()
+_CAP_STORE_MAX = 4096
+
+
+def learned(sig, what):
+    """What the last run of fragment `sig` stored under `what`, or None."""
+    return _CAP_STORE.get((sig, what))
+
+
+def learn(sig, what, value):
+    """Store `value` under `what` for the next run of fragment `sig`."""
+    key = (sig, what)
+    _CAP_STORE[key] = value
+    _CAP_STORE.move_to_end(key)
+    if len(_CAP_STORE) > _CAP_STORE_MAX:
+        _CAP_STORE.popitem(last=False)
+
+
+def learned_sigs() -> set:
+    """The signatures the store holds something for: the shapes traffic
+    converged on."""
+    # snapshot: concurrent queries mutate the store un-locked, and a
+    # resize mid-iteration raises
+    try:
+        return {k[0] for k in list(_CAP_STORE)}
+    except RuntimeError:
+        return set()
+
+
 def device_agg(plan, chunk: Chunk, conds, ctx=None) -> Chunk:
     """Fused filter+group+aggregate on device. Raises DeviceUnsupported to
     trigger host fallback."""
@@ -1011,8 +1054,8 @@ def device_agg(plan, chunk: Chunk, conds, ctx=None) -> Chunk:
      slots) = _plan_agg(plan, dcols)
     n_keys = max(len(key_fns), 1)
     sig_exprs, dict_refs = _agg_sig(plan, conds, dcols)
-    est = _estimate_groups(plan, n, ctx)
-    capacity = dev.next_pow2(min(n, max(est, 16)))
+    capacity, cap_sig = _scan_agg_start(plan, n, sig_exprs, key_pack,
+                                        agg_ops, ctx)
     note_agg_arm(key_pack, agg_ops)
     _bump("scan_resident")
     while True:
@@ -1032,12 +1075,35 @@ def device_agg(plan, chunk: Chunk, conds, ctx=None) -> Chunk:
             break
         capacity = dev.next_pow2(ng)
         note_rerun("agg", capacity, ng)
+    if cap_sig is not None:
+        learn(cap_sig, "scan_agg", ng)
     if ng == 0 and not plan.group_exprs:
         # global aggregate over zero kept rows still yields ONE row
         # (count=0, sum/min/max NULL) — host path has the special case
         raise DeviceUnsupported("empty global aggregate")
     body = f.body()
     return _assemble_agg(plan, key_meta, slots, dcols, body, f.out_rows)
+
+
+def _scan_agg_start(plan, n, sig_exprs, key_pack, agg_ops, ctx=None):
+    """(first capacity, the store's key or None) of `device_agg`'s loop:
+    the tightest capacity the fragment knows before it runs, never past
+    its `n` rows.  The dense arm compacts at most its bucket count, so it
+    starts there and never overflows (nothing to learn); the sort arm
+    starts at the group count its last kept run stored under the
+    pipeline's key less the capacity, else at `_estimate_groups`.  Counts
+    the sort arm's start in ``agg_start_learned`` / ``agg_start_estimated``
+    (the benchmark's ``agg.learned_start_share``)."""
+    if dev.agg_arm(key_pack, agg_ops) == "dense":
+        first, cap_sig = 1 << sum(b for b, _o in key_pack), None
+    else:
+        cap_sig = (sig_exprs, key_pack, tuple(agg_ops))
+        first = learned(cap_sig, "scan_agg")
+        _bump("agg_start_estimated" if first is None
+              else "agg_start_learned")
+        if first is None:
+            first = _estimate_groups(plan, n, ctx)
+    return dev.next_pow2(min(n, max(first, 16))), cap_sig
 
 
 def _upload_mark(sp):

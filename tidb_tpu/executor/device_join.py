@@ -65,9 +65,13 @@ from ..expression import phys_kind, K_FLOAT, K_STR
 from ..expression.core import Column as ExprColumn, ScalarFunc as _SF
 from ..ops import device as dev
 from ..ops.device import DeviceUnsupported
-from .device_exec import (
-    _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
+# the learned-capacity store lives in device_exec, whose scan aggregate
+# reads it too; _CAP_STORE and learned_sigs are re-exported for the users
+# that reach them here
+from .device_exec import (  # noqa: F401
+    _CAP_STORE, _assemble_agg, _count_trace, _estimate_groups, _expr_sig,
     _plan_agg, _timed_jit, acquire_pipeline, exists_expands, join_expands,
+    learn, learned, learned_sigs,
     note_agg_arm, note_agg_spans, note_join_chain, note_join_compactions,
     note_join_derived, note_join_expansion, note_join_gathers,
     note_join_layouts, note_join_probe, note_join_residual, note_rerun,
@@ -677,43 +681,6 @@ def _strategy_sig(jn):
         return f"S{jn.pos}:-"
     kind, side, idx = st
     return f"S{jn.pos}:{kind}/{side}/{idx.sig()}"
-
-
-#: learned exact sizes per fragment, through `learned` / `learn` alone:
-#: (sig, join_pos) → last observed match total, (sig, ("live", pos)) → a
-#: compaction point's live count, (sig, "agg") → group count ("groups":
-#: the pages' merged one); the mesh's: converged "caps", "xcaps" and
-#: aggregate capacity. In-process, LRU-bounded like _PIPE_CACHE (sig
-#: strings embed data-dependent packs, so stale data versions must age
-#: out); repeat fragments (bench steady state, plan-cache hits) start
-#: tight and never pay a discovery recompile again.
-_CAP_STORE: "collections.OrderedDict" = collections.OrderedDict()
-_CAP_STORE_MAX = 4096
-
-
-def learned(sig, what):
-    """What the last run of fragment `sig` stored under `what`, or None."""
-    return _CAP_STORE.get((sig, what))
-
-
-def learn(sig, what, value):
-    """Store `value` under `what` for the next run of fragment `sig`."""
-    key = (sig, what)
-    _CAP_STORE[key] = value
-    _CAP_STORE.move_to_end(key)
-    if len(_CAP_STORE) > _CAP_STORE_MAX:
-        _CAP_STORE.popitem(last=False)
-
-
-def learned_sigs() -> set:
-    """The signatures the store holds something for: the shapes traffic
-    converged on."""
-    # snapshot: concurrent queries mutate the store un-locked, and a
-    # resize mid-iteration raises
-    try:
-        return {k[0] for k in list(_CAP_STORE)}
-    except RuntimeError:
-        return set()
 
 
 def _null_extend(nulls, bidx_map, hit):
